@@ -244,9 +244,7 @@ def bench_device_write(writers: int, combine: bool, vocab: int = 8192,
     from brpc_tpu import obs, rpc
     from brpc_tpu.ps_remote import DevicePsShardServer
 
-    fake = os.path.join(ROOT, "cpp", "build", "libbrt_fake_pjrt.so")
-    plugin = os.environ.get("BRT_PJRT_PLUGIN") or fake
-    dev = rpc.DeviceClient(plugin if os.path.exists(plugin) else None)
+    dev = rpc.DeviceClient(rpc.fake_pjrt_plugin_path())
     obs.set_enabled(True)
     wasted0 = obs.counter("ps_device_wasted_launches").get_value()
     applies0 = obs.counter("ps_combined_applies").get_value()

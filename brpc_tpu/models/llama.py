@@ -32,7 +32,7 @@ Params = Dict[str, Any]
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
-    vocab_size: int = 32000
+    vocab_size: int = 128256
     hidden: int = 4096
     n_layers: int = 32
     n_heads: int = 32
@@ -59,7 +59,10 @@ class LlamaConfig:
 
     @staticmethod
     def llama3_8b() -> "LlamaConfig":
-        return LlamaConfig()  # defaults are Llama-3-8B
+        """The defaults are Llama-3-8B as published (meta-llama/Meta-Llama-3-8B
+        config.json: vocab 128,256, hidden 4,096, 32 layers, 32 heads over 8
+        KV heads of 128, intermediate 14,336, rope theta 500,000)."""
+        return LlamaConfig()
 
 
 def _dense_init(key, shape, dtype, fan_in):

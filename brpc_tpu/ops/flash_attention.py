@@ -7,7 +7,11 @@ programs stop at the diagonal tile, so the wasted-FLOPs triangle is skipped
 at tile granularity (guide: /opt/skills/guides/pallas_guide.md).
 
 GQA layout matches brpc_tpu.models.llama: q [B, T, Hq, D], k/v
-[B, T, Hkv, D]; the kv head for q head h is h // (Hq // Hkv).
+[B, T, Hkv, D]; the kv head for q head h is h // (Hq // Hkv). Inside, the
+kernel works head-major ([B, H, T, D]): Mosaic wants the last two block
+dimensions to be (a multiple of 8, a multiple of 128) or the whole array
+dimension, and a one-head block of the [B, T, H, D] layout has 1 against H
+there. The wrapper pays two transposes for it.
 
 ``flash_attention(..., interpret=True)`` runs the same kernel through the
 pallas interpreter (CPU tests); on TPU leave it False.
@@ -25,7 +29,7 @@ from jax.experimental import pallas as pl
 def _kernel(q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: int,
             seq_len: int, causal: bool, scale: float):
     qi = pl.program_id(2)
-    q = q_ref[0, :, 0, :].astype(jnp.float32) * scale  # [BQ, D]
+    q = q_ref[0, 0].astype(jnp.float32) * scale  # [BQ, D]
     bq, d = q.shape
 
     row = qi * block_q + jax.lax.broadcasted_iota(
@@ -41,8 +45,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: int,
 
     def body(kj, carry):
         m, l, acc = carry
-        k = k_ref[0, pl.ds(kj * block_k, block_k), 0, :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kj * block_k, block_k), 0, :].astype(jnp.float32)
+        k = k_ref[0, 0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
+        v = v_ref[0, 0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # [BQ, BK]
         if causal:
             col = kj * block_k + jax.lax.broadcasted_iota(
@@ -61,7 +65,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: int,
     acc0 = jnp.zeros((bq, d), jnp.float32)
     _, l, acc = jax.lax.fori_loop(0, n_kv, body, (m0, l0, acc0))
     out = acc / jnp.maximum(l, 1e-20)
-    o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+    o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -90,21 +94,22 @@ def flash_attention(
     scale = d ** -0.5
 
     grid = (b, hq, t // block_q)
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))  # [B, H, T, D]
     out = pl.pallas_call(
         functools.partial(_kernel, block_q=block_q, block_k=block_k,
                           seq_len=t, causal=causal, scale=scale),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, d),
-                         lambda bi, h, qi: (bi, qi, h, 0)),
-            pl.BlockSpec((1, t, 1, d),
-                         lambda bi, h, qi: (bi, 0, h // group, 0)),
-            pl.BlockSpec((1, t, 1, d),
-                         lambda bi, h, qi: (bi, 0, h // group, 0)),
+            pl.BlockSpec((1, 1, block_q, d),
+                         lambda bi, h, qi: (bi, h, qi, 0)),
+            pl.BlockSpec((1, 1, t, d),
+                         lambda bi, h, qi: (bi, h // group, 0, 0)),
+            pl.BlockSpec((1, 1, t, d),
+                         lambda bi, h, qi: (bi, h // group, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, d),
-                               lambda bi, h, qi: (bi, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, t, hq, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, d),
+                               lambda bi, h, qi: (bi, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, hq, t, d), q.dtype),
         interpret=interpret,
     )(q, k, v)
-    return out.reshape(b, t, hq * d)
+    return out.transpose(0, 2, 1, 3).reshape(b, t, hq * d)

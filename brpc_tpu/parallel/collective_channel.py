@@ -24,9 +24,8 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from brpc_tpu import obs
-from brpc_tpu._compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 

@@ -17,8 +17,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from brpc_tpu._compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

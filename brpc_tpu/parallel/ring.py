@@ -20,8 +20,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from brpc_tpu._compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -33,7 +32,12 @@ def _block_attend(q, k, v, mask):
     d = q.shape[-1]
     scores = jnp.einsum("bthgd,bshd->bhgts", q, k).astype(jnp.float32)
     scores = scores * (d ** -0.5) + mask[None, None, None]
-    m = jnp.max(scores, axis=-1)                        # [B,H,G,Tq]
+    # The row max is only the softmax's shift: the result does not depend on
+    # it, so its total derivative is exactly zero and it is held constant
+    # (as flash attention's backward does). Left in, the max's VJP into the
+    # bf16 scores made every dq/dk NaN on a v5e — finite on the CPU — in the
+    # sp=2 ring and in the model's backward even at sp=1 (PR 21 chip runs).
+    m = lax.stop_gradient(jnp.max(scores, axis=-1))     # [B,H,G,Tq]
     # guard fully-masked rows (exp(-inf - -inf))
     m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
     p = jnp.exp(scores - m_safe[..., None])             # [B,H,G,Tq,Ts]

@@ -25,8 +25,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from brpc_tpu._compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 

@@ -2925,6 +2925,20 @@ class DevicePsShardServer(PsShardServer):
         if release:
             self.dev.release(release)
 
+    def resident_device(self) -> Optional[int]:
+        """Addressable index of the device PJRT says holds the live
+        table generation; None while serving from the host mirror.
+        After an apply the live generation IS a scatter launch's
+        output, so this is also where that launch ran."""
+        pinned = self._pin_current()
+        if pinned is None:
+            return None
+        key, table_h = pinned
+        try:
+            return self.dev.buffer_device(table_h)
+        finally:
+            self._unpin(key)
+
     def _stage_up_locked(self) -> None:
         """Stage the host mirror into HBM and serve from it.  Caller
         holds the table WRITE lock.  Already serving: the fresh host
@@ -3042,7 +3056,8 @@ class DevicePsShardServer(PsShardServer):
             if exe is None:
                 mlir = self.dev.mlir("gather_rows", self.rows_per,
                                      self.dim, k)
-                exe = self._gather[k] = self.dev.compile(mlir)
+                exe = self._gather[k] = self.dev.compile(
+                    mlir, first_device=self.device_index)
             return exe
 
     def _scatter_exe(self, k: int):
@@ -3051,7 +3066,8 @@ class DevicePsShardServer(PsShardServer):
             if exe is None:
                 mlir = self.dev.mlir("scatter_sub", self.rows_per,
                                      self.dim, k)
-                exe = self._scatter[k] = self.dev.compile(mlir)
+                exe = self._scatter[k] = self.dev.compile(
+                    mlir, first_device=self.device_index)
             return exe
 
     @staticmethod

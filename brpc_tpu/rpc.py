@@ -9,7 +9,9 @@ wait-free socket transport and cluster layer. Payloads are bytes; structure
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
+import shutil
 import subprocess
 import sys
 import threading
@@ -56,9 +58,8 @@ _load_mu = _race.checked_lock("rpc.load")
 
 class NativeCoreUnavailable(RuntimeError):
     """The native core (cpp/ → libbrpc_tpu_c.so) could not be built or
-    loaded — usually a missing cmake/ninja toolchain, a failed build, or
-    an unloadable .so.  Callers that can degrade (tests, pure-Python
-    tiers) catch this; ``native_core_available()`` probes without
+    loaded — a missing cmake/ninja toolchain, a failed build, or an
+    unloadable .so.  ``native_core_available()`` probes without
     raising."""
 
 
@@ -78,16 +79,48 @@ def native_core_available() -> bool:
 
 
 def _load_inner():
-    so = os.path.join(_build_dir(), "libbrpc_tpu_c.so")
-    if not os.path.exists(so):
-        build = _build_dir()
+    """Builds, then loads, the native core and the fake PJRT plug-in the
+    device-tier tests name.  The incremental build always runs (a no-op
+    when fresh, ~0.1 s) instead of trusting whatever ``.so`` is lying
+    there: a stale one silently misses newer ABI entries.  cmake re-runs
+    too because the build globs its sources."""
+    build = _build_dir()
+    src = os.path.dirname(build)
+    # Concurrent first touches (pytest + bench children) share one tree:
+    # serialize them on the source directory.
+    lock = os.open(src, os.O_RDONLY)
+    try:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        try:
+            with open(os.path.join(build, "CMakeCache.txt"),
+                      encoding="utf-8", errors="replace") as f:
+                home = next((ln.partition("=")[2].strip() for ln in f
+                             if ln.startswith("CMAKE_HOME_DIRECTORY:")), "")
+        except FileNotFoundError:
+            home = src
+        if os.path.realpath(home) != os.path.realpath(src):
+            # A build tree copied from another checkout: its ninja would
+            # re-run cmake against THAT path and rebuild it instead.
+            shutil.rmtree(build)
         os.makedirs(build, exist_ok=True)
         subprocess.run(["cmake", "-G", "Ninja",
-                        "-DCMAKE_BUILD_TYPE=Release", ".."],
+                        "-DCMAKE_BUILD_TYPE=Release", src],
                        cwd=build, check=True, capture_output=True)
-        subprocess.run(["ninja", "brpc_tpu_c"], cwd=build, check=True,
-                       capture_output=True)
-    return ctypes.CDLL(so)
+        subprocess.run(["ninja", "brpc_tpu_c", "brt_fake_pjrt"], cwd=build,
+                       check=True, capture_output=True)
+    finally:
+        os.close(lock)
+    return ctypes.CDLL(os.path.join(build, "libbrpc_tpu_c.so"))
+
+
+def fake_pjrt_plugin_path() -> str:
+    """The in-repo fake N-device PJRT plug-in (cpp/device/
+    fake_pjrt_plugin.cc), built alongside the core.  Tests and CPU dry
+    runs pass it to :class:`DeviceClient` explicitly: default discovery
+    loads libtpu, which without a chip retries for minutes before client
+    creation fails."""
+    _load()
+    return os.path.join(_build_dir(), "libbrt_fake_pjrt.so")
 
 
 def _load():
@@ -109,12 +142,12 @@ def _load_locked():
     try:
         lib = _load_inner()
     except FileNotFoundError as e:
-        _load_error = (f"native build toolchain missing ({e}); install "
-                       f"cmake+ninja or use a prebuilt "
-                       f"{_build_dir()}/libbrpc_tpu_c.so")
+        _load_error = (f"native build toolchain missing ({e}); the core "
+                       f"is built from source with cmake + ninja")
         raise NativeCoreUnavailable(_load_error) from e
     except subprocess.CalledProcessError as e:
-        tail = (e.stderr or b"").decode(errors="replace")[-2000:]
+        tail = ((e.stdout or b"") + (e.stderr or b"")).decode(
+            errors="replace")[-2000:]
         _load_error = f"native build failed ({e.cmd}):\n{tail}"
         raise NativeCoreUnavailable(_load_error) from e
     except OSError as e:
@@ -303,6 +336,15 @@ def _load_locked():
         ctypes.c_char_p, ctypes.c_char_p, ctypes.c_size_t]
     lib.brt_device_count.argtypes = [ctypes.c_void_p]
     lib.brt_device_count.restype = ctypes.c_int
+    lib.brt_device_platform_name.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_size_t]
+    lib.brt_device_platform_name.restype = None
+    lib.brt_device_kind.argtypes = [
+        ctypes.c_void_p, ctypes.c_int, ctypes.c_char_p, ctypes.c_size_t]
+    lib.brt_device_kind.restype = ctypes.c_int
+    lib.brt_device_buffer_device.argtypes = [ctypes.c_void_p,
+                                             ctypes.c_uint64]
+    lib.brt_device_buffer_device.restype = ctypes.c_int
     lib.brt_device_stage.restype = ctypes.c_uint64
     lib.brt_device_stage.argtypes = [
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t, ctypes.c_int,
@@ -325,8 +367,8 @@ def _load_locked():
         ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64]
     lib.brt_device_compile.restype = ctypes.c_void_p
     lib.brt_device_compile.argtypes = [
-        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int, ctypes.c_char_p,
-        ctypes.c_size_t]
+        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int, ctypes.c_int,
+        ctypes.c_char_p, ctypes.c_size_t]
     lib.brt_device_executable_num_outputs.argtypes = [ctypes.c_void_p]
     lib.brt_device_executable_num_outputs.restype = ctypes.c_int
     lib.brt_device_execute.argtypes = [
@@ -1989,6 +2031,30 @@ class DeviceClient:
     def device_count(self) -> int:
         return self._lib.brt_device_count(self._ptr)
 
+    @property
+    def platform(self) -> str:
+        """PJRT's platform name: ``"tpu"`` on libtpu, ``"brt_fake"`` on
+        the in-repo test plug-in."""
+        buf = ctypes.create_string_buffer(128)
+        self._lib.brt_device_platform_name(self._ptr, buf, 128)
+        return buf.value.decode(errors="replace")
+
+    def device_kind(self, device_index: int = 0) -> str:
+        """PJRT's kind string for one addressable device (e.g. ``"TPU v5
+        lite"``)."""
+        buf = ctypes.create_string_buffer(128)
+        if self._lib.brt_device_kind(self._ptr, device_index, buf, 128):
+            raise ValueError(f"no addressable device {device_index}")
+        return buf.value.decode(errors="replace")
+
+    def buffer_device(self, handle: int) -> int:
+        """Addressable index of the device PJRT says holds the buffer
+        behind ``handle`` (asked of the plug-in, not remembered)."""
+        index = self._lib.brt_device_buffer_device(self._ptr, handle)
+        if index < 0:
+            raise RpcError(5002, f"no device for buffer handle {handle}")
+        return index
+
     def stage(self, data, device_index: int = 0, dtype: str = "u8",
               dims=None) -> int:
         """DMAs bytes (or a numpy array) into device memory; returns a
@@ -2042,11 +2108,14 @@ class DeviceClient:
         finally:
             self._lib.brt_free(p)
 
-    def compile(self, mlir_text: str,
-                num_replicas: int = 1) -> DeviceExecutable:
+    def compile(self, mlir_text: str, num_replicas: int = 1,
+                first_device: int = 0) -> DeviceExecutable:
+        """Replica r is bound to addressable device ``first_device + r``:
+        its arguments must be staged there and its results land there."""
         errbuf = ctypes.create_string_buffer(1024)
         ptr = self._lib.brt_device_compile(
-            self._ptr, mlir_text.encode(), num_replicas, errbuf, 1024)
+            self._ptr, mlir_text.encode(), num_replicas, first_device,
+            errbuf, 1024)
         if not ptr:
             raise RpcError(5003, errbuf.value.decode(errors="replace"))
         return DeviceExecutable(self._lib, ptr)

@@ -549,6 +549,32 @@ int brt_device_count(void* client) {
   return static_cast<brt::PjrtClient*>(client)->addressable_device_count();
 }
 
+void brt_device_platform_name(void* client, char* buf, size_t buf_len) {
+  if (buf == nullptr || buf_len == 0) return;
+  snprintf(buf, buf_len, "%s",
+           static_cast<brt::PjrtClient*>(client)->platform_name().c_str());
+}
+
+int brt_device_kind(void* client, int device_index, char* buf,
+                    size_t buf_len) {
+  auto* c = static_cast<brt::PjrtClient*>(client);
+  if (device_index < 0 || device_index >= c->addressable_device_count()) {
+    return EINVAL;
+  }
+  if (buf && buf_len) {
+    snprintf(buf, buf_len, "%s", c->device_kind(device_index).c_str());
+  }
+  return 0;
+}
+
+int brt_device_buffer_device(void* client, uint64_t handle) {
+  PJRT_Buffer* buf = brt::DeviceBufferRegistry::Pin(handle);
+  if (buf == nullptr) return -1;
+  const int index = static_cast<brt::PjrtClient*>(client)->DeviceIndexOf(buf);
+  brt::DeviceBufferRegistry::Unpin(handle);
+  return index;
+}
+
 uint64_t brt_device_stage(void* client, const void* data, size_t len,
                           int device_index, char* errbuf, size_t errbuf_len) {
   // Same single-contiguous-region discipline as brt_device_stage_shaped
@@ -659,10 +685,11 @@ char* brt_mlir_module(const char* kind, int64_t p0, int64_t p1, int64_t p2) {
 }
 
 void* brt_device_compile(void* client, const char* mlir, int num_replicas,
-                         char* errbuf, size_t errbuf_len) {
+                         int first_device, char* errbuf, size_t errbuf_len) {
   std::string err;
   auto exe = brt::PjrtExecutable::Compile(
-      static_cast<brt::PjrtClient*>(client), mlir, num_replicas, &err);
+      static_cast<brt::PjrtClient*>(client), mlir, num_replicas, &err,
+      first_device);
   if (exe == nullptr) {
     if (errbuf && errbuf_len) snprintf(errbuf, errbuf_len, "%s", err.c_str());
     return nullptr;

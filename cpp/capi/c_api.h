@@ -369,10 +369,20 @@ void brt_init(int fiber_workers);
 
 // ---- device (native PJRT staging — the RDMA-analog tier) ----
 // Creates a PJRT client over the given plugin (NULL/"" = $BRT_PJRT_PLUGIN
-// or the platform default). NULL on failure; errbuf holds the reason.
+// or the installed libtpu). NULL on failure; errbuf holds the reason.
 void* brt_device_client_new(const char* plugin_path, char* errbuf,
                             size_t errbuf_len);
 int brt_device_count(void* client);
+// What PJRT reports the client runs on: the platform name ("tpu" for
+// libtpu) and the kind of addressable device device_index (e.g. "TPU v5
+// lite"), NUL-terminated into buf. brt_device_kind returns 0, or EINVAL
+// for a bad index.
+void brt_device_platform_name(void* client, char* buf, size_t buf_len);
+int brt_device_kind(void* client, int device_index, char* buf,
+                    size_t buf_len);
+// Addressable index of the device PJRT says holds the buffer behind
+// handle; -1 if the handle is stale or the device unknown.
+int brt_device_buffer_device(void* client, uint64_t handle);
 // DMAs bytes to device memory on device_index; returns a nonzero 64-bit
 // buffer handle (the lkey analog carried in IOBuf meta), 0 on failure.
 uint64_t brt_device_stage(void* client, const void* data, size_t len,
@@ -398,9 +408,11 @@ uint64_t brt_device_stage_shaped(void* client, const void* data, size_t len,
 // p1=replicas) or "gather_rows"|"scatter_sub" (p0=rows, p1=dim, p2=k).
 // malloc'd string (free with brt_free); NULL on unknown kind.
 char* brt_mlir_module(const char* kind, int64_t p0, int64_t p1, int64_t p2);
-// Compiles textual StableHLO for num_replicas. NULL on failure.
+// Compiles textual StableHLO for num_replicas; replica r is bound to
+// addressable device first_device + r (its arguments must live there).
+// NULL on failure.
 void* brt_device_compile(void* client, const char* mlir, int num_replicas,
-                         char* errbuf, size_t errbuf_len);
+                         int first_device, char* errbuf, size_t errbuf_len);
 int brt_device_executable_num_outputs(void* exe);
 // Launches across all replicas. args is row-major [nreplicas][nargs]
 // buffer handles; outs receives [nreplicas][num_outputs] fresh handles

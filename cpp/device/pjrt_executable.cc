@@ -145,19 +145,39 @@ std::string MlirScatterSubF32(size_t rows, size_t dim, size_t k) {
          "  }\n}\n";
 }
 
-std::string EncodeCompileOptions(int num_replicas, int num_partitions) {
+std::string EncodeCompileOptions(const std::vector<int>& replica_device_ids) {
+  // Field numbers from xla/pjrt/proto/compile_options.proto and
+  // xla/xla_data.proto (cited by the PJRT C API header at
+  // PJRT_Client_Compile_Args). Everything absent takes plugin defaults.
+  //
+  // xla.DeviceAssignmentProto: replica_count=1, computation_count=2,
+  // computation_devices=3 { replica_device_ids=1 }. One computation
+  // (partition); replica r runs on replica_device_ids[r].
+  std::string comp;
+  for (int id : replica_device_ids) {
+    AppendTag(&comp, 1, 0);
+    AppendVarint(&comp, uint64_t(id));
+  }
+  std::string assign;
+  AppendTag(&assign, 1, 0);
+  AppendVarint(&assign, replica_device_ids.size());
+  AppendTag(&assign, 2, 0);
+  AppendVarint(&assign, 1);
+  AppendTag(&assign, 3, 2);
+  AppendVarint(&assign, comp.size());
+  assign += comp;
   // xla.ExecutableBuildOptionsProto: device_ordinal=1, num_replicas=4,
-  // num_partitions=5 (field numbers from
-  // tensorflow/compiler/xla/pjrt/compile_options.proto — cited by the PJRT
-  // C API header at PJRT_Client_Compile_Args). Everything absent takes
-  // plugin defaults.
+  // num_partitions=5, device_assignment=9.
   std::string build;
   AppendTag(&build, 1, 0);                    // device_ordinal = -1
-  AppendVarint(&build, uint64_t(int64_t(-1)));  // ("unset": don't pin)
-  AppendTag(&build, 4, 0);
-  AppendVarint(&build, uint64_t(num_replicas));
+  AppendVarint(&build, uint64_t(int64_t(-1)));  // ("unset": the assignment
+  AppendTag(&build, 4, 0);                    //  below places the replicas)
+  AppendVarint(&build, replica_device_ids.size());
   AppendTag(&build, 5, 0);
-  AppendVarint(&build, uint64_t(num_partitions));
+  AppendVarint(&build, 1);
+  AppendTag(&build, 9, 2);
+  AppendVarint(&build, assign.size());
+  build += assign;
   // xla.CompileOptionsProto: executable_build_options = field 3.
   std::string opts;
   AppendTag(&opts, 3, 2);
@@ -168,9 +188,23 @@ std::string EncodeCompileOptions(int num_replicas, int num_partitions) {
 
 std::unique_ptr<PjrtExecutable> PjrtExecutable::Compile(
     PjrtClient* client, const std::string& mlir_text, int num_replicas,
-    std::string* error) {
+    std::string* error, int first_device) {
   const PjrtApi* api = client->api();
-  const std::string copts = EncodeCompileOptions(num_replicas, 1);
+  if (num_replicas < 1 || first_device < 0 ||
+      first_device + num_replicas > client->addressable_device_count()) {
+    if (error) *error = "replicas do not fit the addressable devices";
+    return nullptr;
+  }
+  std::vector<int> device_ids;
+  for (int r = 0; r < num_replicas; ++r) {
+    const int id = client->device_id(first_device + r);
+    if (id < 0) {
+      if (error) *error = "device has no PJRT id";
+      return nullptr;
+    }
+    device_ids.push_back(id);
+  }
+  const std::string copts = EncodeCompileOptions(device_ids);
 
   auto prog = BRT_PJRT_ARGS(PJRT_Program);
   prog.code = const_cast<char*>(mlir_text.data());
@@ -315,10 +349,12 @@ int PjrtExecutable::Execute(const std::vector<std::vector<uint64_t>>& args,
   outs->assign(ndev, std::vector<uint64_t>(nouts, 0));
   for (size_t d = 0; d < ndev; ++d) {
     for (size_t o = 0; o < nouts; ++o) {
-      // All Mlir* builder programs produce f32 results on replica d's
-      // device — recorded so shipped handles can be placement-checked.
+      // All Mlir* builder programs produce f32 results; the device is
+      // where PJRT says the result lives — recorded so shipped handles
+      // can be placement-checked.
       (*outs)[d][o] = DeviceBufferRegistry::Register(
-          api, out_bufs[d][o], int(d), int(PjrtClient::DType::kF32));
+          api, out_bufs[d][o], client_->DeviceIndexOf(out_bufs[d][o]),
+          int(PjrtClient::DType::kF32));
     }
   }
   return 0;

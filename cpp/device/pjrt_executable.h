@@ -44,19 +44,22 @@ std::string MlirGatherRowsF32(size_t rows, size_t dim, size_t k);
 // lr-scaled grads scattered-subtracted at ids (SGD embedding update).
 std::string MlirScatterSubF32(size_t rows, size_t dim, size_t k);
 
-// Hand-rolled serialized xla.CompileOptionsProto carrying num_replicas /
-// num_partitions (the only fields the fabric needs; everything else takes
-// plugin defaults).
-std::string EncodeCompileOptions(int num_replicas, int num_partitions);
+// Hand-rolled serialized xla.CompileOptionsProto: one partition,
+// replica_device_ids.size() replicas, replica r assigned to the device with
+// PJRT id replica_device_ids[r] (the only fields the fabric needs;
+// everything else takes plugin defaults).
+std::string EncodeCompileOptions(const std::vector<int>& replica_device_ids);
 
 class PjrtExecutable {
  public:
-  // Compiles textual StableHLO for `num_replicas` replicas (replica i runs
-  // on client->addressable_device(i), the default device assignment).
+  // Compiles textual StableHLO for `num_replicas` replicas; replica r is
+  // bound to client->addressable_device(first_device + r), so its
+  // arguments must live there and its results land there.
   static std::unique_ptr<PjrtExecutable> Compile(PjrtClient* client,
                                                  const std::string& mlir_text,
                                                  int num_replicas,
-                                                 std::string* error);
+                                                 std::string* error,
+                                                 int first_device = 0);
   ~PjrtExecutable();
   PjrtExecutable(const PjrtExecutable&) = delete;
   PjrtExecutable& operator=(const PjrtExecutable&) = delete;

@@ -311,7 +311,7 @@ def test_impure_jit_function_flagged(tmp_path):
 def test_shard_map_lock_flagged(tmp_path):
     fs = _lint_src(tmp_path, """\
         from functools import partial
-        from brpc_tpu._compat import shard_map
+        from jax import shard_map
 
         class C:
             def op(self, x):

@@ -69,17 +69,6 @@ def test_sharded_train_step_matches_single_device():
 
 
 def test_dryrun_multichip():
-    # jax 0.4.x's GSPMD partitioner returns a wrong PRIMAL loss for this
-    # exact composition (3-axis dp*tp*sp mesh + value_and_grad; the plain
-    # forward agrees with the single-device reference, the value_and_grad
-    # one is off by ~2.7) — reproduced with dense attention and no
-    # shard_map anywhere, so it's the partitioner, not this repo's code.
-    # Fixed upstream by the jax 0.5+ partitioner rewrite.
-    if tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5):
-        import pytest
-
-        pytest.skip("jax<0.5 GSPMD miscompiles value_and_grad primal on "
-                    "3-axis meshes (verified against plain forward)")
     import __graft_entry__ as ge
 
     ge.dryrun_multichip(8)

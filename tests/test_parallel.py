@@ -113,10 +113,13 @@ def test_ring_attention_grads():
     def loss_dense(q, k, v):
         return jnp.sum(llama.attention(q, k, v) ** 2)
 
-    g_ring = jax.grad(loss_ring)(q, k, v)
-    g_dense = jax.grad(loss_dense)(q, k, v)
-    np.testing.assert_allclose(np.asarray(g_ring), np.asarray(g_dense),
-                               rtol=1e-3, atol=1e-3)
+    # dq, dk and dv: the running max is held constant in the backward pass,
+    # which must leave all three exact.
+    g_ring = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
+    g_dense = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    for got, want in zip(g_ring, g_dense):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-3, atol=1e-3)
 
 
 def test_pipeline_matches_sequential():

@@ -184,20 +184,7 @@ def test_cpu_shard_no_torn_rows_under_read_write_race():
 
 def _device_client():
     from brpc_tpu import rpc
-    plugin = os.environ.get("BRT_PJRT_PLUGIN")
-    if plugin is None:
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        for d in ("cpp/build", "build"):
-            fake = os.path.join(root, d, "libbrt_fake_pjrt.so")
-            if os.path.exists(fake):
-                plugin = fake
-                break
-        else:
-            pytest.skip("no PJRT plugin reachable (no fake built)")
-    try:
-        return rpc.DeviceClient(plugin)
-    except Exception as e:  # noqa: BLE001
-        pytest.skip(f"no native PJRT device: {e}")
+    return rpc.DeviceClient(rpc.fake_pjrt_plugin_path())
 
 
 @pytest.mark.needs_native
