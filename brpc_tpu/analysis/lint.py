@@ -215,7 +215,7 @@ _MUTATORS = {
 #: obs surface that hot paths must NOT touch directly (the no-op-able
 #: helpers counter/recorder/record_span/span/enabled stay allowed)
 _OBS_GUARDED = {
-    "Registry", "default_registry", "expose", "Adder", "Maxer", "Miner",
+    "Registry", "default_registry", "expose", "Adder", "Maxer",
     "LatencyRecorder", "Window", "PerSecond", "PassiveStatus",
 }
 _TRACERS = {"jit", "shard_map", "pjit"}

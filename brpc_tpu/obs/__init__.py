@@ -2,14 +2,15 @@
 
 Two layers, both pure Python/numpy (no native build required):
 
-- :mod:`brpc_tpu.obs.vars` — the metrics core: ``Adder``/``Maxer``/
-  ``Miner`` thread-local-agent reducers, ``PassiveStatus``, ``Window`` /
+- :mod:`brpc_tpu.obs.vars` — the metrics core: ``Adder``/``Maxer``
+  thread-local-agent reducers, ``PassiveStatus``, ``Window`` /
   ``PerSecond`` time-windowed views, ``LatencyRecorder`` (count/qps/avg +
   log-bucket percentiles), and a global ``Registry`` behind
   ``expose`` / ``dump_exposed`` (the /vars page).
-- :mod:`brpc_tpu.obs.rpcz` — per-call ``Span`` records in a bounded ring
-  (``dump_rpcz``, the /rpcz page) plus a ``span(...)`` context manager
-  for user code.
+- :mod:`brpc_tpu.obs.rpcz` — per-call ``Span`` records in a bounded
+  store (``dump_rpcz``, the /rpcz page), one span tree per traced
+  request (``begin`` / ``end`` at the layer boundaries of the request
+  path), plus a ``span(...)`` context manager for user code.
 
 The RPC/PS fabric (``brpc_tpu.rpc``, ``brpc_tpu.ps_remote``,
 ``brpc_tpu.parallel.collective_channel``) is instrumented through the
@@ -32,7 +33,6 @@ from brpc_tpu.obs.vars import (  # noqa: F401
     Adder,
     LatencyRecorder,
     Maxer,
-    Miner,
     PassiveStatus,
     PerSecond,
     Registry,
@@ -46,8 +46,10 @@ from brpc_tpu.obs.vars import (  # noqa: F401
 from brpc_tpu.obs.rpcz import (  # noqa: F401
     Span,
     SpanRing,
+    begin,
     default_ring,
     dump_rpcz,
+    end,
     format_rpcz,
     record_span,
     span,
@@ -55,12 +57,12 @@ from brpc_tpu.obs.rpcz import (  # noqa: F401
 
 __all__ = [
     # vars
-    "Adder", "Maxer", "Miner", "PassiveStatus", "Window", "PerSecond",
+    "Adder", "Maxer", "PassiveStatus", "Window", "PerSecond",
     "LatencyRecorder", "Registry", "Variable", "default_registry",
     "expose", "dump_exposed", "dump_exposed_dict",
     # rpcz
     "Span", "SpanRing", "default_ring", "dump_rpcz", "format_rpcz",
-    "record_span", "span",
+    "record_span", "span", "begin", "end",
     # gate + cached fabric helpers
     "enabled", "set_enabled", "recorder", "counter", "maxer", "gauge",
     "drop_var", "reset_fabric_vars",

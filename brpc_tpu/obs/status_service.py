@@ -9,8 +9,10 @@ Wire mapping (payloads are UTF-8/JSON, like the naming bridge):
 - ``vars_json``  req = optional filter string → rsp = JSON object
 - ``rpcz``       req = optional JSON query {limit, service, method, side,
                  errors_only} → rsp = JSON list of span dicts (newest
-                 first)
-- ``rpcz_text``  same query → rsp = one-line-per-span text
+                 first); a traced request carries its tree under
+                 ``children`` (and ``phases``, see ``obs.rpcz``)
+- ``rpcz_text``  same query → rsp = one-line-per-span text, a traced
+                 request's children indented under it
 - ``health``     empty req → ``ok`` (the plain liveness probe the
                  resilience tier's HealthProber and the reference's
                  health checker use); any non-empty req (convention:

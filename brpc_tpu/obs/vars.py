@@ -3,16 +3,19 @@
 The reference's bvar layer is write-mostly optimized: each writer thread
 mutates a thread-local agent with no synchronization, and readers combine
 agents on demand (``Reducer::get_value`` walks the agent list).  The same
-shape here: ``Adder``/``Maxer``/``Miner`` write to a per-thread cell (a
+shape here: ``Adder``/``Maxer`` write to a per-thread cell (a
 one-element list — plain attribute stores under the GIL, no lock on the
-hot path) and fold across cells on read.
+hot path) and fold across cells on read.  A thread's cell is found by its
+id, not in a ``threading.local``: a native thread that enters Python
+through a ctypes callback (every RPC handler) gets a fresh thread state,
+and with it fresh thread-locals, on every callback, while its id stays.
 
 Windowed views (``Window``, ``PerSecond``) mirror bvar's sampler: one
 sample per second of the underlying reducer, kept in a bounded deque.
 Instead of a sampler thread, samples are taken lazily on read against an
 injectable ``clock`` (tests drive a fake clock; production uses
 ``time.monotonic``).  For invertible ops (Adder) the window value is
-``newest - oldest``; for non-invertible ops (Maxer/Miner) each sample is
+``newest - oldest``; for non-invertible ops (Maxer) each sample is
 taken with get-and-reset and the window folds the per-second samples, the
 reference's ReducerSampler behaviour for ops without an inverse.
 
@@ -40,7 +43,7 @@ import numpy as np
 from brpc_tpu.analysis.race import checked_lock
 
 __all__ = [
-    "Variable", "Adder", "Maxer", "Miner", "PassiveStatus", "Window",
+    "Variable", "Adder", "Maxer", "PassiveStatus", "Window",
     "PerSecond", "LatencyRecorder", "Registry", "default_registry",
     "expose", "dump_exposed", "dump_exposed_dict",
 ]
@@ -75,24 +78,21 @@ class _TlsReducer(Variable):
     _INVERTIBLE = False
 
     def __init__(self):
-        self._local = threading.local()
         self._mu = checked_lock("obs.reducer")
-        self._cells: List[list] = []        # all threads' [value] cells
+        # thread id -> that thread's [value] cell (a later thread that
+        # gets a dead one's id goes on with its cell: the fold is the same)
+        self._cells: Dict[int, list] = {}
         self._retired = self._IDENTITY      # folded cells of reset() epochs
 
     def _cell(self) -> list:
-        cell = getattr(self._local, "cell", None)
-        if cell is None:
-            cell = [self._IDENTITY]
-            with self._mu:
-                self._cells.append(cell)
-            self._local.cell = cell
-        return cell
+        with self._mu:
+            return self._cells.setdefault(threading.get_ident(),
+                                          [self._IDENTITY])
 
     def get_value(self):
         with self._mu:
             acc = self._retired
-            for cell in self._cells:
+            for cell in self._cells.values():
                 acc = self._OP(acc, cell[0])
         return acc
 
@@ -100,7 +100,7 @@ class _TlsReducer(Variable):
         """Zero the reducer (best-effort under concurrent writers)."""
         with self._mu:
             self._retired = self._IDENTITY
-            for cell in self._cells:
+            for cell in self._cells.values():
                 cell[0] = self._IDENTITY
 
     def _take_window_sample(self):
@@ -115,7 +115,7 @@ class _TlsReducer(Variable):
         with self._mu:
             acc = self._retired
             self._retired = self._IDENTITY
-            for cell in self._cells:
+            for cell in self._cells.values():
                 acc = self._OP(acc, cell[0])
                 cell[0] = self._IDENTITY
         return acc
@@ -129,7 +129,7 @@ class Adder(_TlsReducer):
     _INVERTIBLE = True
 
     def add(self, v=1):
-        cell = getattr(self._local, "cell", None) or self._cell()
+        cell = self._cells.get(threading.get_ident()) or self._cell()
         cell[0] += v
 
     def __lshift__(self, v):
@@ -145,7 +145,7 @@ class Maxer(_TlsReducer):
     _INVERTIBLE = False
 
     def update(self, v):
-        cell = getattr(self._local, "cell", None) or self._cell()
+        cell = self._cells.get(threading.get_ident()) or self._cell()
         if v > cell[0]:
             cell[0] = v
 
@@ -154,25 +154,6 @@ class Maxer(_TlsReducer):
     def get_value(self):
         v = super().get_value()
         return 0 if v == float("-inf") else v
-
-
-class Miner(_TlsReducer):
-    """Running minimum (bvar::Miner)."""
-
-    _OP = staticmethod(min)
-    _IDENTITY = float("inf")
-    _INVERTIBLE = False
-
-    def update(self, v):
-        cell = getattr(self._local, "cell", None) or self._cell()
-        if v < cell[0]:
-            cell[0] = v
-
-    __lshift__ = update
-
-    def get_value(self):
-        v = super().get_value()
-        return 0 if v == float("inf") else v
 
 
 class PassiveStatus(Variable):
@@ -234,7 +215,7 @@ class Window(Variable):
             for s in itertools.islice(self._samples, 1, None):
                 acc = self._reducer._OP(acc, s)
             if acc == self._reducer._IDENTITY and not isinstance(acc, int):
-                return 0  # Maxer/Miner with no samples in window
+                return 0  # Maxer with no samples in window
             return acc
 
     def elapsed(self) -> float:

@@ -32,6 +32,7 @@ itself.  See the "Replication & failover" README section.
 from __future__ import annotations
 
 import collections
+import contextlib
 import itertools
 import json
 import struct
@@ -44,9 +45,25 @@ import numpy as np
 
 from brpc_tpu import obs, resilience, rpc, wire
 from brpc_tpu.analysis.race import checked_lock, checked_rwlock
+from brpc_tpu.obs import rpcz
 from brpc_tpu.limiter import ServerLimiter
 from brpc_tpu.naming import (PartitionScheme, ReplicaSet, parse_claims,
                              parse_schemes, parse_shard_tag)
+
+
+@contextlib.contextmanager
+def _op_span(op: str):
+    """The ``emb.<op>`` root of one client operation: the per-shard
+    client calls made inside hang below it and, across the socket, the
+    shards' own trees below them."""
+    if not obs.enabled():
+        yield
+        return
+    root = rpcz.start_root("emb", op, "user")
+    try:
+        yield
+    finally:
+        rpcz.finish_root(root)
 
 
 def _reject_frame(method: str) -> None:
@@ -66,7 +83,6 @@ def _record_ps_server(shard_index: int, method: str, count: int,
     obs.recorder(f"ps_server_shard{shard_index}_{method}").record(
         (time.monotonic_ns() - t0) / 1e9)
     obs.counter("ps_server_keys").add(count)
-    obs.counter("ps_server_bytes_in").add(req_len)
     obs.counter("ps_server_bytes_out").add(rsp_len)
 
 
@@ -476,6 +492,15 @@ class GradCombiner:
         # queueing inside the combiner — the PR-12 deferral).
         entry = [ids, grads, threading.Event() if wait else None, None,
                  meta, deadline_us]
+        # enqueue -> this entry's batch applied; the leader's own covers
+        # the batch it applies (its stage and launch hang below it)
+        waited = rpcz.begin("ps.combine_wait") if wait else None
+        try:
+            self._enqueue(entry, waited)
+        finally:
+            rpcz.end(waited)
+
+    def _enqueue(self, entry: list, waited) -> None:
         with self._mu:
             if self._shut:
                 # Server teardown: late contributions (a dead client's
@@ -494,14 +519,19 @@ class GradCombiner:
                 if entry[3] is not None:
                     raise entry[3]
             return
-        self._drain()
+        self._drain(entry, waited)
         if entry[3] is not None:
             raise entry[3]
 
-    def _drain(self) -> None:
+    def _drain(self, own: list, waited) -> None:
         """Leader loop: drain batches until the queue is empty (entries
-        enqueued while a batch applies land in the next one)."""
+        enqueued while a batch applies land in the next one).  The
+        leader's ``ps.combine_wait`` ends with the batch that holds its
+        ``own`` entry; later batches hang from its root."""
         while True:
+            if waited is not None and own[2].is_set():
+                rpcz.end(waited)
+                waited = None
             with self._mu:
                 batch = self._q
                 if not batch:
@@ -539,8 +569,10 @@ class GradCombiner:
                 if len(batch) == 1:
                     ids, grads = batch[0][0], batch[0][1]
                 else:
+                    sp = rpcz.begin("ps.pad", copy=True)
                     ids = np.concatenate([e[0] for e in batch])
                     grads = np.concatenate([e[1] for e in batch])
+                    rpcz.end(sp, ids.nbytes + grads.nbytes)
                 if ids.size:
                     if self._pass_meta:
                         self._apply(ids, grads,
@@ -2906,7 +2938,9 @@ class DevicePsShardServer(PsShardServer):
         read (or write) lock whenever the pinned buffer must
         correspond to ``_install_gen`` — installs hold the write lock,
         so the pair is consistent there."""
+        lw = rpcz.begin("ps.lock_wait")
         with self._dev_mu:
+            rpcz.end(lw)
             key = self._dev_cur
             if key is None:
                 return None
@@ -3280,10 +3314,12 @@ class DevicePsShardServer(PsShardServer):
             if m[1] > updates.get(m[0], 0):
                 updates[m[0]] = m[1]
         bucket = self._bucket(int(ids.size))
+        sp = rpcz.begin("ps.pad", copy=True)
         padded_ids = np.zeros(bucket, np.int32)
         padded_ids[:ids.size] = ids
         padded_g = np.zeros((bucket, self.dim), np.float32)
         padded_g[:ids.size] = grads
+        rpcz.end(sp, padded_ids.nbytes + padded_g.nbytes)
         rep = mig = dur = None
         gen = 0
         ids_h = self.dev.stage(padded_ids, self.device_index)
@@ -3309,7 +3345,9 @@ class DevicePsShardServer(PsShardServer):
                     new_table = outs[0][0]
                     installed = False
                     serving = True
+                    lw = rpcz.begin("ps.lock_wait")
                     with self._mu.write():
+                        rpcz.end(lw)
                         # Same fence discipline as the CPU tier: an
                         # apply that raced SchemeFence refuses inside
                         # the lock and the caller re-resolves.
@@ -3406,10 +3444,14 @@ class DevicePsShardServer(PsShardServer):
         if method == "Lookup":
             if self._importing:
                 self._check_scheme()
+            lw = rpcz.begin("ps.lock_wait")
             with self._seq_mu:
+                rpcz.end(lw)
                 self._read_count += 1
             pinned = None
+            lw = rpcz.begin("ps.lock_wait")
             with self._mu.read():
+                rpcz.end(lw)
                 if self._dev_serving:
                     pinned = self._pin_current()
                 else:
@@ -3425,8 +3467,10 @@ class DevicePsShardServer(PsShardServer):
                 return gathered.tobytes()
             key, table_h = pinned
             bucket = self._bucket(count)
+            sp = rpcz.begin("ps.pad", copy=True)
             padded_ids = np.zeros(bucket, np.int32)
             padded_ids[:count] = ids
+            rpcz.end(sp, padded_ids.nbytes)
             ids_h = self.dev.stage(padded_ids, self.device_index)
             try:
                 outs = self._gather_exe(bucket).execute(
@@ -4762,11 +4806,8 @@ class RemoteEmbedding:
 
     def _lookup_view(self, view: _SchemeView, flat: np.ndarray,
                      out: np.ndarray):
-        """One whole-batch lookup under one scheme view.  Returns
-        ``(bytes_out, bytes_in)``; raises on any shard miss (the caller
-        falls back across schemes)."""
-        nbytes_in = 0
-        nbytes_out = 0
+        """One whole-batch lookup under one scheme view; raises on any
+        shard miss (the caller falls back across schemes)."""
         zc = zerocopy_enabled()
 
         def _consume(rsp, owned):
@@ -4799,11 +4840,9 @@ class RemoteEmbedding:
                     req = _pack_lookup_req_iobuf(owned) \
                         if zc and owned.nbytes >= _ZC_MIN_BYTES \
                         else _pack_lookup_req(owned)
-                    nbytes_out += len(req)
                     items.append((s, req))
                 rsps = self._fan_out(view, "Lookup", items)
                 for (s, positions, owned), rsp in zip(split, rsps):
-                    nbytes_in += len(rsp)
                     out[positions] = _consume(rsp, owned)
             finally:
                 for _, req in items:
@@ -4819,15 +4858,12 @@ class RemoteEmbedding:
                 req = _pack_lookup_req_iobuf(owned) \
                     if zc and owned.nbytes >= _ZC_MIN_BYTES \
                     else _pack_lookup_req(owned)
-                nbytes_out += len(req)
                 try:
                     rsp = self._call_shard(view, s, "Lookup", req)
                 finally:
                     if isinstance(req, rpc.IOBuf):
                         req.close()
-                nbytes_in += len(rsp)
                 out[positions] = _consume(rsp, owned)
-        return nbytes_out, nbytes_in
 
     def lookup(self, ids: np.ndarray) -> np.ndarray:
         rec = obs.enabled()
@@ -4841,32 +4877,29 @@ class RemoteEmbedding:
         # answers EMIGRATING; a draining scheme's tables are frozen at
         # exactly the cutover state, so its answers stay correct).
         views = self._read_views()
-        nbytes_out = nbytes_in = 0
-        for i, view in enumerate(views):
-            try:
-                nbytes_out, nbytes_in = self._lookup_view(view, flat,
-                                                          out)
-                break
-            except rpc.RpcError:
-                if i + 1 >= len(views):
-                    raise
-                if obs.enabled():
-                    obs.counter("ps_scheme_fallback_reads").add(1)
+        with _op_span("lookup"):
+            for i, view in enumerate(views):
+                try:
+                    self._lookup_view(view, flat, out)
+                    break
+                except rpc.RpcError:
+                    if i + 1 >= len(views):
+                        raise
+                    if obs.enabled():
+                        obs.counter("ps_scheme_fallback_reads").add(1)
         if rec:
             # Whole-batch latency across all owner shards (each per-shard
             # RPC is additionally recorded by Channel.call/call_async).
             obs.recorder("ps_client_lookup").record(
                 (time.monotonic_ns() - t0) / 1e9)
             obs.counter("ps_client_lookup_keys").add(int(flat.size))
-            obs.counter("ps_client_bytes_out").add(nbytes_out)
-            obs.counter("ps_client_bytes_in").add(nbytes_in)
         return out.reshape(*np.shape(ids), self.dim)
 
     def _apply_unit(self, view: _SchemeView, uids: np.ndarray,
-                    ugrads: np.ndarray, guards: tuple) -> int:
+                    ugrads: np.ndarray, guards: tuple) -> None:
         """Apply one write unit (global ids + grads + scheme guards)
         under ``view`` via idempotent ``ApplyGradId`` items, one per
-        owner shard.  Returns bytes sent.  A scheme boundary raises
+        owner shard.  A scheme boundary raises
         :class:`_SchemeMovedError` carrying the UNAPPLIED remainder —
         each unacked item becomes a unit whose guard chain grows by its
         own (writer key, seq), so re-routing it through the successor
@@ -4874,7 +4907,6 @@ class RemoteEmbedding:
         split = list(self._owner_split(view, uids))
         items = []
         meta = []
-        nbytes = 0
         for s, positions, owned in split:
             wkey = self._unary_writer_key(view, s)
             seq = view.useq.get(s, 0) + 1
@@ -4882,7 +4914,6 @@ class RemoteEmbedding:
             item_guards = guards + ((wkey, seq),)
             req = bytes(_pack_apply_id_req(wkey, seq, guards, owned,
                                            ugrads[positions]))
-            nbytes += len(req)
             items.append((s, req))
             meta.append((owned, ugrads[positions], item_guards))
         done: List[Optional[bytes]] = [None] * len(items)
@@ -4905,9 +4936,8 @@ class RemoteEmbedding:
             remainder = [(meta[i][0], meta[i][1], meta[i][2])
                          for i in range(len(items)) if done[i] is None]
             raise _SchemeMovedError(e.code, remainder) from e
-        return nbytes
 
-    def _apply_units(self, units: List[tuple]) -> int:
+    def _apply_units(self, units: List[tuple]) -> None:
         """Drive write units to completion across scheme moves: a unit
         interrupted by a cutover re-splits through the refreshed write
         view (guard chain intact), an EMIGRATING unit waits out the
@@ -4915,7 +4945,6 @@ class RemoteEmbedding:
         SEQUENTIALLY so per-(scheme, shard) seqs stay in arrival order
         (one batch normally is one unit — the fan-out inside it is
         still concurrent)."""
-        nbytes = 0
         moves = 0
         backoff = resilience.Backoff(base_ms=5.0, max_ms=100.0)
         queue = list(units)
@@ -4923,7 +4952,7 @@ class RemoteEmbedding:
             view = self._write_view()
             uids, ugrads, guards = queue[0]
             try:
-                nbytes += self._apply_unit(view, uids, ugrads, guards)
+                self._apply_unit(view, uids, ugrads, guards)
             except _SchemeMovedError as e:
                 moves += 1
                 if moves > 16:
@@ -4940,7 +4969,6 @@ class RemoteEmbedding:
                     resilience.sleep_ms(backoff.delay_ms(min(moves, 6)))
                 continue
             queue.pop(0)
-        return nbytes
 
     def apply_gradients(self, ids: np.ndarray, grads: np.ndarray) -> None:
         rec = obs.enabled()
@@ -4948,12 +4976,12 @@ class RemoteEmbedding:
             t0 = time.monotonic_ns()
         flat = np.asarray(ids, np.int32).reshape(-1)
         g = np.asarray(grads, np.float32).reshape(flat.size, self.dim)
-        nbytes_out = self._apply_units([(flat, g, ())])
+        with _op_span("apply_gradients"):
+            self._apply_units([(flat, g, ())])
         if rec:
             obs.recorder("ps_client_apply").record(
                 (time.monotonic_ns() - t0) / 1e9)
             obs.counter("ps_client_apply_keys").add(int(flat.size))
-            obs.counter("ps_client_bytes_out").add(nbytes_out)
 
     # -- streaming gradient push (the write-path mirror of the native
     # -- read path: framed deltas over one ordered flow-controlled
@@ -5139,19 +5167,14 @@ class RemoteEmbedding:
         unacked window — this batch included — onto the successor
         scheme as guarded unary writes (exactly-once either side of the
         boundary), after which pushes stream to the new shards."""
-        rec = obs.enabled()
-        if rec:
-            t0 = time.monotonic_ns()
         flat = np.asarray(ids, np.int32).reshape(-1)
         g = np.asarray(grads, np.float32).reshape(flat.size, self.dim)
         view = self._write_view()
-        nbytes_out = 0
         shards = []
         # Frame every owner shard FIRST: a scheme fence hit while
         # writing shard k must transfer the whole batch, not a prefix.
         for s, positions, owned in self._owner_split(view, flat):
             body = bytes(_pack_apply_req(owned, g[positions]))
-            nbytes_out += len(body)
             seq = self._push_seq.get(s, 0) + 1
             self._push_seq[s] = seq
             # Unacked until the flush barrier confirms: the window is
@@ -5165,11 +5188,6 @@ class RemoteEmbedding:
             if e.code != resilience.ESCHEMEMOVED:
                 raise
             self._transfer_pushes(view, None)
-        if rec:
-            obs.recorder("ps_client_push").record(
-                (time.monotonic_ns() - t0) / 1e9)
-            obs.counter("ps_client_push_keys").add(int(flat.size))
-            obs.counter("ps_client_bytes_out").add(nbytes_out)
 
     def _transfer_pushes(self, old_view: _SchemeView,
                          new_view: Optional[_SchemeView]) -> None:

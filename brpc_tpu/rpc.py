@@ -10,7 +10,9 @@ from __future__ import annotations
 
 import ctypes
 import fcntl
+import itertools
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -22,6 +24,7 @@ from typing import Callable, Optional
 from brpc_tpu import fault, obs, resilience
 from brpc_tpu.analysis import handles as _handles
 from brpc_tpu.analysis import race as _race
+from brpc_tpu.obs import rpcz as _rpcz
 
 _INT64_MIN = -(2 ** 63)  # "inherit the channel option" timeout sentinel
 
@@ -123,6 +126,35 @@ def fake_pjrt_plugin_path() -> str:
     return os.path.join(_build_dir(), "libbrt_fake_pjrt.so")
 
 
+class _LateStamps:
+    """The native core's late stamps (``brt_late_stamps``): the end of
+    work that outlives the call which started it — a response's last byte
+    handed to the socket, an H2D transfer done with its host buffer.  A
+    traced span that wants one takes a slot, passes it down with the call
+    and leaves the slot with its root (``obs.rpcz.record_late``), which
+    reads the stamp when the tree is looked at."""
+
+    def __init__(self, lib):
+        n = ctypes.c_size_t()
+        base = lib.brt_late_stamps(ctypes.byref(n))
+        self._table = (ctypes.c_int64 * n.value).from_address(base)
+        self._slots = n.value - 1           # slot 0 asks for nothing
+        self._asks = itertools.count()      # (its next() is one step)
+
+    def take(self) -> int:
+        """A zeroed slot (reused after all the others were)."""
+        slot = next(self._asks) % self._slots + 1
+        self._table[slot] = 0
+        return slot
+
+    def read(self, slot: int) -> int:
+        """The slot's stamp on ``monotonic_ns``; 0: not finished yet."""
+        return self._table[slot]
+
+
+_late: Optional[_LateStamps] = None
+
+
 def _load():
     global _lib
     if _lib is not None:
@@ -179,8 +211,24 @@ def _load_locked():
     lib.brt_server_destroy.restype = None
     lib.brt_session_respond.argtypes = [
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t, ctypes.c_int,
-        ctypes.c_char_p]
+        ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64), ctypes.c_uint32]
     lib.brt_session_respond.restype = None
+    # The tracing getters and setters touch a few words of the session:
+    # called through a PyDLL handle they keep the interpreter lock.  A
+    # plain ctypes call drops and retakes it, and a handler that does so
+    # a few more times a request hands the lock to the next handler each
+    # time (1.2 ms on a 5 ms lookup's median, on the chip, PR 26).
+    pylib = ctypes.PyDLL(lib._name)
+    pylib.brt_session_trace.argtypes = [
+        ctypes.c_void_p, ctypes.POINTER(ctypes.c_uint64),
+        ctypes.POINTER(ctypes.c_int64)]
+    pylib.brt_session_trace.restype = ctypes.c_uint64
+    pylib.brt_call_trace_next.argtypes = [ctypes.c_uint64, ctypes.c_uint64]
+    pylib.brt_call_trace_next.restype = None
+    for fn in ("brt_session_trace", "brt_call_trace_next"):
+        setattr(lib, fn, getattr(pylib, fn))
+    lib.brt_late_stamps.argtypes = [ctypes.POINTER(ctypes.c_size_t)]
+    lib.brt_late_stamps.restype = ctypes.c_void_p
     lib.brt_channel_new.restype = ctypes.c_void_p
     lib.brt_channel_new.argtypes = [
         ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int64, ctypes.c_int]
@@ -304,7 +352,8 @@ def _load_locked():
         ctypes.c_size_t]
     lib.brt_call_join_iobuf.restype = ctypes.c_void_p
     lib.brt_session_respond_iobuf.argtypes = [
-        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int, ctypes.c_char_p]
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int, ctypes.c_char_p,
+        ctypes.POINTER(ctypes.c_int64), ctypes.c_uint32]
     lib.brt_session_respond_iobuf.restype = None
     lib.brt_stream_writev.argtypes = [
         ctypes.c_uint64, ctypes.POINTER(ctypes.c_void_p), ctypes.c_int,
@@ -353,10 +402,12 @@ def _load_locked():
     lib.brt_device_stage_shaped.argtypes = [
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t, ctypes.c_int,
         ctypes.c_int, ctypes.POINTER(ctypes.c_int64), ctypes.c_size_t,
-        ctypes.c_char_p, ctypes.c_size_t]
+        ctypes.c_char_p, ctypes.c_size_t, ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_uint32]
     lib.brt_device_fetch.argtypes = [
         ctypes.c_void_p, ctypes.c_uint64, ctypes.POINTER(ctypes.c_void_p),
-        ctypes.POINTER(ctypes.c_size_t), ctypes.c_char_p, ctypes.c_size_t]
+        ctypes.POINTER(ctypes.c_size_t), ctypes.c_char_p, ctypes.c_size_t,
+        ctypes.POINTER(ctypes.c_int64)]
     lib.brt_device_fetch.restype = ctypes.c_int
     lib.brt_device_release.argtypes = [ctypes.c_uint64]
     lib.brt_device_release.restype = ctypes.c_int
@@ -387,6 +438,9 @@ def _load_locked():
     lib.brt_init(0)
     if _handles.enabled():
         _install_handle_ledger(lib)
+    global _late
+    _late = _LateStamps(lib)
+    _rpcz.set_late_stamps(_late.read)
     return lib
 
 
@@ -909,8 +963,6 @@ def _stream_dispatch(user, stream_id, data, length, closed):
                 # what retires the native stream for a sid no receiver
                 # will ever claim.
                 lib.brt_stream_close(sid)
-                if obs.enabled():
-                    obs.counter("stream_orphans_evicted").add(1)
             return
         if receiver is None:
             return
@@ -949,8 +1001,6 @@ def _make_stream_accept(lib, session):
         # frame can arrive until the client learns the peer stream id
         # from the response meta.
         _register_stream_receiver(sid.value, receiver)
-        if obs.enabled():
-            obs.counter("stream_accepts").add(1)
         # track=False: the server half's lifecycle belongs to the close
         # handshake in _stream_dispatch (receiver registry is the ledger
         # entry); this wrapper is a write surface, not an owner.
@@ -1012,24 +1062,43 @@ def uninstall_drop_hook() -> None:
 _SHED_TAGS = {2004: "shed=limiter", 2014: "shed=deadline"}
 
 
-def _record_server_call(service: str, method: str, t0: int, wall: float,
-                        req_len: int, rsp_len: int,
+def _record_server_call(root: "_rpcz.Span", req_len: int, rsp_len: int,
                         error: Optional[str],
                         error_code: int = 2001) -> None:
-    end = time.monotonic_ns()
-    obs.recorder(f"rpc_server_{service}_{method}").record((end - t0) / 1e9)
+    """Closes the handler's root span and feeds the per-method recorder
+    and the byte counters."""
+    root.end_ns = end = time.monotonic_ns()
+    obs.recorder(f"rpc_server_{root.service}_{root.method}").record(
+        (end - root.start_ns) / 1e9)
     obs.counter("rpc_server_in_bytes").add(req_len)
-    obs.counter("rpc_server_out_bytes").add(rsp_len)
+    root.request_bytes, root.response_bytes = req_len, rsp_len
     if error is not None:
         obs.counter("rpc_server_errors").add(1)
-    tag = _SHED_TAGS.get(error_code) if error is not None else None
-    obs.record_span(obs.Span(
-        service=service, method=method, side="server",
-        request_bytes=req_len, response_bytes=rsp_len, start_ns=t0,
-        end_ns=end, wall_time=wall,
-        error_code=error_code if error else 0,
-        error_text=error or "",
-        annotations=[tag] if tag else []))
+        root.error_code, root.error_text = error_code, error
+        tag = _SHED_TAGS.get(error_code)
+        if tag:
+            root.annotate(tag)
+    _rpcz.finish_root(root)
+
+
+def _open_server_root(lib, session, service: str, method: str,
+                      req_len: int) -> "_rpcz.Span":
+    """Opens the handler's root span of one request and, where it is
+    traced, hands it what the native core stamped before Python ran —
+    the receive, the scheduling, the flattening copy, the wait for the
+    interpreter lock: the root's siblings when the store is read."""
+    wire = lib.brt_session_trace(session, None, None)
+    root = _rpcz.start_root(service, method, "server", trace_id=wire,
+                            request_bytes=req_len)
+    if root.trace_id:           # the rest only for a request that is traced
+        parent = ctypes.c_uint64()
+        stamps = (ctypes.c_int64 * 4)()
+        lib.brt_session_trace(session, parent, stamps)
+        root.parent_id = parent.value
+        if all(stamps):
+            # (a dispatch path that did not stamp leaves a 0: no phases)
+            root._phases = tuple(stamps)
+    return root
 
 
 def _error_code_of(e: BaseException) -> int:
@@ -1040,22 +1109,37 @@ def _error_code_of(e: BaseException) -> int:
     return code if isinstance(code, int) and code != 0 else 2001
 
 
-def _record_client_call(service: str, method: str, peer: str, t0: int,
-                        wall: float, req_len: int, rsp_len: int,
-                        error_code: int, error_text: str,
+def _start_client_call(service: str, method: str, peer: str,
+                       req_len: int) -> "_rpcz.Span":
+    """Opens the client span of one call (never a thread's current
+    span: it has no Python below it, and an async one ends elsewhere)."""
+    return _rpcz.start_root(service, method, "client", peer=peer,
+                            request_bytes=req_len, push=False)
+
+
+def _trace_next_call(lib, sp: "Optional[_rpcz.Span]") -> None:
+    """Right before the native call: the ids of a client span somebody
+    asked to see (``obs.rpcz``: under ``obs.span``, in a profiler
+    session, or for a request that came with ids) ride the wire with the
+    call this thread starts next, so the server's spans join it."""
+    if sp is not None and sp._spread:
+        lib.brt_call_trace_next(sp.trace_id, sp.span_id)
+
+
+def _record_client_call(sp: "_rpcz.Span", rsp_len: int, error_code: int,
+                        error_text: str,
                         tag: Optional[str] = None) -> None:
-    end = time.monotonic_ns()
-    obs.recorder(f"rpc_client_{service}_{method}").record((end - t0) / 1e9)
-    obs.counter("rpc_client_out_bytes").add(req_len)
-    obs.counter("rpc_client_in_bytes").add(rsp_len)
+    sp.end_ns = end = time.monotonic_ns()
+    obs.recorder(f"rpc_client_{sp.service}_{sp.method}").record(
+        (end - sp.start_ns) / 1e9)
+    obs.counter("rpc_client_out_bytes").add(sp.request_bytes)
     if error_code:
         obs.counter("rpc_client_errors").add(1)
-    obs.record_span(obs.Span(
-        service=service, method=method, side="client", peer=peer,
-        request_bytes=req_len, response_bytes=rsp_len, start_ns=t0,
-        end_ns=end, wall_time=wall, error_code=error_code,
-        error_text=error_text,
-        annotations=[tag] if tag else []))
+    sp.response_bytes = rsp_len
+    sp.error_code, sp.error_text = error_code, error_text
+    if tag:
+        sp.annotate(tag)
+    _rpcz.finish_root(sp)
 
 
 class Server:
@@ -1120,83 +1204,98 @@ class Server:
             # t0 is unconditional: the method gate's limiter needs the
             # handler latency whether or not obs is recording
             t0 = time.monotonic_ns()
-            wall = time.time() if rec else 0.0
-            m = b""
-            mstr = ""
+            m = method
+            mstr = m.decode(errors="replace")
+            root = _open_server_root(lib, session, name, mstr,
+                                     req_len) if rec else None
             out_len = 0
             err = None
             err_code = 0
             gate = None
             try:
-                m = method
-                mstr = m.decode()
-                lim = self._limiter
-                if lim is not None:
-                    g = lim.gate(mstr)
-                    if g is not None:
-                        if not g.admit():
-                            # per-method overload shed: answered before
-                            # the handler (or even the request bytes)
-                            # are touched — the MethodStatus::OnRequested
-                            # contract
-                            raise RpcError(
-                                resilience.ELIMIT,
-                                f"{name}.{mstr} shed: concurrency limit "
-                                f"{g.max_concurrency} reached")
-                        gate = g
-                data = ctypes.string_at(req, req_len) if req_len else b""
-                if rec and req_len:
-                    obs.counter("rpc_bytes_copied").add(req_len)
-                if fault.active():
-                    fault.server_intercept(name, mstr, self._listen)
-                if pass_accept:
-                    out = handler(mstr, data,
-                                  _make_stream_accept(lib, session))
-                else:
-                    out = handler(mstr, data)
-                if out is None:
-                    out = b""
-                out_len = len(out)
-            except Exception as e:  # noqa: BLE001
-                err = str(e)
-                err_code = _error_code_of(e)
-            # Accounting BEFORE the response leaves: the moment the
-            # client sees the reply it may read this server's vars —
-            # a record landing after the respond races that read.
-            try:
-                if gate is not None:
-                    gate.on_responded(
-                        err_code, (time.monotonic_ns() - t0) // 1000)
-                if rec:
-                    _record_server_call(
-                        name, mstr or m.decode(errors="replace"), t0,
-                        wall, req_len, out_len, err,
-                        err_code if err else 2001)
-            finally:
-                if err is None:
-                    if isinstance(out, IOBuf) and not out.force_iobuf \
-                            and out_len < IOBUF_MIN_BYTES:
-                        # Sub-crossover response: the bytes twin is
-                        # cheaper than the respond_iobuf handle dance
-                        # (identical wire bytes).
-                        data = out.tobytes()
-                        out.close()
-                        lib.brt_session_respond(session, data, out_len,
-                                                0, None)
-                    elif isinstance(out, IOBuf):
-                        # The response SHARES the handler's blocks (no
-                        # copy); the handle is not consumed — close it
-                        # here, which defers actual destruction past the
-                        # socket write via the block refcounts.
-                        lib.brt_session_respond_iobuf(
-                            session, out._require(), 0, None)
-                        out.close()
+                try:
+                    lim = self._limiter
+                    if lim is not None:
+                        g = lim.gate(mstr)
+                        if g is not None:
+                            if not g.admit():
+                                # per-method overload shed: answered
+                                # before the handler (or even the request
+                                # bytes) are touched — the
+                                # MethodStatus::OnRequested contract
+                                raise RpcError(
+                                    resilience.ELIMIT,
+                                    f"{name}.{mstr} shed: concurrency "
+                                    f"limit {g.max_concurrency} reached")
+                            gate = g
+                    sp = _rpcz.begin("rpc.copy_in", req_len, True)
+                    data = ctypes.string_at(req, req_len) if req_len else b""
+                    _rpcz.end(sp)
+                    if rec and req_len:
+                        obs.counter("rpc_bytes_copied").add(req_len)
+                    if fault.active():
+                        fault.server_intercept(name, mstr, self._listen)
+                    if pass_accept:
+                        out = handler(mstr, data,
+                                      _make_stream_accept(lib, session))
                     else:
-                        lib.brt_session_respond(session, out, out_len, 0,
-                                                None)
-                else:
-                    lib.brt_session_respond(session, None, 0, err_code,
-                                            err.encode())
+                        out = handler(mstr, data)
+                    if out is None:
+                        out = b""
+                    out_len = len(out)
+                except Exception as e:  # noqa: BLE001
+                    err = str(e)
+                    err_code = _error_code_of(e)
+                # Accounting BEFORE the response leaves: the moment the
+                # client sees the reply it may read this server's vars —
+                # a record landing after the respond races that read.
+                try:
+                    if gate is not None:
+                        gate.on_responded(
+                            err_code, (time.monotonic_ns() - t0) // 1000)
+                    if root is not None:
+                        _record_server_call(root, req_len, out_len, err,
+                                            err_code if err else 2001)
+                finally:
+                    # a traced request's respond hands back when it
+                    # entered, when the response was in its buffer and
+                    # what that copied, and stamps "written" late
+                    sent, slot = None, 0
+                    if root is not None and root._phases is not None:
+                        sent, slot = (ctypes.c_int64 * 3)(), _late.take()
+                    if err is None:
+                        if isinstance(out, IOBuf) and not out.force_iobuf \
+                                and out_len < IOBUF_MIN_BYTES:
+                            # Sub-crossover response: the bytes twin is
+                            # cheaper than the respond_iobuf handle dance
+                            # (identical wire bytes).
+                            data = out.tobytes()
+                            out.close()
+                            lib.brt_session_respond(session, data, out_len,
+                                                    0, None, sent, slot)
+                        elif isinstance(out, IOBuf):
+                            # The response SHARES the handler's blocks (no
+                            # copy); the handle is not consumed — close it
+                            # here, which defers actual destruction past
+                            # the socket write via the block refcounts.
+                            lib.brt_session_respond_iobuf(
+                                session, out._require(), 0, None, sent,
+                                slot)
+                            out.close()
+                        else:
+                            lib.brt_session_respond(session, out, out_len,
+                                                    0, None, sent, slot)
+                    else:
+                        lib.brt_session_respond(session, None, 0, err_code,
+                                                err.encode(), sent, slot)
+                    if sent is not None:
+                        root._sent = (*sent, slot)
+            finally:
+                # whatever got past the handlers above (a BaseException,
+                # the gate's own failure), this pooled thread must not
+                # keep the request as its current span
+                if root is not None and root._pushed:
+                    _rpcz.finish_root(root)
 
         return trampoline
 
@@ -1269,7 +1368,9 @@ class Server:
             rec = obs.enabled()
             t0 = time.monotonic_ns()  # gate latency needs it without obs
             if rec:
-                wall = time.time()
+                # respond may run on any thread: the root is the flat
+                # record, never a thread's current span
+                root = _rpcz.start_root(name, m, "server", push=False)
                 nreq = req_len
             gate = None
 
@@ -1286,16 +1387,15 @@ class Server:
                         (time.monotonic_ns() - t0) // 1000)
                 if error is not None:
                     if rec:
-                        _record_server_call(name, m, t0, wall, nreq, 0,
-                                            error, error_code)
+                        _record_server_call(root, nreq, 0, error,
+                                            error_code)
                     lib.brt_session_respond(sess, None, 0, error_code,
-                                            error.encode())
+                                            error.encode(), None, 0)
                 else:
                     if rec:
-                        _record_server_call(name, m, t0, wall, nreq,
-                                            len(payload), None)
+                        _record_server_call(root, nreq, len(payload), None)
                     lib.brt_session_respond(sess, payload, len(payload), 0,
-                                            None)
+                                            None, None, 0)
 
             lim = self._limiter
             if lim is not None:
@@ -1380,19 +1480,12 @@ class PendingCall:
     straggler shards.
     """
 
-    __slots__ = ("_lib", "_ptr", "_service", "_method", "_peer",
-                 "_req_len", "_t0", "_wall", "_tag", "_iobuf")
+    __slots__ = ("_lib", "_ptr", "_span", "_tag", "_iobuf")
 
-    def __init__(self, lib, ptr, service, method, peer, req_len, t0, wall,
-                 tag=None, iobuf=False):
+    def __init__(self, lib, ptr, span, tag=None, iobuf=False):
         self._lib = lib
         self._ptr = ptr
-        self._service = service
-        self._method = method
-        self._peer = peer
-        self._req_len = req_len
-        self._t0 = t0      # None when obs was disabled at start
-        self._wall = wall
+        self._span = span  # the client span; None: obs disabled at start
         self._tag = tag
         # Calls started with an IOBuf request join to an IOBuf response
         # (brt_call_join_iobuf swaps the blocks out — no copy).
@@ -1436,10 +1529,8 @@ class PendingCall:
                                          ctypes.byref(rsp_len), errbuf, 256)
             if rc != 0:
                 text = errbuf.value.decode(errors="replace")
-                if self._t0 is not None:
-                    _record_client_call(self._service, self._method,
-                                        self._peer, self._t0, self._wall,
-                                        self._req_len, 0, rc, text,
+                if self._span is not None:
+                    _record_client_call(self._span, 0, rc, text,
                                         self._tag)
                 raise RpcError(rc, text)
             try:
@@ -1448,11 +1539,9 @@ class PendingCall:
                 self._lib.brt_free(rsp)
         finally:
             self._lib.brt_call_destroy(ptr)
-        if self._t0 is not None:
+        if self._span is not None:
             # start -> join latency: the caller-visible async window
-            _record_client_call(self._service, self._method, self._peer,
-                                self._t0, self._wall, self._req_len,
-                                len(out), 0, "", self._tag)
+            _record_client_call(self._span, len(out), 0, "", self._tag)
             obs.counter("rpc_bytes_copied").add(len(out))
         return out
 
@@ -1467,19 +1556,15 @@ class PendingCall:
                                               errbuf, 256)
             if not h:
                 text = errbuf.value.decode(errors="replace")
-                if self._t0 is not None:
-                    _record_client_call(self._service, self._method,
-                                        self._peer, self._t0, self._wall,
-                                        self._req_len, 0, err.value, text,
+                if self._span is not None:
+                    _record_client_call(self._span, 0, err.value, text,
                                         self._tag)
                 raise RpcError(err.value or -1, text)
         finally:
             self._lib.brt_call_destroy(ptr)
         out = IOBuf._adopt(self._lib, h)
-        if self._t0 is not None:
-            _record_client_call(self._service, self._method, self._peer,
-                                self._t0, self._wall, self._req_len,
-                                len(out), 0, "", self._tag)
+        if self._span is not None:
+            _record_client_call(self._span, len(out), 0, "", self._tag)
         return out
 
     def close(self) -> None:
@@ -1800,9 +1885,8 @@ class Channel:
             return self.call_async(service, method, request,
                                    timeout_ms=timeout_ms).join()
         rec = obs.enabled()
-        if rec:
-            t0 = time.monotonic_ns()
-            wall = time.time()
+        sp = _start_client_call(service, method, self._addr,
+                                len(request)) if rec else None
         if fault.active():
             fault.client_intercept(service, method, self._addr)
         if _race.enabled():
@@ -1814,6 +1898,7 @@ class Channel:
             # wire bytes; the caller still closes its handle, and the
             # response comes back as plain bytes).
             request = request.tobytes()
+        _trace_next_call(self._lib, sp)
         if isinstance(request, IOBuf):
             # Zero-copy currency: the request's blocks are shared into
             # the native call (no payload copy; the caller's handle keeps
@@ -1827,14 +1912,11 @@ class Channel:
             if not h:
                 text = errbuf.value.decode(errors="replace")
                 if rec:
-                    _record_client_call(service, method, self._addr, t0,
-                                        wall, len(request), 0, err.value,
-                                        text)
+                    _record_client_call(sp, 0, err.value, text)
                 raise RpcError(err.value or -1, text)
             out = IOBuf._adopt(self._lib, h)
             if rec:
-                _record_client_call(service, method, self._addr, t0, wall,
-                                    len(request), len(out), 0, "")
+                _record_client_call(sp, len(out), 0, "")
             return out
         rsp = ctypes.c_void_p()
         rsp_len = ctypes.c_size_t()
@@ -1846,16 +1928,14 @@ class Channel:
         if rc != 0:
             text = errbuf.value.decode(errors="replace")
             if rec:
-                _record_client_call(service, method, self._addr, t0, wall,
-                                    len(request), 0, rc, text)
+                _record_client_call(sp, 0, rc, text)
             raise RpcError(rc, text)
         try:
             out = ctypes.string_at(rsp, rsp_len.value)
         finally:
             self._lib.brt_free(rsp)
         if rec:
-            _record_client_call(service, method, self._addr, t0, wall,
-                                len(request), len(out), 0, "")
+            _record_client_call(sp, len(out), 0, "")
             obs.counter("rpc_bytes_copied").add(len(out))
         return out
 
@@ -1872,9 +1952,8 @@ class Channel:
         the channel deadline for this one call (the retry loop's
         shrinking budget rides this); ``tag`` annotates the client rpcz
         span (attempt/hedge labels)."""
-        rec = obs.enabled()
-        t0 = time.monotonic_ns() if rec else None
-        wall = time.time() if rec else 0.0
+        sp = _start_client_call(service, method, self._addr,
+                                len(request)) if obs.enabled() else None
         if fault.active():
             fault.client_intercept(service, method, self._addr, timeout_ms)
         if isinstance(request, IOBuf) and not request.force_iobuf \
@@ -1882,6 +1961,7 @@ class Channel:
             # Same bytes-twin routing as the sync call: sub-crossover
             # payloads skip the handle tax (join() then returns bytes).
             request = request.tobytes()
+        _trace_next_call(self._lib, sp)
         if isinstance(request, IOBuf):
             ptr = self._lib.brt_channel_call_start_iobuf(
                 self._ptr, service.encode(), method.encode(),
@@ -1889,16 +1969,14 @@ class Channel:
                 _INT64_MIN if timeout_ms is None else int(timeout_ms))
             if not ptr:
                 raise RpcError(-1, f"call_start failed for {self._addr}")
-            return PendingCall(self._lib, ptr, service, method, self._addr,
-                               len(request), t0, wall, tag, iobuf=True)
+            return PendingCall(self._lib, ptr, sp, tag, iobuf=True)
         ptr = self._lib.brt_channel_call_start_opts(
             self._ptr, service.encode(), method.encode(),
             _req_ptr(request), len(request),
             _INT64_MIN if timeout_ms is None else int(timeout_ms))
         if not ptr:
             raise RpcError(-1, f"call_start failed for {self._addr}")
-        return PendingCall(self._lib, ptr, service, method, self._addr,
-                           len(request), t0, wall, tag)
+        return PendingCall(self._lib, ptr, sp, tag)
 
     def stream(self, service: str, method: str, request: bytes = b"", *,
                max_buf_size: int = 0, receiver=None) -> Stream:
@@ -1925,8 +2003,10 @@ class Channel:
         closed callback is what frees it)."""
         rec = obs.enabled()
         if rec:
-            t0 = time.monotonic_ns()
-            wall = time.time()
+            # flat: the stream's set-up call takes no ids to the wire
+            sp = _rpcz.start_root(service, method, "client",
+                                  peer=self._addr,
+                                  request_bytes=len(request), push=False)
         if fault.active():
             fault.client_intercept(service, method, self._addr)
         if _race.enabled():
@@ -1950,19 +2030,14 @@ class Channel:
         if rc != 0:
             text = errbuf.value.decode(errors="replace")
             if rec:
-                _record_client_call(service, method, self._addr, t0, wall,
-                                    len(request), 0, rc, text,
-                                    tag="stream")
+                _record_client_call(sp, 0, rc, text, tag="stream")
             raise RpcError(rc, text)
         try:
             out = ctypes.string_at(rsp, rsp_len.value)
         finally:
             self._lib.brt_free(rsp)
         if rec:
-            obs.counter("stream_creates").add(1)
-            _record_client_call(service, method, self._addr, t0, wall,
-                                len(request), len(out), 0, "",
-                                tag="stream")
+            _record_client_call(sp, len(out), 0, "", tag="stream")
         _handles.note_create("stream", sid.value)
         if receiver is not None:
             # Registration drains any frames the server raced ahead of
@@ -1977,6 +2052,10 @@ class Channel:
             self._ptr = None
 
 
+#: ``module @brt_gather_rows ...``: the builtin builders name their module
+_MLIR_MODULE = re.compile(r"module @(?:brt_)?(\w+)")
+
+
 class DeviceExecutable:
     """A compiled StableHLO program launched via the native executable tier
     (cpp/device/pjrt_executable.cc) — no JAX in the launch path."""
@@ -1985,6 +2064,9 @@ class DeviceExecutable:
         self._lib = lib
         self._ptr = ptr
         self.num_outputs = lib.brt_device_executable_num_outputs(ptr)
+        # the launch's span; DeviceClient.compile names it after the
+        # program: dev.execute.<module name less its brt_>
+        self._span_name = "dev.execute.program"
 
     def execute(self, args, nreplicas: int = 1):
         """args: flat list of buffer handles, row-major [replica][arg].
@@ -1995,8 +2077,13 @@ class DeviceExecutable:
         a = (ctypes.c_uint64 * len(args))(*args)
         outs = (ctypes.c_uint64 * (nreplicas * self.num_outputs))()
         errbuf = ctypes.create_string_buffer(512)
-        rc = self._lib.brt_device_execute(
-            self._ptr, a, nargs, nreplicas, outs, len(outs), errbuf, 512)
+        sp = _rpcz.begin(self._span_name)
+        try:
+            rc = self._lib.brt_device_execute(
+                self._ptr, a, nargs, nreplicas, outs, len(outs), errbuf,
+                512)
+        finally:
+            _rpcz.end(sp)
         if rc != 0:
             raise RpcError(rc, errbuf.value.decode(errors="replace"))
         flat = list(outs)
@@ -2060,22 +2147,38 @@ class DeviceClient:
         """DMAs bytes (or a numpy array) into device memory; returns a
         buffer handle."""
         import numpy as np
-        if isinstance(data, np.ndarray):
+        sp = _rpcz.begin("dev.stage")
+        try:
+            if isinstance(data, np.ndarray):
+                if dims is None:
+                    dims = list(data.shape)
+                if dtype == "u8" and data.dtype != np.uint8:
+                    dtype = {"float32": "f32", "int32": "i32"}.get(
+                        data.dtype.name, dtype)
+                cp = _rpcz.begin("dev.stage.tobytes", data.nbytes, True)
+                data = np.ascontiguousarray(data).tobytes()
+                _rpcz.end(cp)
             if dims is None:
-                dims = list(data.shape)
-            if dtype == "u8" and data.dtype != np.uint8:
-                dtype = {"float32": "f32", "int32": "i32"}.get(
-                    data.dtype.name, dtype)
-            data = np.ascontiguousarray(data).tobytes()
-        if dims is None:
-            dims = [len(data)]
-        errbuf = ctypes.create_string_buffer(512)
-        d = (ctypes.c_int64 * len(dims))(*dims)
-        h = self._lib.brt_device_stage_shaped(
-            self._ptr, data, len(data), device_index, self.DTYPE[dtype], d,
-            len(dims), errbuf, 512)
-        if h == 0:
-            raise RpcError(5002, errbuf.value.decode(errors="replace"))
+                dims = [len(data)]
+            errbuf = ctypes.create_string_buffer(512)
+            d = (ctypes.c_int64 * len(dims))(*dims)
+            stamps, slot = None, 0
+            if sp is not None:
+                stamps, slot = (ctypes.c_int64 * 2)(), _late.take()
+            h = self._lib.brt_device_stage_shaped(
+                self._ptr, data, len(data), device_index,
+                self.DTYPE[dtype], d, len(dims), errbuf, 512, stamps, slot)
+            if h == 0:
+                raise RpcError(5002, errbuf.value.decode(errors="replace"))
+            if sp is not None:
+                t0, copied = stamps
+                _rpcz.record("dev.stage.pool_copy", t0, copied, len(data),
+                             True)
+                # BufferFromHostBuffer called -> the plug-in done with
+                # the host block: the transfer, which outlives this call
+                _rpcz.record_late("dev.stage.h2d", copied, slot, len(data))
+        finally:
+            _rpcz.end(sp, len(data))
         return h
 
     def fetch(self, handle: int) -> bytes:
@@ -2086,15 +2189,30 @@ class DeviceClient:
         out = ctypes.c_void_p()
         out_len = ctypes.c_size_t()
         errbuf = ctypes.create_string_buffer(512)
-        rc = self._lib.brt_device_fetch(
-            self._ptr, handle, ctypes.byref(out), ctypes.byref(out_len),
-            errbuf, 512)
-        if rc != 0:
-            raise RpcError(rc, errbuf.value.decode(errors="replace"))
+        sp = _rpcz.begin("dev.fetch")
         try:
-            return ctypes.string_at(out, out_len.value)
+            stamps = (ctypes.c_int64 * 5)() if sp is not None else None
+            rc = self._lib.brt_device_fetch(
+                self._ptr, handle, ctypes.byref(out),
+                ctypes.byref(out_len), errbuf, 512, stamps)
+            if rc != 0:
+                raise RpcError(rc, errbuf.value.decode(errors="replace"))
+            n = out_len.value
+            if sp is not None:
+                t0, landed, repacked, flat, moved = stamps
+                _rpcz.record("dev.fetch.d2h", t0, landed, n)
+                _rpcz.record("dev.fetch.repack", landed, repacked, moved,
+                             True)
+                _rpcz.record("dev.fetch.copy_out", repacked, flat, n, True)
+            try:
+                cp = _rpcz.begin("dev.fetch.copy_out", n, True)
+                raw = ctypes.string_at(out, n)
+                _rpcz.end(cp)
+                return raw
+            finally:
+                self._lib.brt_free(out)
         finally:
-            self._lib.brt_free(out)
+            _rpcz.end(sp, out_len.value)
 
     def release(self, handle: int) -> None:
         self._lib.brt_device_release(handle)
@@ -2118,7 +2236,11 @@ class DeviceClient:
             errbuf, 1024)
         if not ptr:
             raise RpcError(5003, errbuf.value.decode(errors="replace"))
-        return DeviceExecutable(self._lib, ptr)
+        exe = DeviceExecutable(self._lib, ptr)
+        named = _MLIR_MODULE.search(mlir_text)
+        if named:
+            exe._span_name = "dev.execute." + named.group(1)
+        return exe
 
     def close(self) -> None:
         if self._ptr:

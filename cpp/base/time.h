@@ -1,6 +1,7 @@
 // Monotonic/realtime clock helpers (reference: src/butil/time.h).
 #pragma once
 
+#include <atomic>
 #include <cstdint>
 #include <ctime>
 
@@ -13,6 +14,23 @@ inline int64_t monotonic_ns() {
 }
 
 inline int64_t monotonic_us() { return monotonic_ns() / 1000; }
+
+// Late stamps: the end of work that outlives the call which started it (a
+// response's last byte handed to the socket, an H2D transfer done with its
+// host buffer). Whoever traces such work picks a slot, zeroes it, hands it
+// down with the call and reads it when it looks at the span; whoever
+// finishes the work stamps it, from any thread, with one store. Slot 0
+// means "not asked for". The span itself lives in the asker's store: this
+// table holds clock readings and nothing else, so a slot reused before a
+// very late finish (65,535 asks later) reads that finish's time.
+constexpr uint32_t kLateStampSlots = 1u << 16;
+inline std::atomic<int64_t> g_late_stamps[kLateStampSlots];
+
+inline void stamp_late(uint32_t slot) {
+  if (slot != 0 && slot < kLateStampSlots) {
+    g_late_stamps[slot].store(monotonic_ns(), std::memory_order_release);
+  }
+}
 
 inline int64_t realtime_us() {
   timespec ts;

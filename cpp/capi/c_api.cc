@@ -1,5 +1,6 @@
 #include "capi/c_api.h"
 
+#include <cstddef>
 #include <cstdlib>
 #include <cstring>
 #include <memory>
@@ -14,6 +15,7 @@
 #include "fiber/sync.h"
 #include "rpc/channel.h"
 #include "rpc/server.h"
+#include "rpc/span.h"
 #include "transport/socket.h"
 
 namespace {
@@ -33,6 +35,7 @@ class CService : public Service {
                   Closure done) override {
     auto* sess = new CSession{cntl, response, std::move(done)};
     const std::string req = request.to_string();
+    cntl->stamps.handler_ns = monotonic_ns();
     handler_(user_, method.c_str(), req.data(), req.size(), sess);
   }
 
@@ -71,6 +74,18 @@ void group_notify(CCallGroup* g) {
 // marks completion (releasing any registered call groups), then signals
 // the CountdownEvent; join/destroy wait on it before reading
 // cntl/response or freeing, so completion never races the caller.
+// brt_call_trace_next's ids, until this thread's next call takes them.
+thread_local uint64_t tls_next_trace_id = 0;
+thread_local uint64_t tls_next_span_id = 0;
+
+void take_call_trace(Controller* cntl) {
+  if (tls_next_trace_id == 0) return;
+  cntl->trace_id = tls_next_trace_id;
+  cntl->span_id = tls_next_span_id;
+  cntl->caller_owns_span = true;
+  tls_next_trace_id = tls_next_span_id = 0;
+}
+
 struct CCall {
   Controller cntl;
   IOBuf response;
@@ -155,24 +170,73 @@ void brt_server_destroy(void* server) {
   brt_capi::handle_dec(HandleKind::kServer);
 }
 
+uint64_t brt_session_trace(void* session, uint64_t* parent_span_id,
+                           int64_t* stamps) {
+  Controller* cntl = static_cast<CSession*>(session)->cntl;
+  if (parent_span_id != nullptr) *parent_span_id = cntl->parent_span_id;
+  if (stamps != nullptr) {
+    stamps[0] = cntl->stamps.first_byte_ns;
+    stamps[1] = cntl->stamps.complete_ns;
+    stamps[2] = cntl->stamps.dispatch_ns;
+    stamps[3] = cntl->stamps.handler_ns;
+  }
+  return cntl->trace_id;
+}
+
+int64_t* brt_late_stamps(size_t* nslots) {
+  static_assert(sizeof(std::atomic<int64_t>) == sizeof(int64_t),
+                "a late stamp is read as a plain int64");
+  *nslots = brt::kLateStampSlots;
+  return reinterpret_cast<int64_t*>(brt::g_late_stamps);
+}
+
+void brt_call_trace_next(uint64_t trace_id, uint64_t span_id) {
+  tls_next_trace_id = trace_id;
+  tls_next_span_id = span_id;
+}
+
+namespace {
+
+// The response is in its buffer (`copied` bytes of it by memcpy): what a
+// tracing binding asked to know, before `done` sends it.
+void note_responded(CSession* sess, size_t copied, int64_t* stamps_ns,
+                    uint32_t written_slot) {
+  RequestStamps& st = sess->cntl->stamps;
+  st.written_slot = written_slot;
+  if (stamps_ns != nullptr) {
+    stamps_ns[0] = st.respond_ns;
+    stamps_ns[1] = monotonic_ns();
+    stamps_ns[2] = int64_t(copied);
+  }
+}
+
+}  // namespace
+
 void brt_session_respond(void* session, const void* data, size_t len,
-                         int error_code, const char* error_text) {
+                         int error_code, const char* error_text,
+                         int64_t* stamps_ns, uint32_t written_slot) {
   auto* sess = static_cast<CSession*>(session);
+  sess->cntl->stamps.respond_ns = monotonic_ns();
+  size_t copied = 0;
   if (error_code != 0) {
     sess->cntl->SetFailed(error_code, "%s",
                           error_text ? error_text : "handler error");
   } else if (data != nullptr && len > 0) {
     sess->response->append(data, len);
+    copied = len;
   }
+  note_responded(sess, copied, stamps_ns, written_slot);
   Closure done = std::move(sess->done);
   delete sess;
   done();
 }
 
 void brt_session_respond_iobuf(void* session, const void* iobuf,
-                               int error_code, const char* error_text) {
+                               int error_code, const char* error_text,
+                               int64_t* stamps_ns, uint32_t written_slot) {
   auto* sess = static_cast<CSession*>(session);
   auto* io = static_cast<const brt_capi::CIobuf*>(iobuf);
+  sess->cntl->stamps.respond_ns = monotonic_ns();
   if (error_code != 0) {
     sess->cntl->SetFailed(error_code, "%s",
                           error_text ? error_text : "handler error");
@@ -182,6 +246,7 @@ void brt_session_respond_iobuf(void* session, const void* iobuf,
     // drops the last ref.
     sess->response->append(io->buf);
   }
+  note_responded(sess, 0, stamps_ns, written_slot);  // blocks shared
   Closure done = std::move(sess->done);
   delete sess;
   done();
@@ -219,6 +284,7 @@ int brt_channel_call(void* channel, const char* service, const char* method,
                      size_t* rsp_len, char* errbuf, size_t errbuf_len) {
   auto* c = static_cast<CChannel*>(channel);
   Controller cntl;
+  take_call_trace(&cntl);
   IOBuf request, response;
   if (req && req_len) request.append(req, req_len);
   c->channel->CallMethod(service, method, &cntl, request, &response,
@@ -249,6 +315,7 @@ void* brt_channel_call_iobuf(void* channel, const char* service,
                              size_t errbuf_len) {
   auto* c = static_cast<CChannel*>(channel);
   Controller cntl;
+  take_call_trace(&cntl);
   IOBuf request, response;
   if (req_iobuf != nullptr) {
     // Shares the request blocks (refcount bump): borrowed numpy-backed
@@ -288,6 +355,7 @@ void* brt_channel_call_start_opts(void* channel, const char* service,
   auto* call = new CCall;
   brt_capi::handle_inc(HandleKind::kCall);
   call->cntl.timeout_ms = timeout_ms;  // INT64_MIN inherits the channel
+  take_call_trace(&call->cntl);
   IOBuf request;
   if (req && req_len) request.append(req, req_len);
   // The done closure runs exactly once, in a fiber, after cntl/response
@@ -317,6 +385,7 @@ void* brt_channel_call_start_iobuf(void* channel, const char* service,
   auto* call = new CCall;
   brt_capi::handle_inc(HandleKind::kCall);
   call->cntl.timeout_ms = timeout_ms;  // INT64_MIN inherits the channel
+  take_call_trace(&call->cntl);
   IOBuf request;
   if (req_iobuf != nullptr) {
     request.append(static_cast<const brt_capi::CIobuf*>(req_iobuf)->buf);
@@ -600,11 +669,13 @@ uint64_t brt_device_stage(void* client, const void* data, size_t len,
 }
 
 int brt_device_fetch(void* client, uint64_t handle, void** out,
-                     size_t* out_len, char* errbuf, size_t errbuf_len) {
+                     size_t* out_len, char* errbuf, size_t errbuf_len,
+                     int64_t* stamps_ns) {
   brt::IOBuf buf;
   std::string err;
+  if (stamps_ns != nullptr) stamps_ns[0] = brt::monotonic_ns();
   int rc = static_cast<brt::PjrtClient*>(client)->StageFromDevice(
-      handle, &buf, &err);
+      handle, &buf, &err, stamps_ns != nullptr ? stamps_ns + 1 : nullptr);
   if (rc != 0) {
     if (errbuf && errbuf_len) snprintf(errbuf, errbuf_len, "%s", err.c_str());
     return rc;
@@ -616,6 +687,11 @@ int brt_device_fetch(void* client, uint64_t handle, void** out,
     return ENOMEM;
   }
   buf.copy_to(mem, n);
+  if (stamps_ns != nullptr) {
+    // StageFromDevice left [1] landed, [2] repacked, [3] repacked bytes
+    stamps_ns[4] = stamps_ns[3];
+    stamps_ns[3] = brt::monotonic_ns();
+  }
   *out = mem;
   *out_len = n;
   return 0;
@@ -628,7 +704,8 @@ int brt_device_release(uint64_t handle) {
 uint64_t brt_device_stage_shaped(void* client, const void* data, size_t len,
                                  int device_index, int dtype,
                                  const int64_t* dims, size_t ndims,
-                                 char* errbuf, size_t errbuf_len) {
+                                 char* errbuf, size_t errbuf_len,
+                                 int64_t* stamps_ns, uint32_t done_slot) {
   if (dtype < 0 || dtype > 2) {
     if (errbuf && errbuf_len) snprintf(errbuf, errbuf_len, "bad dtype");
     return 0;
@@ -642,6 +719,7 @@ uint64_t brt_device_stage_shaped(void* client, const void* data, size_t len,
   // region below is pinned by the transfer until its done event.
   brt::IOBuf buf;
   size_t cap = 0;
+  if (stamps_ns != nullptr) stamps_ns[0] = brt::monotonic_ns();
   char* flat = static_cast<char*>(
       brt::DeviceBlockPool::singleton().Acquire(len ? len : 1, &cap));
   if (flat == nullptr) {
@@ -649,12 +727,13 @@ uint64_t brt_device_stage_shaped(void* client, const void* data, size_t len,
     return 0;
   }
   memcpy(flat, data, len);
+  if (stamps_ns != nullptr) stamps_ns[1] = brt::monotonic_ns();
   buf.append_user_data(flat, len, brt::DeviceBlockPool::IOBufDeleter,
                        reinterpret_cast<void*>(uintptr_t(cap)));
   std::string err;
   uint64_t h = static_cast<brt::PjrtClient*>(client)->StageToDeviceShaped(
       buf, device_index, brt::PjrtClient::DType(dtype),
-      std::vector<int64_t>(dims, dims + ndims), &err);
+      std::vector<int64_t>(dims, dims + ndims), &err, done_slot);
   if (h == 0 && errbuf && errbuf_len) {
     snprintf(errbuf, errbuf_len, "%s", err.c_str());
   }

@@ -42,8 +42,37 @@ int brt_server_set_concurrency_limiter(void* server, const char* name,
 // adaptive gauge for the native path.
 int brt_server_max_concurrency(void* server);
 
+// stamps_ns (may be NULL; CLOCK_MONOTONIC ns) receives [0] respond
+// entered, [1] the response is in its buffer, [2] the bytes that took a
+// memcpy. written_slot (0: none): the late stamp (brt_late_stamps) taken
+// when the response's last byte has been handed to the socket.
 void brt_session_respond(void* session, const void* data, size_t len,
-                         int error_code, const char* error_text);
+                         int error_code, const char* error_text,
+                         int64_t* stamps_ns, uint32_t written_slot);
+
+// ---- request tracing (rpc/span.h; all times CLOCK_MONOTONIC ns) ----
+
+// Where the in-flight request behind `session` has been (call inside the
+// handler). Returns the trace id that came over the wire (0: none);
+// *parent_span_id (may be NULL): the client's span id that came with it.
+// stamps[0..3] (may be NULL): read event that brought the frame's first
+// byte; frame whole; the service entered (the request is then flattened
+// into the handler's contiguous buffer, one copy); the handler called. A
+// binding asks with both NULL first and for the rest only where it traces.
+uint64_t brt_session_trace(void* session, uint64_t* parent_span_id,
+                           int64_t* stamps);
+// Late stamps: the end of work that outlives the call which started it
+// (brt_session_respond's written_slot, brt_device_stage_shaped's
+// done_slot). The table of *nslots CLOCK_MONOTONIC ns readings lives as
+// long as the library. A tracing binding picks a slot in [1, *nslots),
+// zeroes it, passes it with the call and reads it later (0: not finished
+// yet); slot 0 asks for nothing. The core stamps with one store from
+// whichever thread finishes the work.
+int64_t* brt_late_stamps(size_t* nslots);
+// The next brt_channel_call* made by THIS thread carries trace_id /
+// span_id on the wire as the caller's own span: the server's spans join
+// it, and the channel records no native span for the call.
+void brt_call_trace_next(uint64_t trace_id, uint64_t span_id);
 
 // ---- client ----
 
@@ -282,8 +311,10 @@ void* brt_call_join_iobuf(void* call, int* error_code, char* errbuf,
 // Responds with the iobuf's blocks shared into the RPC response (no
 // payload copy; borrowed blocks stay pinned until the socket write
 // drains).  The iobuf handle is NOT consumed — destroy it after.
+// stamps_ns / written_slot: as brt_session_respond ([2] is 0 here).
 void brt_session_respond_iobuf(void* session, const void* iobuf,
-                               int error_code, const char* error_text);
+                               int error_code, const char* error_text,
+                               int64_t* stamps_ns, uint32_t written_slot);
 // Batched ordered writes: each iobuf is ONE framed stream message,
 // written in order with a single ABI crossing for the batch.  Stops at
 // the first failing write: returns its error code with *nwritten the
@@ -389,9 +420,12 @@ uint64_t brt_device_stage(void* client, const void* data, size_t len,
                           int device_index, char* errbuf, size_t errbuf_len);
 // DMAs the buffer behind handle back to host. *out is malloc'd (free with
 // brt_free); the calling fiber (or thread) parks while the DMA runs.
-// Returns 0 on success.
+// Returns 0 on success. stamps_ns (may be NULL; CLOCK_MONOTONIC ns)
+// receives [0] start, [1] D2H landed, [2] layout repacked, [3] copied
+// into *out, and [4] the bytes the repack moved (0: landed row-major).
 int brt_device_fetch(void* client, uint64_t handle, void** out,
-                     size_t* out_len, char* errbuf, size_t errbuf_len);
+                     size_t* out_len, char* errbuf, size_t errbuf_len,
+                     int64_t* stamps_ns);
 // Frees the device buffer behind handle. Returns 0, or EINVAL if stale.
 int brt_device_release(uint64_t handle);
 void brt_device_client_destroy(void* client);
@@ -399,10 +433,16 @@ void brt_device_client_destroy(void* client);
 // ---- compiled execution (device/pjrt_executable.h) ----
 // Shaped staging for executable arguments. dtype: 0=u8, 1=f32, 2=i32.
 // len must equal product(dims)*elemsize. Returns a handle (0 on failure).
+// stamps_ns (may be NULL; CLOCK_MONOTONIC ns) receives [0] start, [1]
+// data copied into a registered pool block and BufferFromHostBuffer
+// about to be called. That call only queues the transfer: done_slot (0:
+// none) is the late stamp (brt_late_stamps) taken when the plug-in
+// reports it is done with the host block — the transfer's end.
 uint64_t brt_device_stage_shaped(void* client, const void* data, size_t len,
                                  int device_index, int dtype,
                                  const int64_t* dims, size_t ndims,
-                                 char* errbuf, size_t errbuf_len);
+                                 char* errbuf, size_t errbuf_len,
+                                 int64_t* stamps_ns, uint32_t done_slot);
 // Textual StableHLO from the builtin builders (device/pjrt_executable.h).
 // kind: "add"|"reduce_sum"|"all_reduce_sum"|"all_gather" (p0=n,
 // p1=replicas) or "gather_rows"|"scatter_sub" (p0=rows, p1=dim, p2=k).

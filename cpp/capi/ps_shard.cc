@@ -83,6 +83,7 @@ class CPsService : public Service {
     }
     auto* sess = new CSession{cntl, response, std::move(done)};
     const std::string req = request.to_string();
+    cntl->stamps.handler_ns = monotonic_ns();
     fallback_(user_, method.c_str(), req.data(), req.size(), sess);
   }
 

@@ -13,6 +13,7 @@
 #include <unordered_map>
 
 #include "base/logging.h"
+#include "base/time.h"
 #include "device/pjrt_args.h"
 #include "fiber/butex.h"
 #include "third_party/pjrt/pjrt_c_api.h"
@@ -491,10 +492,12 @@ struct HostPin {
   IOBuf pinned;
   const PjrtApi* api;
   PJRT_Event* done;
+  uint32_t done_slot;  // late stamp (base/time.h) for this moment, or 0
 };
 
 void ReleaseHostPin(PJRT_Error* err, void* user_arg) {
   auto* pin = static_cast<HostPin*>(user_arg);
+  stamp_late(pin->done_slot);
   if (err != nullptr) {
     BRT_LOG(ERROR) << "H2D done-with-host-buffer event failed: "
                    << pin->api->ConsumeError(err);
@@ -516,7 +519,8 @@ uint64_t PjrtClient::StageToDevice(const IOBuf& data, int device_index,
 uint64_t PjrtClient::StageToDeviceShaped(const IOBuf& data, int device_index,
                                          DType dtype,
                                          const std::vector<int64_t>& dims,
-                                         std::string* error) {
+                                         std::string* error,
+                                         uint32_t done_slot) {
   if (device_index < 0 || device_index >= addressable_device_count()) {
     if (error) *error = "bad device index";
     return 0;
@@ -574,8 +578,8 @@ uint64_t PjrtClient::StageToDeviceShaped(const IOBuf& data, int device_index,
   }
   // Pin the host blocks until the plugin is done DMA-ing from them.
   if (args.done_with_host_buffer != nullptr) {
-    auto* pin =
-        new HostPin{std::move(src), api_, args.done_with_host_buffer};
+    auto* pin = new HostPin{std::move(src), api_,
+                            args.done_with_host_buffer, done_slot};
     auto rargs = BRT_PJRT_ARGS(PJRT_Event_OnReady_Args);
     rargs.event = args.done_with_host_buffer;
     rargs.callback = &ReleaseHostPin;
@@ -586,6 +590,8 @@ uint64_t PjrtClient::StageToDeviceShaped(const IOBuf& data, int device_index,
       // Conservatively keep the pin (leak) rather than risk a
       // use-after-free DMA; this path indicates a broken plugin.
     }
+  } else {
+    stamp_late(done_slot);  // the plugin copied before it returned
   }
   return DeviceBufferRegistry::Register(api_, args.buffer, device_index,
                                         int(dtype));
@@ -733,7 +739,7 @@ char* PjrtClient::RepackDeviceLayout(PJRT_Buffer* buf, char* src, size_t n,
 }
 
 int PjrtClient::StageFromDevice(uint64_t handle, IOBuf* out,
-                                std::string* error) {
+                                std::string* error, int64_t* stamps_ns) {
   // Pin across the blocking DMA: a concurrent Release of the same handle
   // (the "ship the handle" pattern) must not destroy the buffer mid-read.
   PJRT_Buffer* buf = DeviceBufferRegistry::Pin(handle);
@@ -789,7 +795,12 @@ int PjrtClient::StageFromDevice(uint64_t handle, IOBuf* out,
   // another permutation, while the PS tier's wide tables land row-major.
   // Un-permute host-side into dense row-major so callers always see
   // numpy-compatible bytes.
+  if (stamps_ns != nullptr) stamps_ns[0] = monotonic_ns();
   char* repacked = RepackDeviceLayout(buf, dst, n, &cap);
+  if (stamps_ns != nullptr) {
+    stamps_ns[1] = monotonic_ns();
+    stamps_ns[2] = repacked != nullptr ? int64_t(n) : 0;
+  }
   unpin();
   if (repacked != nullptr) dst = repacked;
   out->append_user_data(dst, n, DeviceBlockPool::IOBufDeleter,

@@ -190,18 +190,24 @@ class PjrtClient {
 
   // Shaped variant for executable arguments: stages `data` as an array of
   // `dtype` with the given dims (byte size must match). Same zero-copy /
-  // host-pin behavior as StageToDevice.
+  // host-pin behavior as StageToDevice. `done_slot` (0: none) is the late
+  // stamp (base/time.h) taken when the plugin is done with the host
+  // memory: the transfer's end, usually after this call has returned.
   uint64_t StageToDeviceShaped(const IOBuf& data, int device_index,
                                DType dtype,
                                const std::vector<int64_t>& dims,
-                               std::string* error);
+                               std::string* error, uint32_t done_slot = 0);
 
   // DMAs the device buffer behind `handle` back to host, landing the bytes
   // directly in a fresh block appended to `out` as user data with
   // meta=handle — no intermediate host copy, and the device buffer stays
   // alive (resident in HBM) until the handle is released. The calling
   // fiber parks while the DMA runs. Returns 0 or errno-style code.
-  int StageFromDevice(uint64_t handle, IOBuf* out, std::string* error);
+  // stamps_ns (may be null; CLOCK_MONOTONIC ns): [0] the D2H landed in
+  // the host block, [1] the layout repack is done, [2] bytes it moved (0:
+  // the landing was row-major already).
+  int StageFromDevice(uint64_t handle, IOBuf* out, std::string* error,
+                      int64_t* stamps_ns = nullptr);
 
   // Synchronous convenience: device round trip (H2D then D2H), releasing
   // the device buffer afterwards. The fiber parks during both DMAs.

@@ -121,7 +121,8 @@ void Channel::CallMethod(const std::string& service, const std::string& method,
   c.remaining_retries = max_retry;
   c.abs_deadline_us = timeout_ms < 0 ? -1 : c.start_us + timeout_ms * 1000;
 
-  if (cntl->trace_id != 0 || SpanShouldSample()) {
+  if (!cntl->caller_owns_span &&
+      (cntl->trace_id != 0 || SpanShouldSample())) {
     auto* sp = new Span;
     sp->trace_id = cntl->trace_id ? cntl->trace_id : SpanRandomId();
     sp->span_id = SpanRandomId();

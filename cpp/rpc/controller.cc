@@ -87,6 +87,8 @@ void Controller::Reset() {
   connection_type = -1;
   call = Call();
   trace_id = span_id = parent_span_id = 0;
+  caller_owns_span = false;
+  stamps = RequestStamps();
 }
 
 namespace {

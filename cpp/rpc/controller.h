@@ -111,6 +111,12 @@ class Controller {
   uint64_t trace_id = 0;
   uint64_t span_id = 0;
   uint64_t parent_span_id = 0;
+  // Client: the caller records this call's span itself under `span_id`
+  // (a binding's tree); the ids go on the wire as they are and the
+  // channel opens no native span of its own.
+  bool caller_owns_span = false;
+  // Server: where this request was, and when (span.h).
+  RequestStamps stamps;
 
   // ---- http-protocol calls (ChannelOptions.protocol = "http") ----
   // Request line + headers out, status + headers back (reference

@@ -62,18 +62,47 @@ struct RpcSession {
   Span* span = nullptr;  // rpcz (sampled or trace-propagated)
 };
 
+// What rides a traced response's write request until its last byte is
+// handed to the socket: the native rpcz span (or null) and the late-stamp
+// slot of a binding that traces the request (or 0).
+struct SendTrace {
+  Span* span;
+  uint32_t written_slot;
+};
+
+void OnResponseWritten(void* arg, int /*error*/) {
+  auto* t = static_cast<SendTrace*>(arg);
+  stamp_late(t->written_slot);
+  if (t->span != nullptr) {
+    const int64_t now_us = monotonic_us();
+    t->span->annotations.emplace_back(now_us, "written");
+    t->span->end_us = now_us;
+    SpanSubmit(std::move(*t->span));
+    delete t->span;
+  }
+  delete t;
+}
+
 void SendResponse(RpcSession* sess) {
   // brt_std cannot stream a response: a progressive attachment the
   // handler created must fail loudly for its writer, not buffer forever.
   AbortProgressiveIfAny(&sess->cntl);
-  const int64_t lat = monotonic_us() - sess->start_us;
+  const int64_t now_ns = monotonic_ns();
+  const int64_t lat = now_ns / 1000 - sess->start_us;
+  RequestStamps& st = sess->cntl.stamps;
+  if (st.respond_ns == 0) st.respond_ns = now_ns;
+  SendTrace* trace = nullptr;
+  if (sess->span != nullptr || st.written_slot != 0) {
+    trace = new SendTrace{sess->span, st.written_slot};
+  }
   if (sess->span != nullptr) {
-    sess->span->annotate("sending response");
-    sess->span->end_us = monotonic_us();
-    sess->span->error_code = sess->cntl.ErrorCode();
-    SpanSubmit(std::move(*sess->span));
-    delete sess->span;
-    sess->span = nullptr;
+    // The request's phase boundaries, as stamped where they happened.
+    Span* sp = sess->span;
+    sp->annotations.emplace_back(st.complete_ns / 1000, "frame complete");
+    sp->annotations.emplace_back(st.dispatch_ns / 1000, "dispatched");
+    sp->annotations.emplace_back(st.respond_ns / 1000, "respond");
+    sp->error_code = sess->cntl.ErrorCode();
+    sess->span = nullptr;  // the write request owns it now
   }
   RpcMeta meta;
   meta.type = MetaType::RESPONSE;
@@ -97,7 +126,12 @@ void SendResponse(RpcSession* sess) {
   IOBuf frame;
   PackFrame(&frame, meta, std::move(body));
   SocketUniquePtr ptr;
-  if (Socket::Address(sess->sock, &ptr) == 0) ptr->Write(&frame);
+  if (Socket::Address(sess->sock, &ptr) == 0) {
+    ptr->Write(&frame, 0, trace != nullptr ? OnResponseWritten : nullptr,
+               trace);
+  } else if (trace != nullptr) {
+    OnResponseWritten(trace, ECONNRESET);
+  }
   if (sess->mstatus) sess->mstatus->OnResponded(meta.error_code, lat);
   if (sess->server) {
     sess->server->ReturnSessionData(sess->cntl.session_local_data());
@@ -125,7 +159,7 @@ void SendErrorResponse(SocketId sock, uint64_t cid, int code,
 }
 
 void ProcessRequest(RpcMeta&& meta, IOBuf&& body, SocketId sock,
-                    Socket* s) {
+                    Socket* s, const RecvStamps& recv) {
   auto* server = static_cast<Server*>(s->user());
   if (!server || !server->IsRunning()) {
     SendErrorResponse(sock, meta.correlation_id, ELOGOFF, nullptr);
@@ -184,6 +218,8 @@ void ProcessRequest(RpcMeta&& meta, IOBuf&& body, SocketId sock,
   sess->server = server;
   sess->mstatus = ms;
   sess->start_us = monotonic_us();
+  sess->cntl.stamps.first_byte_ns = recv.first_byte_ns;
+  sess->cntl.stamps.complete_ns = recv.complete_ns;
   sess->cntl.set_remote_side(s->remote());
   sess->cntl.trace_id = meta.trace_id;
   sess->cntl.parent_span_id = meta.span_id;
@@ -200,9 +236,9 @@ void ProcessRequest(RpcMeta&& meta, IOBuf&& body, SocketId sock,
     sp->service = meta.service;
     sp->method = meta.method;
     sp->remote = s->remote();
-    sp->start_us = sess->start_us;
-    sp->start_real_us = realtime_us();
-    sp->annotate("request received");
+    // from the frame's first byte, not from where the parse ended
+    sp->start_us = recv.first_byte_ns / 1000;
+    sp->start_real_us = realtime_us() - (sess->start_us - sp->start_us);
     sess->span = sp;
     sess->cntl.trace_id = sp->trace_id;
     sess->cntl.span_id = sp->span_id;
@@ -237,11 +273,13 @@ void ProcessRequest(RpcMeta&& meta, IOBuf&& body, SocketId sock,
     // starve the fiber workers driving IO
     // (reference details/usercode_backup_pool.cpp:37).
     UsercodePool::singleton().Run([svc, method, sess] {
+      sess->cntl.stamps.dispatch_ns = monotonic_ns();
       svc->CallMethod(method, &sess->cntl, sess->request, &sess->response,
                       [sess] { SendResponse(sess); });
     });
     return;
   }
+  sess->cntl.stamps.dispatch_ns = monotonic_ns();
   svc->CallMethod(method, &sess->cntl, sess->request, &sess->response,
                   [sess] { SendResponse(sess); });
 }
@@ -258,6 +296,7 @@ void ProcessResponse(RpcMeta&& meta, IOBuf&& body) {
 }
 
 void BrtProcess(IOBuf&& msg, SocketId sock) {
+  const RecvStamps recv = CurrentRecvStamps();  // first: see the header
   RpcMeta meta;
   IOBuf body;
   const int rc = ParseFrame(&msg, &meta, &body);
@@ -269,7 +308,8 @@ void BrtProcess(IOBuf&& msg, SocketId sock) {
   }
   switch (meta.type) {
     case MetaType::REQUEST:
-      ProcessRequest(std::move(meta), std::move(body), sock, ptr.get());
+      ProcessRequest(std::move(meta), std::move(body), sock, ptr.get(),
+                     recv);
       break;
     case MetaType::RESPONSE:
       ProcessResponse(std::move(meta), std::move(body));

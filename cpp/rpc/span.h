@@ -41,6 +41,23 @@ struct Span {
   int64_t latency_us() const { return end_us - start_us; }
 };
 
+// Where one server-side request was, on CLOCK_MONOTONIC ns (base/time.h):
+// stamped unconditionally where each thing happens, no allocation. 0 =
+// not reached. These are the boundaries of the request's phases — receive,
+// scheduling, the handler, the send — for the native span's annotations
+// and for a bound language's span tree (capi brt_session_trace).
+struct RequestStamps {
+  int64_t first_byte_ns = 0;  // read event that brought the frame's 1st byte
+  int64_t complete_ns = 0;    // frame whole and cut from the read buffer
+  int64_t dispatch_ns = 0;    // the service's CallMethod entered
+  int64_t handler_ns = 0;     // a bound handler called (request flattened)
+  int64_t respond_ns = 0;     // respond entered
+  // Late-stamp slot (base/time.h) for "written": the response's last
+  // byte handed to the socket. Set by a binding that traces this request
+  // (capi brt_session_respond); 0 = not asked for.
+  uint32_t written_slot = 0;
+};
+
 // 0 disables tracing; N → ~N per million unsampled requests start traces.
 // A request arriving WITH a trace id is always recorded (propagation).
 extern uint32_t FLAGS_rpcz_sample_ppm;

@@ -7,6 +7,7 @@
 
 #include "base/logging.h"
 #include "base/object_pool.h"
+#include "base/time.h"
 #include "fiber/fiber.h"
 
 namespace brt {
@@ -80,15 +81,20 @@ struct ProcessArg {
   const Protocol* proto;
   IOBuf msg;
   SocketId sid;
+  RecvStamps stamps;
 };
+
+thread_local RecvStamps tls_recv_stamps;
 
 // One ProcessArg per dispatched message: pooled, not malloc'd (reference
 // runs these through butil::ObjectPool for the same reason).
-ProcessArg* GetProcessArg(const Protocol* proto, IOBuf&& msg, SocketId sid) {
+ProcessArg* GetProcessArg(const Protocol* proto, IOBuf&& msg, SocketId sid,
+                          const RecvStamps& stamps) {
   ProcessArg* a = ObjectPool<ProcessArg>::Get();
   a->proto = proto;
   a->msg = std::move(msg);
   a->sid = sid;
+  a->stamps = stamps;
   return a;
 }
 
@@ -99,6 +105,7 @@ void PutProcessArg(ProcessArg* a) {
 
 void* process_entry(void* argp) {
   auto* arg = static_cast<ProcessArg*>(argp);
+  tls_recv_stamps = arg->stamps;
   arg->proto->process(std::move(arg->msg), arg->sid);
   PutProcessArg(arg);
   return nullptr;
@@ -138,8 +145,14 @@ int cut_message(Socket* s, IOBuf* source, IOBuf* msg) {
 
 }  // namespace
 
+const RecvStamps& CurrentRecvStamps() { return tls_recv_stamps; }
+
 void* InputMessengerOnEdgeTriggered(Socket* s) {
   IOPortal& portal = s->read_buf;
+  // The bytes this event reads arrived no later than now: the first byte
+  // of a frame that starts in this event is stamped with it.
+  const int64_t event_ns = monotonic_ns();
+  if (portal.empty()) s->frame_first_byte_ns = event_ns;
   // Read to EAGAIN first; EOF/errors are acted on only AFTER dispatching any
   // complete messages already buffered (a peer may write a full request and
   // immediately close — the reference processes those too).
@@ -173,6 +186,9 @@ void* InputMessengerOnEdgeTriggered(Socket* s) {
       return nullptr;
     }
     s->messages_read.fetch_add(1, std::memory_order_relaxed);
+    const RecvStamps stamps{s->frame_first_byte_ns, monotonic_ns()};
+    // What is left in the buffer begins the next frame.
+    s->frame_first_byte_ns = event_ns;
     const Protocol& proto = g_protocols[pi];
     if (proto.is_ordered != nullptr && proto.is_ordered(msg)) {
       // Ordered frames (streams) are handed over NOW, in arrival order —
@@ -180,7 +196,7 @@ void* InputMessengerOnEdgeTriggered(Socket* s) {
       proto.process(std::move(msg), s->id());
       continue;
     }
-    batch.push_back(GetProcessArg(&proto, std::move(msg), s->id()));
+    batch.push_back(GetProcessArg(&proto, std::move(msg), s->id(), stamps));
   }
   if (pending_err != 0) {
     s->SetFailed(pending_err, "%s", pending_msg);

@@ -44,6 +44,17 @@ struct Protocol {
 // Registers at startup (not thread-safe vs traffic; mirror of the
 // reference's GlobalInitializeOrDie, global.cpp:409-589). Returns index.
 int RegisterProtocol(const Protocol& p);
+
+// When the message now being handed to Protocol::process arrived, on
+// CLOCK_MONOTONIC ns: the read event that brought its first byte, and the
+// moment it was cut whole from the read buffer. Valid on entry to
+// `process` (read it first: the values are the thread's, and the next
+// message dispatched on this thread replaces them).
+struct RecvStamps {
+  int64_t first_byte_ns = 0;
+  int64_t complete_ns = 0;
+};
+const RecvStamps& CurrentRecvStamps();
 const Protocol* GetProtocol(int index);
 int protocol_count();
 

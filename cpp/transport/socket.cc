@@ -31,7 +31,17 @@ Socket::WriteReq* GetWriteReq() {
   r->next.store(nullptr, std::memory_order_relaxed);
   r->cid = 0;
   r->raw = false;
+  r->on_written = nullptr;
+  r->written_arg = nullptr;
   return r;
+}
+
+// The request's bytes have left (or were dropped with `err`).
+void FireWritten(Socket::WriteReq* r, int err) {
+  if (r->on_written == nullptr) return;
+  Socket::WrittenFn fn = r->on_written;
+  r->on_written = nullptr;
+  fn(r->written_arg, err);
 }
 
 void PutWriteReq(Socket::WriteReq* r) {
@@ -366,16 +376,20 @@ int Socket::QueueOrFlush(WriteReq* req) {
   return FlushWriteChain(req, /*in_keepwrite_fiber=*/false);
 }
 
-int Socket::Write(IOBuf* data, fid_t cid) {
+int Socket::Write(IOBuf* data, fid_t cid, WrittenFn on_written,
+                  void* written_arg) {
   int err = failed_.load(std::memory_order_acquire);
   if (err != 0) {
     data->clear();
     if (cid != 0) fid_error(cid, err);
+    if (on_written != nullptr) on_written(written_arg, err);
     return err;
   }
   WriteReq* req = GetWriteReq();
   req->data.swap(*data);
   req->cid = cid;
+  req->on_written = on_written;
+  req->written_arg = written_arg;
   return QueueOrFlush(req);
 }
 
@@ -402,6 +416,7 @@ void* Socket::KeepWriteEntry(void* argp) {
     while (c) {
       Socket::WriteReq* n = c->next.load(std::memory_order_acquire);
       if (c->cid) fid_error(c->cid, ECONNRESET);
+      FireWritten(c, ECONNRESET);
       PutWriteReq(c);
       c = n;
     }
@@ -476,6 +491,7 @@ int Socket::FlushWriteChain(WriteReq* cur, bool in_keepwrite_fiber) {
       return err;
     }
     // cur fully written: advance or terminate.
+    FireWritten(cur, 0);
     WriteReq* next = AdvanceWriteChain(cur);
     if (next == nullptr) {
       // Chain drained: honor a pending graceful close. This is a Dekker
@@ -528,6 +544,7 @@ void Socket::ReleaseChainOnError(WriteReq* cur, int err) {
   // propagate err to each request's correlation id.
   while (cur != nullptr) {
     if (cur->cid != 0) fid_error(cur->cid, err);
+    FireWritten(cur, err);
     cur = AdvanceWriteChain(cur);
   }
 }

@@ -129,7 +129,13 @@ class Socket {
   // Wait-free write: steals *data. Thread/fiber-safe. On socket failure the
   // data is dropped and cid (if non-zero) receives fid_error(err).
   // Returns 0 if accepted (delivery still asynchronous).
-  int Write(IOBuf* data, fid_t cid = 0);
+  // `on_written(written_arg, error)`, if given, runs exactly once: when
+  // the last byte of *data has been handed to the fd (error 0), or when
+  // the data is dropped because the socket failed (the errno) — from
+  // whichever thread flushes, so it must not block.
+  using WrittenFn = void (*)(void* arg, int error);
+  int Write(IOBuf* data, fid_t cid = 0, WrittenFn on_written = nullptr,
+            void* written_arg = nullptr);
 
   // Hints that ~n more Write calls are imminent on this socket (the
   // messenger just dispatched a batch of n messages, each of which will
@@ -177,6 +183,10 @@ class Socket {
   // Last-matched protocol index for InputMessenger (reference keeps this on
   // the socket too, input_messenger.cpp:77).
   int preferred_protocol = -1;
+  // CLOCK_MONOTONIC ns of the read event that brought the first byte of
+  // the frame now at the head of read_buf (0: read_buf is empty). Only
+  // the connection's one read fiber touches it (input_messenger.cc).
+  int64_t frame_first_byte_ns = 0;
 
   // Per-protocol connection state (HTTP parser, h2 session, ...). Owned by
   // the socket: the destroyer runs at recycle (reference keeps
@@ -250,6 +260,8 @@ class Socket {
     // Bytes are already wire-format (TLS handshake records / encrypted):
     // the flusher must not run them through the session again.
     bool raw = false;
+    WrittenFn on_written = nullptr;
+    void* written_arg = nullptr;
     std::atomic<WriteReq*> next{nullptr};
   };
 

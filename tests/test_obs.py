@@ -14,7 +14,6 @@ from brpc_tpu.obs.vars import (
     Adder,
     LatencyRecorder,
     Maxer,
-    Miner,
     PassiveStatus,
     PerSecond,
     Registry,
@@ -51,14 +50,11 @@ def test_adder_semantics():
 
 
 def test_maxer_miner_semantics():
-    mx, mn = Maxer(), Miner()
+    mx = Maxer()
     assert mx.get_value() == 0  # empty -> 0, like bvar's default dump
-    assert mn.get_value() == 0
     for v in (3, 9, 1):
         mx.update(v)
-        mn.update(v)
     assert mx.get_value() == 9
-    assert mn.get_value() == 1
 
 
 def test_adder_across_threads():
@@ -345,6 +341,39 @@ def test_channel_call_records_spans_and_latency():
     finally:
         ch.close()
         srv.close()
+
+
+@pytest.mark.needs_native
+def test_a_handler_threads_cell_outlives_its_callbacks():
+    """A native thread enters Python through a ctypes callback with a
+    fresh thread state, and fresh thread-locals, every time; its id
+    stays.  The reducers keep a cell a thread, not one a request."""
+    from brpc_tpu import rpc
+
+    obs.reset_fabric_vars()
+    fresh, idents = [], set()
+    local = threading.local()
+
+    def echo(method, req):
+        fresh.append(not hasattr(local, "seen"))
+        local.seen = True
+        idents.add(threading.get_ident())
+        return req
+
+    srv = rpc.Server()
+    srv.add_service("Echo", echo)
+    port = srv.start("127.0.0.1:0")
+    ch = rpc.Channel(f"127.0.0.1:{port}")
+    try:
+        for _ in range(200):
+            ch.call("Echo", "Echo", b"x")
+    finally:
+        ch.close()
+        srv.close()
+    assert all(fresh)           # (why a threading.local would not do)
+    counter = obs.counter("rpc_server_in_bytes")
+    assert counter.get_value() == 200
+    assert len(counter._cells) <= len(idents) < 200
 
 
 @pytest.mark.needs_native
