@@ -27,6 +27,10 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from brpc_tpu.ops.flash_attention import (flash_attention,
+                                          supported as flash_supported)
+from brpc_tpu.ops.lowered import count_lowering
+
 Params = Dict[str, Any]
 
 
@@ -150,8 +154,9 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def attention(q, k, v, *, causal: bool = True):
-    """Grouped-query attention. q: [B,T,Hq,D], k/v: [B,T,Hkv,D]."""
+def dense_attention(q, k, v, *, causal: bool = True):
+    """Grouped-query attention with the scores materialised. q: [B,T,Hq,D],
+    k/v: [B,T,Hkv,D]."""
     b, t, hq, d = q.shape
     hkv = k.shape[2]
     group = hq // hkv
@@ -164,6 +169,31 @@ def attention(q, k, v, *, causal: bool = True):
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgts,bshd->bthgd", probs, v)
     return out.reshape(b, t, hq * d)
+
+
+def attention(q, k, v, *, causal: bool = True):
+    """Grouped-query attention, by the implementation the operands and the
+    platform being lowered for allow: the fused blockwise kernel
+    (``ops.flash_attention``, no [T,T] array in HBM in either pass) for
+    causal bf16 attention at shapes it supports when lowered for TPU, the
+    dense form for everything else. One traced function serves every
+    platform. ``attn_kernel_lowerings`` / ``attn_dense_lowerings`` count
+    which one each lowered program holds."""
+
+    def dense(q, k, v):
+        with jax.named_scope("attn.dense"):
+            return dense_attention(
+                count_lowering(q, "attn_dense_lowerings"), k, v,
+                causal=causal)
+
+    def kernel(q, k, v):
+        return flash_attention(
+            count_lowering(q, "attn_kernel_lowerings"), k, v, causal=causal)
+
+    if not (causal and q.dtype == k.dtype == v.dtype == jnp.bfloat16
+            and flash_supported(q.shape, k.shape, q.dtype)):
+        return dense(q, k, v)
+    return lax.platform_dependent(q, k, v, tpu=kernel, default=dense)
 
 
 def _layer(cfg: LlamaConfig, x: jax.Array, lp: Params, positions: jax.Array,

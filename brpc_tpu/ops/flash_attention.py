@@ -1,20 +1,38 @@
-"""Flash attention — pallas TPU kernel for the model hot path.
+"""Flash attention — Pallas TPU kernels for the model hot path, forward and
+backward.
 
-The attention score matrix never touches HBM: each grid program owns one
-[BLOCK_Q, D] query tile in VMEM and streams K/V tiles through the MXU with
-the online-softmax recurrence (running max / sum / accumulator). Causal
-programs stop at the diagonal tile, so the wasted-FLOPs triangle is skipped
-at tile granularity (guide: /opt/skills/guides/pallas_guide.md).
+No array shaped like the score matrix touches HBM in either pass. The
+forward kernel owns one [BLOCK_Q, D] query tile and streams K/V tiles
+through the MXU with the online-softmax recurrence (running max / sum /
+accumulator); it also returns each row's log-sum-exp, which with q, k, v and
+the output is all the backward pass keeps. The backward pass recomputes the
+probabilities tile by tile from q, k and the log-sum-exp: one kernel per
+query tile for dQ, one per KV head and key tile for dK and dV, which loops
+over the head's whole query group so that the GQA sum happens in its
+accumulator. Causal programs stop at the diagonal, so the wasted triangle is
+skipped at tile granularity, and only the tiles the diagonal crosses pay for
+a mask (guide: /opt/skills/guides/pallas_guide.md).
+
+Precision: the MXU gets its operands in the dtype they arrive in (bf16 on
+the training path) and accumulates in float32; scores, softmax statistics,
+``delta`` and every accumulator are float32; the probabilities and dS are
+cast to the operand dtype only as matmul operands.
 
 GQA layout matches brpc_tpu.models.llama: q [B, T, Hq, D], k/v
 [B, T, Hkv, D]; the kv head for q head h is h // (Hq // Hkv). Inside, the
-kernel works head-major ([B, H, T, D]): Mosaic wants the last two block
+kernels work head-major ([B, H, T, D]): Mosaic wants the last two block
 dimensions to be (a multiple of 8, a multiple of 128) or the whole array
 dimension, and a one-head block of the [B, T, H, D] layout has 1 against H
-there. The wrapper pays two transposes for it.
+there. The wrapper pays the transposes for it, outside the custom VJP, so
+the residuals are kept head-major and the backward pass repeats none. Per-row
+statistics travel as [B, H, T/BLOCK, 1, BLOCK]: a row vector per tile, whole
+in its last two dimensions whatever the block.
 
-``flash_attention(..., interpret=True)`` runs the same kernel through the
-pallas interpreter (CPU tests); on TPU leave it False.
+K and V of one head, and in the dK/dV kernel Q and dO of one query group,
+stay whole in VMEM (``supported`` bounds the sequence by that).
+
+``flash_attention(..., interpret=True)`` runs the same kernels through the
+Pallas interpreter (CPU tests); on TPU leave it False.
 """
 
 from __future__ import annotations
@@ -23,49 +41,267 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_MASKED = -1e30          # finite: exp(_MASKED - finite) is 0, never NaN
+_VMEM_LIMIT = 64 << 20   # of a v5e core's 128 MiB; Mosaic's default is 16
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: int,
-            seq_len: int, causal: bool, scale: float):
+def choose_block(t: int) -> int:
+    """The tile along a sequence of ``t``, queries and keys alike, in all
+    three kernels: 512 where it divides. On a v5e at T = 2,048, D = 128 each
+    kernel is within 2% of its best there (0.60 / 0.61 / 0.73 ms), 256 costs
+    the three 30–40% more and 128 2–3 times (PERF.md section 6, PR 27);
+    larger tiles gain nothing and waste more of the diagonal. A sequence
+    none of them divides is one tile."""
+    return next((c for c in (512, 256, 128) if t % c == 0), t)
+
+
+def supported(q_shape, kv_shape, dtype) -> bool:
+    """Whether the compiled kernels take these operands: tiles that fill the
+    MXU's 128 lanes, whole query groups, and a sequence whose resident K, V,
+    Q and dO (double-buffered by the pipeline) leave VMEM room for the
+    score tiles."""
+    _, t, hq, d = q_shape
+    hkv = kv_shape[2]
+    if d % 128 or t % 128 or hq % hkv:
+        return False
+    resident = 2 * 2 * (hq // hkv) * t * d * jnp.dtype(dtype).itemsize
+    return resident <= _VMEM_LIMIT // 2
+
+
+def _nt(a, b):
+    """a @ b.T on the MXU, float32 out."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _nn(a, b):
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def _scores(a, b, scale: float, masked: bool, q0, k0, q_axis: int):
+    """The float32 score tile ``a @ b.T * scale``. ``masked``: causally, for
+    a tile whose queries start at ``q0`` along ``q_axis`` and whose keys
+    start at ``k0`` along the other axis."""
+    s = _nt(a, b) * scale
+    if not masked:
+        return s
+    q_pos = q0 + lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k_pos = k0 + lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    return jnp.where(k_pos <= q_pos, s, _MASKED)
+
+
+def _key_tiles(qi, block_q: int, block_k: int, t: int, causal: bool):
+    """For query tile ``qi``: how many key tiles lie wholly at or below the
+    diagonal (no mask needed), and how many it attends to at all."""
+    n = t // block_k
+    if not causal:
+        return n, n
+    return (qi * block_q + 1) // block_k, pl.cdiv((qi + 1) * block_q, block_k)
+
+
+def _query_tiles(kj, block_q: int, block_k: int, t: int, causal: bool):
+    """For key tile ``kj``: the first query tile that attends to it, and the
+    first that sees all of it (no mask needed)."""
+    if not causal:
+        return 0, 0
+    return ((kj * block_k) // block_q,
+            jnp.minimum(pl.cdiv((kj + 1) * block_k - 1, block_q),
+                        t // block_q))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
+                causal: bool, scale: float):
+    block_q, d = q_ref.shape[2:]
+    t = k_ref.shape[2]
     qi = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32) * scale  # [BQ, D]
-    bq, d = q.shape
+    q = q_ref[0, 0]                                          # [BQ, D]
 
-    row = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-
-    n_kv_total = seq_len // block_k
-    if causal:
-        # tiles fully above the diagonal contribute nothing
-        last_row = qi * block_q + block_q - 1
-        n_kv = jnp.minimum((last_row // block_k) + 1, n_kv_total)
-    else:
-        n_kv = n_kv_total
-
-    def body(kj, carry):
+    def step(kj, carry, masked):
         m, l, acc = carry
-        k = k_ref[0, 0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, 0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # [BQ, BK]
-        if causal:
-            col = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(col <= row, s, -1e30)
+        rows = pl.ds(pl.multiple_of(kj * block_k, block_k), block_k)
+        k = k_ref[0, 0, rows, :]
+        v = v_ref[0, 0, rows, :]
+        s = _scores(q, k, scale, masked, qi * block_q, kj * block_k, 0)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
         l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jnp.dot(p, v,
-                                    preferred_element_type=jnp.float32)
+        acc = acc * alpha + _nn(p.astype(v.dtype), v)
         return m_new, l, acc
 
-    m0 = jnp.full((bq, 1), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, n_kv, body, (m0, l0, acc0))
-    out = acc / jnp.maximum(l, 1e-20)
-    o_ref[0, 0] = out.astype(o_ref.dtype)
+    n_clear, n_k = _key_tiles(qi, block_q, block_k, t, causal)
+    carry = (jnp.full((block_q, 1), -jnp.inf, jnp.float32),
+             jnp.zeros((block_q, 1), jnp.float32),
+             jnp.zeros((block_q, d), jnp.float32))
+    carry = lax.fori_loop(0, n_clear,
+                          functools.partial(step, masked=False), carry)
+    m, l, acc = lax.fori_loop(n_clear, n_k,
+                              functools.partial(step, masked=True), carry)
+    o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
+    lse_ref[0, 0, 0] = (m + jnp.log(l)).reshape(1, block_q)
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
+               block_k: int, causal: bool, scale: float):
+    block_q, d = q_ref.shape[2:]
+    t = k_ref.shape[2]
+    qi = pl.program_id(2)
+    q = q_ref[0, 0]
+    do = do_ref[0, 0]
+    lse = lse_ref[0, 0, 0].reshape(block_q, 1)
+    delta = delta_ref[0, 0, 0].reshape(block_q, 1)
+
+    def step(kj, dq, masked):
+        rows = pl.ds(pl.multiple_of(kj * block_k, block_k), block_k)
+        k = k_ref[0, 0, rows, :]
+        v = v_ref[0, 0, rows, :]
+        s = _scores(q, k, scale, masked, qi * block_q, kj * block_k, 0)
+        p = jnp.exp(s - lse)
+        ds = p * (_nt(do, v) - delta)
+        return dq + _nn(ds.astype(k.dtype), k)
+
+    n_clear, n_k = _key_tiles(qi, block_q, block_k, t, causal)
+    dq = lax.fori_loop(0, n_clear, functools.partial(step, masked=False),
+                       jnp.zeros((block_q, d), jnp.float32))
+    dq = lax.fori_loop(n_clear, n_k, functools.partial(step, masked=True),
+                       dq)
+    dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                dv_ref, *, block_q: int, causal: bool, scale: float):
+    """Scores are held transposed ([BK, BQ]), so the per-query statistics
+    broadcast as the row vectors they are stored as."""
+    group, t = q_ref.shape[1:3]
+    block_k, d = k_ref.shape[2:]
+    kj = pl.program_id(2)
+    k = k_ref[0, 0]
+    v = v_ref[0, 0]
+    first, clear = _query_tiles(kj, block_q, block_k, t, causal)
+
+    def step(qj, carry, g, masked):
+        dk, dv = carry
+        rows = pl.ds(pl.multiple_of(qj * block_q, block_q), block_q)
+        q = q_ref[0, g, rows, :]
+        do = do_ref[0, g, rows, :]
+        s = _scores(k, q, scale, masked, qj * block_q, kj * block_k, 1)
+        p = jnp.exp(s - lse_ref[0, g, qj])
+        dv = dv + _nn(p.astype(do.dtype), do)
+        ds = p * (_nt(v, do) - delta_ref[0, g, qj])
+        return dk + _nn(ds.astype(q.dtype), q), dv
+
+    carry = (jnp.zeros((block_k, d), jnp.float32),) * 2
+    for g in range(group):
+        carry = lax.fori_loop(
+            first, clear, functools.partial(step, g=g, masked=True), carry)
+        carry = lax.fori_loop(
+            clear, t // block_q, functools.partial(step, g=g, masked=False),
+            carry)
+    dk, dv = carry
+    dk_ref[0, 0] = (dk * scale).astype(dk_ref.dtype)
+    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+
+
+def _call(kernel, name, interpret, **kwargs):
+    return pl.pallas_call(
+        kernel, name=name, interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3,
+            vmem_limit_bytes=_VMEM_LIMIT),
+        **kwargs)
+
+
+def _forward(q, k, v, causal: bool, blocks: tuple, interpret: bool):
+    """Head-major q [B,Hq,T,D], k/v [B,Hkv,T,D] -> (o like q, float32 lse
+    [B,Hq,T/BQ,1,BQ])."""
+    b, hq, t, d = q.shape
+    group = hq // k.shape[1]
+    block_q, block_k = blocks
+    q_spec = pl.BlockSpec((1, 1, block_q, d), lambda bi, h, i: (bi, h, i, 0))
+    kv_spec = pl.BlockSpec((1, 1, t, d),
+                           lambda bi, h, i: (bi, h // group, 0, 0))
+    with jax.named_scope("attn.flash_fwd"):
+        return _call(
+            functools.partial(_fwd_kernel, block_k=block_k, causal=causal,
+                              scale=d ** -0.5),
+            "attn_flash_fwd", interpret,
+            grid=(b, hq, t // block_q),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=[q_spec,
+                       pl.BlockSpec((1, 1, 1, 1, block_q),
+                                    lambda bi, h, i: (bi, h, i, 0, 0))],
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                       jax.ShapeDtypeStruct((b, hq, t // block_q, 1, block_q),
+                                            jnp.float32)],
+        )(q, k, v)
+
+
+def _backward(q, k, v, o, lse, do, causal: bool, blocks: tuple,
+              interpret: bool):
+    b, hq, t, d = q.shape
+    hkv = k.shape[1]
+    group = hq // hkv
+    scale = d ** -0.5
+    block_q, block_k = blocks
+    with jax.named_scope("attn.flash_bwd"):
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                        axis=-1).reshape(lse.shape)
+
+        q_spec = pl.BlockSpec((1, 1, block_q, d),
+                              lambda bi, h, i: (bi, h, i, 0))
+        kv_spec = pl.BlockSpec((1, 1, t, d),
+                               lambda bi, h, i: (bi, h // group, 0, 0))
+        row_spec = pl.BlockSpec((1, 1, 1, 1, block_q),
+                                lambda bi, h, i: (bi, h, i, 0, 0))
+        dq = _call(
+            functools.partial(_dq_kernel, block_k=block_k, causal=causal,
+                              scale=scale),
+            "attn_flash_bwd_dq", interpret,
+            grid=(b, hq, t // block_q),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        )(q, k, v, do, lse, delta)
+
+        group_spec = pl.BlockSpec((1, group, t, d),
+                                  lambda bi, h, j: (bi, h, 0, 0))
+        kv_spec = pl.BlockSpec((1, 1, block_k, d),
+                               lambda bi, h, j: (bi, h, j, 0))
+        row_spec = pl.BlockSpec((1, group, t // block_q, 1, block_q),
+                                lambda bi, h, j: (bi, h, 0, 0, 0))
+        dk, dv = _call(
+            functools.partial(_dkv_kernel, block_q=block_q, causal=causal,
+                              scale=scale),
+            "attn_flash_bwd_dkv", interpret,
+            grid=(b, hkv, t // block_k),
+            in_specs=[group_spec, kv_spec, kv_spec, group_spec, row_spec,
+                      row_spec],
+            out_specs=[kv_spec, kv_spec],
+            out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                       jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        )(q, k, v, do, lse, delta)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _attend(q, k, v, causal, blocks, interpret):
+    return _forward(q, k, v, causal, blocks, interpret)[0]
+
+
+def _attend_fwd(q, k, v, causal, blocks, interpret):
+    o, lse = _forward(q, k, v, causal, blocks, interpret)
+    return o, (q, k, v, o, lse)
+
+
+def _attend_bwd(causal, blocks, interpret, residuals, do):
+    return _backward(*residuals, do, causal, blocks, interpret)
+
+
+_attend.defvjp(_attend_fwd, _attend_bwd)
 
 
 @functools.partial(
@@ -78,38 +314,18 @@ def flash_attention(
     v: jax.Array,
     *,
     causal: bool = True,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: int | None = None,
+    block_k: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """q: [B,T,Hq,D], k/v: [B,T,Hkv,D] -> [B,T,Hq*D] (llama.attention
-    contract)."""
+    contract), differentiable. The tile is chosen from T; ``block_q`` /
+    ``block_k`` override it (tests)."""
     b, t, hq, d = q.shape
-    hkv = k.shape[2]
-    group = hq // hkv
-    block_q = min(block_q, t)
-    block_k = min(block_k, t)
-    if t % block_q or t % block_k:
-        raise ValueError(f"seq {t} must divide blocks {block_q}/{block_k}")
-    scale = d ** -0.5
-
-    grid = (b, hq, t // block_q)
+    blocks = (min(block_q or choose_block(t), t),
+              min(block_k or choose_block(t), t))
+    if t % blocks[0] or t % blocks[1]:
+        raise ValueError(f"seq {t} must divide blocks {blocks}")
     q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))  # [B, H, T, D]
-    out = pl.pallas_call(
-        functools.partial(_kernel, block_q=block_q, block_k=block_k,
-                          seq_len=t, causal=causal, scale=scale),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda bi, h, qi: (bi, h, qi, 0)),
-            pl.BlockSpec((1, 1, t, d),
-                         lambda bi, h, qi: (bi, h // group, 0, 0)),
-            pl.BlockSpec((1, 1, t, d),
-                         lambda bi, h, qi: (bi, h // group, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, d),
-                               lambda bi, h, qi: (bi, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hq, t, d), q.dtype),
-        interpret=interpret,
-    )(q, k, v)
+    out = _attend(q, k, v, causal, blocks, interpret)
     return out.transpose(0, 2, 1, 3).reshape(b, t, hq * d)

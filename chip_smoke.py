@@ -12,8 +12,10 @@
 It drives the two halves of the main path once, through the entry points a
 user calls, at Llama-3-8B widths with seeded random weights:
 
-  kernel   the Pallas flash-attention kernel, compiled (not interpreted), at
-           Llama-3-8B head geometry against float32 ``llama.attention``.
+  kernel   the Pallas flash-attention kernels, forward and backward,
+           compiled (not interpreted), at Llama-3-8B head geometry: output,
+           dQ, dK and dV against float32 ``llama.dense_attention``, and one
+           forward + backward of kernels and dense form timed side by side.
   train    three ``llama.make_train_step`` steps (AdamW, donated state) on
            one seeded batch: loss finite and falling.
   ps       ``DevicePsShardServer`` holding Llama-3-8B's 128,256 x 4,096
@@ -212,6 +214,8 @@ def phase_train(sizes: Sizes, dry: bool) -> dict:
 
 
 def phase_kernel(sizes: Sizes, dry: bool) -> dict:
+    import functools
+
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -222,41 +226,71 @@ def phase_kernel(sizes: Sizes, dry: bool) -> dict:
     clock = Timer()
     _, out = _jax_devices(dry)
     b, t, hq, hkv, d = sizes.kernel_shape
-    kq, kk, kv = jax.random.split(jax.random.PRNGKey(SEED), 3)
+    kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(SEED), 4)
     q = jax.random.normal(kq, (b, t, hq, d), jnp.bfloat16)
     k = jax.random.normal(kk, (b, t, hkv, d), jnp.bfloat16)
     v = jax.random.normal(kv, (b, t, hkv, d), jnp.bfloat16)
+    cotangent = jax.random.normal(kw, (b, t, hq * d), jnp.float32)
     out["shape"] = list(sizes.kernel_shape)
     out["interpret"] = dry      # the Mosaic compiler exists only for TPUs
+
+    def forward_backward(attn):
+        """(o, dq, dk, dv) of one forward and one backward pass."""
+        def run(q, k, v, cotangent):
+            o, vjp = jax.vjp(attn, q, k, v)
+            return (o, *vjp(cotangent.astype(o.dtype)))
+        return jax.jit(run)
+
+    args = (q, k, v, cotangent)
+    dense_form = forward_backward(llama.dense_attention)
     with clock("compile_s"):
-        lowered = flash_attention.lower(q, k, v, interpret=dry)
-        if not dry and "tpu_custom_call" not in lowered.as_text():
-            raise SystemExit("chip_smoke: the lowered attention holds no "
-                             "tpu_custom_call: the kernel did not lower "
-                             "to Mosaic")
-        compiled = lowered.compile()
+        lowered = forward_backward(functools.partial(
+            flash_attention, interpret=dry)).lower(*args)
+        if not dry and lowered.as_text().count("tpu_custom_call") < 3:
+            raise SystemExit("chip_smoke: the lowered attention holds fewer "
+                             "than three tpu_custom_calls: the forward, dQ "
+                             "and dK/dV kernels did not all lower to Mosaic")
+        kernel = lowered.compile()
+        dense = dense_form.lower(*args).compile()
     with clock("run_s"):
-        got = np.asarray(compiled(q, k, v), np.float32)
+        got = [np.asarray(x, np.float32) for x in kernel(*args)]
     with clock("reference_s"):
         with jax.default_matmul_precision("highest"):
-            want = np.asarray(llama.attention(
-                q.astype(jnp.float32), k.astype(jnp.float32),
-                v.astype(jnp.float32)))
-    if got.shape != (b, t, hq * d) or not np.isfinite(got).all():
-        raise SystemExit(f"chip_smoke: kernel output {got.shape} not finite "
-                         f"or not {(b, t, hq * d)}")
-    # Outputs are O(1) averages of N(0,1) values returned in bf16 (8
-    # mantissa bits: 2^-9 = 0.002 relative), and the kernel's f32 matmuls
-    # run on the MXU at its default precision, bf16 operands, which costs
-    # the same again on the scores and on the probabilities. 2e-2 passes
-    # that and fails a wrong mask, scale or KV-head mapping (errors O(1)).
+            want = [np.asarray(x) for x in dense_form(
+                *(x.astype(jnp.float32) for x in args))]
+    # Forward + backward of the kernels and of the dense form in bf16, side
+    # by side (not a rate: one op alone, outside any step).
+    out["forward_backward_ms"] = {}
+    reps = 1 if dry else 20
+    for name, fn in (("kernel", kernel), ("dense", dense)):
+        jax.block_until_ready(fn(*args))
+        t0 = time.monotonic()
+        for _ in range(reps):
+            last = fn(*args)
+        jax.block_until_ready(last)
+        out["forward_backward_ms"][name] = round(
+            (time.monotonic() - t0) / reps * 1e3, 3)
+    # One rule for the four arrays: the largest error is within 2e-2 of the
+    # array's scale. The output's scale is 1: O(1) averages of N(0,1) values
+    # returned in bf16 (8 mantissa bits: 2^-9 = 0.002 relative), after bf16
+    # operands on the scores and on the probabilities cost the same again.
+    # A gradient's scale is its reference's largest element: dS is rounded
+    # to bf16 as the probabilities are. 2e-2 passes that and fails a wrong
+    # mask, scale, KV-head mapping or group sum (errors O(scale)).
     tol = 2e-2
-    err = float(np.max(np.abs(got - want)))
-    out["max_abs_err"] = err
     out["tolerance"] = tol
-    if not err <= tol:
-        raise SystemExit(f"chip_smoke: kernel differs from llama.attention "
-                         f"by {err} > {tol}")
+    out["max_err_over_scale"] = {}
+    for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+        if g.shape != w.shape or not np.isfinite(g).all():
+            raise SystemExit(f"chip_smoke: kernel {name} {g.shape} not "
+                             f"finite or not {w.shape}")
+        scale = 1.0 if name == "o" else float(np.max(np.abs(w)))
+        err = float(np.max(np.abs(g - w))) / scale
+        out["max_err_over_scale"][name] = err
+        if not err <= tol:
+            raise SystemExit(f"chip_smoke: kernel {name} differs from "
+                             f"float32 llama.dense_attention by {err} of "
+                             f"its scale > {tol}")
     out["seconds"] = clock.seconds
     return out
 

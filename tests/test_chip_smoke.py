@@ -45,7 +45,14 @@ def test_cpu_dry_run_covers_every_phase_and_says_cpu():
         assert line["platform"] == "cpu", name
         assert line["device_kind"] and line["device_count"] >= 1, name
         assert "run_s" in line["seconds"] or name == "multichip_jax", name
-    assert phases["kernel"]["interpret"] is True
+    kernel = phases["kernel"]
+    assert kernel["interpret"] is True
+    # Output and all three gradients were compared, each within the
+    # tolerance of its scale, and both forms were timed.
+    assert set(kernel["max_err_over_scale"]) == {"o", "dq", "dk", "dv"}
+    assert all(e <= kernel["tolerance"]
+               for e in kernel["max_err_over_scale"].values())
+    assert set(kernel["forward_backward_ms"]) == {"kernel", "dense"}
     assert phases["ps"]["pjrt_platform"] == "brt_fake"
     assert phases["ps"]["leaked_handles"] == 0
     assert phases["multichip_native"]["launched_on"] == [0, 1, 2, 3]

@@ -1,14 +1,21 @@
 """Pallas kernel tests. Numerics run in interpret mode on the CPU; that the
-same kernel reaches the TPU compiler is checked without a chip, by lowering
+same kernels reach the TPU compiler is checked without a chip, by lowering
 for the TPU platform and by compiling ahead of time for a v5e topology
-(libtpu compiles without devices). The compiled kernel's numerics on the
-chip are chip_smoke.py's ``kernel`` phase."""
+(libtpu compiles without devices). The compiled kernels' numerics on the
+chip are chip_smoke.py's ``kernel`` phase. Last, the choice
+``llama.attention`` makes between the kernels and the dense form."""
+
+import dataclasses
+import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 import pytest
 
+from brpc_tpu import obs
 from brpc_tpu.models import llama
 from brpc_tpu.ops import flash_attention
 
@@ -99,3 +106,140 @@ def test_flash_bf16():
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         rtol=5e-2, atol=5e-2)
+
+
+# -- the backward pass ------------------------------------------------------
+
+def _weighted(attn, w):
+    """A scalar of attention whose cotangent is not constant."""
+    return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
+
+
+@pytest.mark.parametrize("blocks", [(32, 32), (16, 64)],
+                         ids=lambda b: "x".join(map(str, b)))
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("group", [4, 1])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 4e-2)],
+                         ids=["float32", "bfloat16"])
+def test_flash_grad_matches_dense(dtype, tol, group, causal, blocks):
+    """dQ, dK, dV of the kernels against autodiff of the dense form. The
+    bf16 tolerance is relative to each gradient's largest element: both
+    sides round p and dS to 8 mantissa bits, at different places."""
+    q, k, v = _inputs(jax.random.PRNGKey(3), b=1, hq=4, hkv=4 // group,
+                      dtype=dtype)
+    w = jax.random.normal(jax.random.PRNGKey(4), (1, 128, 4 * 32))
+    got = jax.grad(_weighted(functools.partial(
+        flash_attention, causal=causal, block_q=blocks[0], block_k=blocks[1],
+        interpret=True), w), (0, 1, 2))(q, k, v)
+    want = jax.grad(_weighted(functools.partial(
+        llama.dense_attention, causal=causal), w), (0, 1, 2))(q, k, v)
+    for name, g, r in zip(("dq", "dk", "dv"), got, want):
+        assert g.dtype == r.dtype == dtype and g.shape == r.shape, name
+        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        assert np.max(np.abs(g - r)) <= tol * np.max(np.abs(r)), name
+
+
+def _grad_of_sum(**blocks):
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, interpret=False, **blocks)
+                       .astype(jnp.float32))
+    return jax.jit(jax.grad(loss, (0, 1, 2)))
+
+
+@pytest.mark.parametrize("shape", _TPU_SHAPES)
+def test_flash_backward_lowers_for_tpu(shape):
+    """Forward, dQ and dK/dV kernels: three Mosaic custom calls."""
+    args, blocks = _abstract_inputs(**shape)
+    lowered = _grad_of_sum(**blocks).trace(*args).lower(
+        lowering_platforms=("tpu",))
+    assert lowered.as_text().count("stablehlo.custom_call @tpu_custom_call") \
+        == 3
+
+
+@pytest.mark.parametrize("shape", _TPU_SHAPES)
+def test_flash_backward_compiles_for_v5e(v5e_device, shape):
+    args, blocks = _abstract_inputs(
+        jax.sharding.SingleDeviceSharding(v5e_device), **shape)
+    compiled = _grad_of_sum(**blocks).trace(*args).lower().compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+# -- the choice llama.attention makes ---------------------------------------
+
+# Kernel-eligible and small: 4 heads of 128 over 2 KV heads, bf16, and a
+# sequence (three tiles of 128) that is no weight's dimension, so that an
+# array with two trailing dimensions of T can only be a score matrix.
+_T = 384
+_ELIGIBLE = llama.LlamaConfig(vocab_size=1024, hidden=512, n_layers=2,
+                              n_heads=4, n_kv_heads=2, head_dim=128,
+                              intermediate=1024)
+
+
+def _abstract_step(cfg, sharding=None):
+    optimizer = optax.adamw(1e-4)
+    params = jax.eval_shape(lambda k: llama.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    state = (params, jax.eval_shape(optimizer.init, params),
+             jax.ShapeDtypeStruct((1, _T), jnp.int32))
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        state)
+    return jax.jit(llama.make_train_step(cfg, optimizer)).trace(*state)
+
+
+@pytest.fixture
+def lowerings():
+    """Reads (kernel, dense) lowerings counted since the test began."""
+    obs.set_enabled(True)       # other modules' tests leave it off
+    names = ("attn_kernel_lowerings", "attn_dense_lowerings")
+    before = [obs.counter(n).get_value() for n in names]
+    return lambda: tuple(obs.counter(n).get_value() - b
+                         for n, b in zip(names, before))
+
+
+def test_train_step_takes_the_kernel_on_tpu(v5e_device, lowerings):
+    text = _abstract_step(
+        _ELIGIBLE, jax.sharding.SingleDeviceSharding(v5e_device)
+    ).lower().compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert re.findall(rf"\w+\[[\d,]*{_T},{_T}\]", text) == []
+    assert lowerings() == (1, 0)
+
+
+def test_train_step_stays_dense_on_cpu(lowerings):
+    text = _abstract_step(_ELIGIBLE).lower(
+        lowering_platforms=("cpu",)).as_text()
+    assert "tpu_custom_call" not in text
+    assert re.search(rf"tensor<[\dx]*{_T}x{_T}xf32>", text)
+    assert lowerings() == (0, 1)
+
+
+@pytest.mark.parametrize("change", [dict(dtype=jnp.float32),
+                                    dict(head_dim=32)],
+                         ids=["float32", "head_dim32"])
+def test_ineligible_operands_stay_dense_on_tpu(change, lowerings):
+    text = _abstract_step(dataclasses.replace(_ELIGIBLE, **change)).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" not in text
+    assert lowerings() == (0, 1)
+
+
+def test_one_step_through_the_kernel_matches_dense():
+    """Loss and every gradient leaf's norm of the small model, the kernels
+    (interpreted) against the dense form, to what bf16 allows: the two round
+    the scores and the probabilities at different places."""
+    params = llama.init_params(jax.random.PRNGKey(5), _ELIGIBLE)
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (1, _T), 0,
+                                _ELIGIBLE.vocab_size)
+    grad = jax.jit(jax.value_and_grad(llama.loss_fn), static_argnums=(2, 3))
+    loss, grads = grad(params, tokens, _ELIGIBLE,
+                       functools.partial(flash_attention, interpret=True))
+    want_loss, want = grad(params, tokens, _ELIGIBLE, llama.dense_attention)
+    assert abs(float(loss) - float(want_loss)) <= 2e-3 * float(want_loss)
+    norms = jax.tree_util.tree_map(
+        lambda g, w: (float(jnp.linalg.norm(g)), float(jnp.linalg.norm(w))),
+        grads, want)
+    for path, (g, w) in jax.tree_util.tree_leaves_with_path(
+            norms, is_leaf=lambda x: isinstance(x, tuple)):
+        assert abs(g - w) <= 2e-2 * w, (jax.tree_util.keystr(path), g, w)
