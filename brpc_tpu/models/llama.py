@@ -156,7 +156,7 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 def dense_attention(q, k, v, *, causal: bool = True):
     """Grouped-query attention with the scores materialised. q: [B,T,Hq,D],
-    k/v: [B,T,Hkv,D]."""
+    k: [B,T,Hkv,D], v: [B,T,Hkv,Dv] (Dv = D but for latent attention)."""
     b, t, hq, d = q.shape
     hkv = k.shape[2]
     group = hq // hkv
@@ -168,7 +168,7 @@ def dense_attention(q, k, v, *, causal: bool = True):
         scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgts,bshd->bthgd", probs, v)
-    return out.reshape(b, t, hq * d)
+    return out.reshape(b, t, hq * v.shape[-1])
 
 
 def attention(q, k, v, *, causal: bool = True):
@@ -191,7 +191,7 @@ def attention(q, k, v, *, causal: bool = True):
             count_lowering(q, "attn_kernel_lowerings"), k, v, causal=causal)
 
     if not (causal and q.dtype == k.dtype == v.dtype == jnp.bfloat16
-            and flash_supported(q.shape, k.shape, q.dtype)):
+            and flash_supported(q.shape, k.shape, q.dtype, v.shape)):
         return dense(q, k, v)
     return lax.platform_dependent(q, k, v, tpu=kernel, default=dense)
 

@@ -12,8 +12,12 @@ Expert parallelism is the PartitionChannel shape at the model tier (SURVEY
   cross-device movement compiles to ICI all-to-alls inside jit when the
   token batch is dp-sharded and experts are ep-sharded.
 
-Used by ``moe_llama`` (an MoE variant of the flagship) and the driver's
-multi-chip dry run to exercise the 'ep' axis.
+This is the top-1 toy layer that the multi-chip dry run
+(``__graft_entry__.dryrun_multichip``) uses to exercise the 'ep' axis; no
+model calls it. The expert layer real models use is ``deepseek.moe_mlp``
+(models/deepseek.py): top-k of sigmoid scores over all the experts, no
+capacity and no dropped token, a grouped product over the experts a chip
+holds (ops/grouped_matmul.py), shared experts.
 """
 
 from __future__ import annotations

@@ -19,7 +19,10 @@ the training path) and accumulates in float32; scores, softmax statistics,
 cast to the operand dtype only as matmul operands.
 
 GQA layout matches brpc_tpu.models.llama: q [B, T, Hq, D], k/v
-[B, T, Hkv, D]; the kv head for q head h is h // (Hq // Hkv). Inside, the
+[B, T, Hkv, D]; the kv head for q head h is h // (Hq // Hkv). q and k share
+one width and v (with the output and dO) may have another: latent attention
+(models/deepseek.py) has q, k of 192 = 128 + 64 rope dims and v of 128. The
+scores are scaled by the q/k width. Inside, the
 kernels work head-major ([B, H, T, D]): Mosaic wants the last two block
 dimensions to be (a multiple of 8, a multiple of 128) or the whole array
 dimension, and a one-head block of the [B, T, H, D] layout has 1 against H
@@ -54,21 +57,34 @@ def choose_block(t: int) -> int:
     three kernels: 512 where it divides. On a v5e at T = 2,048, D = 128 each
     kernel is within 2% of its best there (0.60 / 0.61 / 0.73 ms), 256 costs
     the three 30–40% more and 128 2–3 times (PERF.md section 6, PR 27);
-    larger tiles gain nothing and waste more of the diagonal. A sequence
-    none of them divides is one tile."""
+    larger tiles gain nothing and waste more of the diagonal. With two
+    widths the same rule holds: at T = 8,192, 32 heads, q/k 192 and v 128
+    the forward pass and forward + backward read 8.3 / 29.4 ms at (512, 512),
+    8.5 / 31.4 at (256, 512), 12.6 / 36.4 at (256, 256) and 8.7 / 29.2 at
+    (1,024, 1,024) (PERF.md section 6, PR 28): 512 is within 1% of the
+    best. A sequence none of them divides is one tile."""
     return next((c for c in (512, 256, 128) if t % c == 0), t)
 
 
-def supported(q_shape, kv_shape, dtype) -> bool:
-    """Whether the compiled kernels take these operands: tiles that fill the
-    MXU's 128 lanes, whole query groups, and a sequence whose resident K, V,
-    Q and dO (double-buffered by the pipeline) leave VMEM room for the
-    score tiles."""
-    _, t, hq, d = q_shape
+def supported(q_shape, kv_shape, dtype, v_shape=None) -> bool:
+    """Whether the compiled kernels take these operands: whole query groups,
+    a v width (``v_shape``, k's where it is not given) that fills the MXU's
+    128 lanes, a q/k width that is a multiple of 64 (192 is a block as wide
+    as its array, which Mosaic takes whole and pads to 256 lanes in VMEM: on
+    a v5e the three kernels at 192/128 run the 2.06 TFLOP a layer needs at
+    T = 8,192 in 29.4 ms, 36% of the MXU's peak against 47% at 128/128,
+    since a 192-wide contraction fills one and a half passes of the 128-wide
+    MXU; PERF.md section 6, PR 28), and a
+    sequence whose resident K and V, or Q and dO of one query group
+    (double-buffered by the pipeline), leave VMEM room for the score
+    tiles."""
+    _, t, hq, d_qk = q_shape
     hkv = kv_shape[2]
-    if d % 128 or t % 128 or hq % hkv:
+    d_v = (v_shape or kv_shape)[3]
+    if d_qk % 64 or d_v % 128 or t % 128 or hq % hkv:
         return False
-    resident = 2 * 2 * (hq // hkv) * t * d * jnp.dtype(dtype).itemsize
+    resident = (2 * (hq // hkv) * t * (-(-d_qk // 128) * 128 + d_v)
+                * jnp.dtype(dtype).itemsize)
     return resident <= _VMEM_LIMIT // 2
 
 
@@ -115,10 +131,10 @@ def _query_tiles(kj, block_q: int, block_k: int, t: int, causal: bool):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
                 causal: bool, scale: float):
-    block_q, d = q_ref.shape[2:]
-    t = k_ref.shape[2]
+    block_q = q_ref.shape[2]
+    t, d_v = v_ref.shape[2:]
     qi = pl.program_id(2)
-    q = q_ref[0, 0]                                          # [BQ, D]
+    q = q_ref[0, 0]                                          # [BQ, Dqk]
 
     def step(kj, carry, masked):
         m, l, acc = carry
@@ -136,7 +152,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     n_clear, n_k = _key_tiles(qi, block_q, block_k, t, causal)
     carry = (jnp.full((block_q, 1), -jnp.inf, jnp.float32),
              jnp.zeros((block_q, 1), jnp.float32),
-             jnp.zeros((block_q, d), jnp.float32))
+             jnp.zeros((block_q, d_v), jnp.float32))
     carry = lax.fori_loop(0, n_clear,
                           functools.partial(step, masked=False), carry)
     m, l, acc = lax.fori_loop(n_clear, n_k,
@@ -178,6 +194,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
     broadcast as the row vectors they are stored as."""
     group, t = q_ref.shape[1:3]
     block_k, d = k_ref.shape[2:]
+    d_v = v_ref.shape[3]
     kj = pl.program_id(2)
     k = k_ref[0, 0]
     v = v_ref[0, 0]
@@ -194,7 +211,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         ds = p * (_nt(v, do) - delta_ref[0, g, qj])
         return dk + _nn(ds.astype(q.dtype), q), dv
 
-    carry = (jnp.zeros((block_k, d), jnp.float32),) * 2
+    dk = jnp.zeros((block_k, d), jnp.float32)
+    carry = (dk, dk if d_v == d else jnp.zeros((block_k, d_v), jnp.float32))
     for g in range(group):
         carry = lax.fori_loop(
             first, clear, functools.partial(step, g=g, masked=True), carry)
@@ -215,26 +233,37 @@ def _call(kernel, name, interpret, **kwargs):
         **kwargs)
 
 
+def _tile_spec(rows: int, width: int):
+    """One head's [rows, width] tile of a head-major array, by grid (batch,
+    head, tile)."""
+    return pl.BlockSpec((1, 1, rows, width), lambda bi, h, i: (bi, h, i, 0))
+
+
+def _whole_spec(t: int, width: int, group: int):
+    """The whole [T, width] of the KV head that query head ``h`` attends."""
+    return pl.BlockSpec((1, 1, t, width),
+                        lambda bi, h, i: (bi, h // group, 0, 0))
+
+
 def _forward(q, k, v, causal: bool, blocks: tuple, interpret: bool):
-    """Head-major q [B,Hq,T,D], k/v [B,Hkv,T,D] -> (o like q, float32 lse
-    [B,Hq,T/BQ,1,BQ])."""
+    """Head-major q [B,Hq,T,Dqk], k [B,Hkv,T,Dqk], v [B,Hkv,T,Dv] -> (o
+    [B,Hq,T,Dv], float32 lse [B,Hq,T/BQ,1,BQ])."""
     b, hq, t, d = q.shape
+    d_v = v.shape[3]
     group = hq // k.shape[1]
     block_q, block_k = blocks
-    q_spec = pl.BlockSpec((1, 1, block_q, d), lambda bi, h, i: (bi, h, i, 0))
-    kv_spec = pl.BlockSpec((1, 1, t, d),
-                           lambda bi, h, i: (bi, h // group, 0, 0))
     with jax.named_scope("attn.flash_fwd"):
         return _call(
             functools.partial(_fwd_kernel, block_k=block_k, causal=causal,
                               scale=d ** -0.5),
             "attn_flash_fwd", interpret,
             grid=(b, hq, t // block_q),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=[q_spec,
+            in_specs=[_tile_spec(block_q, d), _whole_spec(t, d, group),
+                      _whole_spec(t, d_v, group)],
+            out_specs=[_tile_spec(block_q, d_v),
                        pl.BlockSpec((1, 1, 1, 1, block_q),
                                     lambda bi, h, i: (bi, h, i, 0, 0))],
-            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+            out_shape=[jax.ShapeDtypeStruct((b, hq, t, d_v), q.dtype),
                        jax.ShapeDtypeStruct((b, hq, t // block_q, 1, block_q),
                                             jnp.float32)],
         )(q, k, v)
@@ -243,6 +272,7 @@ def _forward(q, k, v, causal: bool, blocks: tuple, interpret: bool):
 def _backward(q, k, v, o, lse, do, causal: bool, blocks: tuple,
               interpret: bool):
     b, hq, t, d = q.shape
+    d_v = v.shape[3]
     hkv = k.shape[1]
     group = hq // hkv
     scale = d ** -0.5
@@ -250,11 +280,6 @@ def _backward(q, k, v, o, lse, do, causal: bool, blocks: tuple,
     with jax.named_scope("attn.flash_bwd"):
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1).reshape(lse.shape)
-
-        q_spec = pl.BlockSpec((1, 1, block_q, d),
-                              lambda bi, h, i: (bi, h, i, 0))
-        kv_spec = pl.BlockSpec((1, 1, t, d),
-                               lambda bi, h, i: (bi, h // group, 0, 0))
         row_spec = pl.BlockSpec((1, 1, 1, 1, block_q),
                                 lambda bi, h, i: (bi, h, i, 0, 0))
         dq = _call(
@@ -262,25 +287,28 @@ def _backward(q, k, v, o, lse, do, causal: bool, blocks: tuple,
                               scale=scale),
             "attn_flash_bwd_dq", interpret,
             grid=(b, hq, t // block_q),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-            out_specs=q_spec,
+            in_specs=[_tile_spec(block_q, d), _whole_spec(t, d, group),
+                      _whole_spec(t, d_v, group), _tile_spec(block_q, d_v),
+                      row_spec, row_spec],
+            out_specs=_tile_spec(block_q, d),
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         )(q, k, v, do, lse, delta)
 
-        group_spec = pl.BlockSpec((1, group, t, d),
-                                  lambda bi, h, j: (bi, h, 0, 0))
-        kv_spec = pl.BlockSpec((1, 1, block_k, d),
-                               lambda bi, h, j: (bi, h, j, 0))
+        def group_spec(width):
+            return pl.BlockSpec((1, group, t, width),
+                                lambda bi, h, j: (bi, h, 0, 0))
+
         row_spec = pl.BlockSpec((1, group, t // block_q, 1, block_q),
                                 lambda bi, h, j: (bi, h, 0, 0, 0))
+        kv_specs = [_tile_spec(block_k, d), _tile_spec(block_k, d_v)]
         dk, dv = _call(
             functools.partial(_dkv_kernel, block_q=block_q, causal=causal,
                               scale=scale),
             "attn_flash_bwd_dkv", interpret,
             grid=(b, hkv, t // block_k),
-            in_specs=[group_spec, kv_spec, kv_spec, group_spec, row_spec,
+            in_specs=[group_spec(d), *kv_specs, group_spec(d_v), row_spec,
                       row_spec],
-            out_specs=[kv_spec, kv_spec],
+            out_specs=kv_specs,
             out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                        jax.ShapeDtypeStruct(v.shape, v.dtype)],
         )(q, k, v, do, lse, delta)
@@ -318,14 +346,14 @@ def flash_attention(
     block_k: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """q: [B,T,Hq,D], k/v: [B,T,Hkv,D] -> [B,T,Hq*D] (llama.attention
-    contract), differentiable. The tile is chosen from T; ``block_q`` /
-    ``block_k`` override it (tests)."""
-    b, t, hq, d = q.shape
+    """q: [B,T,Hq,Dqk], k: [B,T,Hkv,Dqk], v: [B,T,Hkv,Dv] -> [B,T,Hq*Dv]
+    (llama.attention contract), differentiable. The tile is chosen from T;
+    ``block_q`` / ``block_k`` override it (tests)."""
+    b, t, hq, _ = q.shape
     blocks = (min(block_q or choose_block(t), t),
               min(block_k or choose_block(t), t))
     if t % blocks[0] or t % blocks[1]:
         raise ValueError(f"seq {t} must divide blocks {blocks}")
     q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))  # [B, H, T, D]
     out = _attend(q, k, v, causal, blocks, interpret)
-    return out.transpose(0, 2, 1, 3).reshape(b, t, hq * d)
+    return out.transpose(0, 2, 1, 3).reshape(b, t, hq * v.shape[3])

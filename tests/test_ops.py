@@ -243,3 +243,213 @@ def test_one_step_through_the_kernel_matches_dense():
     for path, (g, w) in jax.tree_util.tree_leaves_with_path(
             norms, is_leaf=lambda x: isinstance(x, tuple)):
         assert abs(g - w) <= 2e-2 * w, (jax.tree_util.keystr(path), g, w)
+
+
+# -- two widths: latent attention's q/k of 192 and v of 128 -----------------
+
+def _latent_inputs(key, t=128, h=2, d_qk=192, d_v=128, dtype=jnp.float32,
+                   sharding=None, abstract=False):
+    shapes = ((1, t, h, d_qk), (1, t, h, d_qk), (1, t, h, d_v))
+    if abstract:
+        return tuple(jax.ShapeDtypeStruct(s, dtype, sharding=sharding)
+                     for s in shapes)
+    return tuple(jax.random.normal(k, s, dtype)
+                 for k, s in zip(jax.random.split(key, 3), shapes))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 4e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("widths", [(192, 128), (24, 16)],
+                         ids=lambda w: "x".join(map(str, w)))
+def test_flash_two_widths_match_dense(widths, dtype, tol):
+    """Output, dQ, dK and dV at d_qk != d_v against the dense form, which
+    scales by the q/k width and returns heads of the v width."""
+    q, k, v = _latent_inputs(jax.random.PRNGKey(7), d_qk=widths[0],
+                             d_v=widths[1], dtype=dtype)
+    kernel = functools.partial(flash_attention, block_q=64, block_k=32,
+                               interpret=True)
+    got, want = kernel(q, k, v), llama.dense_attention(q, k, v)
+    assert got.shape == want.shape == (1, 128, 2 * widths[1])
+    w = jax.random.normal(jax.random.PRNGKey(8), want.shape)
+    grads = jax.grad(_weighted(kernel, w), (0, 1, 2))(q, k, v)
+    wants = jax.grad(_weighted(llama.dense_attention, w), (0, 1, 2))(q, k, v)
+    for name, g, r in zip(("o", "dq", "dk", "dv"), (got, *grads),
+                          (want, *wants)):
+        assert g.dtype == r.dtype == dtype and g.shape == r.shape, name
+        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        assert np.max(np.abs(g - r)) <= tol * np.max(np.abs(r)), name
+
+
+def test_supported_states_the_rule_for_two_widths():
+    from brpc_tpu.ops.flash_attention import supported
+    bf16 = jnp.bfloat16
+    assert supported((1, 8192, 32, 192), (1, 8192, 32, 192), bf16,
+                     (1, 8192, 32, 128))
+    assert supported((1, 2048, 32, 128), (1, 2048, 8, 128), bf16)
+    assert not supported((1, 8192, 32, 192), (1, 8192, 32, 192), bf16,
+                         (1, 8192, 32, 64))         # v narrower than a lane
+    assert not supported((1, 8192, 32, 96), (1, 8192, 32, 96), bf16,
+                         (1, 8192, 32, 128))
+    assert not supported((1, 32768, 32, 192), (1, 32768, 32, 192), bf16,
+                         (1, 32768, 32, 128))       # K, V past the VMEM room
+
+
+def test_flash_two_widths_compile_for_v5e(v5e_device):
+    """Forward, dQ and dK/dV at the kanana cell's geometry (8,192 x 32
+    heads, 192 / 128): Mosaic takes the 192-wide blocks whole."""
+    args = _latent_inputs(None, t=8192, h=32, dtype=jnp.bfloat16,
+                          sharding=jax.sharding.SingleDeviceSharding(
+                              v5e_device), abstract=True)
+    compiled = _grad_of_sum().trace(*args).lower().compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+# -- the grouped product over the experts held -------------------------------
+
+from brpc_tpu.models import deepseek  # noqa: E402
+from brpc_tpu.ops import grouped_matmul as gm  # noqa: E402
+
+
+def _grouped_case(seed=0, n=40, k=2, groups=3, h=32, f=16, tile=8):
+    """Assignments over 3 held groups and 2 absent ones; group 1 is chosen
+    by nobody, group 0 by far the most."""
+    rng = np.random.default_rng(seed)
+    group_of = np.minimum(rng.integers(0, groups + 2, n * k), groups)
+    group_of[group_of == 1] = 0
+    lay = gm.group_layout(jnp.asarray(group_of, jnp.int32), groups, tile)
+    arrays = [rng.standard_normal(s).astype(np.float32) for s in (
+        (n, h), (groups, h, f), (groups, f, h))]
+    weights = rng.uniform(0.1, 1, (n, k)).astype(np.float32)
+    return group_of.reshape(n, k), lay, (*map(jnp.asarray, arrays),
+                                         jnp.asarray(weights))
+
+
+def _routed(lay, k, interpret):
+    dest, held = lay.dest.reshape(-1, k), lay.held.reshape(-1, k)
+    row_token, row_slot = lay.row_source // k, lay.row_source % k
+
+    def fn(x, w_in, w_out, weights):
+        rows = gm.dispatch(x, row_token, lay.row_valid, dest, held)
+        hidden = jax.nn.silu(gm.grouped_matmul(
+            rows, w_in, lay.tile_group, lay.n_tiles, interpret))
+        rows = gm.grouped_matmul(hidden, w_out, lay.tile_group, lay.n_tiles,
+                                 interpret)
+        return gm.combine(rows, weights, dest, held, row_token, row_slot,
+                          lay.row_valid)
+    return fn
+
+
+def _routed_plainly(group_of):
+    def fn(x, w_in, w_out, weights):
+        out = 0.0
+        for g in range(w_in.shape[0]):
+            weight = jnp.sum(jnp.where(group_of == g, weights, 0.0), axis=1)
+            out = out + weight[:, None] * (jax.nn.silu(x @ w_in[g]) @ w_out[g])
+        return out
+    return fn
+
+
+def test_group_layout_places_every_held_assignment_once():
+    group_of, lay, _ = _grouped_case()
+    flat = group_of.reshape(-1)
+    held = flat < 3
+    assert np.array_equal(np.asarray(lay.held), held)
+    assert np.array_equal(np.asarray(lay.group_sizes),
+                          np.bincount(flat[held], minlength=3))
+    assert int(lay.group_sizes[1]) == 0
+    dest = np.asarray(lay.dest)[held]
+    assert len(set(dest)) == held.sum() == np.asarray(lay.row_valid).sum()
+    # a row's source is the assignment that was sent there, and its tile
+    # belongs to that assignment's group
+    assert np.array_equal(np.asarray(lay.row_source)[dest],
+                          np.flatnonzero(held))
+    assert np.array_equal(np.asarray(lay.tile_group)[dest // 8], flat[held])
+    # every group has a tile, an empty one too; the bound has idle tiles
+    used = np.asarray(lay.tile_group)[:int(lay.n_tiles[0])]
+    assert set(used) == {0, 1, 2} and int(lay.n_tiles[0]) < len(
+        np.asarray(lay.tile_group))
+
+
+@pytest.mark.parametrize("interpret", [None, True],
+                         ids=["einsum", "kernels_interpreted"])
+def test_grouped_product_matches_a_loop_over_the_experts(interpret):
+    """Forward and the gradients of rows, both weight stacks and the router
+    weights, with an expert nobody chose (zero gradient, not garbage) and
+    idle tiles past the rows present."""
+    group_of, lay, args = _grouped_case()
+    with jax.default_matmul_precision("highest"):
+        fn, plain = _routed(lay, 2, interpret), _routed_plainly(group_of)
+        w = jax.random.normal(jax.random.PRNGKey(1), (40, 32))
+        loss = lambda f: (lambda *a: jnp.sum(f(*a) * w))  # noqa: E731
+        got = (fn(*args), *jax.grad(loss(fn), (0, 1, 2, 3))(*args))
+        want = (plain(*args), *jax.grad(loss(plain), (0, 1, 2, 3))(*args))
+    for name, g, r in zip(("out", "dx", "dw_in", "dw_out", "dweights"), got,
+                          want):
+        g, r = np.asarray(g), np.asarray(r)
+        assert np.max(np.abs(g - r)) <= 2e-5 * np.max(np.abs(r)), name
+    assert not np.any(np.asarray(got[2])[1]) and not np.any(
+        np.asarray(got[3])[1])
+
+
+def test_grouped_kernels_compile_for_v5e(v5e_device):
+    """The three kernels at the kanana cell's size: 49,152 assignments of
+    which any number may be held, 16 experts of 2,048 x 768."""
+    tile = gm.choose_tile(49152, 16)
+    m = gm.bound_rows(49152, 16, tile)
+    assert (tile, m) == (256, 53248)
+    sharding = jax.sharding.SingleDeviceSharding(v5e_device)
+    s = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=sharding)
+
+    def grads(x, w_in, w_out, tile_group, n_tiles):
+        def loss(x, w_in, w_out):
+            hidden = gm.grouped_matmul(x, w_in, tile_group, n_tiles)
+            return jnp.sum(gm.grouped_matmul(
+                hidden, w_out, tile_group, n_tiles).astype(jnp.float32))
+        return jax.grad(loss, (0, 1, 2))(x, w_in, w_out)
+
+    text = jax.jit(grads).lower(
+        s((m, 2048), jnp.bfloat16), s((16, 2048, 768), jnp.bfloat16),
+        s((16, 768, 2048), jnp.bfloat16), s((m // tile,), jnp.int32),
+        s((1,), jnp.int32)).compile().as_text()
+    assert {"moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"} <= set(
+        re.findall(r"%(moe_gmm_\w+?)(?:\.\d+)? =", text))
+
+
+# Kernel-eligible and small, as _ELIGIBLE is: 4 heads of 128 + 64 / 128, 8
+# experts of which 2 are held, each [256, 128]; 384 tokens, top-2.
+_LATENT = deepseek.DeepseekConfig(
+    vocab_size=1024, hidden=256, n_layers=2, n_dense_layers=1, n_heads=4,
+    kv_lora_rank=128, intermediate=512, moe_intermediate=128,
+    n_routed_experts=8, n_shared_experts=1, experts_per_token=2, n_held=2)
+
+
+def test_deepseek_step_takes_both_kernels_on_tpu(v5e_device, lowerings):
+    """The step compiled for a v5e holds the attention kernels and the
+    grouped product's, and no array shaped like the scores. The attention
+    choice is counted once although four places hold it (two scans, each
+    with its recomputation): JAX lowers a repeated sub-program once and
+    calls it."""
+    obs.set_enabled(True)
+    grouped = obs.counter("moe_grouped_lowerings")
+    before = grouped.get_value()
+    optimizer = optax.adamw(1e-4)
+    params = jax.eval_shape(lambda k: deepseek.init_params(k, _LATENT),
+                            jax.random.PRNGKey(0))
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype,
+            sharding=jax.sharding.SingleDeviceSharding(v5e_device)),
+        (params, jax.eval_shape(optimizer.init, params),
+         jax.ShapeDtypeStruct((1, _T), jnp.int32)))
+    text = jax.jit(deepseek.make_train_step(_LATENT, optimizer)).trace(
+        *state).lower().compile().as_text()
+    assert {"attn_flash_fwd", "attn_flash_bwd_dq", "attn_flash_bwd_dkv",
+            "moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"} <= set(
+                re.findall(r"%((?:attn_flash|moe_gmm)_\w+?)(?:\.\d+)? =",
+                           text))
+    assert re.findall(rf"\w+\[[\d,]*{_T},{_T}\]", text) == []
+    kernel, dense = lowerings()
+    assert (kernel, dense) == (1, 0)
+    assert grouped.get_value() - before >= 1
