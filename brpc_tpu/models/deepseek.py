@@ -43,8 +43,20 @@ by expert load between steps); no auxiliary balance loss; no rope scaling
 and no ``mscale``; no multi-token prediction.
 
 Memory, the normal path of this model: each layer is recomputed in the
-backward pass (``jax.checkpoint`` around the scans' bodies: the layer's
-input is all that is kept), and the loss takes the output head in chunks of
+backward pass (``jax.checkpoint`` around the scans' bodies) from its input
+and the few values its policy saves by name (``SAVED_NAMES``). One is the
+attention kernel's output with its log-sum-exp
+(``flash_attention.RESIDUAL_NAMES``; 68 MB a layer at 8,192 tokens of 32
+heads, for a forward kernel that is the dearest thing in the layer to run
+again): the backward kernels get q, k, v from the recomputed projections and
+those two from the forward scan's stack. The other is an expert layer's
+integer routing — the experts selected and the ``group_layout`` made from
+them (``grouped_matmul.LAYOUT_NAME``, 1.5 MB a layer) — so that the
+recomputation runs the router's matmul and sigmoid, whose gradient needs
+them, and neither ``top_k`` nor the sort. On a v5e at the kanana cell's size
+the first takes 35 ms off a 430 ms step and the second 4.6 ms more (PERF.md
+section 6, PR 29). Where attention is the dense form (a CPU, float32, narrow
+heads) no activation has a name. The loss takes the output head in chunks of
 the sequence, each recomputed, so that no [T, vocab] float32 logits exist.
 
 ``make_train_step``'s step also returns ``stats``: per expert layer the
@@ -56,18 +68,26 @@ experts each token selected.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from brpc_tpu.models.llama import _dense_init, attention, rms_norm
 from brpc_tpu.ops import grouped_matmul as gm
+from brpc_tpu.ops.flash_attention import RESIDUAL_NAMES
 
 Params = Dict[str, Any]
 
 _FLOAT32_LEAVES = ("router", "router_bias")     # never cast to the compute dtype
+
+# What a layer keeps across its recomputation beside its input, by the names
+# the values are given where they are made: the attention kernel's output
+# and log-sum-exp, and an expert layer's routing layout.
+SAVED_NAMES = (*RESIDUAL_NAMES, gm.LAYOUT_NAME)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -211,9 +231,10 @@ def route(cfg: DeepseekConfig, y: jax.Array, router: jax.Array,
     s = jax.nn.sigmoid(jnp.dot(y.astype(jnp.float32), router,
                                precision=lax.Precision.HIGHEST))
     _, selected = lax.top_k(s + bias, cfg.experts_per_token)
+    selected = checkpoint_name(selected.astype(jnp.int32), gm.LAYOUT_NAME)
     w = jnp.take_along_axis(s, selected, axis=1)
     w = w / jnp.sum(w, axis=1, keepdims=True) * cfg.routed_scaling
-    return selected.astype(jnp.int32), w
+    return selected, w
 
 
 def moe_mlp(cfg: DeepseekConfig, y: jax.Array, lp: Params):
@@ -264,19 +285,22 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: DeepseekConfig):
     """tokens: [B, T] -> (final-normed states [B, T, H], per-expert-layer
     stats). Master weights stay float32; each layer's compute-dtype copy is
     made inside its scan step, and each step is recomputed in the backward
-    pass."""
+    pass from its input and what ``SAVED_NAMES`` names."""
     x = params["embed"][tokens].astype(cfg.dtype)
     b, t, h = x.shape
     positions = jnp.broadcast_to(jnp.arange(t), tokens.shape)
+    recomputed = functools.partial(
+        jax.checkpoint,
+        policy=jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES))
 
-    @jax.checkpoint
+    @recomputed
     def dense_layer(x, lp):
         lp = _cast(lp, cfg.dtype)
         x = mla(cfg, x, lp, positions)
         y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         return x + _swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"]), None
 
-    @jax.checkpoint
+    @recomputed
     def moe_layer(x, lp):
         lp = _cast(lp, cfg.dtype)
         x = mla(cfg, x, lp, positions)

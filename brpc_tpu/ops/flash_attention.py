@@ -5,7 +5,12 @@ No array shaped like the score matrix touches HBM in either pass. The
 forward kernel owns one [BLOCK_Q, D] query tile and streams K/V tiles
 through the MXU with the online-softmax recurrence (running max / sum /
 accumulator); it also returns each row's log-sum-exp, which with q, k, v and
-the output is all the backward pass keeps. The backward pass recomputes the
+the output is all the backward pass keeps. Of those five the kernel's own two
+results carry names (``RESIDUAL_NAMES``: the head-major output and the
+log-sum-exp), so that a caller who recomputes its layers under
+``jax.checkpoint`` can save them by name and not run the forward kernel a
+second time; q, k and v are its inputs and are the caller's to keep or
+recompute. The backward pass recomputes the
 probabilities tile by tile from q, k and the log-sum-exp: one kernel per
 query tile for dQ, one per KV head and key tile for dK and dV, which loops
 over the head's whole query group so that the GQA sum happens in its
@@ -45,11 +50,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _MASKED = -1e30          # finite: exp(_MASKED - finite) is 0, never NaN
 _VMEM_LIMIT = 64 << 20   # of a v5e core's 128 MiB; Mosaic's default is 16
+
+# ``checkpoint_name``s of the forward kernel's head-major output and its
+# log-sum-exp. A ``jax.checkpoint`` whose policy saves them does not run the
+# forward kernel again in its recomputation; anywhere else they are
+# identities.
+RESIDUAL_NAMES = ("attn_flash_out", "attn_flash_lse")
 
 
 def choose_block(t: int) -> int:
@@ -322,6 +334,8 @@ def _attend(q, k, v, causal, blocks, interpret):
 
 def _attend_fwd(q, k, v, causal, blocks, interpret):
     o, lse = _forward(q, k, v, causal, blocks, interpret)
+    o = checkpoint_name(o, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
     return o, (q, k, v, o, lse)
 
 

@@ -34,12 +34,20 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from brpc_tpu.ops.lowered import count_lowering
 
 _VMEM_LIMIT = 64 << 20
+
+# The ``checkpoint_name`` that every field of a ``group_layout`` carries, and
+# that a caller gives the routing the layout was made from: integers only,
+# about 1.5 MB an expert layer at 49,152 assignments. A ``jax.checkpoint``
+# whose policy saves it does not sort the assignments again in its
+# recomputation; anywhere else it is an identity.
+LAYOUT_NAME = "moe_group_layout"
 
 
 class GroupLayout(NamedTuple):
@@ -98,8 +106,9 @@ def group_layout(group_of: jax.Array, n_groups: int, tile: int) -> GroupLayout:
     in_group = row - row_start[g]
     row_valid = (in_group < sizes[g]) & (row // tile < tile_end[-1])
     row_source = order[jnp.clip(first[g] + in_group, 0, a - 1)]
-    return GroupLayout(dest.astype(jnp.int32), held, row_source, row_valid,
-                       tile_group, tile_end[-1:].astype(jnp.int32), sizes)
+    return GroupLayout(*(checkpoint_name(field, LAYOUT_NAME) for field in (
+        dest.astype(jnp.int32), held, row_source, row_valid, tile_group,
+        tile_end[-1:].astype(jnp.int32), sizes)))
 
 
 # -- rows in, rows out: gathers both ways -------------------------------------

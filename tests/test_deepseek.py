@@ -14,6 +14,7 @@ few percent element by element and to under 3% of a leaf's norm.
 """
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -30,6 +31,7 @@ import reference  # noqa: E402
 import reference_dsv3  # noqa: E402
 
 from brpc_tpu.models import deepseek  # noqa: E402
+from brpc_tpu.ops import flash_attention  # noqa: E402
 
 SIZES = {
     "hidden_size": 64, "intermediate_size": 128, "moe_intermediate_size": 32,
@@ -126,6 +128,53 @@ def test_three_adamw_steps_follow_the_reference(params, tokens):
     # (to an ulp of 0.01: its change is taken against the init recomputed
     # inside another program)
     assert want["delta_norms"]["['moe']['router_bias']"] <= 1e-8
+
+
+# -- what the recomputation keeps changes no gradient -------------------------
+
+# Kernel-eligible and small enough for the Pallas interpreter: 2 heads of
+# 128 + 64 / 128 over one tile of 128 tokens, 1 dense + 1 expert layer.
+KERNEL = dataclasses.replace(
+    TINY, vocab_size=64, n_layers=2, n_heads=2, qk_nope_dim=128,
+    qk_rope_dim=64, v_dim=128)
+
+
+def _gradients(monkeypatch, cfg, params, tokens, keeps):
+    """``keeps``: "names" (the model as it is), "input" (a bare
+    ``jax.checkpoint``: the policy is given no name) or "all" (no checkpoint
+    at all)."""
+    with monkeypatch.context() as mp:
+        if keeps == "input":
+            mp.setattr(deepseek, "SAVED_NAMES", ())
+        elif keeps == "all":
+            mp.setattr(jax, "checkpoint", lambda fun, **_: fun)
+        return jax.jit(jax.grad(
+            lambda p, t: deepseek.loss_fn(p, t, cfg)[0]))(params, tokens)
+
+
+@pytest.mark.parametrize("cfg,interpreted", [
+    (TINY, False), (TINY32, False), (KERNEL, True)],
+    ids=["dense_bfloat16", "dense_float32", "kernels_interpreted"])
+def test_gradients_do_not_depend_on_what_the_recomputation_keeps(
+        monkeypatch, cfg, interpreted):
+    """Every leaf under the policy, under a bare checkpoint and with no
+    checkpoint at all, bit for bit: on the CPU attention is the dense form
+    and nothing has a name; through the interpreted kernels the policy saves
+    their output and log-sum-exp, which are what the recomputation would
+    have produced."""
+    if interpreted:
+        monkeypatch.setattr(deepseek, "attention", functools.partial(
+            flash_attention, interpret=True))
+    params = deepseek.init_params(jax.random.PRNGKey(1), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (1, 128), 0,
+                                cfg.vocab_size)
+    want = _gradients(monkeypatch, cfg, params, tokens, "all")
+    for keeps in ("names", "input"):
+        got = _gradients(monkeypatch, cfg, params, tokens, keeps)
+        for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                                jax.tree_util.tree_leaves(want)):
+            assert np.array_equal(np.asarray(g), np.asarray(w)), (
+                keeps, jax.tree_util.keystr(path))
 
 
 # -- one expert layer ---------------------------------------------------------

@@ -18,6 +18,7 @@ import pytest
 from brpc_tpu import obs
 from brpc_tpu.models import llama
 from brpc_tpu.ops import flash_attention
+from brpc_tpu.ops.flash_attention import choose_block
 
 
 def _inputs(key, b=2, t=128, hq=4, hkv=2, d=32, dtype=jnp.float32):
@@ -176,16 +177,20 @@ _ELIGIBLE = llama.LlamaConfig(vocab_size=1024, hidden=512, n_layers=2,
                               intermediate=1024)
 
 
-def _abstract_step(cfg, sharding=None):
+def _abstract_step(cfg, sharding=None, model=llama):
     optimizer = optax.adamw(1e-4)
-    params = jax.eval_shape(lambda k: llama.init_params(k, cfg),
+    params = jax.eval_shape(lambda k: model.init_params(k, cfg),
                             jax.random.PRNGKey(0))
     state = (params, jax.eval_shape(optimizer.init, params),
              jax.ShapeDtypeStruct((1, _T), jnp.int32))
     state = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
         state)
-    return jax.jit(llama.make_train_step(cfg, optimizer)).trace(*state)
+    return jax.jit(model.make_train_step(cfg, optimizer)).trace(*state)
+
+
+# The attention kernels' custom calls in a compiled program's text.
+_ATTN_CALLS = re.compile(r"%(attn_flash_\w+?)(?:\.\d+)? = [^\n]*custom-call\(")
 
 
 @pytest.fixture
@@ -202,7 +207,12 @@ def test_train_step_takes_the_kernel_on_tpu(v5e_device, lowerings):
     text = _abstract_step(
         _ELIGIBLE, jax.sharding.SingleDeviceSharding(v5e_device)
     ).lower().compile().as_text()
-    assert text.count("tpu_custom_call") >= 3
+    # one call of each kernel and no other: the names the forward kernel
+    # gives its results (RESIDUAL_NAMES) are identities where no checkpoint
+    # saves by name
+    assert sorted(_ATTN_CALLS.findall(text)) == [
+        "attn_flash_bwd_dkv", "attn_flash_bwd_dq", "attn_flash_fwd"]
+    assert text.count("tpu_custom_call") == 3
     assert re.findall(rf"\w+\[[\d,]*{_T},{_T}\]", text) == []
     assert lowerings() == (1, 0)
 
@@ -425,26 +435,22 @@ _LATENT = deepseek.DeepseekConfig(
     n_routed_experts=8, n_shared_experts=1, experts_per_token=2, n_held=2)
 
 
+def _compiled_latent_step(device) -> str:
+    """The text of _LATENT's train step compiled for ``device``."""
+    return _abstract_step(_LATENT, jax.sharding.SingleDeviceSharding(device),
+                          deepseek).lower().compile().as_text()
+
+
 def test_deepseek_step_takes_both_kernels_on_tpu(v5e_device, lowerings):
     """The step compiled for a v5e holds the attention kernels and the
     grouped product's, and no array shaped like the scores. The attention
     choice is counted once although four places hold it (two scans, each
-    with its recomputation): JAX lowers a repeated sub-program once and
-    calls it."""
+    with its recomputation, which keeps the choice and not the forward
+    kernel): JAX lowers a repeated sub-program once and calls it."""
     obs.set_enabled(True)
     grouped = obs.counter("moe_grouped_lowerings")
     before = grouped.get_value()
-    optimizer = optax.adamw(1e-4)
-    params = jax.eval_shape(lambda k: deepseek.init_params(k, _LATENT),
-                            jax.random.PRNGKey(0))
-    state = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype,
-            sharding=jax.sharding.SingleDeviceSharding(v5e_device)),
-        (params, jax.eval_shape(optimizer.init, params),
-         jax.ShapeDtypeStruct((1, _T), jnp.int32)))
-    text = jax.jit(deepseek.make_train_step(_LATENT, optimizer)).trace(
-        *state).lower().compile().as_text()
+    text = _compiled_latent_step(v5e_device)
     assert {"attn_flash_fwd", "attn_flash_bwd_dq", "attn_flash_bwd_dkv",
             "moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"} <= set(
                 re.findall(r"%((?:attn_flash|moe_gmm)_\w+?)(?:\.\d+)? =",
@@ -453,3 +459,86 @@ def test_deepseek_step_takes_both_kernels_on_tpu(v5e_device, lowerings):
     kernel, dense = lowerings()
     assert (kernel, dense) == (1, 0)
     assert grouped.get_value() - before >= 1
+
+
+# -- what the deepseek step keeps across its recomputation -------------------
+
+def _kernels_in(jaxpr):
+    """Names of the Pallas calls in a jaxpr, nested ones too."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _kernels_in(sub)
+    return names
+
+
+def _attention_scans(monkeypatch, saved: bool):
+    """The scans of the differentiated loss of _LATENT that hold an
+    attention kernel, in program order (dense forward, expert forward, then
+    the backward scans): [(scan equation, {kernel name: count})].
+    ``saved`` False: the layers under a bare ``jax.checkpoint``, by giving
+    the policy no name to save."""
+    if not saved:
+        monkeypatch.setattr(deepseek, "SAVED_NAMES", ())
+    params = jax.eval_shape(lambda k: deepseek.init_params(k, _LATENT),
+                            jax.random.PRNGKey(0))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p, t: deepseek.loss_fn(p, t, _LATENT)[0]))(
+            params, jax.ShapeDtypeStruct((1, _T), jnp.int32)).jaxpr
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            names = [n for n in _kernels_in(eqn.params["jaxpr"].jaxpr)
+                     if n.startswith("attn_flash")]
+            if names:
+                found.append((eqn, {n: names.count(n) for n in set(names)}))
+    return found
+
+
+_FWD_ONLY = {"attn_flash_fwd": 1}
+_BWD_ONLY = {"attn_flash_bwd_dq": 1, "attn_flash_bwd_dkv": 1}
+
+
+@pytest.mark.parametrize("saved,backward", [
+    (True, _BWD_ONLY), (False, {**_FWD_ONLY, **_BWD_ONLY})],
+    ids=["names_saved", "bare_checkpoint"])
+def test_forward_kernel_runs_once_a_scan(monkeypatch, saved, backward):
+    """With the kernel's output and log-sum-exp saved by name, the two
+    backward scans hold the backward kernels alone, 2 forward kernels in
+    all; under a bare checkpoint each runs the forward kernel again, 4."""
+    counts = [c for _, c in _attention_scans(monkeypatch, saved)]
+    assert counts == [_FWD_ONLY, _FWD_ONLY, backward, backward]
+
+
+def test_compiled_step_holds_the_forward_kernel_once_a_scan(v5e_device):
+    """What XLA:TPU keeps of it: two forward kernels (dense scan, expert
+    scan), none in the backward scans' recomputation."""
+    text = _compiled_latent_step(v5e_device)
+    assert sorted(_ATTN_CALLS.findall(text)) == ["attn_flash_bwd_dkv"] * 2 + [
+        "attn_flash_bwd_dq"] * 2 + ["attn_flash_fwd"] * 2
+
+
+def test_expert_layer_saves_output_lse_and_layout_and_no_q_or_k(monkeypatch):
+    """The expert scan's stacked residuals: the layer's input, the kernel's
+    head-major output and its log-sum-exp, and the integer routing layout
+    the backward pass reads; nothing 192 wide (q, k)."""
+    (_, (scan, _), *_) = _attention_scans(monkeypatch, True)
+    heads, width = _LATENT.n_heads, _LATENT.qk_nope_dim + _LATENT.qk_rope_dim
+    block = choose_block(_T)
+    stacked = [(str(v.aval.dtype), v.aval.shape[1:])
+               for v in scan.outvars[scan.params["num_carry"]:]]
+    assert sorted(s for s in stacked if "float" in s[0] and len(s[1]) > 1) \
+        == sorted([("bfloat16", (1, _T, _LATENT.hidden)),
+                   ("bfloat16", (1, heads, _T, _LATENT.v_dim)),
+                   ("float32", (1, heads, _T // block, 1, block))])
+    k = _LATENT.experts_per_token
+    tile = gm.choose_tile(_T * k, _LATENT.n_held)
+    rows = gm.bound_rows(_T * k, _LATENT.n_held, tile)
+    assert {("int32", (_T, k)),                              # selected
+            ("int32", (_T * k,)), ("bool", (_T * k,)),       # dest, held
+            ("int32", (rows,)), ("bool", (rows,)),   # row_source, row_valid
+            ("int32", (rows // tile,)), ("int32", (1,))      # tiles
+            } <= set(stacked)
+    assert not any(width in shape for _, shape in stacked)
