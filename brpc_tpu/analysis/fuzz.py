@@ -777,7 +777,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                              "entries")
     parser.add_argument("--no-memcheck", action="store_true",
                         help="skip tracemalloc allocation bounding "
-                             "(faster; used by the bench block)")
+                             "(faster)")
     args = parser.parse_args(argv)
 
     if args.corpus:

@@ -24,7 +24,7 @@ always captured, later sampled-out creations carry a placeholder.  The
 ledger itself (the dict insert/remove) always runs, so live counts stay
 exact; only stack *context* degrades.  With ``BRPC_TPU_HANDLECHECK``
 unset nothing is wrapped at all — the steady-state ABI carries zero
-overhead (asserted by ``bench_analysis.py``).
+overhead (``tests/test_handles.py``).
 
 Stdlib-only, below ``rpc`` in the import order (``rpc._load`` imports
 this module).

@@ -43,8 +43,8 @@ stable-finding-id, and baseline machinery of
 Everything here is stdlib-only and operates on source text; no
 compiler, no clang bindings, no build tree.  The extraction layer
 (:func:`strip_comments_and_strings`, :func:`extract_functions`,
-:func:`wire_reads_of`) is public so tests and the bench harness can
-drive it over fixture TUs directly.
+:func:`wire_reads_of`) is public so tests can drive it over fixture TUs
+directly.
 """
 
 from __future__ import annotations

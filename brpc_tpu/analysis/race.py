@@ -20,7 +20,7 @@ What the harness reports (``findings()`` / ``report()``):
 
 When ``BRPC_TPU_RACECHECK`` is unset, :func:`checked_lock` returns a plain
 ``threading.Lock`` — the steady-state fabric carries zero extra overhead
-(asserted by ``bench_analysis.py`` / ``tests/test_race_harness.py``).
+(asserted by ``tests/test_race_harness.py``).
 
 Ordering edges are keyed by lock *name*, not instance: the fabric creates
 many instances per name (every reducer has a ``_mu``), and it is the
@@ -42,7 +42,7 @@ captures its stack eagerly — but the FIRST observation of a new ordering
 edge always captures the acquiring stack (lazily, at edge-record time),
 so the order graph itself stays exact: sampling degrades stack
 *context* on repeat acquisitions (shown as a placeholder), never edge or
-cycle detection.  ``bench_analysis.py`` records the sampled overhead.
+cycle detection.
 
 This module imports only the stdlib — it sits below ``obs`` and ``rpc``
 in the dependency order, never above.

@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache, at one fixed place.
 
-Every entry point that jits (``chip_smoke.py``, ``bench_device.py``,
-``__graft_entry__.py``) calls :func:`enable` before its first compilation,
+Every entry point that jits (``chip_smoke.py``, ``__graft_entry__.py``,
+``benchmark/harness.py``) calls :func:`enable` before its first compilation,
 so a second cold process finds what the first compiled.  The directory is
 part of the cache key, hence never a temp name, a pid or a time.  The
 native tier's PJRT compilations (``DeviceClient.compile``) do not pass

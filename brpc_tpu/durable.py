@@ -201,8 +201,8 @@ class CheckpointStore:
     arithmetic-free: it moves bytes, the server owns the math.
 
     ``fsync=False`` (the default) rides the OS page cache — that is
-    durable across process death, which is the failure mode the bench
-    kills with; power-loss durability costs ``fsync=True`` per record.
+    durable across process death, the failure the cold-restart tests
+    kill with; power-loss durability costs ``fsync=True`` per record.
     """
 
     def __init__(self, root: str, *, fsync: bool = False,
@@ -300,7 +300,6 @@ class CheckpointStore:
             self._delta_bytes = 0
         if obs.enabled():
             obs.counter("ps_ckpt_snapshots").add(1)
-            obs.counter("ps_ckpt_snapshot_bytes").add(len(payload))
             if compacting:
                 obs.counter("ps_ckpt_compactions").add(1)
 
@@ -333,7 +332,6 @@ class CheckpointStore:
             self._last_gen = gen
         if obs.enabled():
             obs.counter("ps_ckpt_deltas").add(1)
-            obs.counter("ps_ckpt_delta_bytes").add(len(rec))
         return True
 
     def should_compact(self) -> bool:

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax, shard_map
 from brpc_tpu import obs
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def _record_collective(op: str, x) -> None:  # lint: allow-trace-impure
@@ -201,42 +201,3 @@ class CollectiveChannel:
             return reducer(fn(shard), self.axis)
 
         return _mr(x)
-
-
-def allreduce_benchmark(
-    mesh: Mesh,
-    axis: str = "dp",
-    size_mb: float = 64.0,
-    iters: int = 20,
-    dtype=jnp.float32,
-):
-    """The BASELINE #3 workload: fp32 AllReduce over ICI; returns GB/s/chip.
-
-    Algorithm bandwidth = 2*(n-1)/n * bytes / time per chip (ring allreduce
-    moves each byte twice around all-but-one hops).
-    """
-    import time
-
-    n = mesh.shape[axis]
-    elems = int(size_mb * 1e6 / np.dtype(dtype).itemsize)
-    elems = max(elems - elems % (n * 128), n * 128)
-    chan = CollectiveChannel(mesh, axis)
-    x = jax.device_put(
-        jnp.ones((elems,), dtype),
-        NamedSharding(mesh, P(axis)),
-    )
-    ar = jax.jit(lambda a: chan.all_reduce(a, "sum"))
-    jax.block_until_ready(ar(x))  # compile
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = ar(x)
-    jax.block_until_ready(out)
-    dt = (time.perf_counter() - t0) / iters
-    nbytes = elems * np.dtype(dtype).itemsize
-    algo_bytes = 2 * (n - 1) / n * nbytes
-    return {
-        "bytes": nbytes,
-        "seconds": dt,
-        "gbps_per_chip": algo_bytes / dt / 1e9,
-        "devices": n,
-    }

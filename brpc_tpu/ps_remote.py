@@ -86,23 +86,6 @@ def _record_ps_server(shard_index: int, method: str, count: int,
     obs.counter("ps_server_bytes_out").add(rsp_len)
 
 
-class _ExclusiveAsRw:
-    """Presents a plain mutex through the ``read()``/``write()`` surface
-    (the pre-parallel single-lock serving model — kept as the bench
-    baseline for ``bench_ps.py``'s mutex-vs-rwlock comparison)."""
-
-    __slots__ = ("_lock",)
-
-    def __init__(self, lock):
-        self._lock = lock
-
-    def read(self):
-        return self._lock
-
-    def write(self):
-        return self._lock
-
-
 def _pack_lookup_req(owned: np.ndarray) -> bytearray:
     """Frame a Lookup request into ONE pre-sized buffer, written in place
     (the old ``struct.pack + tobytes + concat`` built three intermediate
@@ -226,34 +209,14 @@ def _pack_stream_frame(seq: int, epoch: int, gen: int,
 # Byte-identical on the wire to the bytearray packers above (the
 # wire-contract registry claims them under the same schemas), but the
 # payload rides as BORROWED blocks: the few-byte header is the only copy.
-# Runtime-switchable so the zerocopy bench can measure the copy path as
-# its baseline in the same process.
-
-_zerocopy = [True]
-
 
 #: borrow-path engagement floor: below this payload size the per-call
 #: handle lifecycle (new/pin/destroy + finalizers) costs more than the
-#: memcpys it saves (bench_zerocopy's 16-byte cell measures the
-#: crossover), so small unary legs stay on the bytes path.  The RPC
-#: tier enforces the same floor for explicit IOBuf callers
+#: memcpys it saves, so small unary legs stay on the bytes path.  The
+#: RPC tier enforces the same floor for explicit IOBuf callers
 #: (rpc.IOBUF_MIN_BYTES routes sub-floor payloads to the bytes twin),
 #: so the two crossovers are one constant.
 _ZC_MIN_BYTES = rpc.IOBUF_MIN_BYTES
-
-
-def zerocopy_enabled() -> bool:
-    """True when the PS hot paths frame through borrowed IOBuf blocks
-    instead of copying into request buffers."""
-    return _zerocopy[0] and rpc.native_core_available()
-
-
-def set_zerocopy(on: bool) -> bool:
-    """Flips the zero-copy hot paths (returns the previous setting) —
-    the A/B switch for ``bench_zerocopy``."""
-    prev = _zerocopy[0]
-    _zerocopy[0] = bool(on)
-    return prev
 
 
 def _pack_lookup_req_iobuf(owned: np.ndarray) -> "rpc.IOBuf":
@@ -1178,35 +1141,23 @@ class _Replicator:
             return None
         last = peer_gen
         tail_bytes = 0
-        if zerocopy_enabled():
-            # Whole tail in one batched native crossing, delta bodies
-            # borrowed rather than copied into frame bytes.
-            batch = []
+        # Whole tail in one batched native crossing, delta bodies
+        # borrowed rather than copied into frame bytes.
+        batch = []
+        try:
+            for gen, body in deltas:
+                batch.append(_pack_stream_frame_iobuf(
+                    gen, self.epoch, gen, body))
+                tail_bytes += len(batch[-1])
+                last = gen
             try:
-                for gen, body in deltas:
-                    batch.append(_pack_stream_frame_iobuf(
-                        gen, self.epoch, gen, body))
-                    tail_bytes += len(batch[-1])
-                    last = gen
-                try:
-                    st.writev(batch)
-                except rpc.RpcError:
-                    st.close()
-                    return None   # died mid-tail: wholesale converges
-            finally:
-                for io in batch:
-                    io.close()
-        else:
-            try:
-                for gen, body in deltas:
-                    frame = bytes(_pack_stream_frame(gen, self.epoch,
-                                                     gen, body))
-                    st.write(frame)
-                    tail_bytes += len(frame)
-                    last = gen
+                st.writev(batch)
             except rpc.RpcError:
                 st.close()
-                return None   # stream died mid-tail: wholesale converges
+                return None   # died mid-tail: wholesale converges
+        finally:
+            for io in batch:
+                io.close()
         with self._mu:
             p.stream = st
             p.synced_gen = last
@@ -1255,63 +1206,49 @@ class _Replicator:
                 p.wake.wait(0.05)
                 p.wake.clear()
                 continue
-            gen, frame = item
-            if gen <= p.synced_gen:
+            if item[0] <= p.synced_gen:
                 with self._mu:
                     if p.queue and p.queue[0] is item:
                         p.queue.popleft()
                 continue
-            if zerocopy_enabled():
-                # Drain the eligible head run in ONE native crossing —
-                # queue gens are append-ordered, so once the head
-                # clears ``synced_gen`` the whole run does.  Frame
-                # bytes are pinned (not copied) by ``writev``.
-                with self._mu:
-                    batch = []
-                    for it in p.queue:
-                        if it[0] <= p.synced_gen:
-                            break
-                        batch.append(it)
-                        if len(batch) >= 64:
-                            break
-                try:
-                    p.stream.writev([it[1] for it in batch])
-                except rpc.RpcError as e:
-                    # frames before the break ARE on the wire: pop
-                    # them so the resync does not re-ship
-                    nw = getattr(e, "frames_written", 0)
-                    st, p.stream = p.stream, None
-                    if st is not None:
-                        st.close()
-                    with self._mu:
-                        for it in batch[:nw]:
-                            if p.queue and p.queue[0] is it:
-                                p.queue.popleft()
-                        p.need_sync = True
-                    continue
-                with self._mu:
-                    for it in batch:
-                        if p.queue and p.queue[0] is it:
-                            p.queue.popleft()
-                continue
+            # Drain the eligible head run in ONE native crossing —
+            # queue gens are append-ordered, so once the head clears
+            # ``synced_gen`` the whole run does.  Frame bytes are
+            # pinned (not copied) by ``writev``.
+            with self._mu:
+                batch = []
+                for it in p.queue:
+                    if it[0] <= p.synced_gen:
+                        break
+                    batch.append(it)
+                    if len(batch) >= 64:
+                        break
             try:
-                p.stream.write(frame)
-            except rpc.RpcError:
+                p.stream.writev([it[1] for it in batch])
+            except rpc.RpcError as e:
+                # frames before the break ARE on the wire: pop them so
+                # the resync does not re-ship; the rest stay queued
+                # and the resync covers ordering
+                nw = getattr(e, "frames_written", 0)
                 st, p.stream = p.stream, None
                 if st is not None:
                     st.close()
                 with self._mu:
+                    for it in batch[:nw]:
+                        if p.queue and p.queue[0] is it:
+                            p.queue.popleft()
                     p.need_sync = True
-                continue  # frame stays queued; resync covers ordering
+                continue
             with self._mu:
-                if p.queue and p.queue[0] is item:
-                    p.queue.popleft()
+                for it in batch:
+                    if p.queue and p.queue[0] is it:
+                        p.queue.popleft()
 
     def stop(self, join: bool = True, fenced: bool = False) -> None:
         """Stop propagation.  Channels/streams are closed only AFTER
         every worker exited: a worker can be mid-``ch.call`` on one of
         them, and closing the native channel under it is a
-        use-after-free (the bring-up crash the churn bench found — a
+        use-after-free (a bring-up crash under churn: a
         fence-driven ``stop(join=False)`` used to close the channel
         set while a sibling worker's Sync was still on the wire).
         ``join=False`` (and any call from a worker/receiver thread —
@@ -1396,10 +1333,9 @@ class PsShardServer:
 
     def __init__(self, vocab: int, dim: int, shard_index: int,
                  num_shards: int, lr: float = 0.1, seed: int = 0,
-                 lock_mode: str = "rw", native_read: bool = False,
-                 combine: bool = False, stream: bool = False,
-                 importing: bool = False, scheme_version: int = 0,
-                 limiter=None):
+                 native_read: bool = False, combine: bool = False,
+                 stream: bool = False, importing: bool = False,
+                 scheme_version: int = 0, limiter=None):
         if vocab % num_shards:
             raise ValueError("num_shards must divide vocab")
         self.shard_index = shard_index
@@ -1416,14 +1352,7 @@ class PsShardServer:
         # Lookup gather racing an ApplyGrad scatter-sub on overlapping
         # rows reads torn updates.  Reads share, writes exclude: hot read
         # loads gather in parallel while ApplyGrad takes the write side.
-        # lock_mode="mutex" restores the old fully-serialized model (the
-        # bench baseline).
-        if lock_mode == "rw":
-            self._mu = checked_rwlock("ps.shard")
-        elif lock_mode == "mutex":
-            self._mu = _ExclusiveAsRw(checked_lock("ps.shard"))
-        else:
-            raise ValueError(f"unknown lock_mode {lock_mode!r}")
+        self._mu = checked_rwlock("ps.shard")
         self.native_read = bool(native_read)
         self.combine = bool(combine)
         self.stream = bool(stream)
@@ -2746,10 +2675,10 @@ class PsShardServer:
             with self._mu.read():
                 gathered = self.table[ids]
             # The gather above is the ONE unavoidable copy (fancy
-            # indexing materializes the rows); zero-copy mode responds
-            # with the gathered array pinned as a borrowed block instead
+            # indexing materializes the rows); past the borrow floor the
+            # gathered array responds pinned as a borrowed block instead
             # of paying tobytes + the respond append on top of it.
-            if zerocopy_enabled() and gathered.nbytes >= _ZC_MIN_BYTES:
+            if gathered.nbytes >= _ZC_MIN_BYTES:
                 out = rpc.IOBuf()
                 out.append_pinned(gathered)
                 return out
@@ -2913,7 +2842,7 @@ class DevicePsShardServer(PsShardServer):
         self._exe_mu = checked_lock("ps.device_shard.exe")
         self.lr_h = 0
         super().__init__(vocab, dim, shard_index, num_shards, lr=lr,
-                         seed=seed, lock_mode="rw", native_read=False,
+                         seed=seed, native_read=False,
                          combine=combine, stream=stream,
                          importing=importing,
                          scheme_version=scheme_version,
@@ -3459,8 +3388,7 @@ class DevicePsShardServer(PsShardServer):
             if pinned is None:
                 # Host-mirror read (backup serving a failover window /
                 # importing destination): identical to the CPU tier.
-                if zerocopy_enabled() and \
-                        gathered.nbytes >= _ZC_MIN_BYTES:
+                if gathered.nbytes >= _ZC_MIN_BYTES:
                     out = rpc.IOBuf()
                     out.append_pinned(gathered)
                     return out
@@ -3483,8 +3411,7 @@ class DevicePsShardServer(PsShardServer):
                 raw = self.dev.fetch(rows_h)
             finally:
                 self.dev.release(rows_h)
-            if zerocopy_enabled() and \
-                    count * self.dim * 4 >= _ZC_MIN_BYTES:
+            if count * self.dim * 4 >= _ZC_MIN_BYTES:
                 # Borrow the fetched bytes (pinning them) instead of
                 # slicing off a truncated copy + the respond append.
                 out = rpc.IOBuf()
@@ -3682,8 +3609,7 @@ class RemoteEmbedding:
     Per-shard requests fan out CONCURRENTLY via ``Channel.call_async``
     (the ParallelChannel-over-PartitionChannel shape, cpp/cluster/
     parallel_channel.* + partition_channel.*): whole-batch latency is
-    max(shard RTT) instead of sum(shard RTT).  ``parallel=False``
-    restores the sequential per-shard loop (the bench baseline).
+    max(shard RTT) instead of sum(shard RTT).
 
     Fault tolerance (brpc_tpu.resilience) is per shard:
 
@@ -3813,7 +3739,7 @@ class RemoteEmbedding:
         return emb
 
     def __init__(self, addresses: Sequence, vocab: int, dim: int,
-                 timeout_ms: int = 2000, parallel: bool = True, *,
+                 timeout_ms: int = 2000, *,
                  retry: "Optional[resilience.RetryPolicy]" = None,
                  deadline_ms: Optional[float] = None,
                  backup_ms: Optional[float] = None,
@@ -3826,7 +3752,6 @@ class RemoteEmbedding:
                  deadline_mode: str = "absolute"):
         self.vocab = vocab
         self.dim = dim
-        self.parallel = parallel
         self.timeout_ms = timeout_ms
         #: deadline propagation: with a ``deadline_ms`` budget set,
         #: every data-plane request (and every retry/hedge leg,
@@ -4723,38 +4648,6 @@ class RemoteEmbedding:
                     pc.cancel()
                     pc.close()
 
-    def _call_shard(self, view: _SchemeView, s: int, method: str,
-                    req: bytes) -> bytes:
-        """Sequential-path shard call with the same per-shard policy
-        (routed; a routing-correction error fails over once)."""
-        deadline = time.monotonic() + self.deadline_ms / 1000.0 \
-            if self.deadline_ms is not None else None
-        addr = self._route_read(view, s) if method == "Lookup" \
-            else self._route_write(view, s)
-        stamped = self._stamp(req, deadline)
-        try:
-            return self._chan(addr).call(
-                "Ps", method, stamped,
-                retry=self.retry, deadline_ms=self.deadline_ms,
-                backup_ms=self.backup_ms,
-                breaker=self._addr_breaker(addr))
-        except rpc.RpcError as e:
-            if method != "Lookup" and not self._scheme_miss(e) and \
-                    self._reroutable(view, s, e):
-                addr = self._route_write(view, s, {addr})
-                restamped = self._stamp(req, deadline)
-                try:
-                    return self._chan(addr).call(
-                        "Ps", method, restamped,
-                        retry=self.retry, deadline_ms=self.deadline_ms,
-                        backup_ms=self.backup_ms,
-                        breaker=self._addr_breaker(addr))
-                finally:
-                    self._close_stamped(req, restamped)
-            raise
-        finally:
-            self._close_stamped(req, stamped)
-
     def _owner_split(self, view: _SchemeView, flat_ids: np.ndarray):
         if flat_ids.size and (flat_ids.min() < 0
                               or flat_ids.max() >= self.vocab):
@@ -4808,8 +4701,6 @@ class RemoteEmbedding:
                      out: np.ndarray):
         """One whole-batch lookup under one scheme view; raises on any
         shard miss (the caller falls back across schemes)."""
-        zc = zerocopy_enabled()
-
         def _consume(rsp, owned):
             """Response rows as float32 — zero-copy for single-block
             IOBuf replies (one gather for multi-block), plain
@@ -4826,44 +4717,32 @@ class RemoteEmbedding:
             return np.frombuffer(rsp, np.float32).reshape(
                 owned.size, self.dim)
 
-        if self.parallel:
-            # Start every owner-shard call before joining any: the
-            # shards serve concurrently and the batch pays max(shard),
-            # not sum(shard).  _fan_out applies the per-shard
-            # resilience policy (retry/hedge/breaker) and cancels
-            # stragglers on an unrecoverable partial failure.
-            split = list(self._owner_split(view, flat))
-            items = []
-            rsps: List[object] = []
-            try:
-                for s, positions, owned in split:
-                    req = _pack_lookup_req_iobuf(owned) \
-                        if zc and owned.nbytes >= _ZC_MIN_BYTES \
-                        else _pack_lookup_req(owned)
-                    items.append((s, req))
-                rsps = self._fan_out(view, "Lookup", items)
-                for (s, positions, owned), rsp in zip(split, rsps):
-                    out[positions] = _consume(rsp, owned)
-            finally:
-                for _, req in items:
-                    if isinstance(req, rpc.IOBuf):
-                        req.close()
-                # a consume interrupted mid-batch must not strand the
-                # remaining response handles (close() is idempotent)
-                for rsp in rsps:
-                    if isinstance(rsp, rpc.IOBuf):
-                        rsp.close()
-        else:
-            for s, positions, owned in self._owner_split(view, flat):
+        # Start every owner-shard call before joining any: the shards
+        # serve concurrently and the batch pays max(shard), not
+        # sum(shard).  _fan_out applies the per-shard resilience policy
+        # (retry/hedge/breaker) and cancels stragglers on an
+        # unrecoverable partial failure.
+        split = list(self._owner_split(view, flat))
+        items = []
+        rsps: List[object] = []
+        try:
+            for s, positions, owned in split:
                 req = _pack_lookup_req_iobuf(owned) \
-                    if zc and owned.nbytes >= _ZC_MIN_BYTES \
+                    if owned.nbytes >= _ZC_MIN_BYTES \
                     else _pack_lookup_req(owned)
-                try:
-                    rsp = self._call_shard(view, s, "Lookup", req)
-                finally:
-                    if isinstance(req, rpc.IOBuf):
-                        req.close()
+                items.append((s, req))
+            rsps = self._fan_out(view, "Lookup", items)
+            for (s, positions, owned), rsp in zip(split, rsps):
                 out[positions] = _consume(rsp, owned)
+        finally:
+            for _, req in items:
+                if isinstance(req, rpc.IOBuf):
+                    req.close()
+            # a consume interrupted mid-batch must not strand the
+            # remaining response handles (close() is idempotent)
+            for rsp in rsps:
+                if isinstance(rsp, rpc.IOBuf):
+                    rsp.close()
 
     def lookup(self, ids: np.ndarray) -> np.ndarray:
         rec = obs.enabled()
@@ -4923,13 +4802,7 @@ class RemoteEmbedding:
             self._note_acked_gen(view, items[i][0], rsp)
 
         try:
-            if self.parallel:
-                self._fan_out(view, "ApplyGradId", items,
-                              on_done=_on_done)
-            else:
-                for i, (s, req) in enumerate(items):
-                    _on_done(i, self._call_shard(view, s, "ApplyGradId",
-                                                 req))
+            self._fan_out(view, "ApplyGradId", items, on_done=_on_done)
         except rpc.RpcError as e:
             if not self._scheme_miss(e):
                 raise
@@ -5065,53 +4938,38 @@ class RemoteEmbedding:
                 # seqs are contiguous per shard: the unsent tail starts
                 # right past the cursor
                 start = max(0, sent - frames[0][0] + 1) if frames else 0
-                if zerocopy_enabled():
-                    # Batched zero-copy replay: every eligible frame in
-                    # ONE native crossing (header blocks owned, bodies
-                    # borrowed).  The fence check moves to batch
-                    # granularity — a fence landing mid-batch is the
-                    # same race the per-frame path had between check
-                    # and write.
-                    if recv is not None and recv.fenced:
-                        raise rpc.RpcError(
-                            self._fence_code(recv),
-                            f"shard {s} push stream fenced")
-                    seqs = []
-                    batch = []
-                    try:
-                        for seq, body in frames[start:]:
-                            if seq <= sent:
-                                continue
-                            seqs.append(seq)
-                            batch.append(
-                                _pack_stream_frame_iobuf(seq, 0, 0,
-                                                         body))
-                        if batch:
-                            try:
-                                st.writev(batch)
-                            except rpc.RpcError as e:
-                                nw = getattr(e, "frames_written", 0)
-                                if nw:
-                                    # frames before the break ARE on
-                                    # the wire: advance the cursor so
-                                    # the reconnect replays the tail
-                                    self._push_sent[s] = sent = \
-                                        seqs[nw - 1]
-                                raise
-                            self._push_sent[s] = sent = seqs[-1]
-                    finally:
-                        for io in batch:
-                            io.close()
-                else:
+                # Batched zero-copy replay: every eligible frame in ONE
+                # native crossing (header blocks owned, bodies
+                # borrowed).  The fence is checked at batch granularity,
+                # before the write and again after it.
+                if recv is not None and recv.fenced:
+                    raise rpc.RpcError(
+                        self._fence_code(recv),
+                        f"shard {s} push stream fenced")
+                seqs = []
+                batch = []
+                try:
                     for seq, body in frames[start:]:
-                        if recv is not None and recv.fenced:
-                            raise rpc.RpcError(
-                                self._fence_code(recv),
-                                f"shard {s} push stream fenced")
                         if seq <= sent:
                             continue
-                        st.write(_pack_stream_frame(seq, 0, 0, body))
-                        self._push_sent[s] = sent = seq
+                        seqs.append(seq)
+                        batch.append(
+                            _pack_stream_frame_iobuf(seq, 0, 0, body))
+                    if batch:
+                        try:
+                            st.writev(batch)
+                        except rpc.RpcError as e:
+                            nw = getattr(e, "frames_written", 0)
+                            if nw:
+                                # frames before the break ARE on the
+                                # wire: advance the cursor so the
+                                # reconnect replays the tail
+                                self._push_sent[s] = sent = seqs[nw - 1]
+                            raise
+                        self._push_sent[s] = sent = seqs[-1]
+                finally:
+                    for io in batch:
+                        io.close()
                 if recv is not None and recv.fenced:
                     raise rpc.RpcError(
                         self._fence_code(recv),

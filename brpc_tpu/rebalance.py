@@ -232,7 +232,7 @@ class Rebalancer(threading.Thread):
     retirement).  Both callbacks run on the rebalancer thread.
 
     :meth:`step` is one full observe→decide→execute cycle and is public
-    so tests (and the churn bench) can drive it deterministically; the
+    so tests can drive it deterministically; the
     thread just calls it on a loop.  Every action is also counted
     (``ps_rebalance_splits`` / ``ps_rebalance_merges`` /
     ``ps_failbacks`` / ``ps_rebalance_errors``)."""
@@ -284,7 +284,7 @@ class Rebalancer(threading.Thread):
         #: trail behind ps_rebalance_errors
         self.errors: List[str] = []
         #: decision trail (bounded): what was decided, on which scheme,
-        #: off which rates — the churn bench's post-mortem surface
+        #: off which rates — the post-mortem surface
         self.log: List[str] = []
 
     # -- plumbing ----------------------------------------------------------

@@ -54,7 +54,7 @@ from brpc_tpu.naming import (NamingClient, PartitionScheme,
 from brpc_tpu.ps_remote import (_pack_apply_req, _pack_stream_frame,
                                 _pack_stream_frame_iobuf, _pack_windows,
                                 _reject_frame, _unpack_apply,
-                                _unpack_windows, zerocopy_enabled)
+                                _unpack_windows)
 
 
 class _ShipperAckReceiver:
@@ -372,7 +372,7 @@ class MigrationShipper:
         glast = mark        # last source gen RELEVANT to this target
         slast = mark        # last source gen covered (relevant or not)
         tail_bytes = 0
-        batch = []          # zero-copy mode: whole tail in one writev
+        batch = []          # whole tail in one writev, bodies borrowed
         try:
             for gen, body in deltas:
                 windows, off = _unpack_windows(body)
@@ -388,15 +388,9 @@ class MigrationShipper:
                             + bytes(_pack_apply_req(
                                 gids[mask].astype(np.int32),
                                 grads[mask])))
-                if zerocopy_enabled():
-                    batch.append(_pack_stream_frame_iobuf(
-                        gen, self.scheme, gen, filtered))
-                    tail_bytes += len(batch[-1])
-                else:
-                    frame = bytes(_pack_stream_frame(
-                        gen, self.scheme, gen, filtered))
-                    st.write(frame)
-                    tail_bytes += len(frame)
+                batch.append(_pack_stream_frame_iobuf(
+                    gen, self.scheme, gen, filtered))
+                tail_bytes += len(batch[-1])
                 glast = gen
             if batch:
                 st.writev(batch)
@@ -493,54 +487,41 @@ class MigrationShipper:
                 t.wake.wait(0.05)
                 t.wake.clear()
                 continue
-            gen, frame = item
-            if gen <= t.synced_gen:
+            if item[0] <= t.synced_gen:
                 with self._mu:
                     if t.queue and t.queue[0] is item:
                         t.queue.popleft()
                 continue
-            if zerocopy_enabled():
-                # Batch the eligible head run through one writev —
-                # queue gens are append-ordered, so once the head
-                # clears ``synced_gen`` the whole run does.
-                with self._mu:
-                    batch = []
-                    for it in t.queue:
-                        if it[0] <= t.synced_gen:
-                            break
-                        batch.append(it)
-                        if len(batch) >= 64:
-                            break
-                try:
-                    t.stream.writev([it[1] for it in batch])
-                except rpc.RpcError as e:
-                    nw = getattr(e, "frames_written", 0)
-                    st, t.stream = t.stream, None
-                    if st is not None:
-                        st.close()
-                    with self._mu:
-                        for it in batch[:nw]:
-                            if t.queue and t.queue[0] is it:
-                                t.queue.popleft()
-                        t.need_sync = True
-                    continue
-                with self._mu:
-                    for it in batch:
-                        if t.queue and t.queue[0] is it:
-                            t.queue.popleft()
-                continue
+            # Batch the eligible head run through one writev — queue
+            # gens are append-ordered, so once the head clears
+            # ``synced_gen`` the whole run does.
+            with self._mu:
+                batch = []
+                for it in t.queue:
+                    if it[0] <= t.synced_gen:
+                        break
+                    batch.append(it)
+                    if len(batch) >= 64:
+                        break
             try:
-                t.stream.write(frame)
-            except rpc.RpcError:
+                t.stream.writev([it[1] for it in batch])
+            except rpc.RpcError as e:
+                # frames before the break ARE on the wire; the rest
+                # stay queued and the resync covers ordering
+                nw = getattr(e, "frames_written", 0)
                 st, t.stream = t.stream, None
                 if st is not None:
                     st.close()
                 with self._mu:
+                    for it in batch[:nw]:
+                        if t.queue and t.queue[0] is it:
+                            t.queue.popleft()
                     t.need_sync = True
-                continue  # frame stays queued; resync covers ordering
+                continue
             with self._mu:
-                if t.queue and t.queue[0] is item:
-                    t.queue.popleft()
+                for it in batch:
+                    if t.queue and t.queue[0] is it:
+                        t.queue.popleft()
 
     def stop(self, join: bool = True) -> None:
         self._stop.set()

@@ -89,7 +89,8 @@ def _load_inner():
     too because the build globs its sources."""
     build = _build_dir()
     src = os.path.dirname(build)
-    # Concurrent first touches (pytest + bench children) share one tree:
+    # Concurrent first touches (pytest workers, a benchmark's worker
+    # processes) share one tree:
     # serialize them on the source directory.
     lock = os.open(src, os.O_RDONLY)
     try:

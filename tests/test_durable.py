@@ -19,6 +19,7 @@ instead of a wholesale Sync off the live source.
 
 import os
 import struct
+import threading
 import time
 
 import numpy as np
@@ -28,7 +29,9 @@ from brpc_tpu import durable, fault, obs, rpc, wire
 from brpc_tpu.durable import (CheckpointStore, _pack_delta, _pack_marker,
                               _pack_snapshot, _unpack_delta,
                               _unpack_marker, _unpack_snapshot)
-from brpc_tpu.ps_remote import (_pack_apply_req, _pack_windows,
+from brpc_tpu.naming import ReplicaSet
+from brpc_tpu.ps_remote import (PsShardServer, RemoteEmbedding,
+                                _pack_apply_req, _pack_windows,
                                 _unpack_apply)
 
 ROWS, DIM = 16, 4
@@ -363,34 +366,135 @@ def _wait(pred, deadline_s=10.0):
     return False
 
 
+def _fleet(root, nshards, nrep):
+    """``nshards`` x ``nrep`` servers on one seed, each attached to a
+    store of its own under ``root`` before replication is configured,
+    replica 0 the declared primary.  ``points[s][r]`` is what the
+    attach recovered (None from an empty store)."""
+    servers, stores, sets, points = [], [], [], []
+    for s in range(nshards):
+        row = [PsShardServer(VOCAB, DIM, s, nshards, lr=1.0, seed=3)
+               for _ in range(nrep)]
+        srow = [CheckpointStore(os.path.join(str(root), f"s{s}r{r}"))
+                for r in range(nrep)]
+        points.append([sv.attach_checkpoint(st)
+                       for sv, st in zip(row, srow)])
+        rs = ReplicaSet(tuple(sv.address for sv in row), primary=0)
+        if nrep > 1:
+            for r, sv in enumerate(row):
+                sv.configure_replication(rs, r)
+        servers.append(row)
+        stores.append(srow)
+        sets.append(rs)
+    return servers, stores, sets, points
+
+
+def _close_fleet(servers, stores):
+    for row in servers:
+        for sv in row:
+            sv.close()
+    for srow in stores:
+        for st in srow:
+            st.close()
+
+
 @pytest.mark.needs_native
-def test_server_tee_and_cold_restart_exact(tmp_path):
-    from brpc_tpu.ps_remote import PsShardServer
-    sv = PsShardServer(VOCAB, DIM, 0, 1, lr=1.0, seed=3)
-    store = CheckpointStore(str(tmp_path))
+@pytest.mark.parametrize("nshards,nrep,midload", [
+    (1, 1, False),      # one server, closed at rest
+    (2, 2, True),       # every server of a replicated fleet, mid-load
+])
+def test_server_tee_and_cold_restart_exact(tmp_path, nshards, nrep,
+                                           midload):
+    """Every server dies; fresh servers on the same stores replay base
+    + deltas to the exact acked ledger.  Mid-load the one write in
+    flight at the kill was never acknowledged, so a shard may hold it
+    or not: that, and nothing else, is allowed either way."""
+    grad = np.float32(2.0 ** -6)
+    batch, enough = 8, 12
+    servers, stores, sets, points = _fleet(tmp_path, nshards, nrep)
+    assert points == [[None] * nrep] * nshards      # nothing to recover
+    init = np.concatenate([row[0].table.copy() for row in servers])
+    emb = RemoteEmbedding(sets, VOCAB, DIM, timeout_ms=5000)
+    counts = np.zeros(VOCAB, np.int64)              # acked occurrences
+    acked = [0]
+    unacked = [None]                                # the batch in flight
+    stop = threading.Event()
+
+    def writer():
+        rng = np.random.default_rng(4)
+        while not stop.is_set():
+            ids = rng.integers(0, VOCAB, batch).astype(np.int32)
+            try:
+                emb.apply_gradients(ids, np.full((batch, DIM), grad))
+            except rpc.RpcError:
+                unacked[0] = ids
+                return
+            np.add.at(counts, ids, 1)
+            acked[0] += 1
+            if not midload and acked[0] == enough:
+                return
+
+    wt = threading.Thread(target=writer, daemon=True)
     try:
-        assert sv.attach_checkpoint(store) is None  # nothing to recover
-        for g in range(1, 6):
-            _apply(sv.address, [g % VOCAB, (g + 7) % VOCAB], g)
-        expect = sv.table.copy()
-        gen = sv._install_gen
+        wt.start()
+        if midload:
+            assert _wait(lambda: acked[0] >= enough or not wt.is_alive())
+            # The kill: every endpoint dead at one instant, so that no
+            # client failover can promote a backup inside the kill and
+            # take an acked write the declared primary's store lacks;
+            # then every server closes under the running writer.
+            fault.install(fault.FaultPlan(fault.kill_rules(
+                *[sv.address for row in servers for sv in row]), seed=5))
+        else:
+            wt.join(timeout=30)
+        gens = [row[0]._install_gen for row in servers]
     finally:
-        sv.close()
-        store.close()
-    # cold restart: fresh process state, same store root
-    sv2 = PsShardServer(VOCAB, DIM, 0, 1, lr=1.0, seed=3)
-    store2 = CheckpointStore(str(tmp_path))
+        _close_fleet(servers, stores)
+        stop.set()
+        wt.join(timeout=30)
+        emb.close()
+        fault.clear()
+    assert not wt.is_alive() and acked[0] >= enough
+    assert midload or unacked[0] is None
+
+    # cold restart: fresh process state, same store roots
+    servers2, stores2, sets2, points = _fleet(tmp_path, nshards, nrep)
+    emb2 = RemoteEmbedding(sets2, VOCAB, DIM, timeout_ms=5000)
     try:
-        point = sv2.attach_checkpoint(store2)
-        assert point is not None and point.gen == gen
-        assert sv2._install_gen == gen
-        assert np.array_equal(sv2.table, expect)    # bit-exact ledger
-        # the tee re-armed on a fresh base: applies keep checkpointing
-        _apply(sv2.address, [1, 2], 9)
-        assert store2.last_gen == sv2._install_gen
+        expect = init.copy()
+        for step in range(int(counts.max())):
+            expect[counts > step] -= grad
+        rows_per = VOCAB // nshards
+        for s, row in enumerate(servers2):
+            prim, point, lo = row[0], points[s][0], s * rows_per
+            assert point is not None and prim._install_gen == point.gen
+            allowed = [expect[lo:lo + rows_per]]
+            if unacked[0] is None:
+                assert point.gen == gens[s]
+            else:
+                mine = unacked[0][(unacked[0] >= lo)
+                                  & (unacked[0] < lo + rows_per)] - lo
+                landed = allowed[0].copy()
+                np.subtract.at(landed, mine, grad)
+                allowed.append(landed)
+            assert any(np.array_equal(prim.table, t) for t in allowed), \
+                f"shard {s}: restored table is neither the acked " \
+                f"ledger nor the ledger plus the unacked batch"
+        # the tee re-armed on a fresh base: the restored fleet keeps
+        # taking acked writes, keeps checkpointing them, and every
+        # backup converges on its primary
+        before = [row[0].table.copy() for row in servers2]
+        ids = np.arange(VOCAB, dtype=np.int32)
+        emb2.apply_gradients(ids, np.full((VOCAB, DIM), grad))
+        for s, row in enumerate(servers2):
+            assert np.array_equal(row[0].table, before[s] - grad)
+            assert stores2[s][0].last_gen == row[0]._install_gen
+            for backup in row[1:]:
+                assert _wait(lambda: np.array_equal(backup.table,
+                                                    row[0].table))
     finally:
-        sv2.close()
-        store2.close()
+        emb2.close()
+        _close_fleet(servers2, stores2)
 
 
 @pytest.mark.needs_native

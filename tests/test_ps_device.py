@@ -217,6 +217,32 @@ def _close_all(*servers):
         sv.close()
 
 
+def _wait_for(cond, what, deadline_s=30.0):
+    """Poll ``cond`` until it holds; a wait that runs out fails the test
+    by the name of the condition, not by whatever it leaves broken."""
+    deadline = time.monotonic() + deadline_s
+    while not cond():
+        assert time.monotonic() < deadline, \
+            f"timed out after {deadline_s:g}s waiting for: {what}"
+        time.sleep(0.01)
+
+
+def _mirrored_down(server, mirrors0):
+    """``server`` left HBM-serving mode and the fold is on the books:
+    ``_mirror_down`` counts ``ps_device_mirror_downs`` after it has
+    dropped the lock it cleared ``_dev_serving`` under."""
+    return (not server._dev_serving and int(obs.counter(
+        "ps_device_mirror_downs").get_value()) > mirrors0)
+
+
+def _delta_stream_up(primary):
+    """The primary's delta stream to a backup is connected and synced:
+    from here on a write acks only once the backup holds it, and a
+    fence notification can ride the stream's reply half."""
+    return any(p.stream is not None and not p.need_sync
+               for p in primary._replicator._peers)
+
+
 def test_device_kill_primary_failover_zero_failed_lookups():
     """Kill the HBM-serving primary under sustained load: every lookup
     and write still succeeds (redirect + failover), the backup's host
@@ -237,7 +263,14 @@ def test_device_kill_primary_failover_zero_failed_lookups():
     mirrors0 = int(obs.counter("ps_device_mirror_downs").get_value())
     try:
         assert servers[0]._dev_serving and not servers[1]._dev_serving
-        emb.apply_gradients(ids, grads)      # warm: streams + replicas
+        before = servers[0].table.copy()
+        # warm: streams + replicas.  A pair acks a write once its
+        # CONNECTED backups hold it, so a primary killed before its
+        # delta stream came up leaves a backup behind the acked gen,
+        # which the client refuses to promote (2008).
+        _wait_for(lambda: _delta_stream_up(servers[0]),
+                  "the primary's delta stream to its backup is up")
+        emb.apply_gradients(ids, grads)
         prim = servers[0].address
         fault.install(fault.FaultPlan(fault.kill_rules(prim), seed=3))
         # sustained load with the primary dead: every batch must
@@ -261,19 +294,28 @@ def test_device_kill_primary_failover_zero_failed_lookups():
         # the prober revives the corpse; the new primary's propagation
         # fences it into a BACKUP — which folds its HBM table down
         # into the host mirror (nothing device-applied is lost)
-        deadline = time.monotonic() + 3.0
-        while time.monotonic() < deadline and emb._isolated(prim):
-            time.sleep(0.02)
+        _wait_for(lambda: not emb._isolated(prim),
+                  "the prober revives the killed primary's breaker")
         assert not emb._isolated(prim)
         emb.apply_gradients(ids, grads)
-        deadline = time.monotonic() + 3.0
-        while time.monotonic() < deadline and (servers[0].is_primary
-                                               or servers[0]._dev_serving):
-            time.sleep(0.02)
+        _wait_for(lambda: not servers[0].is_primary
+                  and _mirrored_down(servers[0], mirrors0),
+                  "the revived ex-primary is fenced into a backup and "
+                  "its mirror-down (HBM -> host) ends")
         assert not servers[0].is_primary
         assert not servers[0]._dev_serving
         assert int(obs.counter("ps_device_mirror_downs").get_value()) \
             > mirrors0
+        # zero lost acked updates across failover and revival: every
+        # acknowledged write (warm, the window's, the one after the
+        # revival) is on the serving primary exactly once, and the
+        # fenced ex-primary converges on it byte for byte
+        expect = before.copy()
+        for _ in range(writes + 2):
+            expect -= np.float32(1.0)
+        assert np.array_equal(servers[1].table, expect)
+        _wait_for(lambda: np.array_equal(servers[0].table, expect),
+                  "the fenced ex-primary catches up with its usurper")
     finally:
         fault.clear()
         emb.close()
@@ -293,11 +335,8 @@ def test_device_fenced_stale_primary_rejected_and_mirrored_down():
     try:
         # wait for the (eagerly connected) delta stream: the fence
         # notification rides its reply half
-        deadline = time.monotonic() + 5.0
-        while time.monotonic() < deadline and not any(
-                p.stream is not None and not p.need_sync
-                for p in old._replicator._peers):
-            time.sleep(0.01)
+        _wait_for(lambda: _delta_stream_up(old),
+                  "the old primary's delta stream to its backup is up")
         # Partition the old primary's replication CONTROL plane so the
         # new primary cannot inform it (otherwise the eager propagation
         # demotes it instantly) — the old data stream stays up.
@@ -333,7 +372,11 @@ def test_device_fenced_stale_primary_rejected_and_mirrored_down():
         finally:
             ch_old.close()
         assert not old.is_primary
-        # the fence demotion folded the device table into the mirror
+        # the fence demotion folded the device table into the mirror:
+        # the notification's receiver thread drops the primary flag
+        # first and DMAs the table down after, off every lock
+        _wait_for(lambda: _mirrored_down(old, mirrors0),
+                  "the fenced primary's mirror-down (HBM -> host) ends")
         assert not old._dev_serving
         assert int(obs.counter("ps_device_mirror_downs").get_value()) \
             > mirrors0
@@ -493,10 +536,9 @@ def test_device_split_shipper_retargets_to_promoted_dest_backup():
         assert dst_b.is_primary
         emb.apply_gradients(ids, np.full((VOCAB, DIM), 0.25,
                                          np.float32))
-        deadline = time.monotonic() + 10.0
-        while time.monotonic() < deadline and int(obs.counter(
-                "ps_migration_retargets").get_value()) <= retargets0:
-            time.sleep(0.02)
+        _wait_for(lambda: int(obs.counter(
+            "ps_migration_retargets").get_value()) > retargets0,
+            "the stranded shipper re-points at the promoted backup")
         assert int(obs.counter("ps_migration_retargets").get_value()) \
             > retargets0
         drv.wait_caught_up(deadline_s=30)

@@ -114,20 +114,25 @@ VOCAB, DIM, SHARDS = 64, 16, 4
 
 @pytest.mark.needs_native
 def test_parallel_lookup_matches_sequential_client():
+    """The concurrent fan-out answers what one shard after another would:
+    the reference is the servers' own seeded tables, read and updated in
+    numpy row by row in request order (duplicates accumulate through
+    ``subtract.at`` exactly as each shard applies them)."""
     servers = [PsShardServer(VOCAB, DIM, i, SHARDS) for i in range(SHARDS)]
-    addrs = [s.address for s in servers]
-    par = RemoteEmbedding(addrs, VOCAB, DIM)
-    seq = RemoteEmbedding(addrs, VOCAB, DIM, parallel=False)
+    emb = RemoteEmbedding([s.address for s in servers], VOCAB, DIM)
     try:
+        # a copy, taken before the write
+        table = np.concatenate([s.table for s in servers])
         rng = np.random.default_rng(7)
         ids = rng.integers(0, VOCAB, size=(5, 6)).astype(np.int32)
-        np.testing.assert_array_equal(par.lookup(ids), seq.lookup(ids))
+        np.testing.assert_array_equal(emb.lookup(ids), table[ids])
         grads = rng.standard_normal((5, 6, DIM)).astype(np.float32)
-        par.apply_gradients(ids, grads)   # all shards, concurrently
-        np.testing.assert_array_equal(par.lookup(ids), seq.lookup(ids))
+        emb.apply_gradients(ids, grads)   # all shards, concurrently
+        np.subtract.at(table, ids.reshape(-1),
+                       servers[0].lr * grads.reshape(-1, DIM))
+        np.testing.assert_array_equal(emb.lookup(ids), table[ids])
     finally:
-        par.close()
-        seq.close()
+        emb.close()
         for s in servers:
             s.close()
 

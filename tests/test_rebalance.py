@@ -301,19 +301,29 @@ def test_rebalancer_fails_back_revived_primary():
 
 
 @pytest.mark.needs_native
-def test_rebalancer_splits_on_sustained_load_end_to_end():
-    """The full autonomous loop on real servers: sustained per-shard
-    rate above the split threshold -> the rebalancer provisions the
-    successor through its provisioner, drives the migration, retires
-    the old scheme, and hands the old servers to on_retired — no
-    operator call anywhere."""
+@pytest.mark.parametrize("kind,n_old,n_new,thresholds,reads,pause_s", [
+    # sustained per-shard rate above the split threshold
+    ("split", 2, 4, dict(split_qps=30.0, merge_qps=1.0), 10, 0.0),
+    # a cold load: every shard below the merge threshold, held there
+    ("merge", 4, 2, dict(split_qps=1e6, merge_qps=25.0, min_shards=2),
+     1, 0.1),
+])
+def test_rebalancer_splits_on_sustained_load_end_to_end(
+        kind, n_old, n_new, thresholds, reads, pause_s):
+    """The full autonomous loop on real servers, both directions:
+    a sustained per-shard rate past its threshold -> the rebalancer
+    provisions the successor through its provisioner, drives the
+    migration, retires the old scheme, and hands the old servers to
+    on_retired — no operator call anywhere, and the acked ledger exact
+    across the migration."""
     from brpc_tpu import rpc
     from brpc_tpu.naming import (NamingClient, PartitionScheme,
-                                 ReplicaSet, publish_scheme)
+                                 ReplicaSet, parse_schemes,
+                                 publish_scheme)
     from brpc_tpu.ps_remote import PsShardServer, RemoteEmbedding
     reg_server, reg_addr = _registry(rpc)
-    old = [PsShardServer(VOCAB, DIM, s, 2, lr=1.0, stream=True)
-           for s in range(2)]
+    old = [PsShardServer(VOCAB, DIM, s, n_old, lr=1.0, stream=True)
+           for s in range(n_old)]
     sc1 = PartitionScheme(1, tuple(ReplicaSet.of(s.address)
                                    for s in old))
     nc = NamingClient(reg_addr)
@@ -331,8 +341,7 @@ def test_rebalancer_splits_on_sustained_load_end_to_end():
             ReplicaSet.of(s.address) for s in servers))
 
     pol = RebalancePolicy(RebalanceOptions(
-        split_qps=30.0, merge_qps=1.0, sustain_s=0.2,
-        min_interval_s=0.5))
+        sustain_s=0.2, min_interval_s=0.5, **thresholds))
     reb = Rebalancer(reg_addr, "ps", VOCAB, policy=pol,
                      provisioner=provisioner,
                      on_retired=retired.append,
@@ -344,23 +353,25 @@ def test_rebalancer_splits_on_sustained_load_end_to_end():
     try:
         emb.apply_gradients(ids, np.full((VOCAB, DIM), 0.5,
                                          np.float32))
-        # sustained read load above the threshold while stepping
+        # the read load held while stepping
         decided = None
         deadline = time.monotonic() + 30.0
         while time.monotonic() < deadline and decided is None:
-            for _ in range(10):
+            for _ in range(reads):
                 emb.lookup(ids[:64])
+            time.sleep(pause_s)
             decided = reb.step()
-        assert decided is not None and decided.kind == "split"
-        assert decided.num_shards == 4
-        # the split completed: the registry's active scheme is v2 and
-        # the ledger is exact across it
+        assert decided is not None and decided.kind == kind
+        assert decided.num_shards == n_new
+        assert int(obs.counter(f"ps_rebalance_{kind}s").get_value()) >= 1
+        # the migration completed: the registry's active scheme is v2
+        # and the ledger is exact across it
         nodes, _ = nc.list("ps")
-        from brpc_tpu.naming import parse_schemes
         schemes = parse_schemes(nodes)
         assert schemes[2].state == "active"
         assert schemes[1].state == "retired"
         assert retired and retired[0].version == 1
+        assert len(spawned) == n_new
         emb.apply_gradients(ids, np.full((VOCAB, DIM), 0.25,
                                          np.float32))
         expect = before.copy()

@@ -75,6 +75,23 @@ def _retry_policy(attempts=3, attempt_ms=300):
         attempt_timeout_ms=attempt_ms)
 
 
+def _wait_for(cond, what, deadline_s=30.0):
+    """Poll ``cond`` until it holds; a wait that runs out fails the test
+    by the name of the condition, not by whatever it leaves broken."""
+    deadline = time.monotonic() + deadline_s
+    while not cond():
+        assert time.monotonic() < deadline, \
+            f"timed out after {deadline_s:g}s waiting for: {what}"
+        time.sleep(0.01)
+
+
+def _delta_stream_up(primary):
+    """The primary's delta stream to a backup is connected and synced:
+    from here on a pair's write acks only once the backup holds it."""
+    return any(p.stream is not None and not p.need_sync
+               for p in primary._replicator._peers)
+
+
 # ---------------------------------------------------------------------------
 # naming: replica tags
 # ---------------------------------------------------------------------------
@@ -196,7 +213,12 @@ def test_primary_kill_promotion_zero_failed_lookups():
     ids = np.arange(128, dtype=np.int32) * 2
     grads = np.ones((128, DIM), np.float32)
     try:
-        emb.apply_gradients(ids, grads)      # warm: streams + replicas
+        # warm: streams + replicas (a primary killed before its delta
+        # stream came up leaves a backup behind the acked gen, which
+        # the client refuses to promote)
+        _wait_for(lambda: _delta_stream_up(servers[0][0]),
+                  "the primary's delta stream to its backup is up")
+        emb.apply_gradients(ids, grads)
         prim = servers[0][0].address
         fault.install(fault.FaultPlan(fault.kill_rules(prim), seed=3))
         # sustained load with the primary dead: every batch must
@@ -536,6 +558,12 @@ def test_zombie_primary_push_stream_fenced_no_lost_acks():
     try:
         emb.push_gradients(ids, delta)
         emb.flush_gradients()            # frame 1 acked everywhere
+        # "everywhere" is the pair's connected-backups barrier: a flush
+        # that beats the backup's delta stream coming up acks on the
+        # primary alone, and promoting the backup then is a lossy
+        # promotion the client rightly refuses (2008).
+        _wait_for(lambda: new._install_gen >= old._install_gen >= 1,
+                  "the backup holds frame 1 before it is promoted")
         # Out-of-band promotion: the old primary still holds the
         # client's push stream and may not know it is a zombie yet.
         ch = rpc.Channel(new.address, timeout_ms=5000)
