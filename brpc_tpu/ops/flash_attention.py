@@ -10,13 +10,15 @@ results carry names (``RESIDUAL_NAMES``: the head-major output and the
 log-sum-exp), so that a caller who recomputes its layers under
 ``jax.checkpoint`` can save them by name and not run the forward kernel a
 second time; q, k and v are its inputs and are the caller's to keep or
-recompute. The backward pass recomputes the
-probabilities tile by tile from q, k and the log-sum-exp: one kernel per
-query tile for dQ, one per KV head and key tile for dK and dV, which loops
-over the head's whole query group so that the GQA sum happens in its
-accumulator. Causal programs stop at the diagonal, so the wasted triangle is
-skipped at tile granularity, and only the tiles the diagonal crosses pay for
-a mask (guide: /opt/skills/guides/pallas_guide.md).
+recompute. The backward pass is one kernel, per KV head and key tile: it
+recomputes the probabilities and dS from q, k, the log-sum-exp and ``delta``
+once for each pair of tiles and takes all three gradients from them. It loops
+over the head's whole query group, so that the GQA sum happens in dK's and
+dV's accumulators; dQ of the group gathers in VMEM, in float32, across the
+head's key tiles (the grid's last axis, which runs in order) and is written
+once, at the last of them. Causal programs stop at the diagonal, so the
+wasted triangle is skipped at tile granularity, and only the tiles the
+diagonal crosses pay for a mask (guide: /opt/skills/guides/pallas_guide.md).
 
 Precision: the MXU gets its operands in the dtype they arrive in (bf16 on
 the training path) and accumulates in float32; scores, softmax statistics,
@@ -36,8 +38,9 @@ the residuals are kept head-major and the backward pass repeats none. Per-row
 statistics travel as [B, H, T/BLOCK, 1, BLOCK]: a row vector per tile, whole
 in its last two dimensions whatever the block.
 
-K and V of one head, and in the dK/dV kernel Q and dO of one query group,
-stay whole in VMEM (``supported`` bounds the sequence by that).
+K and V of one head in the forward kernel, and in the backward kernel Q, dO
+and both forms of dQ of one query group, stay whole in VMEM (``supported``
+bounds the sequence by that).
 
 ``flash_attention(..., interpret=True)`` runs the same kernels through the
 Pallas interpreter (CPU tests); on TPU leave it False.
@@ -64,39 +67,48 @@ _VMEM_LIMIT = 64 << 20   # of a v5e core's 128 MiB; Mosaic's default is 16
 RESIDUAL_NAMES = ("attn_flash_out", "attn_flash_lse")
 
 
-def choose_block(t: int) -> int:
-    """The tile along a sequence of ``t``, queries and keys alike, in all
-    three kernels: 512 where it divides. On a v5e at T = 2,048, D = 128 each
-    kernel is within 2% of its best there (0.60 / 0.61 / 0.73 ms), 256 costs
-    the three 30–40% more and 128 2–3 times (PERF.md section 6, PR 27);
-    larger tiles gain nothing and waste more of the diagonal. With two
-    widths the same rule holds: at T = 8,192, 32 heads, q/k 192 and v 128
-    the forward pass and forward + backward read 8.3 / 29.4 ms at (512, 512),
-    8.5 / 31.4 at (256, 512), 12.6 / 36.4 at (256, 256) and 8.7 / 29.2 at
-    (1,024, 1,024) (PERF.md section 6, PR 28): 512 is within 1% of the
-    best. A sequence none of them divides is one tile."""
-    return next((c for c in (512, 256, 128) if t % c == 0), t)
+def choose_block(t: int, backward: bool = False) -> int:
+    """The tile along a sequence of ``t``, queries and keys alike: 512 where
+    it divides, and in the backward kernel 1,024 from 8,192 tokens on. On a
+    v5e, each kernel alone (PERF.md section 6, PR 31): at T = 2,048, 32
+    heads over 8 of 128, forward 0.61 ms and backward 0.95 at 512 against
+    0.67 / 1.10 at 1,024 and 1.25 at 256 (the diagonal's tiles are half
+    wasted, and there are few others); at T = 8,192, 32 heads of 192 / 128,
+    forward 7.8 ms at 512 and 8.1 at 1,024, backward 15.5 at 512, 15.0 at
+    1,024 and 18.5–19.8 at 256; at 4,096 tokens the backward kernel reads
+    the same at both (4.59 / 4.60 ms), at 8,192 tokens of 128 / 128 9.8 /
+    9.1. The log-sum-exp's rows are the same bytes whatever the tile, so the
+    two passes need not share one. A sequence none of them divides is one
+    tile."""
+    sizes = (512, 256, 128)
+    if backward and t >= 8192:
+        sizes = (1024, *sizes)
+    return next((c for c in sizes if t % c == 0), t)
 
 
 def supported(q_shape, kv_shape, dtype, v_shape=None) -> bool:
     """Whether the compiled kernels take these operands: whole query groups,
     a v width (``v_shape``, k's where it is not given) that fills the MXU's
     128 lanes, a q/k width that is a multiple of 64 (192 is a block as wide
-    as its array, which Mosaic takes whole and pads to 256 lanes in VMEM: on
-    a v5e the three kernels at 192/128 run the 2.06 TFLOP a layer needs at
-    T = 8,192 in 29.4 ms, 36% of the MXU's peak against 47% at 128/128,
-    since a 192-wide contraction fills one and a half passes of the 128-wide
-    MXU; PERF.md section 6, PR 28), and a
-    sequence whose resident K and V, or Q and dO of one query group
-    (double-buffered by the pipeline), leave VMEM room for the score
-    tiles."""
+    as its array, which Mosaic takes whole and pads to 256 lanes in VMEM; a
+    192-wide contraction or result fills one and a half passes of the
+    128-wide MXU), and a sequence whose resident blocks leave VMEM room for
+    the score tiles. The backward kernel holds the most: of one query group
+    Q and dO (double-buffered by the pipeline), dQ transposed in float32 and
+    dQ's output block (double-buffered too) — 27.3 MB at T = 8,192, one
+    head a group, 192 / 128, and 16.8 MB at T = 2,048, four heads a group,
+    128 / 128, where a group of 4 fits 4,096 tokens. The forward kernel's K
+    and V of a head are less than that at any group size."""
     _, t, hq, d_qk = q_shape
     hkv = kv_shape[2]
     d_v = (v_shape or kv_shape)[3]
     if d_qk % 64 or d_v % 128 or t % 128 or hq % hkv:
         return False
-    resident = (2 * (hq // hkv) * t * (-(-d_qk // 128) * 128 + d_v)
-                * jnp.dtype(dtype).itemsize)
+    lanes_qk = -(-d_qk // 128) * 128
+    size = jnp.dtype(dtype).itemsize
+    resident = (hq // hkv) * t * (2 * (lanes_qk + d_v) * size   # Q, dO
+                                  + d_qk * 4                    # dQ.T
+                                  + 2 * lanes_qk * size)        # dQ's block
     return resident <= _VMEM_LIMIT // 2
 
 
@@ -108,6 +120,12 @@ def _nt(a, b):
 
 def _nn(a, b):
     return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def _tn(a, b):
+    """a.T @ b on the MXU, float32 out."""
+    return lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32)
 
 
 def _scores(a, b, scale: float, masked: bool, q0, k0, q_axis: int):
@@ -173,37 +191,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     lse_ref[0, 0, 0] = (m + jnp.log(l)).reshape(1, block_q)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-               block_k: int, causal: bool, scale: float):
-    block_q, d = q_ref.shape[2:]
-    t = k_ref.shape[2]
-    qi = pl.program_id(2)
-    q = q_ref[0, 0]
-    do = do_ref[0, 0]
-    lse = lse_ref[0, 0, 0].reshape(block_q, 1)
-    delta = delta_ref[0, 0, 0].reshape(block_q, 1)
-
-    def step(kj, dq, masked):
-        rows = pl.ds(pl.multiple_of(kj * block_k, block_k), block_k)
-        k = k_ref[0, 0, rows, :]
-        v = v_ref[0, 0, rows, :]
-        s = _scores(q, k, scale, masked, qi * block_q, kj * block_k, 0)
-        p = jnp.exp(s - lse)
-        ds = p * (_nt(do, v) - delta)
-        return dq + _nn(ds.astype(k.dtype), k)
-
-    n_clear, n_k = _key_tiles(qi, block_q, block_k, t, causal)
-    dq = lax.fori_loop(0, n_clear, functools.partial(step, masked=False),
-                       jnp.zeros((block_q, d), jnp.float32))
-    dq = lax.fori_loop(n_clear, n_k, functools.partial(step, masked=True),
-                       dq)
-    dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                dv_ref, *, block_q: int, causal: bool, scale: float):
-    """Scores are held transposed ([BK, BQ]), so the per-query statistics
-    broadcast as the row vectors they are stored as."""
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                dk_ref, dv_ref, dqt_acc, *, block_q: int, causal: bool,
+                scale: float):
+    """One key tile of one KV head against the query tiles of its whole
+    query group, the probabilities and dS recomputed once a tile pair.
+    Scores are held transposed ([BK, BQ]), so the per-query statistics
+    broadcast as the row vectors they are stored as. dK and dV gather in the
+    loop's carry. dQ gathers transposed, ``k.T @ dS`` into the [D, T] of
+    each head of the group in ``dqt_acc``, across the key tiles (the grid's
+    last axis, which runs in order), and is turned back, scaled and written
+    once, at the head's last key tile: the one product that contracts over
+    the tile's leading dimension then transposes k, the narrow operand,
+    where ``dS.T @ k`` would transpose a [BK, BQ] tile a pair."""
     group, t = q_ref.shape[1:3]
     block_k, d = k_ref.shape[2:]
     d_v = v_ref.shape[3]
@@ -212,16 +212,23 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
     v = v_ref[0, 0]
     first, clear = _query_tiles(kj, block_q, block_k, t, causal)
 
+    def tile(qj):
+        return pl.ds(pl.multiple_of(qj * block_q, block_q), block_q)
+
+    @pl.when(kj == 0)
+    def _():
+        dqt_acc[...] = jnp.zeros_like(dqt_acc)
+
     def step(qj, carry, g, masked):
         dk, dv = carry
-        rows = pl.ds(pl.multiple_of(qj * block_q, block_q), block_q)
-        q = q_ref[0, g, rows, :]
-        do = do_ref[0, g, rows, :]
+        q = q_ref[0, g, tile(qj), :]
+        do = do_ref[0, g, tile(qj), :]
         s = _scores(k, q, scale, masked, qj * block_q, kj * block_k, 1)
         p = jnp.exp(s - lse_ref[0, g, qj])
         dv = dv + _nn(p.astype(do.dtype), do)
-        ds = p * (_nt(v, do) - delta_ref[0, g, qj])
-        return dk + _nn(ds.astype(q.dtype), q), dv
+        ds = (p * (_nt(v, do) - delta_ref[0, g, qj])).astype(q.dtype)
+        dqt_acc[g, :, tile(qj)] += _tn(k, ds)
+        return dk + _nn(ds, q), dv
 
     dk = jnp.zeros((block_k, d), jnp.float32)
     carry = (dk, dk if d_v == d else jnp.zeros((block_k, d_v), jnp.float32))
@@ -235,12 +242,21 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
     dk_ref[0, 0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
+    @pl.when(kj == pl.num_programs(2) - 1)
+    def _():
+        def put(qj, carry, g):
+            dq_ref[0, g, tile(qj), :] = (
+                dqt_acc[g, :, tile(qj)].T * scale).astype(dq_ref.dtype)
+            return carry
+        for g in range(group):
+            lax.fori_loop(0, t // block_q, functools.partial(put, g=g), 0)
 
-def _call(kernel, name, interpret, **kwargs):
+
+def _call(kernel, name, interpret, last_axis="parallel", **kwargs):
     return pl.pallas_call(
         kernel, name=name, interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",) * 3,
+            dimension_semantics=("parallel", "parallel", last_axis),
             vmem_limit_bytes=_VMEM_LIMIT),
         **kwargs)
 
@@ -289,22 +305,16 @@ def _backward(q, k, v, o, lse, do, causal: bool, blocks: tuple,
     group = hq // hkv
     scale = d ** -0.5
     block_q, block_k = blocks
+    if not interpret and block_q % 128:
+        raise ValueError(f"the compiled backward kernel slices dQ's [D, T] "
+                         f"accumulator by query tile along the lanes: a "
+                         f"tile of {block_q} is no multiple of 128")
     with jax.named_scope("attn.flash_bwd"):
+        # a row per query tile: of this pass's tiles, whatever the forward
+        # pass's were (the same bytes in the same order)
+        lse = lse.reshape(b, hq, t // block_q, 1, block_q)
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1).reshape(lse.shape)
-        row_spec = pl.BlockSpec((1, 1, 1, 1, block_q),
-                                lambda bi, h, i: (bi, h, i, 0, 0))
-        dq = _call(
-            functools.partial(_dq_kernel, block_k=block_k, causal=causal,
-                              scale=scale),
-            "attn_flash_bwd_dq", interpret,
-            grid=(b, hq, t // block_q),
-            in_specs=[_tile_spec(block_q, d), _whole_spec(t, d, group),
-                      _whole_spec(t, d_v, group), _tile_spec(block_q, d_v),
-                      row_spec, row_spec],
-            out_specs=_tile_spec(block_q, d),
-            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        )(q, k, v, do, lse, delta)
 
         def group_spec(width):
             return pl.BlockSpec((1, group, t, width),
@@ -313,34 +323,38 @@ def _backward(q, k, v, o, lse, do, causal: bool, blocks: tuple,
         row_spec = pl.BlockSpec((1, group, t // block_q, 1, block_q),
                                 lambda bi, h, j: (bi, h, 0, 0, 0))
         kv_specs = [_tile_spec(block_k, d), _tile_spec(block_k, d_v)]
-        dk, dv = _call(
-            functools.partial(_dkv_kernel, block_q=block_q, causal=causal,
+        dq, dk, dv = _call(
+            functools.partial(_bwd_kernel, block_q=block_q, causal=causal,
                               scale=scale),
-            "attn_flash_bwd_dkv", interpret,
+            "attn_flash_bwd", interpret, last_axis="arbitrary",
             grid=(b, hkv, t // block_k),
             in_specs=[group_spec(d), *kv_specs, group_spec(d_v), row_spec,
                       row_spec],
-            out_specs=kv_specs,
-            out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+            out_specs=[group_spec(d), *kv_specs],
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                       jax.ShapeDtypeStruct(k.shape, k.dtype),
                        jax.ShapeDtypeStruct(v.shape, v.dtype)],
+            scratch_shapes=[pltpu.VMEM((group, d, t), jnp.float32)],
         )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _attend(q, k, v, causal, blocks, interpret):
-    return _forward(q, k, v, causal, blocks, interpret)[0]
+    """``blocks``: the forward pass's (query tile, key tile), then the
+    backward pass's."""
+    return _forward(q, k, v, causal, blocks[0], interpret)[0]
 
 
 def _attend_fwd(q, k, v, causal, blocks, interpret):
-    o, lse = _forward(q, k, v, causal, blocks, interpret)
+    o, lse = _forward(q, k, v, causal, blocks[0], interpret)
     o = checkpoint_name(o, RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
     return o, (q, k, v, o, lse)
 
 
 def _attend_bwd(causal, blocks, interpret, residuals, do):
-    return _backward(*residuals, do, causal, blocks, interpret)
+    return _backward(*residuals, do, causal, blocks[1], interpret)
 
 
 _attend.defvjp(_attend_fwd, _attend_bwd)
@@ -361,12 +375,13 @@ def flash_attention(
     interpret: bool = False,
 ) -> jax.Array:
     """q: [B,T,Hq,Dqk], k: [B,T,Hkv,Dqk], v: [B,T,Hkv,Dv] -> [B,T,Hq*Dv]
-    (llama.attention contract), differentiable. The tile is chosen from T;
-    ``block_q`` / ``block_k`` override it (tests)."""
+    (llama.attention contract), differentiable. The tiles are chosen from T;
+    ``block_q`` / ``block_k`` override them in both passes (tests)."""
     b, t, hq, _ = q.shape
-    blocks = (min(block_q or choose_block(t), t),
-              min(block_k or choose_block(t), t))
-    if t % blocks[0] or t % blocks[1]:
+    blocks = tuple(tuple(min(given or choose_block(t, backward), t)
+                         for given in (block_q, block_k))
+                   for backward in (False, True))
+    if any(t % block for pair in blocks for block in pair):
         raise ValueError(f"seq {t} must divide blocks {blocks}")
     q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))  # [B, H, T, D]
     out = _attend(q, k, v, causal, blocks, interpret)

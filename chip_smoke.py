@@ -246,10 +246,10 @@ def phase_kernel(sizes: Sizes, dry: bool) -> dict:
     with clock("compile_s"):
         lowered = forward_backward(functools.partial(
             flash_attention, interpret=dry)).lower(*args)
-        if not dry and lowered.as_text().count("tpu_custom_call") < 3:
+        if not dry and lowered.as_text().count("tpu_custom_call") < 2:
             raise SystemExit("chip_smoke: the lowered attention holds fewer "
-                             "than three tpu_custom_calls: the forward, dQ "
-                             "and dK/dV kernels did not all lower to Mosaic")
+                             "than two tpu_custom_calls: the forward and the "
+                             "backward kernel did not both lower to Mosaic")
         kernel = lowered.compile()
         dense = dense_form.lower(*args).compile()
     with clock("run_s"):

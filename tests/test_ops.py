@@ -141,6 +141,23 @@ def test_flash_grad_matches_dense(dtype, tol, group, causal, blocks):
         assert np.max(np.abs(g - r)) <= tol * np.max(np.abs(r)), name
 
 
+# The attention kernels' custom calls in a compiled program's text, and what
+# one forward and one backward pass hold: dQ, dK and dV come from one kernel.
+_ATTN_CALLS = re.compile(r"%(attn_flash_\w+?)(?:\.\d+)? = [^\n]*custom-call\(")
+_ONE_OF_EACH = ["attn_flash_bwd", "attn_flash_fwd"]
+
+
+# For the compiled backward kernel, whose query tile runs along the lanes of
+# dQ's accumulator and so is a multiple of 128: even tiles, a key tile of two
+# query tiles, bf16, then Llama-3-8B's head geometry.
+_TPU_BACKWARD_SHAPES = [
+    dict(t=256, block_q=128, block_k=128),
+    dict(t=256, block_q=128, block_k=256),
+    dict(t=256, block_q=128, block_k=128, dtype=jnp.bfloat16),
+    _TPU_SHAPES[-1],
+]
+
+
 def _grad_of_sum(**blocks):
     def loss(q, k, v):
         return jnp.sum(flash_attention(q, k, v, interpret=False, **blocks)
@@ -148,22 +165,23 @@ def _grad_of_sum(**blocks):
     return jax.jit(jax.grad(loss, (0, 1, 2)))
 
 
-@pytest.mark.parametrize("shape", _TPU_SHAPES)
+@pytest.mark.parametrize("shape", _TPU_BACKWARD_SHAPES)
 def test_flash_backward_lowers_for_tpu(shape):
-    """Forward, dQ and dK/dV kernels: three Mosaic custom calls."""
+    """The forward kernel and the one backward kernel: two Mosaic custom
+    calls."""
     args, blocks = _abstract_inputs(**shape)
     lowered = _grad_of_sum(**blocks).trace(*args).lower(
         lowering_platforms=("tpu",))
     assert lowered.as_text().count("stablehlo.custom_call @tpu_custom_call") \
-        == 3
+        == 2
 
 
-@pytest.mark.parametrize("shape", _TPU_SHAPES)
+@pytest.mark.parametrize("shape", _TPU_BACKWARD_SHAPES)
 def test_flash_backward_compiles_for_v5e(v5e_device, shape):
     args, blocks = _abstract_inputs(
         jax.sharding.SingleDeviceSharding(v5e_device), **shape)
     compiled = _grad_of_sum(**blocks).trace(*args).lower().compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert sorted(_ATTN_CALLS.findall(compiled.as_text())) == _ONE_OF_EACH
 
 
 # -- the choice llama.attention makes ---------------------------------------
@@ -189,10 +207,6 @@ def _abstract_step(cfg, sharding=None, model=llama):
     return jax.jit(model.make_train_step(cfg, optimizer)).trace(*state)
 
 
-# The attention kernels' custom calls in a compiled program's text.
-_ATTN_CALLS = re.compile(r"%(attn_flash_\w+?)(?:\.\d+)? = [^\n]*custom-call\(")
-
-
 @pytest.fixture
 def lowerings():
     """Reads (kernel, dense) lowerings counted since the test began."""
@@ -210,9 +224,8 @@ def test_train_step_takes_the_kernel_on_tpu(v5e_device, lowerings):
     # one call of each kernel and no other: the names the forward kernel
     # gives its results (RESIDUAL_NAMES) are identities where no checkpoint
     # saves by name
-    assert sorted(_ATTN_CALLS.findall(text)) == [
-        "attn_flash_bwd_dkv", "attn_flash_bwd_dq", "attn_flash_fwd"]
-    assert text.count("tpu_custom_call") == 3
+    assert sorted(_ATTN_CALLS.findall(text)) == _ONE_OF_EACH
+    assert text.count("tpu_custom_call") == 2
     assert re.findall(rf"\w+\[[\d,]*{_T},{_T}\]", text) == []
     assert lowerings() == (1, 0)
 
@@ -297,6 +310,16 @@ def test_supported_states_the_rule_for_two_widths():
     assert supported((1, 8192, 32, 192), (1, 8192, 32, 192), bf16,
                      (1, 8192, 32, 128))
     assert supported((1, 2048, 32, 128), (1, 2048, 8, 128), bf16)
+    # the backward kernel holds a query group's Q and dO, dQ transposed in
+    # float32 and dQ's output block: 3,328 B a token at 192 / 128, 27.3 MB at
+    # 8,192 tokens, past half the kernels' VMEM after 19 tiles of 512; a
+    # group of 4 at 128 / 128 (8,192 B a token) fits 4,096 tokens
+    assert supported((1, 9728, 32, 192), (1, 9728, 32, 192), bf16,
+                     (1, 9728, 32, 128))
+    assert not supported((1, 10240, 32, 192), (1, 10240, 32, 192), bf16,
+                         (1, 10240, 32, 128))
+    assert supported((1, 4096, 32, 128), (1, 4096, 8, 128), bf16)
+    assert not supported((1, 4608, 32, 128), (1, 4608, 8, 128), bf16)
     assert not supported((1, 8192, 32, 192), (1, 8192, 32, 192), bf16,
                          (1, 8192, 32, 64))         # v narrower than a lane
     assert not supported((1, 8192, 32, 96), (1, 8192, 32, 96), bf16,
@@ -305,14 +328,58 @@ def test_supported_states_the_rule_for_two_widths():
                          (1, 32768, 32, 128))       # K, V past the VMEM room
 
 
+# The attention of the benchmark's two train cells: Mistral-7B's grouped
+# heads at 2,048 tokens, kanana-2's latent heads at 8,192. (T, query heads,
+# KV heads, q/k width, v width, the backward kernel's tile.)
+_CELLS = {"mistral7b": (2048, 32, 8, 128, 128, 512),
+          "kanana2": (8192, 32, 32, 192, 128, 1024)}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELLS))
+def test_flash_backward_is_one_kernel_at_the_cells_geometries(v5e_device,
+                                                              cell):
+    """The backward pass alone, compiled for a v5e: one Mosaic call, named
+    ``attn_flash_bwd``, returns dQ, dK and dV, and what it holds in VMEM
+    (Q, dO, float32 dQ and dQ's block of the query group, with the tiles) is
+    what ``supported`` reckons and inside the kernels' limit."""
+    from brpc_tpu.ops.flash_attention import (_VMEM_LIMIT, _backward,
+                                              supported)
+    t, hq, hkv, d_qk, d_v, block = _CELLS[cell]
+    assert supported((1, t, hq, d_qk), (1, t, hkv, d_qk), jnp.bfloat16,
+                     (1, t, hkv, d_v))
+    assert choose_block(t, backward=True) == block
+    sharding = jax.sharding.SingleDeviceSharding(v5e_device)
+    s = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=sharding)
+    rows = s(1, hq, t // block, 1, block, dtype=jnp.float32)
+    text = jax.jit(functools.partial(
+        _backward, causal=True, blocks=(block, block), interpret=False)
+    ).lower(s(1, hq, t, d_qk), s(1, hkv, t, d_qk), s(1, hkv, t, d_v),
+            s(1, hq, t, d_v), rows, s(1, hq, t, d_v)).compile().as_text()
+    assert _ATTN_CALLS.findall(text) == ["attn_flash_bwd"]
+    assert text.count("tpu_custom_call") == 1
+    (call,) = (line for line in text.splitlines() if "custom-call(" in line)
+    (used,) = re.findall(
+        r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', call)
+    assert int(used) <= _VMEM_LIMIT
+
+
+def test_compiled_backward_kernel_wants_a_query_tile_of_whole_lanes():
+    """The interpreter takes any tile (the numeric tests above); lowering
+    for the chip says why 32 will not do, before Mosaic would."""
+    args, blocks = _abstract_inputs(**_TPU_SHAPES[0])
+    with pytest.raises(ValueError, match="multiple of 128"):
+        _grad_of_sum(**blocks).trace(*args)
+
+
 def test_flash_two_widths_compile_for_v5e(v5e_device):
-    """Forward, dQ and dK/dV at the kanana cell's geometry (8,192 x 32
+    """Forward and backward at the kanana cell's geometry (8,192 x 32
     heads, 192 / 128): Mosaic takes the 192-wide blocks whole."""
     args = _latent_inputs(None, t=8192, h=32, dtype=jnp.bfloat16,
                           sharding=jax.sharding.SingleDeviceSharding(
                               v5e_device), abstract=True)
     compiled = _grad_of_sum().trace(*args).lower().compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert sorted(_ATTN_CALLS.findall(compiled.as_text())) == _ONE_OF_EACH
 
 
 # -- the grouped product over the experts held -------------------------------
@@ -451,8 +518,8 @@ def test_deepseek_step_takes_both_kernels_on_tpu(v5e_device, lowerings):
     grouped = obs.counter("moe_grouped_lowerings")
     before = grouped.get_value()
     text = _compiled_latent_step(v5e_device)
-    assert {"attn_flash_fwd", "attn_flash_bwd_dq", "attn_flash_bwd_dkv",
-            "moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"} <= set(
+    assert {"attn_flash_fwd", "attn_flash_bwd", "moe_gmm_fwd", "moe_gmm_dlhs",
+            "moe_gmm_drhs"} == set(
                 re.findall(r"%((?:attn_flash|moe_gmm)_\w+?)(?:\.\d+)? =",
                            text))
     assert re.findall(rf"\w+\[[\d,]*{_T},{_T}\]", text) == []
@@ -498,7 +565,7 @@ def _attention_scans(monkeypatch, saved: bool):
 
 
 _FWD_ONLY = {"attn_flash_fwd": 1}
-_BWD_ONLY = {"attn_flash_bwd_dq": 1, "attn_flash_bwd_dkv": 1}
+_BWD_ONLY = {"attn_flash_bwd": 1}
 
 
 @pytest.mark.parametrize("saved,backward", [
@@ -516,8 +583,8 @@ def test_compiled_step_holds_the_forward_kernel_once_a_scan(v5e_device):
     """What XLA:TPU keeps of it: two forward kernels (dense scan, expert
     scan), none in the backward scans' recomputation."""
     text = _compiled_latent_step(v5e_device)
-    assert sorted(_ATTN_CALLS.findall(text)) == ["attn_flash_bwd_dkv"] * 2 + [
-        "attn_flash_bwd_dq"] * 2 + ["attn_flash_fwd"] * 2
+    assert sorted(_ATTN_CALLS.findall(text)) == ["attn_flash_bwd"] * 2 + [
+        "attn_flash_fwd"] * 2
 
 
 def test_expert_layer_saves_output_lse_and_layout_and_no_q_or_k(monkeypatch):
