@@ -57,7 +57,8 @@ them, and neither ``top_k`` nor the sort. On a v5e at the kanana cell's size
 the first takes 35 ms off a 430 ms step and the second 4.6 ms more (PERF.md
 section 6, PR 29). Where attention is the dense form (a CPU, float32, narrow
 heads) no activation has a name. The loss takes the output head in chunks of
-the sequence, each recomputed, so that no [T, vocab] float32 logits exist.
+the sequence, each recomputed, so that no [T, vocab] float32 logits exist
+(``models/chunked_loss.py``, shared with ``models/looped.py``).
 
 ``make_train_step``'s step also returns ``stats``: per expert layer the
 assignments routed to held experts, the largest and the mean expert's rows,
@@ -76,6 +77,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from brpc_tpu.models.chunked_loss import chunked_next_token_loss
 from brpc_tpu.models.llama import _dense_init, attention, rms_norm
 from brpc_tpu.ops import grouped_matmul as gm
 from brpc_tpu.ops.flash_attention import RESIDUAL_NAMES
@@ -322,35 +324,14 @@ def forward(params: Params, tokens: jax.Array, cfg: DeepseekConfig):
                    preferred_element_type=jnp.float32)
 
 
-def _loss_chunk(n: int) -> int:
-    """Positions whose logits exist at once: the largest divisor of n up to
-    1,024 (66 MB of float32 at a vocabulary slice of 16,032)."""
-    return next(c for c in range(min(n, 1024), 0, -1) if n % c == 0)
-
-
 def loss_fn(params: Params, tokens: jax.Array, cfg: DeepseekConfig):
     """Next-token cross-entropy (the last position predicts nothing), and
     the forward pass's stats. The head is taken a chunk of positions at a
     time, each chunk's float32 logits recomputed in the backward pass."""
     x, stats = hidden_states(params, tokens, cfg)
-    b, t, h = x.shape
     head = params["lm_head"].astype(cfg.dtype)
-    targets = jnp.roll(tokens, -1, axis=1).reshape(b * t)
-    counts = jnp.broadcast_to(jnp.arange(t) < t - 1, (b, t)).reshape(b * t)
-    chunk = _loss_chunk(b * t)
-
-    @jax.checkpoint
-    def piece(total, args):
-        x_c, target_c, counts_c = args
-        logits = jnp.dot(x_c, head, preferred_element_type=jnp.float32)
-        gold = jnp.take_along_axis(logits, target_c[:, None], axis=1)[:, 0]
-        nll = jax.nn.logsumexp(logits, axis=-1) - gold
-        return total + jnp.sum(jnp.where(counts_c, nll, 0.0)), None
-
-    total, _ = lax.scan(piece, jnp.zeros((), jnp.float32), (
-        x.reshape(-1, chunk, h), targets.reshape(-1, chunk),
-        counts.reshape(-1, chunk)))
-    return total / (b * (t - 1)), stats
+    return chunked_next_token_loss((x,), head, tokens,
+                                   lambda nlls: nlls[0]), stats
 
 
 def make_train_step(cfg: DeepseekConfig, optimizer):

@@ -609,3 +609,35 @@ def test_expert_layer_saves_output_lse_and_layout_and_no_q_or_k(monkeypatch):
             ("int32", (rows // tile,)), ("int32", (1,))      # tiles
             } <= set(stacked)
     assert not any(width in shape for _, shape in stacked)
+
+
+# -- the looped step (models/looped.py): shared weights under two scans ---------------
+
+from brpc_tpu.models import looped  # noqa: E402
+
+_LOOPED = looped.LoopedConfig(vocab_size=1024, hidden=256, n_layers=2,
+                              n_heads=2, n_kv_heads=2, head_dim=128,
+                              intermediate=512, total_ut_steps=3)
+
+
+def test_looped_step_takes_the_kernels_once_a_scan_on_tpu(v5e_device,
+                                                          lowerings):
+    """A query group of one (as many KV heads as heads), a batch of two:
+    the step compiled for a v5e holds one forward kernel (the layer scan
+    inside the pass scan) and one backward kernel, none in the
+    recomputation (output and log-sum-exp are saved by name), and no array
+    shaped like the scores."""
+    optimizer = optax.adamw(1e-4)
+    params = jax.eval_shape(lambda k: looped.init_params(k, _LOOPED),
+                            jax.random.PRNGKey(0))
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype,
+            sharding=jax.sharding.SingleDeviceSharding(v5e_device)),
+        (params, jax.eval_shape(optimizer.init, params),
+         jax.ShapeDtypeStruct((2, _T), jnp.int32)))
+    text = jax.jit(looped.make_train_step(_LOOPED, optimizer)).trace(
+        *state).lower().compile().as_text()
+    assert sorted(_ATTN_CALLS.findall(text)) == _ONE_OF_EACH
+    assert re.findall(rf"\w+\[[\d,]*{_T},{_T}\]", text) == []
+    assert lowerings() == (1, 0)
