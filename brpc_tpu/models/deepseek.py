@@ -62,8 +62,9 @@ the sequence, each recomputed, so that no [T, vocab] float32 logits exist
 
 ``make_train_step``'s step also returns ``stats``: per expert layer the
 assignments routed to held experts, the largest and the mean expert's rows,
-the assignments dropped (0 by construction, counted all the same) and the
-experts each token selected.
+the assignments dropped (0 by construction, counted all the same), the rows
+of the bound in use (whole tiles: what the row movement around the products
+works over) and the experts each token selected.
 """
 
 from __future__ import annotations
@@ -250,20 +251,17 @@ def moe_mlp(cfg: DeepseekConfig, y: jax.Array, lp: Params):
         local = selected - cfg.expert_offset
         group_of = jnp.where((local >= 0) & (local < cfg.n_held), local,
                              cfg.n_held).reshape(n * k)
-        lay = gm.group_layout(group_of, cfg.n_held,
-                              gm.choose_tile(n * k, cfg.n_held))
-        dest, held = lay.dest.reshape(n, k), lay.held.reshape(n, k)
-        row_token, row_slot = lay.row_source // k, lay.row_source % k
+        tile = gm.choose_tile(n * k, cfg.n_held)
+        lay = gm.group_layout(group_of, cfg.n_held, tile)
     with jax.named_scope("moe.experts"):
-        rows = gm.dispatch(y, row_token, lay.row_valid, dest, held)
+        to_gate, to_up = gm.dispatch(y, lay, copies=2)
         product = lambda a, w: gm.grouped_matmul(  # noqa: E731
             a, w, lay.tile_group, lay.n_tiles)
-        hidden = jax.nn.silu(product(rows, lp["w_gate"])) * product(
-            rows, lp["w_up"])
+        hidden = jax.nn.silu(product(to_gate, lp["w_gate"])) * product(
+            to_up, lp["w_up"])
         rows = product(hidden, lp["w_down"])
     with jax.named_scope("moe.combine"):
-        routed = gm.combine(rows, weights, dest, held, row_token, row_slot,
-                            lay.row_valid)
+        routed = gm.combine(rows, weights, lay)
     with jax.named_scope("moe.shared"):
         shared = _swiglu(y, lp["shared_gate"], lp["shared_up"],
                          lp["shared_down"])
@@ -273,6 +271,7 @@ def moe_mlp(cfg: DeepseekConfig, y: jax.Array, lp: Params):
         "dropped": n_routed - jnp.sum(lay.row_valid.astype(jnp.int32)),
         "group_max": jnp.max(lay.group_sizes),
         "group_mean": jnp.mean(lay.group_sizes.astype(jnp.float32)),
+        "rows_in_use": lay.n_tiles[0] * tile,
         "selected": selected,
     }
     return routed + shared, stats

@@ -32,6 +32,7 @@ import reference_dsv3  # noqa: E402
 
 from brpc_tpu.models import deepseek  # noqa: E402
 from brpc_tpu.ops import flash_attention  # noqa: E402
+from brpc_tpu.ops import grouped_matmul as gm  # noqa: E402
 
 SIZES = {
     "hidden_size": 64, "intermediate_size": 128, "moe_intermediate_size": 32,
@@ -233,6 +234,25 @@ def test_no_token_is_dropped_when_all_choose_one_held_expert(params):
     assert int(stats["dropped"]) == 0
     assert int(stats["routed"]) >= y.shape[0]
     assert _scale_gap(out, want) <= 1e-5
+
+
+def test_rows_in_use_are_the_whole_tiles_of_the_layout(params):
+    """``stats["rows_in_use"]``: every held expert's rows rounded up to a
+    tile (an expert nobody chose keeps one), which is the layout's
+    ``n_tiles`` x tile and what the row movement works over."""
+    lp, y = dict(_layer(params)), _tokens_in()
+    _, stats = deepseek.moe_mlp(TINY32, y, lp)
+    selected = np.asarray(stats["selected"])
+    held = selected < TINY32.n_held
+    tile = gm.choose_tile(selected.size, TINY32.n_held)
+    lay = gm.group_layout(
+        jnp.asarray(np.where(held, selected, TINY32.n_held).reshape(-1)),
+        TINY32.n_held, tile)
+    sizes = np.bincount(selected[held], minlength=TINY32.n_held)
+    assert int(stats["rows_in_use"]) == int(lay.n_tiles[0]) * tile == sum(
+        max(-(-int(s) // tile), 1) for s in sizes) * tile
+    assert int(stats["routed"]) <= int(stats["rows_in_use"]) < len(
+        np.asarray(lay.row_valid))
 
 
 def test_selection_sees_the_bias_and_weights_do_not(params):

@@ -402,18 +402,14 @@ def _grouped_case(seed=0, n=40, k=2, groups=3, h=32, f=16, tile=8):
                                          jnp.asarray(weights))
 
 
-def _routed(lay, k, interpret):
-    dest, held = lay.dest.reshape(-1, k), lay.held.reshape(-1, k)
-    row_token, row_slot = lay.row_source // k, lay.row_source % k
-
+def _routed(lay, interpret):
     def fn(x, w_in, w_out, weights):
-        rows = gm.dispatch(x, row_token, lay.row_valid, dest, held)
+        rows = gm.dispatch(x, lay, interpret)
         hidden = jax.nn.silu(gm.grouped_matmul(
             rows, w_in, lay.tile_group, lay.n_tiles, interpret))
         rows = gm.grouped_matmul(hidden, w_out, lay.tile_group, lay.n_tiles,
                                  interpret)
-        return gm.combine(rows, weights, dest, held, row_token, row_slot,
-                          lay.row_valid)
+        return gm.combine(rows, weights, lay, interpret)
     return fn
 
 
@@ -456,7 +452,7 @@ def test_grouped_product_matches_a_loop_over_the_experts(interpret):
     idle tiles past the rows present."""
     group_of, lay, args = _grouped_case()
     with jax.default_matmul_precision("highest"):
-        fn, plain = _routed(lay, 2, interpret), _routed_plainly(group_of)
+        fn, plain = _routed(lay, interpret), _routed_plainly(group_of)
         w = jax.random.normal(jax.random.PRNGKey(1), (40, 32))
         loss = lambda f: (lambda *a: jnp.sum(f(*a) * w))  # noqa: E731
         got = (fn(*args), *jax.grad(loss(fn), (0, 1, 2, 3))(*args))
@@ -467,6 +463,105 @@ def test_grouped_product_matches_a_loop_over_the_experts(interpret):
         assert np.max(np.abs(g - r)) <= 2e-5 * np.max(np.abs(r)), name
     assert not np.any(np.asarray(got[2])[1]) and not np.any(
         np.asarray(got[3])[1])
+
+
+def _rows_case(name):
+    """(lay, k, x [N,H], weights [N,k]) over 3 held groups, tile 8: the
+    layout of ``_grouped_case``; the same with token 0 holding nothing; with
+    every assignment held (the bound's tiles all but in use); and k = 1."""
+    rng = np.random.default_rng(7)
+    n, k, groups, h, tile = 40, 1 if name == "k_is_1" else 2, 3, 32, 8
+    if name == "idle_tiles":
+        group_of, lay, (x, _, _, weights) = _grouped_case()
+        return lay, k, x, weights
+    if name == "bound_full":
+        group_of = rng.integers(0, groups, (n, k))
+    else:
+        group_of = np.minimum(rng.integers(0, groups + 2, (n, k)), groups)
+        group_of[0] = groups
+    lay = gm.group_layout(jnp.asarray(group_of.reshape(-1), jnp.int32),
+                          groups, tile)
+    return (lay, k, jnp.asarray(rng.standard_normal((n, h)), jnp.float32),
+            jnp.asarray(rng.uniform(0.1, 1, (n, k)), jnp.float32))
+
+
+@pytest.mark.parametrize("interpret", [None, True],
+                         ids=["gathers", "kernels_interpreted"])
+@pytest.mark.parametrize("case", ["idle_tiles", "token_without_a_row",
+                                  "bound_full", "k_is_1"])
+def test_rows_move_as_the_layout_says(case, interpret):
+    """``dispatch``, ``combine`` and their gradients (dx, d_rows, d_weights)
+    against the movement written out as matrices, with NaN in every row of
+    the idle tiles: nothing reads them."""
+    lay, k, x, weights = _rows_case(case)
+    (n, h), m = x.shape, lay.row_valid.shape[0]
+    in_use = int(lay.n_tiles[0]) * 8
+    held = np.asarray(lay.held).reshape(n, k)
+    if case == "token_without_a_row":
+        assert not held[0].any()
+    if case == "bound_full":
+        assert held.all() and in_use >= n * k
+    else:
+        assert in_use < m
+    # place[t, j, r]: assignment (t, j) is held and lies in row r
+    place = np.zeros((n, k, m), np.float32)
+    t, j = np.nonzero(held)
+    place[t, j, np.asarray(lay.dest).reshape(n, k)[t, j]] = 1
+    place = jnp.asarray(place)
+    idle = (jnp.arange(m) >= in_use)[:, None]
+    rng = np.random.default_rng(3)
+    rows, d_rows, d_more = (jnp.asarray(rng.standard_normal((m, h)),
+                                        jnp.float32) for _ in range(3))
+    dy = jnp.asarray(rng.standard_normal((n, h)), jnp.float32)
+    clean = lambda a: jnp.where(idle, 0, a)       # noqa: E731
+    dirty = lambda a: jnp.where(idle, jnp.nan, a)   # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        out, pull = jax.vjp(lambda x: gm.dispatch(x, lay, interpret), x)
+        got = {"rows": clean(out), "dx": pull(dirty(d_rows))[0]}
+        # the same rows for two consumers: their cotangents are added
+        (out, same), pull = jax.vjp(
+            lambda x: gm.dispatch(x, lay, interpret, 2), x)
+        assert same is out or np.array_equal(clean(same), clean(out))
+        got["dx_of_two"] = pull((dirty(d_rows), dirty(d_more)))[0]
+        out, pull = jax.vjp(lambda r, w: gm.combine(r, w, lay, interpret),
+                            dirty(rows), weights)
+        got["out"] = out
+        got["d_rows"], got["d_weights"] = pull(dy)
+        got["d_rows"] = clean(got["d_rows"])
+        want = {
+            "rows": jnp.einsum("tjr,th->rh", place, x),
+            "dx": jnp.einsum("tjr,rh->th", place, clean(d_rows)),
+            "dx_of_two": jnp.einsum("tjr,rh->th", place,
+                                    clean(d_rows + d_more)),
+            "out": jnp.einsum("tj,tjr,rh->th", weights, place, clean(rows)),
+            "d_rows": jnp.einsum("tj,tjr,th->rh", weights, place, dy),
+            "d_weights": jnp.einsum("tjr,rh,th->tj", place, clean(rows), dy)}
+    for name, w in want.items():
+        g, w = np.asarray(got[name]), np.asarray(w)
+        assert np.max(np.abs(g - w)) <= 2e-5 * np.max(np.abs(w)), name
+
+
+def test_bf16_rows_move_bit_for_bit_through_the_interpreted_kernels():
+    """bf16, the type the compiled kernels take: a row is copied, not
+    computed, so ``dispatch`` and its transpose of one-row tokens agree with
+    the gathers to the bit, and the float32 sums to their rounding."""
+    lay, k, x, weights = _rows_case("token_without_a_row")
+    x = x.astype(jnp.bfloat16)
+    rows = jax.random.normal(jax.random.PRNGKey(0),
+                             (lay.row_valid.shape[0], x.shape[1]),
+                             jnp.bfloat16)
+    plain, kernels = (
+        (gm.dispatch(x, lay, i), gm.combine(rows, weights, lay, i),
+         *jax.vjp(lambda r, w: gm.combine(r, w, lay, i), rows, weights)[1](x))
+        for i in (None, True))
+    in_use = int(lay.n_tiles[0]) * 8
+    for name, p, q in zip(("rows", "out", "d_rows", "d_weights"), plain,
+                          kernels):
+        p, q = (np.asarray(a, np.float32)[:in_use] for a in (p, q))
+        if name in ("rows", "d_rows"):
+            assert np.array_equal(p, q), name
+        else:
+            assert np.max(np.abs(p - q)) <= 1e-2 * np.max(np.abs(p)), name
 
 
 def test_grouped_kernels_compile_for_v5e(v5e_device):
@@ -492,6 +587,55 @@ def test_grouped_kernels_compile_for_v5e(v5e_device):
         s((1,), jnp.int32)).compile().as_text()
     assert {"moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"} <= set(
         re.findall(r"%(moe_gmm_\w+?)(?:\.\d+)? =", text))
+
+
+def test_rows_kernels_compile_for_v5e(v5e_device):
+    """``dispatch``, ``combine`` and their transposes at the kanana cell's
+    size: 8,192 tokens of 6 assignments, 16 experts held, rows of 2,048 in
+    tiles of 256 inside a bound of 53,248."""
+    n, k, groups, h = 8192, 6, 16, 2048
+    tile = gm.choose_tile(n * k, groups)
+    m = gm.bound_rows(n * k, groups, tile)
+    assert (tile, m) == (256, 53248)
+    assert gm.rows_kernels_take(n, h, tile, jnp.bfloat16)
+    sharding = jax.sharding.SingleDeviceSharding(v5e_device)
+    s = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=sharding)
+
+    def grads(x, weights, group_of, through):
+        lay = gm.group_layout(group_of, groups, tile)
+
+        def loss(x, weights):       # ``through`` stands for the products
+            to_gate, to_up = gm.dispatch(x, lay, copies=2)
+            rows = to_gate * through + to_up
+            return jnp.sum(gm.combine(rows, weights, lay).astype(
+                jnp.float32))
+        return jax.value_and_grad(loss, (0, 1))(x, weights)
+
+    text = jax.jit(grads).lower(
+        s((n, h), jnp.bfloat16), s((n, k), jnp.float32),
+        s((n * k,), jnp.int32), s((m, h), jnp.bfloat16)).compile().as_text()
+    # forward and backward: x and dy gathered into rows; rows, and the sum
+    # of the two consumers' d_rows, packed and summed by token; d_weights
+    # from the second gather's dots
+    assert _rows_kernels_in(text) == {
+        "moe_rows_gather": 2, "moe_rows_pack": 2, "moe_rows_combine": 2}
+    assert _bound_sized_gathers(text, (m, n * k, f"{n},{k}"), h) == []
+
+
+def _rows_kernels_in(text: str) -> dict:
+    """How many calls of each ``moe_rows_*`` kernel a compiled program
+    holds."""
+    calls = re.findall(r"%(moe_rows_\w+?)(?:\.\d+)? = [^\n]*custom-call\(",
+                       text)
+    return {name: calls.count(name) for name in set(calls)}
+
+
+def _bound_sized_gathers(text: str, row_counts, width: int):
+    """The gathers of a compiled program whose result has one of
+    ``row_counts`` as its leading dimensions and ``width`` as its last."""
+    counts = "|".join(str(c) for c in row_counts)
+    return re.findall(rf"= \w+\[(?:{counts}),{width}\]\S* gather\(", text)
 
 
 # Kernel-eligible and small, as _ELIGIBLE is: 4 heads of 128 + 64 / 128, 8
@@ -526,6 +670,27 @@ def test_deepseek_step_takes_both_kernels_on_tpu(v5e_device, lowerings):
     kernel, dense = lowerings()
     assert (kernel, dense) == (1, 0)
     assert grouped.get_value() - before >= 1
+
+
+def test_deepseek_step_moves_rows_by_the_tiles_in_use_on_tpu(v5e_device):
+    """The same step holds the row kernels where the gathers over the bound
+    were — x, its recomputation and dy into rows (3), rows and d_rows by
+    token (2, each packed first) — and counts a program that keeps them."""
+    obs.set_enabled(True)
+    counter = obs.counter("moe_rows_lowerings")
+    before = counter.get_value()
+    text = _compiled_latent_step(v5e_device)
+    assert _rows_kernels_in(text) == {
+        "moe_rows_gather": 3, "moe_rows_pack": 2, "moe_rows_combine": 2}
+    assert counter.get_value() - before >= 1
+    k = _LATENT.experts_per_token
+    bound = gm.bound_rows(_T * k, _LATENT.n_held,
+                          gm.choose_tile(_T * k, _LATENT.n_held))
+    assert _bound_sized_gathers(text, (bound, _T * k, f"{_T},{k}"),
+                                _LATENT.hidden) == []
+    # nor are the two products' cotangents of the rows added over the bound
+    assert re.findall(rf"= \w+\[{bound},{_LATENT.hidden}\]\S* add\(",
+                      text) == []
 
 
 # -- what the deepseek step keeps across its recomputation -------------------
