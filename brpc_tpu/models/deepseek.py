@@ -79,6 +79,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from brpc_tpu.models.chunked_loss import chunked_next_token_loss
+from brpc_tpu.models.experts import expert_mlp, swiglu as _swiglu
 from brpc_tpu.models.llama import _dense_init, attention, rms_norm
 from brpc_tpu.ops import grouped_matmul as gm
 from brpc_tpu.ops.flash_attention import RESIDUAL_NAMES
@@ -199,10 +200,6 @@ def rope_interleaved(x: jax.Array, positions: jax.Array,
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def _swiglu(y, w_gate, w_up, w_down):
-    return (jax.nn.silu(y @ w_gate) * (y @ w_up)) @ w_down
-
-
 def mla(cfg: DeepseekConfig, x: jax.Array, lp: Params,
         positions: jax.Array) -> jax.Array:
     """The attention block with its residual. x: [B, T, H]."""
@@ -243,38 +240,15 @@ def route(cfg: DeepseekConfig, y: jax.Array, router: jax.Array,
 def moe_mlp(cfg: DeepseekConfig, y: jax.Array, lp: Params):
     """The expert layer's MLP on normed tokens y: [N, H] -> ([N, H], stats):
     what the held experts give for the assignments routed to them, plus the
-    shared experts."""
-    n, k = y.shape[0], cfg.experts_per_token
+    shared experts (``models/experts.py``, shared with ``models/hybrid.py``:
+    everything after the routing)."""
     with jax.named_scope("moe.router"):
         selected, weights = route(cfg, y, lp["router"], lp["router_bias"])
-    with jax.named_scope("moe.sort"):
-        local = selected - cfg.expert_offset
-        group_of = jnp.where((local >= 0) & (local < cfg.n_held), local,
-                             cfg.n_held).reshape(n * k)
-        tile = gm.choose_tile(n * k, cfg.n_held)
-        lay = gm.group_layout(group_of, cfg.n_held, tile)
-    with jax.named_scope("moe.experts"):
-        to_gate, to_up = gm.dispatch(y, lay, copies=2)
-        product = lambda a, w: gm.grouped_matmul(  # noqa: E731
-            a, w, lay.tile_group, lay.n_tiles)
-        hidden = jax.nn.silu(product(to_gate, lp["w_gate"])) * product(
-            to_up, lp["w_up"])
-        rows = product(hidden, lp["w_down"])
-    with jax.named_scope("moe.combine"):
-        routed = gm.combine(rows, weights, lay)
-    with jax.named_scope("moe.shared"):
-        shared = _swiglu(y, lp["shared_gate"], lp["shared_up"],
-                         lp["shared_down"])
-    n_routed = jnp.sum(lay.held.astype(jnp.int32))
-    stats = {
-        "routed": n_routed,
-        "dropped": n_routed - jnp.sum(lay.row_valid.astype(jnp.int32)),
-        "group_max": jnp.max(lay.group_sizes),
-        "group_mean": jnp.mean(lay.group_sizes.astype(jnp.float32)),
-        "rows_in_use": lay.n_tiles[0] * tile,
-        "selected": selected,
-    }
-    return routed + shared, stats
+    return expert_mlp(
+        y, selected, weights, lp["w_gate"], lp["w_up"], lp["w_down"],
+        n_held=cfg.n_held, expert_offset=cfg.expert_offset,
+        shared=lambda y: _swiglu(y, lp["shared_gate"], lp["shared_up"],
+                                 lp["shared_down"]))
 
 
 def _cast(lp: Params, dtype) -> Params:

@@ -39,8 +39,13 @@ statistics travel as [B, H, T/BLOCK, 1, BLOCK]: a row vector per tile, whole
 in its last two dimensions whatever the block.
 
 K and V of one head in the forward kernel, and in the backward kernel Q, dO
-and both forms of dQ of one query group, stay whole in VMEM (``supported``
-bounds the sequence by that).
+and both forms of dQ of one query group, stay whole in VMEM. Where a whole
+group is too much for that (8 query heads a KV head of 256 at 8,192 tokens:
+models/hybrid.py's gated attention), the backward kernel takes the query side
+through the grid instead, one query head a program row against its KV head's
+key tiles, writes that head's own dK and dV in float32, and the group's sum
+is one pass outside the kernel. ``supported`` bounds the sequence by what one
+query head needs.
 
 ``flash_attention(..., interpret=True)`` runs the same kernels through the
 Pallas interpreter (CPU tests); on TPU leave it False.
@@ -86,6 +91,24 @@ def choose_block(t: int, backward: bool = False) -> int:
     return next((c for c in sizes if t % c == 0), t)
 
 
+def _resident(group: int, t: int, d_qk: int, d_v: int, dtype) -> int:
+    """Bytes of the backward kernel that stay whole in VMEM for ``group``
+    query heads: Q and dO (double-buffered by the pipeline), dQ transposed
+    in float32 and dQ's output block (double-buffered too)."""
+    lanes_qk = -(-d_qk // 128) * 128
+    size = jnp.dtype(dtype).itemsize
+    return group * t * (2 * (lanes_qk + d_v) * size     # Q, dO
+                        + d_qk * 4                      # dQ.T
+                        + 2 * lanes_qk * size)          # dQ's block
+
+
+def heads_together(group: int, t: int, d_qk: int, d_v: int, dtype) -> bool:
+    """Whether the backward kernel takes a KV head's whole query group at
+    once (the GQA sum then happens in dK's and dV's accumulators), or each
+    query head on its own with the group summed afterwards."""
+    return _resident(group, t, d_qk, d_v, dtype) <= _VMEM_LIMIT // 2
+
+
 def supported(q_shape, kv_shape, dtype, v_shape=None) -> bool:
     """Whether the compiled kernels take these operands: whole query groups,
     a v width (``v_shape``, k's where it is not given) that fills the MXU's
@@ -93,23 +116,21 @@ def supported(q_shape, kv_shape, dtype, v_shape=None) -> bool:
     as its array, which Mosaic takes whole and pads to 256 lanes in VMEM; a
     192-wide contraction or result fills one and a half passes of the
     128-wide MXU), and a sequence whose resident blocks leave VMEM room for
-    the score tiles. The backward kernel holds the most: of one query group
-    Q and dO (double-buffered by the pipeline), dQ transposed in float32 and
-    dQ's output block (double-buffered too) — 27.3 MB at T = 8,192, one
-    head a group, 192 / 128, and 16.8 MB at T = 2,048, four heads a group,
-    128 / 128, where a group of 4 fits 4,096 tokens. The forward kernel's K
-    and V of a head are less than that at any group size."""
+    the score tiles. The backward kernel holds the most (``_resident``):
+    27.3 MB at T = 8,192, one head a group, 192 / 128, and 16.8 MB at
+    T = 2,048, four heads a group, 128 / 128, where a group of 4 fits 4,096
+    tokens. A group that does not fit whole — 8 query heads a KV head of
+    256 / 256 at T = 8,192 would be 268 MB — goes through the kernel a query
+    head at a time (``heads_together``), which is 32 MiB there, the most the
+    kernels admit: 256 / 256 stops at 8,192 tokens, 192 / 128 at 9,728 and
+    128 / 128 at 16,384, whatever the group. The forward kernel's K and V of
+    a head are less than that at any group size."""
     _, t, hq, d_qk = q_shape
     hkv = kv_shape[2]
     d_v = (v_shape or kv_shape)[3]
     if d_qk % 64 or d_v % 128 or t % 128 or hq % hkv:
         return False
-    lanes_qk = -(-d_qk // 128) * 128
-    size = jnp.dtype(dtype).itemsize
-    resident = (hq // hkv) * t * (2 * (lanes_qk + d_v) * size   # Q, dO
-                                  + d_qk * 4                    # dQ.T
-                                  + 2 * lanes_qk * size)        # dQ's block
-    return resident <= _VMEM_LIMIT // 2
+    return _resident(1, t, d_qk, d_v, dtype) <= _VMEM_LIMIT // 2
 
 
 def _nt(a, b):
@@ -302,7 +323,12 @@ def _backward(q, k, v, o, lse, do, causal: bool, blocks: tuple,
     b, hq, t, d = q.shape
     d_v = v.shape[3]
     hkv = k.shape[1]
-    group = hq // hkv
+    # A query group too large to stay whole in VMEM: every query head is a
+    # group of its own against its KV head's tiles, its dK and dV come out
+    # in float32 a query head, and the group is summed after the kernel.
+    together = heads_together(hq // hkv, t, d, d_v, q.dtype)
+    group, shared = (hq // hkv, 1) if together else (1, hq // hkv)
+    heads = hq // group
     scale = d ** -0.5
     block_q, block_k = blocks
     if not interpret and block_q % 128:
@@ -323,19 +349,27 @@ def _backward(q, k, v, o, lse, do, causal: bool, blocks: tuple,
         row_spec = pl.BlockSpec((1, group, t // block_q, 1, block_q),
                                 lambda bi, h, j: (bi, h, 0, 0, 0))
         kv_specs = [_tile_spec(block_k, d), _tile_spec(block_k, d_v)]
+        kv_in = kv_specs if together else [
+            pl.BlockSpec((1, 1, block_k, width),
+                         lambda bi, h, j: (bi, h // shared, j, 0))
+            for width in (d, d_v)]
+        kv_dtype = k.dtype if together else jnp.float32
         dq, dk, dv = _call(
             functools.partial(_bwd_kernel, block_q=block_q, causal=causal,
                               scale=scale),
             "attn_flash_bwd", interpret, last_axis="arbitrary",
-            grid=(b, hkv, t // block_k),
-            in_specs=[group_spec(d), *kv_specs, group_spec(d_v), row_spec,
+            grid=(b, heads, t // block_k),
+            in_specs=[group_spec(d), *kv_in, group_spec(d_v), row_spec,
                       row_spec],
             out_specs=[group_spec(d), *kv_specs],
             out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                       jax.ShapeDtypeStruct(k.shape, k.dtype),
-                       jax.ShapeDtypeStruct(v.shape, v.dtype)],
+                       jax.ShapeDtypeStruct((b, heads, t, d), kv_dtype),
+                       jax.ShapeDtypeStruct((b, heads, t, d_v), kv_dtype)],
             scratch_shapes=[pltpu.VMEM((group, d, t), jnp.float32)],
         )(q, k, v, do, lse, delta)
+        if not together:
+            dk, dv = (x.reshape(b, hkv, shared, t, -1).sum(2).astype(k.dtype)
+                      for x in (dk, dv))
     return dq, dk, dv
 
 

@@ -1,0 +1,468 @@
+"""The gated delta rule in chunked form — the token mixer of a Gated DeltaNet
+layer (models/hybrid.py), forward and backward, as Pallas TPU kernels.
+
+Per value head, with a state S in R^{dk x dv} that starts at 0, log-decay
+g_t <= 0 and write strength beta_t::
+
+    S <- exp(g_t) S;  delta = beta_t (v_t - S^T k_t);  S <- S + k_t delta^T
+    o_t = S^T q_t
+
+A scan of one step a token cannot be the training path, so the sequence is
+taken in chunks of ``CHUNK`` positions in the WY form of the Gated DeltaNet
+paper. Inside a chunk, with gamma the cumulative log-decay from the chunk's
+start, D_ij = exp(gamma_i - gamma_j) for j <= i and S_0 the state on entry::
+
+    A = strict_lower(beta_i (k_i . k_j) D_ij)       T = (I + A)^-1
+    Delta = T (beta v - (beta e^gamma k) S_0)       # every delta of the chunk
+    O = (q e^gamma) S_0 + (q k^T * D) Delta
+    S_C = e^{gamma_C} S_0 + (k e^{gamma_C - gamma})^T Delta
+
+Every exponent is a difference that is <= 0, so nothing overflows however
+strong the decay. ``T`` is made of matrix products alone: A is cut into its
+block diagonal (blocks of 16, each inverted by the Neumann series in its
+doubling form, exact at a nilpotent block) and the rest, which is nilpotent
+by blocks and inverted the same way.
+
+Two kernels, named for the device trace. ``gdn_chunk_fwd`` walks a head's
+chunks in order (the grid's last axis) with the float32 state in VMEM, writes
+the output and each chunk's entry state. ``gdn_chunk_bwd`` walks them in
+reverse with the state's cotangent in VMEM, recomputes every within-chunk
+quantity from q, k, v, gamma, beta and the chunk's entry state, and gives
+dq, dk, dv, dgamma and dbeta: no per-token state is ever stored. The MXU gets
+its operands in the dtype they arrive in (bf16 on the training path); the
+state, its cotangent, the decays, A, T (its own products at ``highest``) and
+every accumulator are float32. The per-chunk mathematics is ONE pair of
+functions, ``_chunk_fwd`` / ``_chunk_bwd``, which the kernels call on their
+blocks and the plain form calls under ``lax.scan``: the same chunked
+algorithm in plain ``jax.numpy`` wherever the kernels are not taken (another
+platform, float32, widths that are no multiple of 128), chosen by
+``lax.platform_dependent`` like ``llama.attention``. ``gdn_lowerings``
+counts the programs lowered with the kernels.
+
+The forward kernel's two results carry names (``RESIDUAL_NAMES``: the
+head-major output and the chunks' entry states), so that a caller who
+recomputes its layers under ``jax.checkpoint`` can save them and not run the
+forward kernel a second time.
+
+Layout: q, k [B, T, Hk, dk], v [B, T, Hv, dv], g, beta [B, T, Hv]; key head
+h // (Hv // Hk) serves value head h. q and k come normalised and scaled as
+the caller wants them. Inside, arrays are head-major, and per-token scalars
+travel as one row a chunk, [B, Hv, T/C, 1, C] (a column is made from a row
+inside the kernel, by the diagonal of its broadcast).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from brpc_tpu.ops.lowered import count_lowering
+
+CHUNK = 64
+_INNER = 16              # the diagonal blocks of A inverted on their own
+_VMEM_LIMIT = 64 << 20
+
+# ``checkpoint_name``s of the forward pass's head-major output and of the
+# chunks' entry states, which is all the backward pass keeps beside its
+# inputs.
+RESIDUAL_NAMES = ("gdn_out", "gdn_states")
+
+
+def _mm(a, b, contract, precision=None):
+    return lax.dot_general(a, b, (contract, ((), ())), precision=precision,
+                           preferred_element_type=jnp.float32)
+
+
+_nn = functools.partial(_mm, contract=((1,), (0,)))      # a @ b
+_nt = functools.partial(_mm, contract=((1,), (1,)))      # a @ b.T
+_tn = functools.partial(_mm, contract=((0,), (0,)))      # a.T @ b
+
+
+def _precise(a, b):
+    """a @ b of float32 operands to float32 accuracy (the inverse's own
+    products: its result is rounded to the operands' dtype once, after)."""
+    return _nn(a, b, precision=lax.Precision.HIGHEST)
+
+
+def _inverse(a, row, col):
+    """(I + a)^-1 of a strictly lower triangular float32 [C, C], C at most
+    ``_INNER`` or a multiple of it, by matrix products alone."""
+    c = a.shape[0]
+    inner = min(_INNER, c)
+    eye = (row == col).astype(jnp.float32)
+
+    def neumann(m, index):
+        """sum_{n < index} (-m)^n = (I - m)(I + m^2)(I + m^4)..., the whole
+        inverse of I + m where m^index = 0."""
+        t, n = eye - m, 2
+        while n < index:
+            m = _precise(m, m)
+            t = _precise(t, eye + m)
+            n *= 2
+        return t
+
+    if c == inner:
+        return neumann(a, c)
+    same = (row // inner) == (col // inner)
+    t_diag = neumann(jnp.where(same, a, 0.0), inner)
+    # I + a = (I + a_diag)(I + t_diag a_rest), the second nilpotent by blocks
+    rest = _precise(t_diag, jnp.where(same, 0.0, a))
+    return _precise(neumann(rest, c // inner), t_diag)
+
+
+def _chunk_parts(q, k, g_row, b_row):
+    """What a chunk's two passes share and the state does not touch. q, k
+    [C, dk]; g_row (gamma), b_row (beta) float32 [1, C]."""
+    c, dt = q.shape[0], q.dtype
+    row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    eye = row == col
+    to_col = lambda r: jnp.sum(jnp.where(eye, r, 0.0), axis=1,  # noqa: E731
+                               keepdims=True)
+    g_col, b_col = to_col(g_row), to_col(b_row)
+    last = col[:1] == c - 1
+    g_last = jnp.sum(jnp.where(last, g_row, 0.0), axis=1, keepdims=True)
+    decay = jnp.exp(jnp.where(row >= col, g_col - g_row, -jnp.inf))
+    kk = _nt(k, k)
+    a = jnp.where(row > col, b_col * kk * decay, 0.0)
+    e_col = jnp.exp(g_col)                    # e^gamma
+    e_rest = jnp.exp(g_last - g_col)          # e^{gamma_C - gamma}
+    kf, qf = k.astype(jnp.float32), q.astype(jnp.float32)
+    return dict(
+        row=row, col=col, eye=eye, last=last, b_col=b_col, e_col=e_col,
+        e_rest=e_rest, e_last=jnp.exp(g_last), decay=decay, kk=kk, a=a,
+        t=_inverse(a, row, col).astype(dt), p=_nt(q, k) * decay, kf=kf,
+        qf=qf, kw=(b_col * e_col * kf).astype(dt),
+        kd=(e_rest * kf).astype(dt), qg=(e_col * qf).astype(dt))
+
+
+def _chunk_fwd(s, q, k, v, g_row, b_row):
+    """One chunk. s: the float32 state on entry [dk, dv] -> (o [C, dv]
+    float32, the state on exit)."""
+    dt = q.dtype
+    x = _chunk_parts(q, k, g_row, b_row)
+    s_in = s.astype(dt)
+    r = x["b_col"] * v.astype(jnp.float32) - _nn(x["kw"], s_in)
+    delta = _nn(x["t"], r.astype(dt)).astype(dt)
+    o = _nn(x["qg"], s_in) + _nn(x["p"].astype(dt), delta)
+    return o, x["e_last"] * s + _tn(x["kd"], delta)
+
+
+def _chunk_bwd(s_in, ds, q, k, v, g_row, b_row, do):
+    """One chunk's transpose. s_in: the entry state as the forward pass
+    used it [dk, dv]; ds: float32 cotangent of the exit state; do [C, dv].
+    Returns float32 (dq, dk, dv, dgamma [1, C], dbeta [1, C], cotangent of
+    the entry state)."""
+    dt = q.dtype
+    x = _chunk_parts(q, k, g_row, b_row)
+    b_col, e_col, e_rest = x["b_col"], x["e_col"], x["e_rest"]
+    vf = v.astype(jnp.float32)
+    r = b_col * vf - _nn(x["kw"], s_in)
+    delta = _nn(x["t"], r.astype(dt)).astype(dt)
+    ds_in = ds.astype(dt)
+
+    d_delta = _tn(x["p"].astype(dt), do) + _nn(x["kd"], ds_in)
+    d_r = _tn(x["t"], d_delta.astype(dt))
+    d_r_in = d_r.astype(dt)
+    d_p = jnp.where(x["row"] >= x["col"], _nt(do, delta), 0.0)
+    d_a = jnp.where(x["row"] > x["col"], -_nt(d_r_in, delta), 0.0)
+    d_qg = _nt(do, s_in)
+    d_kw = -_nt(d_r_in, s_in)
+    d_kd = _nt(delta, ds_in)
+    ds_out = _tn(x["qg"], do) + x["e_last"] * ds - _tn(x["kw"], d_r_in)
+
+    d_qk = (d_p * x["decay"]).astype(dt)
+    d_kk = (d_a * b_col * x["decay"]).astype(dt)
+    dq = _nn(d_qk, k) + e_col * d_qg
+    dk = (_tn(d_qk, q) + _nn(d_kk, k) + _tn(d_kk, k)
+          + b_col * e_col * d_kw + e_rest * d_kd)
+    dv = b_col * d_r
+
+    rows = lambda m: jnp.sum(m, axis=1, keepdims=True)   # noqa: E731
+    to_row = lambda col_: jnp.sum(                       # noqa: E731
+        jnp.where(x["eye"], col_, 0.0), axis=0, keepdims=True)
+    kw_k = rows(d_kw * x["kf"]) * e_col
+    kd_k = rows(d_kd * x["kf"]) * e_rest
+    d_b = rows(d_a * x["kk"] * x["decay"]) + rows(d_r * vf) + kw_k
+    # the decays: D enters P and A; gamma_i adds, gamma_j takes away
+    m = d_p * x["p"] + d_a * x["a"]
+    d_g = (rows(m) + kw_k * b_col + rows(d_qg * x["qf"]) * e_col - kd_k)
+    d_g_last = (jnp.sum(kd_k, axis=0, keepdims=True) + x["e_last"] * jnp.sum(
+        rows(ds * s_in.astype(jnp.float32)), axis=0, keepdims=True))
+    d_g_row = (to_row(d_g) - jnp.sum(m, axis=0, keepdims=True)
+               + jnp.where(x["last"], d_g_last, 0.0))
+    return dq, dk, dv, d_g_row, to_row(d_b), ds_out
+
+
+# -- the kernels --------------------------------------------------------------
+
+def kernels_take(q_shape, v_shape, dtype, chunk: int = CHUNK) -> bool:
+    """Whether the compiled kernels take these operands: bf16, key and
+    value widths that fill the MXU's 128 lanes, whole key-head groups and a
+    sequence of whole chunks of a size the inverse takes."""
+    _, t, hk, dk = q_shape
+    hv, dv = v_shape[2:]
+    return (jnp.dtype(dtype) == jnp.bfloat16 and dk % 128 == 0
+            and dv % 128 == 0 and hv % hk == 0 and chunk % _INNER == 0
+            and t % chunk == 0)
+
+
+def _block(t: int, chunk: int) -> int:
+    """Positions a grid step takes: whole chunks, 1,024 where that divides
+    (the state's round trip through the scratch and a step's fixed cost are
+    then a sixteenth a chunk)."""
+    return next((b for b in (1024, 512, 256, 128)
+                 if t % b == 0 and b % chunk == 0), chunk)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, states_ref, s_acc,
+                *, chunk: int):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_acc[...] = jnp.zeros_like(s_acc)
+
+    def one(ci, carry):
+        rows = pl.ds(pl.multiple_of(ci * chunk, chunk), chunk)
+        s = s_acc[...]
+        states_ref[0, 0, ci] = s.astype(states_ref.dtype)
+        o, s_acc[...] = _chunk_fwd(
+            s, q_ref[0, 0, rows, :], k_ref[0, 0, rows, :],
+            v_ref[0, 0, rows, :], g_ref[0, 0, ci], b_ref[0, 0, ci])
+        o_ref[0, 0, rows, :] = o.astype(o_ref.dtype)
+        return carry
+
+    lax.fori_loop(0, g_ref.shape[2], one, 0)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, states_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_acc, *,
+                chunk: int):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_acc[...] = jnp.zeros_like(ds_acc)
+
+    n = g_ref.shape[2]
+
+    def one(i, carry):
+        ci = n - 1 - i
+        rows = pl.ds(pl.multiple_of(ci * chunk, chunk), chunk)
+        dq, dk, dv, dg, db, ds_acc[...] = _chunk_bwd(
+            states_ref[0, 0, ci], ds_acc[...], q_ref[0, 0, rows, :],
+            k_ref[0, 0, rows, :], v_ref[0, 0, rows, :], g_ref[0, 0, ci],
+            b_ref[0, 0, ci], do_ref[0, 0, rows, :])
+        dq_ref[0, 0, rows, :] = dq.astype(dq_ref.dtype)
+        dk_ref[0, 0, rows, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, 0, rows, :] = dv.astype(dv_ref.dtype)
+        dg_ref[0, 0, ci] = dg
+        db_ref[0, 0, ci] = db
+        return carry
+
+    lax.fori_loop(0, n, one, 0)
+
+
+def _specs(t: int, chunk: int, group: int, dk: int, dv: int, reverse: bool):
+    """Block specs by grid (batch, value head, block of chunks), the blocks
+    taken last to first where ``reverse``."""
+    block = _block(t, chunk)
+    n = t // block
+    at = (lambda i: n - 1 - i) if reverse else (lambda i: i)
+    rows = lambda width, g=1: pl.BlockSpec(              # noqa: E731
+        (1, 1, block, width), lambda bi, h, i: (bi, h // g, at(i), 0))
+    scalars = pl.BlockSpec((1, 1, block // chunk, 1, chunk),
+                           lambda bi, h, i: (bi, h, at(i), 0, 0))
+    states = pl.BlockSpec((1, 1, block // chunk, dk, dv),
+                          lambda bi, h, i: (bi, h, at(i), 0, 0))
+    return n, rows, scalars, states, rows(dk, group)
+
+
+def _call(kernel, name, interpret, **kwargs):
+    return pl.pallas_call(
+        kernel, name=name, interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        **kwargs)
+
+
+def _fwd_kernels(q, k, v, g_rows, b_rows, *, chunk: int, interpret: bool):
+    b, hv, t, dv = v.shape
+    dk = q.shape[3]
+    n, rows, scalars, states, qk = _specs(t, chunk, hv // q.shape[1], dk, dv,
+                                          False)
+    with jax.named_scope("gdn.chunk_fwd"):
+        return tuple(_call(
+            functools.partial(_fwd_kernel, chunk=chunk), "gdn_chunk_fwd",
+            interpret, grid=(b, hv, n),
+            in_specs=[qk, qk, rows(dv), scalars, scalars],
+            out_specs=[rows(dv), states],
+            out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                       jax.ShapeDtypeStruct((b, hv, t // chunk, dk, dv),
+                                            v.dtype)],
+            scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        )(q, k, v, g_rows, b_rows))
+
+
+def _bwd_kernels(q, k, v, g_rows, b_rows, do, states_in, *, chunk: int,
+                 interpret: bool):
+    b, hv, t, dv = v.shape
+    hk, dk = q.shape[1], q.shape[3]
+    n, rows, scalars, states, qk = _specs(t, chunk, hv // hk, dk, dv, True)
+    per_head = jax.ShapeDtypeStruct((b, hv, t, dk), jnp.float32)
+    with jax.named_scope("gdn.chunk_bwd"):
+        dq, dk_, dv_, dg, db = _call(
+            functools.partial(_bwd_kernel, chunk=chunk), "gdn_chunk_bwd",
+            interpret, grid=(b, hv, n),
+            in_specs=[qk, qk, rows(dv), scalars, scalars, rows(dv), states],
+            out_specs=[rows(dk), rows(dk), rows(dv), scalars, scalars],
+            out_shape=[per_head, per_head,
+                       jax.ShapeDtypeStruct(v.shape, v.dtype),
+                       jax.ShapeDtypeStruct(g_rows.shape, jnp.float32),
+                       jax.ShapeDtypeStruct(g_rows.shape, jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        )(q, k, v, g_rows, b_rows, do, states_in)
+    return _group_sum(dq, hk, q.dtype), _group_sum(dk_, hk, k.dtype), dv_, \
+        dg, db
+
+
+def _group_sum(per_value_head, hk: int, dtype):
+    """[B, Hv, T, dk] float32 -> the key heads' [B, Hk, T, dk]."""
+    b, hv, t, d = per_value_head.shape
+    return per_value_head.reshape(b, hk, hv // hk, t, d).sum(2).astype(dtype)
+
+
+# -- the plain form -----------------------------------------------------------
+
+def _by_chunk(x, chunk: int):
+    b, h, t, d = x.shape
+    return x.reshape(b, h, t // chunk, chunk, d)
+
+
+def _over_heads(per_head, *operands):
+    return jax.vmap(jax.vmap(per_head))(*operands)
+
+
+def _fwd_plain(q, k, v, g_rows, b_rows, *, chunk: int):
+    group = v.shape[1] // q.shape[1]
+    q, k = (jnp.repeat(x, group, axis=1) for x in (q, k))
+    dk, dv = q.shape[3], v.shape[3]
+
+    def per_head(q, k, v, g, b):
+        def step(s, xs):
+            o, s_next = _chunk_fwd(s, *xs)
+            return s_next, (o.astype(v.dtype), s.astype(v.dtype))
+        _, (o, states) = lax.scan(step, jnp.zeros((dk, dv), jnp.float32),
+                                  (q, k, v, g, b))
+        return o.reshape(-1, dv), states
+
+    o, states = _over_heads(per_head, _by_chunk(q, chunk),
+                            _by_chunk(k, chunk), _by_chunk(v, chunk), g_rows,
+                            b_rows)
+    return o, states
+
+
+def _bwd_plain(q, k, v, g_rows, b_rows, do, states, *, chunk: int):
+    hk = q.shape[1]
+    group = v.shape[1] // hk
+    dk, dv = q.shape[3], v.shape[3]
+    q_all, k_all = (jnp.repeat(x, group, axis=1) for x in (q, k))
+
+    def per_head(q, k, v, g, b, do, states):
+        def step(ds, xs):
+            s_in, *rest = xs
+            *grads, ds = _chunk_bwd(s_in, ds, *rest)
+            return ds, grads
+        _, grads = lax.scan(step, jnp.zeros((dk, dv), jnp.float32),
+                            (states, q, k, v, g, b, do), reverse=True)
+        dq, dk_, dv_, dg, db = grads
+        return (dq.reshape(-1, dk), dk_.reshape(-1, dk),
+                dv_.reshape(-1, dv).astype(v.dtype), dg, db)
+
+    dq, dk_, dv_, dg, db = _over_heads(
+        per_head, _by_chunk(q_all, chunk), _by_chunk(k_all, chunk),
+        _by_chunk(v, chunk), g_rows, b_rows, _by_chunk(do, chunk), states)
+    return _group_sum(dq, hk, q.dtype), _group_sum(dk_, hk, k.dtype), dv_, \
+        dg, db
+
+
+# -- kernel or plain form, and the rule's VJP ---------------------------------
+
+def _choose(kernels, plain, taken: bool, interpret, chunk: int, *operands):
+    """As ``llama.attention`` chooses: by the operands while tracing, by the
+    platform when lowered; ``interpret`` True / False forces the kernels
+    through the Pallas interpreter or the compiler (tests)."""
+    if interpret is not None:
+        return kernels(*operands, chunk=chunk, interpret=interpret)
+    plain = functools.partial(plain, chunk=chunk)
+    if not taken:
+        return plain(*operands)
+    return lax.platform_dependent(
+        *operands, default=plain,
+        tpu=lambda first, *rest: kernels(
+            count_lowering(first, "gdn_lowerings"), *rest, chunk=chunk,
+            interpret=False))
+
+
+def _rows(x, chunk: int):
+    """[B, H, T] -> float32 [B, H, T/C, 1, C], a row a chunk."""
+    b, h, t = x.shape
+    return x.astype(jnp.float32).reshape(b, h, t // chunk, 1, chunk)
+
+
+def _taken(q, v, chunk: int) -> bool:
+    """``kernels_take`` of head-major q [B, Hk, T, dk] and v."""
+    (b, hk, t, dk), (_, hv, _, dv) = q.shape, v.shape
+    return kernels_take((b, t, hk, dk), (b, t, hv, dv), q.dtype, chunk)
+
+
+def _forward(q, k, v, g, beta, chunk, interpret):
+    gamma = jnp.cumsum(_rows(g, chunk), axis=-1)
+    return _choose(_fwd_kernels, _fwd_plain, _taken(q, v, chunk), interpret,
+                   chunk, q, k, v, gamma, _rows(beta, chunk))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _rule(q, k, v, g, beta, chunk, interpret):
+    return _forward(q, k, v, g, beta, chunk, interpret)[0]
+
+
+def _rule_fwd(q, k, v, g, beta, chunk, interpret):
+    o, states = _forward(q, k, v, g, beta, chunk, interpret)
+    o = checkpoint_name(o, RESIDUAL_NAMES[0])
+    states = checkpoint_name(states, RESIDUAL_NAMES[1])
+    return o, (q, k, v, g, beta, states)
+
+
+def _rule_bwd(chunk, interpret, residuals, do):
+    q, k, v, g, beta, states = residuals
+    gamma = jnp.cumsum(_rows(g, chunk), axis=-1)
+    dq, dk, dv, d_gamma, d_beta = _choose(
+        _bwd_kernels, _bwd_plain, _taken(q, v, chunk), interpret, chunk, q, k,
+        v, gamma, _rows(beta, chunk), do, states)
+    # gamma is a chunk's running sum of g: its transpose runs the other way
+    dg = jnp.flip(jnp.cumsum(jnp.flip(d_gamma, -1), axis=-1), -1)
+    return (dq, dk, dv, dg.reshape(g.shape).astype(g.dtype),
+            d_beta.reshape(beta.shape).astype(beta.dtype))
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK,
+                     interpret: bool | None = None):
+    """q, k: [B, T, Hk, dk]; v: [B, T, Hv, dv]; g (log-decay, <= 0) and beta:
+    [B, T, Hv] -> o [B, T, Hv, dv] in v's dtype, differentiable in all five.
+    T must be whole chunks."""
+    t = q.shape[1]
+    if t % chunk or (chunk > _INNER and chunk % _INNER):
+        raise ValueError(f"seq {t} must be whole chunks of {chunk}, a chunk "
+                         f"at most {_INNER} or a multiple of it")
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))   # [B, H, T, D]
+    g, beta = (x.transpose(0, 2, 1) for x in (g, beta))      # [B, H, T]
+    return _rule(q, k, v, g, beta, chunk, interpret).transpose(0, 2, 1, 3)

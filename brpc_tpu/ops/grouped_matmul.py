@@ -1,6 +1,7 @@
 """Grouped matrix product over the experts a chip holds — the routed half of
-a sparse expert layer (models/deepseek.py), forward and backward, with no
-padding to a capacity and no dropped row.
+a sparse expert layer (models/experts.py, which models/deepseek.py and
+models/hybrid.py both call), forward and backward, with no padding to a
+capacity and no dropped row.
 
 The (token, expert) assignments that fall on held experts are laid out by
 expert: ``group_layout`` sorts them and gives every expert a run of whole

@@ -319,7 +319,16 @@ def test_supported_states_the_rule_for_two_widths():
     assert not supported((1, 10240, 32, 192), (1, 10240, 32, 192), bf16,
                          (1, 10240, 32, 128))
     assert supported((1, 4096, 32, 128), (1, 4096, 8, 128), bf16)
-    assert not supported((1, 4608, 32, 128), (1, 4608, 8, 128), bf16)
+    # past that the group no longer stays whole in VMEM and the backward
+    # kernel takes a query head at a time (PR 34): what bounds the sequence
+    # is one head's residents, 4,096 B a token at 256 / 256
+    from brpc_tpu.ops.flash_attention import heads_together
+    assert heads_together(4, 4096, 128, 128, bf16)
+    assert not heads_together(4, 4608, 128, 128, bf16)
+    assert supported((1, 4608, 32, 128), (1, 4608, 8, 128), bf16)
+    assert not heads_together(8, 8192, 256, 256, bf16)
+    assert supported((1, 8192, 16, 256), (1, 8192, 2, 256), bf16)
+    assert not supported((1, 8320, 16, 256), (1, 8320, 2, 256), bf16)
     assert not supported((1, 8192, 32, 192), (1, 8192, 32, 192), bf16,
                          (1, 8192, 32, 64))         # v narrower than a lane
     assert not supported((1, 8192, 32, 96), (1, 8192, 32, 96), bf16,
@@ -328,11 +337,15 @@ def test_supported_states_the_rule_for_two_widths():
                          (1, 32768, 32, 128))       # K, V past the VMEM room
 
 
-# The attention of the benchmark's two train cells: Mistral-7B's grouped
-# heads at 2,048 tokens, kanana-2's latent heads at 8,192. (T, query heads,
-# KV heads, q/k width, v width, the backward kernel's tile.)
+# The attention of the benchmark's train cells: Mistral-7B's grouped heads
+# at 2,048 tokens, kanana-2's latent heads at 8,192, Ouro's 16 heads at
+# 4,096, Qwen3-Next's 8 query heads a KV head of 256 at 8,192 (the one whose
+# group does not stay whole in VMEM). (T, query heads, KV heads, q/k width,
+# v width, the backward kernel's tile.)
 _CELLS = {"mistral7b": (2048, 32, 8, 128, 128, 512),
-          "kanana2": (8192, 32, 32, 192, 128, 1024)}
+          "kanana2": (8192, 32, 32, 192, 128, 1024),
+          "ouro": (4096, 16, 16, 128, 128, 512),
+          "qwen3next": (8192, 16, 2, 256, 256, 1024)}
 
 
 @pytest.mark.parametrize("cell", sorted(_CELLS))
@@ -806,3 +819,132 @@ def test_looped_step_takes_the_kernels_once_a_scan_on_tpu(v5e_device,
     assert sorted(_ATTN_CALLS.findall(text)) == _ONE_OF_EACH
     assert re.findall(rf"\w+\[[\d,]*{_T},{_T}\]", text) == []
     assert lowerings() == (1, 0)
+
+
+# -- PR 34: a query group too large for VMEM, and the gated delta rule --------
+
+from brpc_tpu.models import hybrid  # noqa: E402
+from brpc_tpu.ops import gated_delta  # noqa: E402
+
+
+@pytest.mark.parametrize("cell", ["mistral7b", "kanana2", "ouro"])
+def test_held_geometries_keep_their_groups_whole(cell):
+    """The three held cells' attention takes the branch it took before
+    PR 34: the whole query group a KV head, grid over KV heads, dK and dV in
+    the operands' dtype and no sum after the kernel (the builder compared
+    the two commits' jaxprs at these geometries: identical, PERF.md)."""
+    from brpc_tpu.ops.flash_attention import heads_together
+    t, hq, hkv, d_qk, d_v, block = _CELLS[cell]
+    assert heads_together(hq // hkv, t, d_qk, d_v, jnp.bfloat16)
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa: E731
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2)))(s(1, t, hq, d_qk), s(1, t, hkv, d_qk),
+                            s(1, t, hkv, d_v))
+
+    def calls(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    (bwd,) = [e for e in calls(jaxpr.jaxpr)
+              if e.params["name"] == "attn_flash_bwd"]
+    assert bwd.params["grid_mapping"].grid == (1, hkv, t // block)
+    assert [v.aval.dtype for v in bwd.outvars] == [jnp.bfloat16] * 3
+    assert bwd.outvars[1].aval.shape == (1, hkv, t, d_qk)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["float32", "bfloat16"])
+def test_a_query_head_at_a_time_matches_dense(monkeypatch, dtype, tol):
+    """8 query heads a KV head of 256 / 256, the room made so small that the
+    group does not fit: the interpreted kernels a query head at a time, dK
+    and dV summed over the group afterwards, against the dense form."""
+    import importlib
+    fa = importlib.import_module("brpc_tpu.ops.flash_attention")
+    q, k, v = _inputs(jax.random.PRNGKey(7), b=1, t=128, hq=16, hkv=2, d=256,
+                      dtype=dtype)
+    w = jax.random.normal(jax.random.PRNGKey(8), (1, 128, 16 * 256),
+                          jnp.float32)
+    wants = jax.grad(_weighted(functools.partial(llama.dense_attention), w),
+                     (0, 1, 2))(q, k, v)
+    monkeypatch.setattr(fa, "_VMEM_LIMIT", 2 * fa._resident(
+        1, 128, 256, 256, dtype))
+    assert not fa.heads_together(8, 128, 256, 256, dtype)
+    assert fa.supported(q.shape, k.shape, dtype)
+    grads = jax.grad(_weighted(functools.partial(
+        flash_attention, block_q=64, block_k=64, interpret=True), w),
+        (0, 1, 2))(q, k, v)
+    for name, g, r in zip(("dq", "dk", "dv"), grads, wants):
+        assert g.dtype == r.dtype == dtype and g.shape == r.shape, name
+        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        assert np.max(np.abs(g - r)) <= tol * np.max(np.abs(r)), name
+
+
+def test_gated_attention_geometry_compiles_for_v5e(v5e_device):
+    """Forward and backward at the Qwen3-Next cell's geometry (8,192 x 16
+    query heads over 2 KV heads of 256): one call of each kernel, no array
+    shaped like the scores."""
+    args, _ = _abstract_inputs(jax.sharding.SingleDeviceSharding(v5e_device),
+                               b=1, t=8192, hq=16, hkv=2, d=256,
+                               dtype=jnp.bfloat16)
+    text = _grad_of_sum().trace(*args).lower().compile().as_text()
+    assert sorted(_ATTN_CALLS.findall(text)) == _ONE_OF_EACH
+    assert re.findall(r"\w+\[[\d,]*8192,8192\]", text) == []
+
+
+_GDN_CALLS = re.compile(r"%(gdn_chunk_\w+?)(?:\.\d+)? = [^\n]*custom-call\(")
+
+
+def test_gated_delta_kernels_compile_for_v5e_at_the_cells_size(v5e_device):
+    """1 x 8,192 tokens, 16 key and 32 value heads of 128, bf16: Mosaic and
+    XLA:TPU take both kernels; the backward pass keeps the chunks' entry
+    states (128 of them a head) and no per-token state."""
+    sharding = jax.sharding.SingleDeviceSharding(v5e_device)
+    s = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=sharding)
+    args = (s(1, 8192, 16, 128), s(1, 8192, 16, 128), s(1, 8192, 32, 128),
+            s(1, 8192, 32, dtype=jnp.float32),
+            s(1, 8192, 32, dtype=jnp.float32))
+    grad = jax.jit(jax.grad(
+        lambda *a: jnp.sum(gated_delta.gated_delta_rule(*a)
+                           .astype(jnp.float32)), argnums=(0, 1, 2, 3, 4)))
+    text = grad.trace(*args).lower().compile().as_text()
+    assert sorted(_GDN_CALLS.findall(text)) == ["gdn_chunk_bwd",
+                                                "gdn_chunk_fwd"]
+    assert "bf16[1,32,128,128,128]" in text           # the entry states
+    assert re.findall(r"\w+\[[\d,]*8192,128,128\]", text) == []
+
+
+# Kernel-eligible and small: one period, 1 key and 2 value heads of 128, 2
+# query heads over 1 KV head of 128, 8 experts of which 2 are held.
+_HYBRID = hybrid.HybridConfig(
+    vocab_size=1024, hidden=256, n_layers=4, n_heads=2, n_kv_heads=1,
+    head_dim=128, linear_key_heads=1, linear_value_heads=2, n_experts=8,
+    experts_per_token=2, moe_intermediate=128, shared_intermediate=128,
+    n_held=2)
+
+
+def test_hybrid_step_takes_every_kernel_on_tpu(v5e_device, lowerings):
+    """The step compiled for a v5e holds the rule's kernels (the forward one
+    twice: a layer's recomputation runs it again, its results are not
+    saved), the attention kernels, the grouped products and the row
+    kernels, and no array shaped like the scores."""
+    obs.set_enabled(True)
+    gdn = obs.counter("gdn_lowerings")
+    before = gdn.get_value()
+    text = _abstract_step(_HYBRID, jax.sharding.SingleDeviceSharding(
+        v5e_device), hybrid).lower().compile().as_text()
+    found = set(re.findall(
+        r"%((?:gdn_chunk|attn_flash|moe_gmm|moe_rows)_\w+?)(?:\.\d+)? =",
+        text))
+    assert found == {"gdn_chunk_fwd", "gdn_chunk_bwd", "attn_flash_fwd",
+                     "attn_flash_bwd", "moe_gmm_fwd", "moe_gmm_dlhs",
+                     "moe_gmm_drhs", "moe_rows_gather", "moe_rows_pack",
+                     "moe_rows_combine"}
+    assert re.findall(rf"\w+\[[\d,]*{_T},{_T}\]", text) == []
+    assert lowerings() == (1, 0)
+    assert gdn.get_value() - before == 1
