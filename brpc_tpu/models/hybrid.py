@@ -22,9 +22,9 @@ and the per-head q/k norms.
   per head, each key head serving ``value/key`` value heads, q scaled by
   dk^-1/2. Per value head, from ``S = 0``: ``S <- exp(g_t) S; delta =
   beta_t (v_t - S^T k_t); S <- S + k_t delta^T; o_t = S^T q_t``
-  (``ops/gated_delta.py``: chunks of 64 in the WY form, Pallas kernels on a
-  TPU). Output ``RMSNorm_128(o) · w · silu(z)`` per head (``w`` initialised
-  1), then ``·W_out``.
+  (``ops/gated_delta.py``: chunks of 64 in the WY form, three Pallas
+  kernels on a TPU). Output ``RMSNorm_128(o) · w · silu(z)`` per head
+  (``w`` initialised 1), then ``·W_out``.
 - **Gated full attention** (16 query heads over 2 KV heads of 256).
   ``y·W_q`` gives each head its query (first half) and its gate (second);
   q and k RMS-normed per head; rope, halves rotated, on the first
@@ -54,10 +54,14 @@ ONE ``lax.scan`` over periods whose body scans the period's linear layers and
 then runs its full layer, so one compiled body of each kind whatever the
 depth. Each layer is recomputed in the backward pass (``jax.checkpoint``)
 from its input and what ``SAVED_NAMES`` names: the attention kernel's output
-and log-sum-exp and the experts' integer routing layout. The rule's output
-and chunk-entry states carry names too (``gated_delta.RESIDUAL_NAMES``) but
-are NOT saved: the forward kernel runs twice a layer, since keeping them
-leaves the compiled step 0.07 GiB of the chip (PERF.md section 4). bf16
+and log-sum-exp, the experts' integer routing layout and the rule's T
+(``gated_delta.INVERSE_NAME``: every chunk's (I + A)^-1, 33.5 MB a linear
+layer at 8,192 tokens, as much as the layer's saved input; it depends on no
+state, so ``gdn_chunk_prep`` runs once a layer and step and the recomputation
+and the backward kernel read what it made). The rule's output and chunk-entry
+states carry names too (``gated_delta.RESIDUAL_NAMES``) but are NOT saved:
+the forward kernel runs twice a layer, since keeping them (201 MB a layer)
+left the compiled step 0.07 GiB of the chip (PERF.md section 4). bf16
 compute; float32 master weights, norms, router, decays, softmax and loss
 (``models/chunked_loss.py``).
 
@@ -96,7 +100,7 @@ _FLOAT32_LEAVES = ("router", "mixer_norm", "mlp_norm", "q_norm", "k_norm",
                    "out_norm", "a_log", "dt_bias", "conv")
 
 # What a layer keeps across its recomputation beside its input.
-SAVED_NAMES = (*RESIDUAL_NAMES, gm.LAYOUT_NAME)
+SAVED_NAMES = (*RESIDUAL_NAMES, gm.LAYOUT_NAME, gated_delta.INVERSE_NAME)
 
 
 @dataclasses.dataclass(frozen=True)

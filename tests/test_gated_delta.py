@@ -7,6 +7,8 @@ which form a program lowered for TPU holds. That Mosaic and XLA:TPU take the
 kernels at the benchmark cell's size is compiled in tests/test_ops.py, beside
 the other kernels (one file loads libtpu)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -173,13 +175,16 @@ def test_program_holds_the_kernels_only_on_tpu_at_shapes_they_take(
     assert obs.counter("gdn_lowerings").get_value() - before == int(kernels)
     assert ("tpu_custom_call" in text) is kernels
     if kernels:
-        assert text.count("stablehlo.custom_call @tpu_custom_call") == 2
+        assert text.count("stablehlo.custom_call @tpu_custom_call") == 3
+        assert sorted(set(re.findall(r"gdn_chunk_\w+", text))) == [
+            "gdn_chunk_bwd", "gdn_chunk_fwd", "gdn_chunk_prep"]
 
 
-def test_results_the_backward_pass_keeps_have_names():
-    """The forward rule names the output and the chunks' entry states, so
-    that a checkpoint policy can save them."""
-    args, _ = _inputs(b=1, t=64, hk=1, hv=1)
+@pytest.mark.parametrize("t", [64, 128], ids=["one_chunk", "a_pair"])
+def test_results_the_backward_pass_keeps_have_names(t):
+    """The forward rule names the output, the chunks' entry states and the
+    chunks' T, so that a checkpoint policy can save them."""
+    args, _ = _inputs(b=1, t=t, hk=1, hv=1)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda *a: jnp.sum(gated_delta_rule(*a))))(*args)
     names = set()
@@ -192,4 +197,101 @@ def test_results_the_backward_pass_keeps_have_names():
                 walk(sub)
 
     walk(jaxpr.jaxpr)
-    assert set(gated_delta.RESIDUAL_NAMES) <= names
+    assert {*gated_delta.RESIDUAL_NAMES, gated_delta.INVERSE_NAME} <= names
+
+
+# -- T = (I + A)^-1, made once a chunk by ``_chunk_prep`` ---------------------
+
+def _hard_case(w, c=64, dk=32):
+    """w chunks' keys in which positions 16..31 of each chunk are one key
+    (a diagonal block of A whose entries are all beta: the Neumann products'
+    terms grow to thousands before they cancel), beta near 1, no decay; and
+    (I + A) of each chunk as float64, built entry by entry."""
+    k = np.array(jax.random.normal(jax.random.PRNGKey(4), (w * c, dk)))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    for j in range(w):
+        k[j * c + 16:j * c + 32] = k[j * c + 16 + j]
+    beta = np.full((w * c,), 0.999, np.float32)
+    beta[::7] = 0.97
+    k32 = k.astype(np.float32)
+    i_plus_a = [np.eye(c) + np.tril(
+        beta[j * c:(j + 1) * c, None].astype(np.float64)
+        * (k32[j * c:(j + 1) * c].astype(np.float64)
+           @ k32[j * c:(j + 1) * c].astype(np.float64).T), -1)
+        for j in range(w)]
+    return jnp.asarray(k32), jnp.asarray(beta)[None], i_plus_a
+
+
+@pytest.mark.parametrize("w", [1, 2], ids=["one_chunk", "two_side_by_side"])
+def test_inverse_of_a_block_of_like_keys_is_a_triangular_solve(w):
+    """The planted hard case: T (I + A) is the identity to float32 accuracy
+    and T is what a triangular solve gives, for one chunk and for two side
+    by side in the lanes (the second's like keys are another key). The test
+    a cheaper inverse has to pass before it is taken (PERF.md section 7)."""
+    c = 64
+    k, b_row, i_plus_a = _hard_case(w)
+    t = np.asarray(gated_delta._chunk_prep(
+        gated_delta._nt(k, k), jnp.zeros_like(b_row), b_row, c, jnp.float32),
+        np.float64)
+    assert t.shape == (c, w * c)
+    for j, m in enumerate(i_plus_a):
+        t_j = t[:, j * c:(j + 1) * c]
+        # not a gentle case: the series' eighth term alone is in the
+        # thousands, and float32 carries the sum to that term's last bit (a
+        # product in one bf16 pass would be off by tens)
+        term = np.max(np.abs(np.linalg.matrix_power(m - np.eye(c), 8)))
+        assert term > 1e3
+        solved = np.linalg.solve(m, np.eye(c))        # m is unit lower
+        assert np.max(np.abs(t_j @ m - np.eye(c))) <= term * 2.0 ** -23
+        assert np.max(np.abs(t_j - solved)) <= term * 2.0 ** -23
+        assert np.all(np.triu(t_j, 1) == 0.0)
+
+
+def _prep_operands(dtype, t=256, hk=2, hv=4, dk=32):
+    (q, k, v, g, beta), _ = _inputs(seed=5, b=1, t=t, hk=hk, hv=hv, dk=dk,
+                                    dtype=dtype)
+    rows = lambda x: gated_delta._rows(x.transpose(0, 2, 1), 64)  # noqa: E731
+    return (k.transpose(0, 2, 1, 3), jnp.cumsum(rows(g), axis=-1),
+            rows(beta))
+
+
+@pytest.mark.parametrize("t,lanes", [(256, 128), (192, 64)],
+                         ids=["pairs", "odd_count"])
+def test_interpreted_kernel_and_plain_form_make_the_same_inverse(t, lanes):
+    """Float32 operands, so nothing is cast: ``gdn_chunk_prep`` through the
+    interpreter against the plain form, stored [B, Hv, T/(w C), C, w C] —
+    two chunks side by side where the chunks come in pairs — and each
+    chunk's T what one chunk alone gives."""
+    k, gamma, b_rows = _prep_operands(jnp.float32, t=t)
+    plain = gated_delta._prep_plain(k, gamma, b_rows, chunk=64)
+    kernel = gated_delta._prep_kernels(k, gamma, b_rows, chunk=64,
+                                       interpret=True)
+    assert plain.shape == kernel.shape == (1, 4, t // lanes, 64, lanes)
+    assert plain.dtype == kernel.dtype == jnp.float32
+    scale = float(jnp.max(jnp.abs(plain)))
+    assert float(jnp.max(jnp.abs(kernel - plain))) <= 1e-6 * scale
+    each = gated_delta._each_chunks(plain, 64)
+    assert each.shape == (1, 4, t // 64, 64, 64)
+    k_heads = jnp.repeat(k, 2, axis=1)
+    for h, ci in ((0, 0), (3, t // 64 - 1)):
+        k_c = k_heads[0, h, ci * 64:(ci + 1) * 64]
+        alone = gated_delta._chunk_prep(
+            gated_delta._nt(k_c, k_c), gamma[0, h, ci], b_rows[0, h, ci], 64,
+            jnp.float32)
+        assert float(jnp.max(jnp.abs(each[0, h, ci] - alone))) <= 1e-6 * scale
+
+
+def test_bf16_inverse_is_the_float32_one_rounded_once():
+    """bf16 keys: k k^T, A and the inverse stay float32 and T is cast at
+    the end, alike in the interpreted kernel and the plain form."""
+    k, gamma, b_rows = _prep_operands(jnp.bfloat16, dk=128)
+    plain = gated_delta._prep_plain(k, gamma, b_rows, chunk=64)
+    kernel = gated_delta._prep_kernels(k, gamma, b_rows, chunk=64,
+                                       interpret=True)
+    assert plain.dtype == kernel.dtype == jnp.bfloat16
+    exact = gated_delta._prep_plain(k.astype(jnp.float32), gamma, b_rows,
+                                    chunk=64)
+    scale = float(jnp.max(jnp.abs(exact)))
+    for got in (plain, kernel):
+        assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - exact))) \
+            <= 2 ** -8 * scale

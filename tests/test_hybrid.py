@@ -40,6 +40,7 @@ import reference_gdn  # noqa: E402
 
 from brpc_tpu import obs  # noqa: E402
 from brpc_tpu.models import deepseek, experts, hybrid  # noqa: E402
+from brpc_tpu.ops import gated_delta  # noqa: E402
 from brpc_tpu.ops import grouped_matmul as gm  # noqa: E402
 
 SIZES = {
@@ -183,30 +184,39 @@ def test_three_adamw_steps_follow_the_reference(params, tokens):
                for k, v in want["delta_norms"].items()) <= 2e-3
 
 
-def test_gradients_do_not_depend_on_what_the_recomputation_keeps(
-        monkeypatch, params, tokens):
-    """The model as it is, a bare checkpoint (no name saved), the rule's
-    results saved too, and no checkpoint at all: the same gradients."""
-    def gradients(keeps):
-        with monkeypatch.context() as mp:
-            if keeps == "input":
-                mp.setattr(hybrid, "SAVED_NAMES", ())
-            elif keeps == "rule_too":
-                mp.setattr(hybrid, "SAVED_NAMES", (
-                    *hybrid.SAVED_NAMES, *hybrid.gated_delta.RESIDUAL_NAMES))
-            elif keeps == "all":
-                mp.setattr(jax, "checkpoint", lambda fun, **_: fun)
-            return jax.jit(jax.grad(
-                lambda p, t: hybrid.loss_fn(p, t, TINY32)[0]))(
-                    params, tokens[0])
+def _loss_gradients(params, tokens):
+    return jax.jit(jax.grad(
+        lambda p, t: hybrid.loss_fn(p, t, TINY32)[0]))(params, tokens[0])
 
-    want = gradients("names")
-    for keeps in ("input", "rule_too", "all"):
-        for (path, g), w in zip(
-                jax.tree_util.tree_leaves_with_path(gradients(keeps)),
-                jax.tree_util.tree_leaves(want)):
-            assert _scale_gap(g, w) <= 1e-4, (keeps,
-                                              jax.tree_util.keystr(path))
+
+@pytest.fixture(scope="module")
+def gradients_as_it_is(params, tokens):
+    return _loss_gradients(params, tokens)
+
+
+@pytest.mark.parametrize("keeps", ["input", "no_inverse", "rule_too", "all"])
+def test_gradients_do_not_depend_on_what_the_recomputation_keeps(
+        keeps, monkeypatch, params, tokens, gradients_as_it_is):
+    """The model as it is against a bare checkpoint (no name saved), the
+    rule's T not saved (``gdn_chunk_prep`` runs again in the recomputation),
+    the rule's output and states saved too, and no checkpoint at all: the
+    same gradients."""
+    inverse, rule = gated_delta.INVERSE_NAME, gated_delta.RESIDUAL_NAMES
+    assert inverse in hybrid.SAVED_NAMES
+    assert not set(rule) & set(hybrid.SAVED_NAMES)
+    if keeps == "all":
+        monkeypatch.setattr(jax, "checkpoint", lambda fun, **_: fun)
+    else:
+        monkeypatch.setattr(hybrid, "SAVED_NAMES", {
+            "input": (),
+            "no_inverse": tuple(n for n in hybrid.SAVED_NAMES
+                                if n != inverse),
+            "rule_too": (*hybrid.SAVED_NAMES, *rule)}[keeps])
+    for (path, g), w in zip(
+            jax.tree_util.tree_leaves_with_path(
+                _loss_gradients(params, tokens)),
+            jax.tree_util.tree_leaves(gradients_as_it_is)):
+        assert _scale_gap(g, w) <= 1e-4, jax.tree_util.keystr(path)
 
 
 # -- a chip's share of the expert layer ---------------------------------------
@@ -365,8 +375,9 @@ def test_the_cells_program_lowered_for_tpu_holds_every_kernel():
     assert obs.counter("moe_grouped_lowerings").get_value() > grouped
     found = set(re.findall(r"(gdn_chunk_\w+|attn_flash_\w+|moe_gmm_\w+|"
                            r"moe_rows_\w+)", text))
-    assert {"gdn_chunk_fwd", "gdn_chunk_bwd", "attn_flash_fwd",
-            "attn_flash_bwd", "moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs",
+    assert {"gdn_chunk_prep", "gdn_chunk_fwd", "gdn_chunk_bwd",
+            "attn_flash_fwd", "attn_flash_bwd", "moe_gmm_fwd", "moe_gmm_dlhs",
+            "moe_gmm_drhs",
             "moe_rows_gather", "moe_rows_combine", "moe_rows_pack"} <= found
 
 

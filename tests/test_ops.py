@@ -5,6 +5,7 @@ for the TPU platform and by compiling ahead of time for a v5e topology
 chip are chip_smoke.py's ``kernel`` phase. Last, the choice
 ``llama.attention`` makes between the kernels and the dense form."""
 
+import collections
 import dataclasses
 import functools
 import re
@@ -901,8 +902,10 @@ _GDN_CALLS = re.compile(r"%(gdn_chunk_\w+?)(?:\.\d+)? = [^\n]*custom-call\(")
 
 def test_gated_delta_kernels_compile_for_v5e_at_the_cells_size(v5e_device):
     """1 x 8,192 tokens, 16 key and 32 value heads of 128, bf16: Mosaic and
-    XLA:TPU take both kernels; the backward pass keeps the chunks' entry
-    states (128 of them a head) and no per-token state."""
+    XLA:TPU take all three kernels; the backward pass keeps the chunks'
+    entry states (128 of them a head), every chunk's T (two chunks side by
+    side in the 128 lanes: 33.5 MB, where [64, 64] tiles would be padded to
+    twice that) and no per-token state."""
     sharding = jax.sharding.SingleDeviceSharding(v5e_device)
     s = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dtype, sharding=sharding)
@@ -913,9 +916,11 @@ def test_gated_delta_kernels_compile_for_v5e_at_the_cells_size(v5e_device):
         lambda *a: jnp.sum(gated_delta.gated_delta_rule(*a)
                            .astype(jnp.float32)), argnums=(0, 1, 2, 3, 4)))
     text = grad.trace(*args).lower().compile().as_text()
-    assert sorted(_GDN_CALLS.findall(text)) == ["gdn_chunk_bwd",
-                                                "gdn_chunk_fwd"]
+    assert sorted(_GDN_CALLS.findall(text)) == [
+        "gdn_chunk_bwd", "gdn_chunk_fwd", "gdn_chunk_prep"]
     assert "bf16[1,32,128,128,128]" in text           # the entry states
+    assert "bf16[1,32,64,64,128]" in text             # T, a pair of chunks
+    assert "bf16[1,32,128,64,64]" not in text
     assert re.findall(r"\w+\[[\d,]*8192,128,128\]", text) == []
 
 
@@ -931,8 +936,10 @@ _HYBRID = hybrid.HybridConfig(
 def test_hybrid_step_takes_every_kernel_on_tpu(v5e_device, lowerings):
     """The step compiled for a v5e holds the rule's kernels (the forward one
     twice: a layer's recomputation runs it again, its results are not
-    saved), the attention kernels, the grouped products and the row
-    kernels, and no array shaped like the scores."""
+    saved; ``gdn_chunk_prep`` once, like the backward one: its T is saved
+    by name and a recomputation that made it again would count two), the
+    attention kernels, the grouped products and the row kernels, and no
+    array shaped like the scores."""
     obs.set_enabled(True)
     gdn = obs.counter("gdn_lowerings")
     before = gdn.get_value()
@@ -941,10 +948,14 @@ def test_hybrid_step_takes_every_kernel_on_tpu(v5e_device, lowerings):
     found = set(re.findall(
         r"%((?:gdn_chunk|attn_flash|moe_gmm|moe_rows)_\w+?)(?:\.\d+)? =",
         text))
-    assert found == {"gdn_chunk_fwd", "gdn_chunk_bwd", "attn_flash_fwd",
-                     "attn_flash_bwd", "moe_gmm_fwd", "moe_gmm_dlhs",
-                     "moe_gmm_drhs", "moe_rows_gather", "moe_rows_pack",
-                     "moe_rows_combine"}
+    assert found == {"gdn_chunk_prep", "gdn_chunk_fwd", "gdn_chunk_bwd",
+                     "attn_flash_fwd", "attn_flash_bwd", "moe_gmm_fwd",
+                     "moe_gmm_dlhs", "moe_gmm_drhs", "moe_rows_gather",
+                     "moe_rows_pack", "moe_rows_combine"}
+    calls = collections.Counter(_GDN_CALLS.findall(text))
+    assert calls["gdn_chunk_bwd"] >= 1
+    assert calls["gdn_chunk_prep"] == calls["gdn_chunk_bwd"]
+    assert calls["gdn_chunk_fwd"] == 2 * calls["gdn_chunk_bwd"]
     assert re.findall(rf"\w+\[[\d,]*{_T},{_T}\]", text) == []
     assert lowerings() == (1, 0)
     assert gdn.get_value() - before == 1
