@@ -24,6 +24,7 @@ import numpy as np
 import harness
 import reference
 import reference_looped
+import trace_reduce
 import work_looped
 
 N_BATCHES = 64           # distinct token batches, cycled through the window
@@ -179,6 +180,9 @@ def run(ctx: harness.Context) -> harness.Outcome:
     last_loss = float(loss)
     ctx.lap("window")
     device = harness.device_report(devices, 1)
+    # Traced runs only, and the window closed: the scope of the program
+    # that each instruction of the compiled step was written under.
+    op_scopes = trace_reduce.op_scopes(step.as_text()) if ctx.trace else None
     entropy = [float(s["exit_entropy"]) for s in stats_log[n_before:]]
     del state, step, stats_log
 
@@ -196,7 +200,7 @@ def run(ctx: harness.Context) -> harness.Outcome:
             "step_flops": work_looped.looped_train_step(
                 m, batch, seq)["flops"],
             "sizes": m, "batch": batch, "sequence": seq,
-            "series": {"exit_entropy": entropy}},
-        trace=window.reduce(1),
+            "series": {"exit_entropy": entropy}, "op_scopes": op_scopes},
+        trace=window.reduce(1, op_scopes),
         counts={"steps": steps, "tokens_per_step": batch * seq,
                 "exit_entropy": float(np.median(entropy))})

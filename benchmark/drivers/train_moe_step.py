@@ -23,6 +23,7 @@ import numpy as np
 import harness
 import reference
 import reference_dsv3
+import trace_reduce
 import work_dsv3
 
 N_BATCHES = 64           # distinct token batches, cycled through the window
@@ -165,6 +166,9 @@ def run(ctx: harness.Context) -> harness.Outcome:
     last_loss = float(loss)
     ctx.lap("window")
     device = harness.device_report(devices, 1)
+    # Traced runs only, and the window closed: the scope of the program
+    # that each instruction of the compiled step was written under.
+    op_scopes = trace_reduce.op_scopes(step.as_text()) if ctx.trace else None
     stats = {k: np.stack([np.asarray(s[k]) for s in stats_log])
              for k in stats_log[0]}                     # each [steps, L]
     del state, step, stats_log
@@ -189,7 +193,8 @@ def run(ctx: harness.Context) -> harness.Outcome:
             "routed_rows": in_trace.tolist(),
             "series": {"expert_load_max_over_mean": (
                 stats["group_max"][n_before:]
-                / stats["group_mean"][n_before:]).max(axis=1).tolist()}},
-        trace=window.reduce(1),
+                / stats["group_mean"][n_before:]).max(axis=1).tolist()},
+            "op_scopes": op_scopes},
+        trace=window.reduce(1, op_scopes),
         counts={"steps": steps, "tokens_per_step": batch * seq,
                 "routed_per_step": float(routed.sum(axis=1).mean())})

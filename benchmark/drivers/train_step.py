@@ -18,6 +18,7 @@ import numpy as np
 
 import harness
 import reference
+import trace_reduce
 import work
 
 N_BATCHES = 64           # distinct token batches, cycled through the window
@@ -132,6 +133,9 @@ def run(ctx: harness.Context) -> harness.Outcome:
     last_loss = float(loss)
     ctx.lap("window")
     device = harness.device_report(devices, 1)
+    # Traced runs only, and the window closed: the scope of the program
+    # that each instruction of the compiled step was written under.
+    op_scopes = trace_reduce.op_scopes(step.as_text()) if ctx.trace else None
     del state, step
 
     want = reference.train_reference(ctx.seed, m, o, tokens, steps_followed)
@@ -144,6 +148,6 @@ def run(ctx: harness.Context) -> harness.Outcome:
         attempted=steps, failed=0, setup_s=setup_s, device=device,
         counters={"calls_in_trace": steps_in_trace,
                   "step_flops": work.llama_train_step(m, batch, seq)["flops"],
-                  "series": {}},
-        trace=window.reduce(1),
+                  "series": {}, "op_scopes": op_scopes},
+        trace=window.reduce(1, op_scopes),
         counts={"steps": steps, "tokens_per_step": batch * seq})

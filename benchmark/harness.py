@@ -205,7 +205,7 @@ class TracedWindow:
         self.t1 = time.monotonic()
         jax.profiler.stop_trace()
 
-    def reduce(self, chips: int):
+    def reduce(self, chips: int, scopes=None):
         if not self.on:
             return None
         import trace_reduce
@@ -215,7 +215,8 @@ class TracedWindow:
                               recursive=True)
             if not files:
                 raise SystemExit("benchmark: the profiler wrote no trace")
-            return trace_reduce.reduce(files[0], chips, self.t1 - self.t0)
+            return trace_reduce.reduce(files[0], chips, self.t1 - self.t0,
+                                       scopes)
         finally:
             shutil.rmtree(TRACE_DIR, ignore_errors=True)
 

@@ -1,7 +1,7 @@
-"""Readers of the train step's named kernels in the profiler's trace
-(reduced by ``trace_reduce``) and of the ``stats`` the step returns. Each
-returns None where there is nothing to read: no trace, or a program without
-such ops."""
+"""Readers of the train step's named kernels and named scopes in the
+profiler's trace (reduced by ``trace_reduce``) and of the ``stats`` the step
+returns. Each returns None where there is nothing to read: no trace, or a
+program without such ops."""
 
 from __future__ import annotations
 
@@ -52,6 +52,25 @@ def op_share_of_step(run, prefix: str, program: str):
         return None
     whole = run["trace"]["module_seconds"].get(program)
     return 100.0 * seconds / whole if whole else None
+
+
+def scope_share_of_step(run, scopes: list, program: str, phases=None):
+    """SELF device seconds of the step's ops written under a scope of the
+    program with a component that starts with one of ``scopes`` (``-``: under
+    none; ``trace_reduce.scope_of``), in one of ``phases`` if given, over
+    the device seconds of the step program's executions. None without a
+    trace, without the driver's scope table, or where no such op ran."""
+    t = run["trace"]
+    table = (t or {}).get("scope_seconds", {}).get(program)
+    if not table:
+        return None
+    seconds = sum(
+        s for scope, by_phase in table.items()
+        if any(part.startswith(p) for part in scope.split("/")
+               for p in scopes)
+        for phase, s in by_phase.items() if not phases or phase in phases)
+    whole = t["module_seconds"].get(program)
+    return 100.0 * seconds / whole if seconds and whole else None
 
 
 def stats_median(run, key: str):

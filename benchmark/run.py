@@ -9,7 +9,9 @@ what the timed path produced with the plain reference, and prints as its
 last line of standard output one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics, or with
 ``--trace 1`` its per-layer metrics), ``device``, with ``--trace 1`` a
-``breakdown``, and last ``checks``, every number compared beside its limit
+``breakdown`` (device time by the program's named scopes where the driver
+has the compiled step's text; the whole table goes to standard error), and
+last ``checks``, every number compared beside its limit
 (also the last lines on standard error). No chip, or fewer than the cell
 asks for: exit code 3 and no result.
 
@@ -145,12 +147,21 @@ def main(argv=None) -> int:
         result["metrics"] = {}
         result["dry_run"] = {"counts": outcome.counts,
                              "would_report": sorted(metrics)}
+        tables = outcome.counters.get("op_scopes")
+        if tables:      # a traced dry run: the scopes the compiled step names
+            result["dry_run"]["scopes"] = sorted(
+                {scope for t in tables.values() for scope, _ in t.values()})
     if args.trace and outcome.trace:
         result["breakdown"] = outcome.trace["breakdown"]
     result["checks"] = ctx.checks
     sys.stdout.flush()
     print(f"benchmark: seconds by phase: {json.dumps(ctx.laps)}",
           file=sys.stderr)
+    if args.trace and outcome.trace and "scope_seconds" in outcome.trace:
+        table = {k: outcome.trace[k] for k in (
+            "module_runs", "module_seconds", "scope_seconds")}
+        print("benchmark: device seconds by program, scope and phase: "
+              f"{json.dumps(table)}", file=sys.stderr)
     for c in ctx.checks:
         print(f"benchmark: check {c['name']}: {c['number']!r} "
               f"(limit {c['limit']!r})", file=sys.stderr)

@@ -45,7 +45,7 @@ def llama_train_step(m: dict, batch: int, seq: int) -> dict:
 
 def all_reduce(bytes_per_chip: int, n: int) -> dict:
     """Ring all-reduce: each chip sends and receives 2(n-1)/n of its
-    shard's bytes (the formula of ``parallel.allreduce_benchmark``)."""
+    shard's bytes."""
     return {"bus_bytes": 2 * (n - 1) * bytes_per_chip // n}
 
 

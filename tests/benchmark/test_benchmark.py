@@ -34,6 +34,20 @@ MANIFESTS = [MANIFEST, CANDIDATES]
 CELLS = [w["name"] for m in MANIFESTS for w in m["workloads"]]
 TRAIN_CELL = "train_mistral7b_1chip.steady"
 TRAIN_MIX = "ps_sarvam105b_1chip.train_mix"
+# The train cells, and scopes of the program that each one's compiled step
+# names (on the CPU attention is the dense form; "-": under no scope).
+_MOE = {"moe.router", "moe.sort", "moe.experts", "moe.combine", "moe.shared"}
+TRAIN_SCOPES = {
+    TRAIN_CELL: {"attn.dense", "-"},
+    "train_kanana2_30b_a3b_1chip.seq8k": _MOE | {
+        "mla.q_proj", "mla.kv_down", "mla.kv_up", "mla.out_proj", "-"},
+    "train_ouro_2_6b_1chip.loop4_2x4k": {
+        "loop.layer.attn", "loop.layer.mlp", "loop.pass_norm",
+        "loop.exit_gate", "loop.head", "loop.head/loop.exit_loss", "-"},
+    "train_qwen3_next_80b_a3b_1chip.lin3full1_8k": _MOE | {
+        "gdn.in_proj", "gdn.conv", "gdn.rule", "gdn.out", "gattn.qkv",
+        "gattn.out", "-"},
+}
 
 
 # -- the manifest ---------------------------------------------------------------
@@ -69,6 +83,32 @@ def test_every_layer_metric_moves_what_its_cells_report(MANIFEST):
         assert callable(getattr(mod, function)), m["name"]
         for key in ("unit", "layer", "moves"):
             assert spec[key] == m[key], (m["name"], key)
+
+
+@pytest.mark.parametrize("cell", sorted(TRAIN_SCOPES))
+def test_a_train_cell_s_layer_metrics_resolve_and_two_read_the_scopes(cell):
+    """Every ``per_layer`` entry of a train cell has its ``layer_metrics/``
+    file and reader, whatever later PRs append; among them the optimizer's
+    share of the step and the share under no scope of the program."""
+    mix = cell.rsplit(".", 1)[1]
+    e2e = {m["name"] for m in bench_run.metrics_of(MANIFEST, "end_to_end",
+                                                   cell)}
+    assert e2e == {"tokens_per_s", "setup_s"}
+    metrics = bench_run.metrics_of(MANIFEST, "per_layer", cell, e2e)
+    assert {f"{n}.{mix}" for n in (
+        "step_mfu", "device_idle_share", "step_gap_ms_p50", "opt_step_share",
+        "unscoped_step_share")} <= {m["name"] for m in metrics}
+    for m in metrics:
+        with open(os.path.join(BENCH, "layer_metrics",
+                               m["name"] + ".json")) as f:
+            spec = json.load(f)
+        module, function = spec["reader"].rsplit(".", 1)
+        mod = __import__("readers." + module, fromlist=[function])
+        assert callable(getattr(mod, function)), m["name"]
+        assert spec["workloads"] == m["workloads"] == [cell], m["name"]
+        if spec["reader"] == "train.scope_share_of_step":
+            assert spec["args"]["program"] == "jit_step" and \
+                spec["args"]["scopes"] and m["unit"] == "%", m["name"]
 
 
 def test_every_held_cell_reports_setup_another_metric_and_a_layer_metric():
@@ -177,6 +217,175 @@ def test_trace_reduce_on_the_recorded_trace():
     top, seconds = r["breakdown"]["device_ops"][0]
     assert top == "brt_scatter_sub:copy.3_f32[131072,4096]"
     assert 0.10 < seconds < 0.12 and len(r["breakdown"]["device_ops"]) <= 10
+    # no operation of these programs contains another: self time is the time
+    assert r["op_self_seconds"] == pytest.approx(r["op_seconds"])
+    assert sum(r["op_self_seconds"].values()) == pytest.approx(r["busy_s"])
+
+
+# Recorded lines of three compiled steps, and what the program wrote each
+# instruction under: a fusion with no metadata of its own through two nested
+# fused computations to its siblings' scope, a kernel behind a ``cond`` that
+# repeats the path, forward / recomputed / backward, a scope around the
+# differentiated call (``jvp(loop.head)``), an einsum's spec, XLA's own
+# shortened and joined names, a ``while``, and a copy the compiler put in.
+_SCOPES = [
+    ("fusion.18", ("moe.router", "bwd")),
+    ("fusion.597", ("moe.router", "bwd")),
+    ("gdn_chunk_prep.11", ("gdn.rule/gdn.chunk_prep/gdn_chunk_prep", "fwd")),
+    ("convolution.149", ("mla.q_proj", "fwd")),
+    ("convolution.163.clone.1", ("mla.q_proj", "bwd")),
+    ("slice.898", ("mla.kv_down", "remat")),
+    ("moe_gmm_fwd.70", ("moe.experts/moe_gmm_fwd", "remat")),
+    ("attn_flash_fwd.14", ("attn.flash_fwd/attn_flash_fwd", "fwd")),
+    ("iota.282", ("moe.sort", "outside")),
+    ("broadcast_multiply_fusion.2", ("-", "outside")),
+    ("while.155", ("-", "fwd")),
+    ("reduce_sum.497", ("mla.kv_down", "outside")),
+    ("reshape.158", ("-", "fwd")),
+    ("ne.53", ("loop.head/loop.exit_loss", "remat")),
+    ("broadcast.1207", ("loop.exit_gate", "bwd")),
+    ("attn_flash_bwd.8", ("loop.layer.attn/attn.flash_bwd/attn_flash_bwd",
+                          "bwd")),
+    ("copy.800", ("-", "none")),
+    ("scatter.8", ("-", "none")),
+]
+
+
+@pytest.fixture(scope="module")
+def recorded_scopes():
+    with open(os.path.join(os.path.dirname(__file__), "data",
+                           "step_hlo_lines.txt")) as f:
+        (program, table), = trace_reduce.op_scopes(f.read()).items()
+    assert program == "jit_step"
+    return table
+
+
+@pytest.mark.parametrize("instruction,want", _SCOPES,
+                         ids=[name for name, _ in _SCOPES])
+def test_the_scope_rule_on_recorded_hlo_lines(recorded_scopes, instruction,
+                                              want):
+    assert tuple(recorded_scopes[instruction]) == want
+
+
+def test_scope_of_takes_jax_s_own_components_out():
+    scope_of = trace_reduce.scope_of
+    assert scope_of("jit(f)/jvp()/while/body/closed_call/mla.q_proj/"
+                    "dot_general") == ("mla.q_proj", "fwd")
+    assert scope_of("jit(f)/transpose(jvp())/while/body/closed_call/"
+                    "checkpoint/rematted_computation/mla.q_proj/tanh") == (
+        "mla.q_proj", "remat")
+    assert scope_of("jit(f)/opt.update/sub") == ("opt.update", "outside")
+    assert scope_of("jit(f)/jvp(a/b)/vmap(c)/custom_jvp_call/d/mul") == (
+        "a/b/c/d", "fwd")
+    assert scope_of("jit(f)/jvp(jit(take_along_axis))/gather") == ("-", "fwd")
+    assert scope_of("reduce_sum") == scope_of("") == ("-", "outside")
+
+
+def test_self_time_of_a_while_is_what_its_body_leaves():
+    """A ``while`` around three ops and a gap, one op after it: the self
+    times add up to the union, a loop keeps only its own microseconds."""
+    ops = [("%while.7 = (s32[]) while(...)", 0.0, 10.0),
+           ("%a = f32[8]{0} fusion(...)", 1.0, 3.0),
+           ("%b = f32[8]{0} fusion(...)", 3.0, 4.0),
+           ("%c = f32[8]{0} fusion(...)", 6.0, 9.5),
+           ("%d = f32[8]{0} fusion(...)", 12.0, 13.0)]
+    own = trace_reduce.self_seconds(ops)
+    assert own == [3.5, 2.0, 1.0, 3.5, 1.0]
+    assert sum(own) == trace_reduce.union_seconds(
+        [(s, e) for _, s, e in ops]) == 11.0
+    # events that overlap without nesting: the one started last has the time
+    assert trace_reduce.self_seconds(
+        [("x", 0.0, 5.0), ("y", 3.0, 8.0), ("z", 4.0, 4.5)]) == [3.0, 4.5, 0.5]
+
+
+_STEP_OPS = [
+    ("%while.7 = (s32[]{:T(128)}, f32[8,8]{1,0}) while(...)", 0.0, 10.0),
+    ("%fusion.1 = f32[8,8]{1,0} fusion(...)", 1.0, 3.0),
+    ("%attn_flash_fwd.2 = bf16[8,8]{1,0} custom-call(...)", 3.0, 4.0),
+    ("%fusion.3 = f32[8,8]{1,0} fusion(...)", 6.0, 9.5),
+    ("%fusion.4 = f32[8]{0} fusion(...)", 12.0, 13.0),
+    ("%copy.5 = f32[8]{0} copy(...)", 13.0, 13.5)]
+_STEP_TABLE = {"jit_step": {
+    "while.7": ["-", "fwd"], "fusion.1": ["mla.q_proj", "fwd"],
+    "attn_flash_fwd.2": ["attn.flash_fwd/attn_flash_fwd", "fwd"],
+    "fusion.3": ["mla.q_proj", "bwd"], "fusion.4": ["-", "outside"]}}
+
+
+def _reduced_step(scopes):
+    devices = {0: {"modules": [("jit_step(9)", 0.0, 14.0)],
+                   "ops": list(_STEP_OPS)}}
+    return trace_reduce.reduce_planes(devices, [], window=(0.0, 14.0),
+                                      scopes=scopes)
+
+
+def test_device_time_by_scope_and_the_breakdown_without_a_while_row():
+    r = _reduced_step(_STEP_TABLE)
+    assert r["op_seconds"]["jit_step:while.7_s32[]"] == 10.0     # as it was
+    assert r["op_self_seconds"]["jit_step:while.7_s32[]"] == 3.5
+    assert sum(r["op_self_seconds"].values()) == r["busy_s"] == 11.5
+    assert r["scope_seconds"] == {"jit_step": {
+        "-": {"fwd": 3.5, "outside": 1.0, "none": 0.5},
+        "mla.q_proj": {"fwd": 2.0, "bwd": 3.5},
+        "attn.flash_fwd/attn_flash_fwd": {"fwd": 1.0}}}
+    assert sum(s for by_phase in r["scope_seconds"]["jit_step"].values()
+               for s in by_phase.values()) == r["busy_s"]
+    assert r["breakdown"]["device_ops"] == [
+        ["jit_step:mla.q_proj", 5.5], ["jit_step:-/while.7_s32[]", 3.5],
+        ["jit_step:attn.flash_fwd/attn_flash_fwd", 1.0],
+        ["jit_step:-/fusion.4_f32[8]", 1.0],
+        ["jit_step:-/copy.5_f32[8]", 0.5]]
+    # on the chip a loop's own time is microseconds: no row of the ten
+    quick = [(n, s, e) if "while" not in n else (n, 1.0, 9.5)
+             for n, s, e in _STEP_OPS]
+    q = trace_reduce.reduce_planes(
+        {0: {"modules": [("jit_step(9)", 0.0, 14.0)], "ops": quick}}, [],
+        window=(0.0, 14.0), scopes=_STEP_TABLE)
+    assert q["op_self_seconds"]["jit_step:while.7_s32[]"] == 2.0
+    # without a table: what it returned before, and the new self times
+    plain = _reduced_step(None)
+    assert "scope_seconds" not in plain
+    assert plain["breakdown"]["device_ops"][0] == [
+        "jit_step:while.7_s32[]", 10.0]
+    assert {k: plain[k] for k in ("op_seconds", "busy_s", "module_seconds",
+                                  "module_gaps_s")} == {
+        k: r[k] for k in ("op_seconds", "busy_s", "module_seconds",
+                          "module_gaps_s")}
+
+
+def test_a_program_without_a_table_keeps_its_ops_in_the_breakdown():
+    """The collective's one op has no scope and needs none: a table of
+    another program, or none, leaves its row as it was."""
+    devices = {i: {"modules": [("jit_all_reduce(3)", 0.0, 5.0)],
+                   "ops": [("%psum.7 = f32[536870912]{0} all-reduce(...)",
+                            0.0, 5.0)]} for i in range(4)}
+    for scopes in (None, _STEP_TABLE):
+        r = trace_reduce.reduce_planes(devices, [], window=(0.0, 5.2),
+                                       scopes=scopes)
+        assert r["breakdown"]["device_ops"] == [
+            ["jit_all_reduce:psum.7_f32[536870912]", 20.0]]
+        assert r["op_self_seconds"] == r["op_seconds"]
+        assert r["busy_s"] == 5.0
+
+
+def test_scope_share_of_step_on_a_hand_made_trace():
+    from readers import train
+    run = {"trace": _reduced_step(_STEP_TABLE)}
+    share = lambda *a, **k: train.scope_share_of_step(  # noqa: E731
+        run, *a, program="jit_step", **k)
+    assert share(["mla."]) == pytest.approx(100 * 5.5 / 14.0)
+    assert share(["mla.q_proj"], phases=["bwd"]) == pytest.approx(25.0)
+    assert share(["attn.flash", "mla.kv_up"]) == pytest.approx(100 / 14.0)
+    assert share(["attn_flash_fwd"]) == pytest.approx(100 / 14.0)  # 2nd part
+    assert share(["-"]) == pytest.approx(100 * 5.0 / 14.0)
+    assert share(["opt.update", "-"], phases=["outside"]) == \
+        pytest.approx(100 / 14.0)
+    # nothing to read: no such scope, no table, no trace, another program
+    assert share(["gdn."]) is None
+    assert train.scope_share_of_step({"trace": _reduced_step(None)}, ["-"],
+                                     "jit_step") is None
+    assert train.scope_share_of_step({"trace": None}, ["-"],
+                                     "jit_step") is None
+    assert train.scope_share_of_step(run, ["-"], "jit_other") is None
 
 
 def test_trace_reduce_names_gaps_by_host_span():
@@ -214,6 +423,16 @@ def test_dry_run_prints_the_last_line(cell, trace):
     assert out["device"]["platform"] == "cpu" and out["metrics"] == {}
     assert out["checks"] and all(
         c["name"] in err for c in out["checks"])       # stderr's last lines
+    if cell in TRAIN_SCOPES:
+        # A traced run hands the compiled step's scopes to the reduction,
+        # with or without a chip; on a CPU there is no device trace, so no
+        # share of the step is among what the run would report.
+        assert ("scopes" in out["dry_run"]) == (trace == "1")
+        if trace == "1":
+            assert TRAIN_SCOPES[cell] <= set(out["dry_run"]["scopes"])
+            by_name = {m["name"]: m for m in MANIFEST["per_layer"]}
+            assert all(by_name[n]["source"] == "program_counter"
+                       for n in out["dry_run"]["would_report"])
 
 
 @pytest.mark.parametrize("cell", [TRAIN_MIX, TRAIN_CELL])

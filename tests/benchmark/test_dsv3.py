@@ -69,16 +69,17 @@ def test_the_config_keeps_the_catalog_s_keys():
 
 
 def test_the_cell_reports_tokens_per_s_and_seven_layer_metrics():
+    """The seven it came with, among whatever later PRs appended (that
+    each entry resolves to a file and a reader: ``test_benchmark.py``)."""
     e2e = {m["name"] for m in bench_run.metrics_of(MANIFEST, "end_to_end",
                                                    CELL)}
     assert e2e == {"tokens_per_s", "setup_s"}
-    names = [m["name"] for m in bench_run.metrics_of(MANIFEST, "per_layer",
-                                                     CELL, e2e)]
-    assert sorted(names) == sorted(
-        n + ".seq8k" for n in ("step_mfu", "device_idle_share",
-                               "step_gap_ms_p50", "mla_attn_roofline",
-                               "moe_gmm_roofline", "moe_step_share",
-                               "expert_load_max_over_mean"))
+    metrics = bench_run.metrics_of(MANIFEST, "per_layer", CELL, e2e)
+    assert {n + ".seq8k" for n in (
+        "step_mfu", "device_idle_share", "step_gap_ms_p50",
+        "mla_attn_roofline", "moe_gmm_roofline", "moe_step_share",
+        "expert_load_max_over_mean")} <= {m["name"] for m in metrics}
+    assert all(m["workloads"] == [CELL] for m in metrics)
 
 
 # -- work, against hand counts ---------------------------------------------------
@@ -135,9 +136,22 @@ _TRACE = {
         "jit_step:moe_gmm_fwd.4_bf16[53248,768]": 0.010,
         "jit_step:moe_gmm_dlhs.5_bf16[53248,2048]": 0.006,
         "jit_step:moe_gmm_drhs.6_bf16[16,2048,768]": 0.004,
+        "jit_step:moe_rows_gather.7_bf16[53248,16,128]": 0.004,
+        "jit_step:moe_rows_combine.8_bf16[8192,2048]": 0.002,
         "jit_step:while.3_s32__": 0.7,
         "jit_other:moe_gmm_fwd.4_bf16[8,8]": 0.002,
-    }}
+    },
+    # self seconds by the program's scope and phase (trace_reduce, given the
+    # compiled step's text)
+    "scope_seconds": {"jit_step": {
+        "mla.q_proj": {"fwd": 0.02, "remat": 0.02, "bwd": 0.04},
+        "mla.kv_up": {"fwd": 0.01, "bwd": 0.02},
+        "mla.out_proj": {"bwd": 0.01},
+        "attn.flash_fwd/attn_flash_fwd": {"fwd": 0.10},
+        "moe.experts/moe_gmm_fwd": {"fwd": 0.005, "remat": 0.005},
+        "moe.router": {"fwd": 0.01, "bwd": 0.02},
+        "moe.sort": {"fwd": 0.02, "outside": 0.001},
+        "-": {"outside": 0.06, "fwd": 0.03, "bwd": 0.05, "none": 0.004}}}}
 
 
 def test_readers_on_a_hand_made_trace():
@@ -153,6 +167,18 @@ def test_readers_on_a_hand_made_trace():
         pytest.approx(100 * least / 0.022)
     assert train.op_share_of_step(run, "moe_gmm", "jit_step") == \
         pytest.approx(100 * 0.022 / 0.8)
+    assert train.op_share_of_step(run, "moe_rows", "jit_step") == \
+        pytest.approx(100 * 0.006 / 0.8)
+    assert train.scope_share_of_step(
+        run, ["mla.q_proj", "mla.kv_down", "mla.kv_up", "mla.out_proj"],
+        "jit_step") == pytest.approx(100 * 0.12 / 0.8)
+    assert train.scope_share_of_step(run, ["moe."], "jit_step") == \
+        pytest.approx(100 * 0.061 / 0.8)
+    assert train.scope_share_of_step(
+        run, ["opt.update", "-"], "jit_step", phases=["outside"]) == \
+        pytest.approx(100 * 0.06 / 0.8)
+    assert train.scope_share_of_step(run, ["-"], "jit_step") == \
+        pytest.approx(100 * 0.144 / 0.8)
     assert train.stats_median(run, "expert_load_max_over_mean") == 1.2
     assert device.step_mfu(run) == pytest.approx(
         100 * 22.9e12 * 2 / 1.0 / 197e12)
@@ -175,6 +201,7 @@ def test_readers_return_none_where_there_is_nothing_to_read():
     assert train.kernel_roofline(other, "moe_gmm", "routed_experts") is None
     assert train.kernel_roofline(other, "attn_flash", "mla_attention") is None
     assert train.op_share_of_step(other, "moe_gmm", "jit_step") is None
+    assert train.scope_share_of_step(other, ["gdn."], "jit_step") is None
     assert train.stats_median(other, "expert_load_max_over_mean") is None
     # the kernels' ops but no sizes to count their work from
     assert train.kernel_roofline(_run(_TRACE, {"calls_in_trace": 2}),

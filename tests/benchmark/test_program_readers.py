@@ -1,8 +1,8 @@
 """The readers of the program's own span trees (benchmark/readers/
 program.py): hand-worked values on a hand-built span list, and a traced
-dry run of each candidate cell through ``run_spans.py`` reports every
-metric that reads them (their entries wait in ``span_metrics.json``: a PR
-that changes the program may not edit ``candidates.json``)."""
+dry run of each candidate cell reports every metric that reads them (their
+entries are ``candidates.json``'s since PR 36; PR 26, which changed the
+program, had to keep them in a side manifest)."""
 
 import json
 import os
@@ -22,8 +22,17 @@ from brpc_tpu.obs import rpcz  # noqa: E402
 
 with open(os.path.join(BENCH, "candidates.json")) as _f:
     CANDIDATES = json.load(_f)
-with open(os.path.join(BENCH, "span_metrics.json")) as _f:
-    NEW = json.load(_f)["per_layer"]
+
+
+def _reader(metric):
+    with open(os.path.join(BENCH, "layer_metrics",
+                           metric["name"] + ".json")) as f:
+        return json.load(f)["reader"]
+
+
+# the metrics that read the program's own span trees
+NEW = [m for m in CANDIDATES["per_layer"]
+       if _reader(m).startswith("program.")]
 MS = 1_000_000
 
 
@@ -149,9 +158,9 @@ def test_the_sixteen_and_no_others():
     # as candidates.json's own entries are held to: each moves what its
     # cells report, and its file names a reader and agrees with the entry
     e2e = {m["name"]: m for m in CANDIDATES["end_to_end"]}
-    taken = {m["name"] for m in CANDIDATES["per_layer"]}
+    names = [m["name"] for m in CANDIDATES["per_layer"]]
+    assert len(names) == len(set(names))
     for m in NEW:
-        assert m["name"] not in taken
         assert set(m["workloads"]) <= set(e2e[m["moves"]]["workloads"])
         with open(os.path.join(BENCH, "layer_metrics",
                                m["name"] + ".json")) as f:
@@ -165,7 +174,7 @@ def test_the_sixteen_and_no_others():
 @pytest.mark.parametrize("cell", [w["name"] for w in CANDIDATES["workloads"]])
 def test_a_traced_dry_run_would_report_every_new_metric(cell):
     proc = subprocess.run(
-        [sys.executable, os.path.join(BENCH, "run_spans.py"), "--workload", cell,
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload", cell,
          "--seed", str(2 ** 31 + 11), "--seconds", "1.5", "--cpu-dry-run",
          "--trace", "1"], capture_output=True, text=True, timeout=300)
     assert proc.returncode == 0, proc.stderr[-2000:]

@@ -96,8 +96,8 @@ def test_the_cell_reports_tokens_per_s_and_nine_layer_metrics():
                                                    CELL)}
     assert e2e == {"tokens_per_s", "setup_s"}
     metrics = bench_run.metrics_of(MANIFEST, "per_layer", CELL, e2e)
-    assert sorted(m["name"] for m in metrics) == sorted(
-        n + ".lin3full1_8k" for n in NINE)
+    # the nine it came with, among whatever later PRs appended
+    assert {n + ".lin3full1_8k" for n in NINE} <= {m["name"] for m in metrics}
     for m in metrics:
         assert m["moves"] == "tokens_per_s" and m["workloads"] == [CELL]
         with open(os.path.join(BENCH, "layer_metrics",
@@ -178,7 +178,15 @@ _TRACE = {
         "jit_step:moe_rows_combine.9_bf16[8192,2048]": 0.006,
         "jit_step:while.3_s32__": 0.5,
         "jit_other:gdn_chunk_fwd.1_bf16[8,8]": 0.002,
-    }}
+    },
+    # self seconds by the program's scope and phase (trace_reduce, given the
+    # compiled step's text)
+    "scope_seconds": {"jit_step": {
+        "gdn.conv": {"fwd": 0.02, "remat": 0.02, "bwd": 0.04},
+        "gdn.rule/gdn.chunk_fwd/gdn_chunk_fwd": {"fwd": 0.05},
+        "moe.experts/moe_gmm_fwd": {"fwd": 0.01},
+        "moe.router": {"fwd": 0.02, "remat": 0.01, "bwd": 0.01},
+        "-": {"outside": 0.05, "fwd": 0.02, "bwd": 0.04, "none": 0.01}}}}
 
 
 def test_readers_on_a_hand_made_trace():
@@ -202,6 +210,15 @@ def test_readers_on_a_hand_made_trace():
         pytest.approx(100 * 0.122 / 0.6)
     assert train.op_share_of_step(run, "moe_rows", "jit_step") == \
         pytest.approx(100 * 0.018 / 0.6)
+    assert train.scope_share_of_step(run, ["gdn.conv"], "jit_step") == \
+        pytest.approx(100 * 0.08 / 0.6)
+    assert train.scope_share_of_step(run, ["moe."], "jit_step") == \
+        pytest.approx(100 * 0.05 / 0.6)
+    assert train.scope_share_of_step(
+        run, ["opt.update", "-"], "jit_step", phases=["outside"]) == \
+        pytest.approx(100 * 0.05 / 0.6)
+    assert train.scope_share_of_step(run, ["-"], "jit_step") == \
+        pytest.approx(100 * 0.12 / 0.6)
     assert device.step_mfu(run) == pytest.approx(
         100 * 11.3e12 * 2 / 1.0 / 197e12)
     for metric in MANIFEST["per_layer"]:
@@ -225,6 +242,7 @@ def test_readers_return_none_where_there_is_nothing_to_read():
     assert hybrid.kernel_roofline(other, "attn_flash", "gqa_attention",
                                   "full") is None
     assert train.op_share_of_step(other, "gdn_", "jit_step") is None
+    assert train.scope_share_of_step(other, ["mla."], "jit_step") is None
     for counters in ({"calls_in_trace": 2}, dict(_COUNTERS, calls_in_trace=0)):
         assert hybrid.kernel_roofline(_run(_TRACE, counters), "gdn_",
                                       "gated_delta_rule", "linear") is None
@@ -252,6 +270,13 @@ def test_dry_run_is_correct_and_names_what_it_would_report(trace):
     if trace == "1":      # no trace on a CPU: the program counter alone
         assert out["dry_run"]["would_report"] == [
             "expert_load_max_over_mean.lin3full1_8k"]
+        # the table of scopes is built from the CPU-compiled step
+        assert {"gdn.in_proj", "gdn.conv", "gdn.rule", "gdn.out",
+                "gattn.qkv", "gattn.out", "moe.router", "moe.sort",
+                "moe.experts", "moe.combine", "moe.shared",
+                "-"} <= set(out["dry_run"]["scopes"])
+    else:
+        assert "scopes" not in out["dry_run"]
 
 
 @pytest.mark.parametrize("control", CONTROLS)
