@@ -50,9 +50,13 @@ def chunked_next_token_loss(states, head, tokens, position_loss, extras=()):
         xs_c, target_c, counts_c, extras_c = args
         nlls = []
         for x_c in xs_c:
-            logits = jnp.dot(x_c, head, preferred_element_type=jnp.float32)
-            gold = jnp.take_along_axis(logits, target_c[:, None], axis=1)[:, 0]
-            nlls.append(jax.nn.logsumexp(logits, axis=-1) - gold)
+            with jax.named_scope("loss.logits"):
+                logits = jnp.dot(x_c, head,
+                                 preferred_element_type=jnp.float32)
+            with jax.named_scope("loss.nll"):
+                gold = jnp.take_along_axis(logits, target_c[:, None],
+                                           axis=1)[:, 0]
+                nlls.append(jax.nn.logsumexp(logits, axis=-1) - gold)
         values = position_loss(tuple(nlls), *extras_c)
         return jax.tree_util.tree_map(
             lambda a, v: counted_sum(a, v, counts_c), total, values), None

@@ -81,6 +81,7 @@ from jax.ad_checkpoint import checkpoint_name
 from brpc_tpu.models.chunked_loss import chunked_next_token_loss
 from brpc_tpu.models.experts import expert_mlp, swiglu as _swiglu
 from brpc_tpu.models.llama import _dense_init, attention, rms_norm
+from brpc_tpu.models.train_step import apply_updates
 from brpc_tpu.ops import grouped_matmul as gm
 from brpc_tpu.ops.flash_attention import RESIDUAL_NAMES
 
@@ -205,7 +206,8 @@ def mla(cfg: DeepseekConfig, x: jax.Array, lp: Params,
     """The attention block with its residual. x: [B, T, H]."""
     b, t, _ = x.shape
     nh, nope, rank = cfg.n_heads, cfg.qk_nope_dim, cfg.kv_lora_rank
-    y = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    with jax.named_scope("mla.norm"):
+        y = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     with jax.named_scope("mla.q_proj"):
         q = (y @ lp["wq"]).reshape(b, t, nh, nope + cfg.qk_rope_dim)
     with jax.named_scope("mla.kv_down"):
@@ -214,12 +216,15 @@ def mla(cfg: DeepseekConfig, x: jax.Array, lp: Params,
         k_rope = ckv[..., None, rank:]                 # one head for all
     with jax.named_scope("mla.kv_up"):
         kv = (latent @ lp["wkv_b"]).reshape(b, t, nh, nope + cfg.v_dim)
-    q_rope = rope_interleaved(q[..., nope:], positions, cfg.rope_theta)
-    k_rope = rope_interleaved(k_rope, positions, cfg.rope_theta)
-    q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
-    k = jnp.concatenate(
-        [kv[..., :nope], jnp.broadcast_to(k_rope, q_rope.shape)], axis=-1)
-    o = attention(q, k, kv[..., nope:])
+    with jax.named_scope("mla.rope"):
+        q_rope = rope_interleaved(q[..., nope:], positions, cfg.rope_theta)
+        k_rope = rope_interleaved(k_rope, positions, cfg.rope_theta)
+        q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rope, q_rope.shape)],
+            axis=-1)
+        v = kv[..., nope:]
+    o = attention(q, k, v)
     with jax.named_scope("mla.out_proj"):
         return x + o @ lp["wo"]
 
@@ -252,8 +257,9 @@ def moe_mlp(cfg: DeepseekConfig, y: jax.Array, lp: Params):
 
 
 def _cast(lp: Params, dtype) -> Params:
-    return {k: v if k in _FLOAT32_LEAVES else v.astype(dtype)
-            for k, v in lp.items()}
+    with jax.named_scope("weights.cast"):
+        return {k: v if k in _FLOAT32_LEAVES else v.astype(dtype)
+                for k, v in lp.items()}
 
 
 def hidden_states(params: Params, tokens: jax.Array, cfg: DeepseekConfig):
@@ -261,9 +267,10 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: DeepseekConfig):
     stats). Master weights stay float32; each layer's compute-dtype copy is
     made inside its scan step, and each step is recomputed in the backward
     pass from its input and what ``SAVED_NAMES`` names."""
-    x = params["embed"][tokens].astype(cfg.dtype)
-    b, t, h = x.shape
-    positions = jnp.broadcast_to(jnp.arange(t), tokens.shape)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.dtype)
+        b, t, h = x.shape
+        positions = jnp.broadcast_to(jnp.arange(t), tokens.shape)
     recomputed = functools.partial(
         jax.checkpoint,
         policy=jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES))
@@ -272,20 +279,25 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: DeepseekConfig):
     def dense_layer(x, lp):
         lp = _cast(lp, cfg.dtype)
         x = mla(cfg, x, lp, positions)
-        y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        return x + _swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"]), None
+        with jax.named_scope("dsv3.dense_mlp"):
+            y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            return x + _swiglu(y, lp["w_gate"], lp["w_up"],
+                               lp["w_down"]), None
 
     @recomputed
     def moe_layer(x, lp):
         lp = _cast(lp, cfg.dtype)
         x = mla(cfg, x, lp, positions)
-        y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps).reshape(b * t, h)
+        with jax.named_scope("dsv3.glue"):
+            y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps).reshape(b * t, h)
         out, stats = moe_mlp(cfg, y, lp)
-        return x + out.reshape(b, t, h), stats
+        with jax.named_scope("dsv3.glue"):
+            return x + out.reshape(b, t, h), stats
 
     x, _ = lax.scan(dense_layer, x, params["dense"])
     x, stats = lax.scan(moe_layer, x, params["moe"])
-    x = rms_norm(x, params["final_norm"].astype(cfg.dtype), cfg.norm_eps)
+    with jax.named_scope("dsv3.glue"):
+        x = rms_norm(x, params["final_norm"].astype(cfg.dtype), cfg.norm_eps)
     return x, stats
 
 
@@ -302,9 +314,10 @@ def loss_fn(params: Params, tokens: jax.Array, cfg: DeepseekConfig):
     the forward pass's stats. The head is taken a chunk of positions at a
     time, each chunk's float32 logits recomputed in the backward pass."""
     x, stats = hidden_states(params, tokens, cfg)
-    head = params["lm_head"].astype(cfg.dtype)
-    return chunked_next_token_loss((x,), head, tokens,
-                                   lambda nlls: nlls[0]), stats
+    with jax.named_scope("loss.chunk"):
+        head = params["lm_head"].astype(cfg.dtype)
+        return chunked_next_token_loss((x,), head, tokens,
+                                       lambda nlls: nlls[0]), stats
 
 
 def make_train_step(cfg: DeepseekConfig, optimizer):
@@ -315,11 +328,9 @@ def make_train_step(cfg: DeepseekConfig, optimizer):
     def step(params, opt_state, tokens):
         (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, tokens, cfg)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        updates = {**updates, "moe": {
-            **updates["moe"],
-            "router_bias": jnp.zeros_like(updates["moe"]["router_bias"])}}
-        params = jax.tree_util.tree_map(lambda p, u: p + u, params, updates)
+        params, opt_state = apply_updates(
+            optimizer, grads, opt_state, params,
+            frozen=(("moe", "router_bias"),))
         return params, opt_state, loss, stats
 
     return step

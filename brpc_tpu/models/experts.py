@@ -54,13 +54,15 @@ def expert_mlp(y, selected, weights, w_gate, w_up, w_down, *, n_held: int,
         routed = gm.combine(rows, weights, lay)
     with jax.named_scope("moe.shared"):
         alike = shared(y)
-    n_routed = jnp.sum(lay.held.astype(jnp.int32))
-    stats = {
-        "routed": n_routed,
-        "dropped": n_routed - jnp.sum(lay.row_valid.astype(jnp.int32)),
-        "group_max": jnp.max(lay.group_sizes),
-        "group_mean": jnp.mean(lay.group_sizes.astype(jnp.float32)),
-        "rows_in_use": lay.n_tiles[0] * tile,
-        "selected": selected,
-    }
-    return routed + alike, stats
+    with jax.named_scope("moe.sort"):               # the layout's counts
+        n_routed = jnp.sum(lay.held.astype(jnp.int32))
+        stats = {
+            "routed": n_routed,
+            "dropped": n_routed - jnp.sum(lay.row_valid.astype(jnp.int32)),
+            "group_max": jnp.max(lay.group_sizes),
+            "group_mean": jnp.mean(lay.group_sizes.astype(jnp.float32)),
+            "rows_in_use": lay.n_tiles[0] * tile,
+            "selected": selected,
+        }
+    with jax.named_scope("moe.shared"):
+        return routed + alike, stats

@@ -70,7 +70,9 @@ layers' order, of what ``experts.expert_mlp`` counts (``routed``,
 ``dropped``, ``group_max``, ``group_mean``, ``rows_in_use``, ``selected``).
 Named scopes: ``gdn.in_proj``, ``gdn.conv``, ``gdn.rule``, ``gdn.out``,
 ``gattn.qkv``, ``gattn.out``, ``moe.router``, ``moe.sort``, ``moe.experts``,
-``moe.combine``, ``moe.shared``.
+``moe.combine``, ``moe.shared``; ``hybrid.glue`` around the norms, residuals
+and reshapes between them, ``embed``, ``weights.cast``, ``loss.chunk`` and
+``opt.update`` as in every model.
 """
 
 from __future__ import annotations
@@ -87,6 +89,7 @@ from jax.ad_checkpoint import checkpoint_name
 from brpc_tpu.models.chunked_loss import chunked_next_token_loss
 from brpc_tpu.models.experts import expert_mlp, swiglu
 from brpc_tpu.models.llama import _dense_init, attention, rope
+from brpc_tpu.models.train_step import apply_updates
 from brpc_tpu.ops import gated_delta
 from brpc_tpu.ops import grouped_matmul as gm
 from brpc_tpu.ops.flash_attention import RESIDUAL_NAMES
@@ -239,7 +242,8 @@ def gated_delta_net(cfg: HybridConfig, x: jax.Array, lp: Params) -> jax.Array:
     hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
     dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
     key_dim, value_dim = hk * dk, hv * dv
-    y = norm(x, lp["mixer_norm"], cfg.norm_eps)
+    with jax.named_scope("hybrid.glue"):
+        y = norm(x, lp["mixer_norm"], cfg.norm_eps)
     with jax.named_scope("gdn.in_proj"):
         qkvz = y @ lp["w_qkvz"]
         ba = jnp.dot(y, lp["w_ba"], preferred_element_type=jnp.float32)
@@ -277,7 +281,8 @@ def gated_attention(cfg: HybridConfig, x: jax.Array, lp: Params,
     b, t, _ = x.shape
     nh, nkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     rot = int(d * cfg.partial_rotary_factor)
-    y = norm(x, lp["mixer_norm"], cfg.norm_eps)
+    with jax.named_scope("hybrid.glue"):
+        y = norm(x, lp["mixer_norm"], cfg.norm_eps)
     with jax.named_scope("gattn.qkv"):
         q_gate = (y @ lp["wq"]).reshape(b, t, nh, 2 * d)
         q = norm(q_gate[..., :d], lp["q_norm"], cfg.norm_eps)
@@ -331,17 +336,19 @@ def moe_mlp(cfg: HybridConfig, y: jax.Array, lp: Params):
 
 
 def _cast(lp: Params, dtype) -> Params:
-    return {k: v if k in _FLOAT32_LEAVES else v.astype(dtype)
-            for k, v in lp.items()}
+    with jax.named_scope("weights.cast"):
+        return {k: v if k in _FLOAT32_LEAVES else v.astype(dtype)
+                for k, v in lp.items()}
 
 
 def hidden_states(params: Params, tokens: jax.Array, cfg: HybridConfig):
     """tokens: [B, T] -> (final-normed states [B, T, H], the expert layers'
     stats, a row a layer). Master weights stay float32; a layer's compute-dtype
     copy is made inside its scan step."""
-    x = params["embed"][tokens].astype(cfg.dtype)
-    b, t, h = x.shape
-    positions = jnp.broadcast_to(jnp.arange(t), tokens.shape)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.dtype)
+        b, t, h = x.shape
+        positions = jnp.broadcast_to(jnp.arange(t), tokens.shape)
 
     def layer(mixer):
         @functools.partial(
@@ -351,9 +358,11 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: HybridConfig):
         def run(x, lp):
             lp = _cast(lp, cfg.dtype)
             x = mixer(x, lp)
-            y = norm(x, lp["mlp_norm"], cfg.norm_eps).reshape(b * t, h)
+            with jax.named_scope("hybrid.glue"):
+                y = norm(x, lp["mlp_norm"], cfg.norm_eps).reshape(b * t, h)
             out, stats = moe_mlp(cfg, y, lp)
-            return x + out.reshape(b, t, h), stats
+            with jax.named_scope("hybrid.glue"):
+                return x + out.reshape(b, t, h), stats
         return run
 
     linear = layer(lambda x, lp: gated_delta_net(cfg, x, lp))
@@ -366,12 +375,13 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: HybridConfig):
 
     x, stats = lax.scan(period, x, {"linear": params["linear"],
                                     "full": params["full"]})
-    # [periods, interval - 1, ...] and [periods, ...] -> [layers, ...]
-    stats = jax.tree_util.tree_map(
-        lambda lin, full: jnp.concatenate([lin, full[:, None]], axis=1
-                                          ).reshape(-1, *full.shape[1:]),
-        stats["linear"], stats["full"])
-    return norm(x, params["final_norm"], cfg.norm_eps), stats
+    with jax.named_scope("hybrid.glue"):
+        # [periods, interval - 1, ...] and [periods, ...] -> [layers, ...]
+        stats = jax.tree_util.tree_map(
+            lambda lin, full: jnp.concatenate(
+                [lin, full[:, None]], axis=1).reshape(-1, *full.shape[1:]),
+            stats["linear"], stats["full"])
+        return norm(x, params["final_norm"], cfg.norm_eps), stats
 
 
 def forward(params: Params, tokens: jax.Array, cfg: HybridConfig):
@@ -386,9 +396,10 @@ def loss_fn(params: Params, tokens: jax.Array, cfg: HybridConfig):
     """Next-token cross-entropy (the last position predicts nothing), and
     the forward pass's stats; the head a chunk of positions at a time."""
     x, stats = hidden_states(params, tokens, cfg)
-    head = params["lm_head"].astype(cfg.dtype)
-    return chunked_next_token_loss((x,), head, tokens,
-                                   lambda nlls: nlls[0]), stats
+    with jax.named_scope("loss.chunk"):
+        head = params["lm_head"].astype(cfg.dtype)
+        return chunked_next_token_loss((x,), head, tokens,
+                                       lambda nlls: nlls[0]), stats
 
 
 def make_train_step(cfg: HybridConfig, optimizer):
@@ -398,8 +409,7 @@ def make_train_step(cfg: HybridConfig, optimizer):
     def step(params, opt_state, tokens):
         (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, tokens, cfg)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = jax.tree_util.tree_map(lambda p, u: p + u, params, updates)
+        params, opt_state = apply_updates(optimizer, grads, opt_state, params)
         return params, opt_state, loss, stats
 
     return step

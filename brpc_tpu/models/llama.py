@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from brpc_tpu.models.train_step import apply_updates
 from brpc_tpu.ops.flash_attention import (flash_attention,
                                           supported as flash_supported)
 from brpc_tpu.ops.lowered import count_lowering
@@ -200,18 +201,22 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Params, positions: jax.Array,
            attn_fn=None) -> jax.Array:
     b, t, h = x.shape
     # attention block
-    y = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (y @ lp["wq"]).reshape(b, t, cfg.n_heads, cfg.head_dim)
-    k = (y @ lp["wk"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
-    v = (y @ lp["wv"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("llama.qkv"):
+        y = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q = (y @ lp["wq"]).reshape(b, t, cfg.n_heads, cfg.head_dim)
+        k = (y @ lp["wk"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+        v = (y @ lp["wv"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
     attend = attn_fn if attn_fn is not None else attention
-    x = x + attend(q, k, v) @ lp["wo"]
+    o = attend(q, k, v)         # under its own scopes (attn.*), not these
+    with jax.named_scope("llama.attn_out"):
+        x = x + o @ lp["wo"]
     # mlp block
-    y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    x = x + (jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"])) @ lp["w_down"]
-    return x
+    with jax.named_scope("llama.mlp"):
+        y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+        return x + (jax.nn.silu(y @ lp["w_gate"])
+                    * (y @ lp["w_up"])) @ lp["w_down"]
 
 
 def forward(params: Params, tokens: jax.Array, cfg: LlamaConfig,
@@ -222,30 +227,35 @@ def forward(params: Params, tokens: jax.Array, cfg: LlamaConfig,
     (bf16) — the cast happens per-layer inside the scan so only one layer's
     bf16 copy is live at a time.
     """
-    cast = lambda t: jax.tree_util.tree_map(  # noqa: E731
-        lambda a: a.astype(cfg.dtype), t
-    )
     # Gather rows first, THEN cast: avoids materializing a full bf16 copy of
     # the [vocab, hidden] table (≈1GB at 128k vocab) just to read B*T rows.
-    x = params["embed"][tokens].astype(cfg.dtype)
-    positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.dtype)
+        positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]),
+                                     tokens.shape)
 
     def body(x, lp):
-        return _layer(cfg, x, cast(lp), positions, attn_fn), None
+        with jax.named_scope("weights.cast"):
+            lp = jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype), lp)
+        return _layer(cfg, x, lp, positions, attn_fn), None
 
     x, _ = lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"].astype(cfg.dtype), cfg.norm_eps)
-    return (x @ cast(params["lm_head"])).astype(jnp.float32)
+    with jax.named_scope("llama.head_loss"):
+        x = rms_norm(x, params["final_norm"].astype(cfg.dtype), cfg.norm_eps)
+        return (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
 
 
 def loss_fn(params: Params, tokens: jax.Array, cfg: LlamaConfig,
             attn_fn=None) -> jax.Array:
     """Next-token cross-entropy (last position predicts nothing)."""
-    logits = forward(params, tokens, cfg, attn_fn)[:, :-1]
-    targets = tokens[:, 1:]
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(logz - gold)
+    logits = forward(params, tokens, cfg, attn_fn)
+    with jax.named_scope("llama.head_loss"):
+        logits = logits[:, :-1]
+        targets = tokens[:, 1:]
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, targets[..., None],
+                                   axis=-1)[..., 0]
+        return jnp.mean(logz - gold)
 
 
 def make_train_step(cfg: LlamaConfig, optimizer, attn_fn=None):
@@ -259,9 +269,7 @@ def make_train_step(cfg: LlamaConfig, optimizer, attn_fn=None):
     def step(params, opt_state, tokens):
         loss, grads = jax.value_and_grad(loss_fn)(params, tokens, cfg,
                                                   attn_fn)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        # params/updates are fp32 master copies; no precision-losing casts.
-        params = jax.tree_util.tree_map(lambda p, u: p + u, params, updates)
+        params, opt_state = apply_updates(optimizer, grads, opt_state, params)
         return params, opt_state, loss
 
     return step

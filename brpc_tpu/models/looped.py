@@ -61,6 +61,7 @@ from jax import lax
 
 from brpc_tpu.models.chunked_loss import chunked_next_token_loss
 from brpc_tpu.models.llama import _dense_init, attention, rms_norm, rope
+from brpc_tpu.models.train_step import apply_updates
 from brpc_tpu.ops.flash_attention import RESIDUAL_NAMES
 
 Params = Dict[str, Any]
@@ -144,15 +145,19 @@ def hidden_states(params: Params, tokens: jax.Array,
     """tokens: [B, T] -> the final-normed states of every pass [R, B, T, H].
     Master weights stay float32; a layer's compute-dtype copy is made inside
     its scan step, so the gradient of the shared stack gathers in float32."""
-    x = params["embed"][tokens].astype(cfg.dtype)
-    positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
-    final_norm = params["final_norm"].astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.dtype)
+        positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]),
+                                     tokens.shape)
+    with jax.named_scope("weights.cast"):
+        final_norm = params["final_norm"].astype(cfg.dtype)
 
     @functools.partial(
         jax.checkpoint,
         policy=jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES))
     def layer(x, lp):
-        lp = jax.tree_util.tree_map(lambda w: w.astype(cfg.dtype), lp)
+        with jax.named_scope("weights.cast"):
+            lp = jax.tree_util.tree_map(lambda w: w.astype(cfg.dtype), lp)
         return _layer(cfg, x, lp, positions), None
 
     def one_pass(x, _):
@@ -219,8 +224,7 @@ def make_train_step(cfg: LoopedConfig, optimizer):
     def step(params, opt_state, tokens):
         (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, tokens, cfg)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = jax.tree_util.tree_map(lambda p, u: p + u, params, updates)
+        params, opt_state = apply_updates(optimizer, grads, opt_state, params)
         return params, opt_state, loss, stats
 
     return step

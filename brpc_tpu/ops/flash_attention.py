@@ -417,6 +417,8 @@ def flash_attention(
                    for backward in (False, True))
     if any(t % block for pair in blocks for block in pair):
         raise ValueError(f"seq {t} must divide blocks {blocks}")
-    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))  # [B, H, T, D]
+    with jax.named_scope("attn.layout"):                    # [B, H, T, D]
+        q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     out = _attend(q, k, v, causal, blocks, interpret)
-    return out.transpose(0, 2, 1, 3).reshape(b, t, hq * v.shape[3])
+    with jax.named_scope("attn.layout"):
+        return out.transpose(0, 2, 1, 3).reshape(b, t, hq * v.shape[3])

@@ -231,6 +231,22 @@ def test_train_step_takes_the_kernel_on_tpu(v5e_device, lowerings):
     assert lowerings() == (1, 0)
 
 
+def test_the_step_compiled_for_v5e_names_its_scopes(v5e_device):
+    """What the benchmark's table of device time reads: the instructions of
+    the compiled step carry the program's scopes in their ``op_name``, the
+    layout work around the kernels (``attn.layout``) among them."""
+    text = _abstract_step(
+        _ELIGIBLE, jax.sharding.SingleDeviceSharding(v5e_device)
+    ).lower().compile().as_text()
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("embed", "llama.qkv", "llama.attn_out", "llama.mlp",
+                  "llama.head_loss", "attn.layout", "attn.flash_fwd",
+                  "attn.flash_bwd", "opt.update"):
+        assert any(re.search(rf"[/(]{re.escape(scope)}[/)]", name)
+                   for name in op_names), scope
+    assert not any("attn.dense" in name for name in op_names)
+
+
 def test_train_step_stays_dense_on_cpu(lowerings):
     text = _abstract_step(_ELIGIBLE).lower(
         lowering_platforms=("cpu",)).as_text()
