@@ -16,7 +16,8 @@ and the per-head q/k norms.
 - **Gated DeltaNet layer** (``linear_key_heads`` key heads, twice as many
   value heads, all of 128). ``[q, k, v, z] = y·W_qkvz``, ``[b, a] = y·W_ba``;
   ``[q, k, v] <- silu(conv(concat(q, k, v)))``, a causal depthwise
-  convolution of ``conv_kernel`` taps over the channels, no bias;
+  convolution of ``conv_kernel`` taps over the channels, no bias
+  (``ops/causal_conv.py``: one Pallas kernel a pass on a TPU);
   ``beta = sigmoid(b)``; ``g = -exp(A_log) · softplus(a + dt_bias)`` in
   float32, one ``A_log`` and ``dt_bias`` a value head; q and k L2-normalised
   per head, each key head serving ``value/key`` value heads, q scaled by
@@ -65,6 +66,22 @@ left the compiled step 0.07 GiB of the chip (PERF.md section 4). bf16
 compute; float32 master weights, norms, router, decays, softmax and loss
 (``models/chunked_loss.py``).
 
+The short convolution and its silu are ``causal_conv.conv_silu`` on the whole
+``qkvz``: on a TPU, at bf16 and whole tiles, the kernels ``conv_silu_fwd`` and
+``conv_silu_bwd`` reach q | k | v — the taps' width — by block index, so no
+slice is copied and nothing is padded. A grid step holds a block of positions
+by 512 channels in VMEM and widens it to float32 there a run of rows at a
+time; the K - 1 rows of history (in the backward pass also the K - 1 rows
+ahead) are one 16-row bf16 tile from a second block spec on the same array,
+zeros at the sequence's ends. bf16 in, float32 taps, products, sums, silu and
+silu', bf16 out; ``dtaps`` a float32 sum that stays in VMEM across the
+positions. The result is not saved: the forward kernel runs again in a
+layer's recomputation (y is 134 MB a layer), the backward kernel recomputes
+the pre-activation from x. ``conv_lowerings`` counts the programs lowered
+with the kernels; their names keep clear of ``gdn_``, the prefix by which the
+benchmark takes an op for one of the rule's kernels. Elsewhere the plain
+form (``causal_conv.causal_conv`` and ``jax.nn.silu``) runs.
+
 ``make_train_step``'s step also returns ``stats``, a row a layer in the
 layers' order, of what ``experts.expert_mlp`` counts (``routed``,
 ``dropped``, ``group_max``, ``group_mean``, ``rows_in_use``, ``selected``).
@@ -92,6 +109,7 @@ from brpc_tpu.models.llama import _dense_init, attention, rope
 from brpc_tpu.models.train_step import apply_updates
 from brpc_tpu.ops import gated_delta
 from brpc_tpu.ops import grouped_matmul as gm
+from brpc_tpu.ops.causal_conv import conv_silu
 from brpc_tpu.ops.flash_attention import RESIDUAL_NAMES
 
 Params = Dict[str, Any]
@@ -226,16 +244,6 @@ def norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
             * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
 
 
-def causal_conv(x: jax.Array, taps: jax.Array) -> jax.Array:
-    """Depthwise causal convolution along T, in float32. x: [B, T, C];
-    taps: [K, C], the last tap on the current position: y_t = sum_j taps[j]
-    x_{t-K+1+j}."""
-    k, t = taps.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
-    return sum(padded[:, j:j + t] * taps[j].astype(jnp.float32)
-               for j in range(k))
-
-
 def gated_delta_net(cfg: HybridConfig, x: jax.Array, lp: Params) -> jax.Array:
     """The linear-attention block with its residual. x: [B, T, H]."""
     b, t, _ = x.shape
@@ -251,8 +259,9 @@ def gated_delta_net(cfg: HybridConfig, x: jax.Array, lp: Params) -> jax.Array:
         g = -jnp.exp(lp["a_log"].astype(jnp.float32)) * jax.nn.softplus(
             ba[..., hv:] + lp["dt_bias"].astype(jnp.float32))
     with jax.named_scope("gdn.conv"):
-        qkv = jax.nn.silu(causal_conv(qkvz[..., :2 * key_dim + value_dim],
-                                      lp["conv"])).astype(x.dtype)
+        # q | k | v are the taps' width of qkvz: the kernels reach them by
+        # block index, nothing is sliced
+        qkv = conv_silu(qkvz, lp["conv"])
     with jax.named_scope("gdn.rule"):
         def unit(a):                       # L2 norm per head, in float32
             a32 = a.astype(jnp.float32)

@@ -40,7 +40,7 @@ import reference_gdn  # noqa: E402
 
 from brpc_tpu import obs  # noqa: E402
 from brpc_tpu.models import deepseek, experts, hybrid  # noqa: E402
-from brpc_tpu.ops import gated_delta  # noqa: E402
+from brpc_tpu.ops import causal_conv, gated_delta  # noqa: E402
 from brpc_tpu.ops import grouped_matmul as gm  # noqa: E402
 
 SIZES = {
@@ -277,7 +277,7 @@ def test_weights_are_a_softmax_s_largest_renormalised(params):
 def test_convolution_is_causal_and_ends_on_the_current_position():
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 10, 3))
     taps = jnp.array([[0.0] * 3, [0.0] * 3, [0.5] * 3, [2.0] * 3])
-    y = hybrid.causal_conv(x, taps)
+    y = causal_conv.causal_conv(x, taps)
     shifted = jnp.pad(x, ((0, 0), (1, 0), (0, 0)))[:, :-1]
     np.testing.assert_allclose(np.asarray(y), np.asarray(2 * x + 0.5 * shifted),
                                rtol=1e-6)
@@ -361,23 +361,25 @@ def test_the_cell_counts_626_million_parameters():
 
 def test_the_cells_program_lowered_for_tpu_holds_every_kernel():
     """At the cell's shapes (4 layers, 1 x 8,192 tokens, 32 of 512 experts)
-    the program lowered for TPU holds the rule's, the attention's and the
-    expert layer's kernels, counts one lowering of each choice and no dense
-    attention. What XLA:TPU keeps of it is compiled in tests/test_ops.py."""
+    the program lowered for TPU holds the rule's, the convolution's, the
+    attention's and the expert layer's kernels, counts one lowering of each
+    choice and no dense attention. What XLA:TPU keeps of it is compiled in
+    tests/test_ops.py."""
     obs.set_enabled(True)
-    names = ("gdn_lowerings", "attn_kernel_lowerings", "attn_dense_lowerings")
+    names = ("gdn_lowerings", "conv_lowerings", "attn_kernel_lowerings",
+             "attn_dense_lowerings")
     before = [obs.counter(n).get_value() for n in names]
     grouped = obs.counter("moe_grouped_lowerings").get_value()
     text = _abstract_step(CELL, 1, 8192).lower(
         lowering_platforms=("tpu",)).as_text()
     assert [obs.counter(n).get_value() - b
-            for n, b in zip(names, before)] == [1, 1, 0]
+            for n, b in zip(names, before)] == [1, 1, 1, 0]
     assert obs.counter("moe_grouped_lowerings").get_value() > grouped
-    found = set(re.findall(r"(gdn_chunk_\w+|attn_flash_\w+|moe_gmm_\w+|"
-                           r"moe_rows_\w+)", text))
+    found = set(re.findall(r"(gdn_chunk_\w+|conv_silu_\w+|attn_flash_\w+|"
+                           r"moe_gmm_\w+|moe_rows_\w+)", text))
     assert {"gdn_chunk_prep", "gdn_chunk_fwd", "gdn_chunk_bwd",
-            "attn_flash_fwd", "attn_flash_bwd", "moe_gmm_fwd", "moe_gmm_dlhs",
-            "moe_gmm_drhs",
+            "conv_silu_fwd", "conv_silu_bwd", "attn_flash_fwd",
+            "attn_flash_bwd", "moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs",
             "moe_rows_gather", "moe_rows_combine", "moe_rows_pack"} <= found
 
 

@@ -841,7 +841,7 @@ def test_looped_step_takes_the_kernels_once_a_scan_on_tpu(v5e_device,
 # -- PR 34: a query group too large for VMEM, and the gated delta rule --------
 
 from brpc_tpu.models import hybrid  # noqa: E402
-from brpc_tpu.ops import gated_delta  # noqa: E402
+from brpc_tpu.ops import causal_conv, gated_delta  # noqa: E402
 
 
 @pytest.mark.parametrize("cell", ["mistral7b", "kanana2", "ouro"])
@@ -940,6 +940,32 @@ def test_gated_delta_kernels_compile_for_v5e_at_the_cells_size(v5e_device):
     assert re.findall(r"\w+\[[\d,]*8192,128,128\]", text) == []
 
 
+_CONV_CALLS = re.compile(r"%(conv_silu_\w+?)(?:\.\d+)? = [^\n]*custom-call\(")
+
+
+def test_conv_silu_kernels_compile_for_v5e_at_the_cells_size(v5e_device):
+    """1 x 8,192 positions of the layer's whole projection (12,288 wide, of
+    which q | k | v are the first 8,192), four float32 taps, bf16: Mosaic
+    and XLA:TPU take both kernels; what XLA keeps beside them is no float32
+    array of the channels' size and no copy of the convolved slice — the
+    kernels reach it by block index."""
+    sharding = jax.sharding.SingleDeviceSharding(v5e_device)
+    s = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=sharding)
+
+    @jax.jit
+    def both(x, taps, dy):
+        y, vjp = jax.vjp(causal_conv.conv_silu, x, taps)
+        return (y, *vjp(dy))
+
+    text = both.trace(s(1, 8192, 12288), s(4, 8192, dtype=jnp.float32),
+                      s(1, 8192, 8192)).lower().compile().as_text()
+    assert sorted(_CONV_CALLS.findall(text)) == [
+        "conv_silu_bwd", "conv_silu_fwd"]
+    assert re.findall(r"f32\[1,819\d,8192\]", text) == []
+    assert "bf16[1,8195,8192]" not in text
+
+
 # Kernel-eligible and small: one period, 1 key and 2 value heads of 128, 2
 # query heads over 1 KV head of 128, 8 experts of which 2 are held.
 _HYBRID = hybrid.HybridConfig(
@@ -954,24 +980,30 @@ def test_hybrid_step_takes_every_kernel_on_tpu(v5e_device, lowerings):
     twice: a layer's recomputation runs it again, its results are not
     saved; ``gdn_chunk_prep`` once, like the backward one: its T is saved
     by name and a recomputation that made it again would count two), the
+    convolution's (the forward one twice likewise: y is not saved), the
     attention kernels, the grouped products and the row kernels, and no
     array shaped like the scores."""
     obs.set_enabled(True)
-    gdn = obs.counter("gdn_lowerings")
-    before = gdn.get_value()
+    gdn, conv = obs.counter("gdn_lowerings"), obs.counter("conv_lowerings")
+    before, conv_before = gdn.get_value(), conv.get_value()
     text = _abstract_step(_HYBRID, jax.sharding.SingleDeviceSharding(
         v5e_device), hybrid).lower().compile().as_text()
     found = set(re.findall(
-        r"%((?:gdn_chunk|attn_flash|moe_gmm|moe_rows)_\w+?)(?:\.\d+)? =",
-        text))
+        r"%((?:gdn_chunk|conv_silu|attn_flash|moe_gmm|moe_rows)_\w+?)"
+        r"(?:\.\d+)? =", text))
     assert found == {"gdn_chunk_prep", "gdn_chunk_fwd", "gdn_chunk_bwd",
-                     "attn_flash_fwd", "attn_flash_bwd", "moe_gmm_fwd",
+                     "conv_silu_fwd", "conv_silu_bwd", "attn_flash_fwd",
+                     "attn_flash_bwd", "moe_gmm_fwd",
                      "moe_gmm_dlhs", "moe_gmm_drhs", "moe_rows_gather",
                      "moe_rows_pack", "moe_rows_combine"}
     calls = collections.Counter(_GDN_CALLS.findall(text))
     assert calls["gdn_chunk_bwd"] >= 1
     assert calls["gdn_chunk_prep"] == calls["gdn_chunk_bwd"]
     assert calls["gdn_chunk_fwd"] == 2 * calls["gdn_chunk_bwd"]
+    conv_calls = collections.Counter(_CONV_CALLS.findall(text))
+    assert conv_calls["conv_silu_bwd"] == calls["gdn_chunk_bwd"]
+    assert conv_calls["conv_silu_fwd"] == 2 * conv_calls["conv_silu_bwd"]
     assert re.findall(rf"\w+\[[\d,]*{_T},{_T}\]", text) == []
     assert lowerings() == (1, 0)
     assert gdn.get_value() - before == 1
+    assert conv.get_value() - conv_before == 1
