@@ -155,9 +155,10 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def dense_attention(q, k, v, *, causal: bool = True):
+def dense_attention(q, k, v, *, causal: bool = True, window=None):
     """Grouped-query attention with the scores materialised. q: [B,T,Hq,D],
-    k: [B,T,Hkv,D], v: [B,T,Hkv,Dv] (Dv = D but for latent attention)."""
+    k: [B,T,Hkv,D], v: [B,T,Hkv,Dv] (Dv = D but for latent attention).
+    ``window``: query i sees key j iff 0 <= i - j < window."""
     b, t, hq, d = q.shape
     hkv = k.shape[2]
     group = hq // hkv
@@ -166,33 +167,38 @@ def dense_attention(q, k, v, *, causal: bool = True):
     scores = scores * (d ** -0.5)
     if causal:
         mask = jnp.tril(jnp.ones((t, t), bool))
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((t, t), bool), -window)
         scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgts,bshd->bthgd", probs, v)
     return out.reshape(b, t, hq * v.shape[-1])
 
 
-def attention(q, k, v, *, causal: bool = True):
+def attention(q, k, v, *, causal: bool = True, window=None):
     """Grouped-query attention, by the implementation the operands and the
     platform being lowered for allow: the fused blockwise kernel
     (``ops.flash_attention``, no [T,T] array in HBM in either pass) for
     causal bf16 attention at shapes it supports when lowered for TPU, the
     dense form for everything else. One traced function serves every
     platform. ``attn_kernel_lowerings`` / ``attn_dense_lowerings`` count
-    which one each lowered program holds."""
+    which one each lowered program holds. ``window``: sliding-window
+    attention, the same mask in both forms (the band kernels)."""
 
     def dense(q, k, v):
         with jax.named_scope("attn.dense"):
             return dense_attention(
                 count_lowering(q, "attn_dense_lowerings"), k, v,
-                causal=causal)
+                causal=causal, window=window)
 
     def kernel(q, k, v):
         return flash_attention(
-            count_lowering(q, "attn_kernel_lowerings"), k, v, causal=causal)
+            count_lowering(q, "attn_kernel_lowerings"), k, v, causal=causal,
+            window=window)
 
     if not (causal and q.dtype == k.dtype == v.dtype == jnp.bfloat16
-            and flash_supported(q.shape, k.shape, q.dtype, v.shape)):
+            and flash_supported(q.shape, k.shape, q.dtype, v.shape,
+                                window)):
         return dense(q, k, v)
     return lax.platform_dependent(q, k, v, tpu=kernel, default=dense)
 

@@ -18,7 +18,7 @@ import optax
 import pytest
 from jax._src import core, source_info_util
 
-from brpc_tpu.models import deepseek, hybrid, llama, looped
+from brpc_tpu.models import deepseek, hybrid, llama, looped, windowed
 
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark"))
@@ -54,6 +54,11 @@ MODELS = {
                _SHARED | _MOE | _CHUNKED | {
                    "hybrid.glue", "gdn.in_proj", "gdn.conv", "gdn.rule",
                    "gdn.out", "gattn.qkv", "gattn.out", "attn.dense"}),
+    "windowed": (windowed, windowed.WindowedConfig.tiny(),
+                 _SHARED | _MOE | _CHUNKED | {
+                     "windowed.glue", "dense.mlp", "swa.qkv", "swa.rope",
+                     "swa.attn/attn.dense", "swa.out", "full.qkv",
+                     "full.rope", "full.attn/attn.dense", "full.out"}),
 }
 
 
