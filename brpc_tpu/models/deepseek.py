@@ -79,7 +79,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from brpc_tpu.models.chunked_loss import chunked_next_token_loss
-from brpc_tpu.models.experts import expert_mlp, swiglu as _swiglu
+from brpc_tpu.models.experts import chosen, expert_mlp, swiglu as _swiglu
 from brpc_tpu.models.llama import _dense_init, attention, rms_norm
 from brpc_tpu.models.train_step import apply_updates
 from brpc_tpu.ops import grouped_matmul as gm
@@ -237,7 +237,7 @@ def route(cfg: DeepseekConfig, y: jax.Array, router: jax.Array,
                                precision=lax.Precision.HIGHEST))
     _, selected = lax.top_k(s + bias, cfg.experts_per_token)
     selected = checkpoint_name(selected.astype(jnp.int32), gm.LAYOUT_NAME)
-    w = jnp.take_along_axis(s, selected, axis=1)
+    w = chosen(s, selected)
     w = w / jnp.sum(w, axis=1, keepdims=True) * cfg.routed_scaling
     return selected, w
 

@@ -27,6 +27,19 @@ def swiglu(y, w_gate, w_up, w_down):
     return (jax.nn.silu(y @ w_gate) * (y @ w_up)) @ w_down
 
 
+def chosen(scores: jax.Array, selected: jax.Array) -> jax.Array:
+    """scores [N, E] over all the router's experts, ``selected`` [N, k] ->
+    [N, k], each token's scores of the experts it chose: ``take_along_axis``
+    to the bit, read a slot at a time by a compare against the experts' numbers
+    and a sum with one term that is not zero. Its transpose is a dense
+    ``where`` into [N, E]. (XLA:TPU gathers, and scatter-adds, a scalar at a
+    time: 8 ns each, PERF.md section 7 row 7.)"""
+    expert = jnp.arange(scores.shape[1], dtype=selected.dtype)
+    return jnp.stack(
+        [jnp.sum(jnp.where(selected[:, j, None] == expert, scores, 0), axis=1)
+         for j in range(selected.shape[1])], axis=1)
+
+
 def expert_mlp(y, selected, weights, w_gate, w_up, w_down, *, n_held: int,
                expert_offset: int, shared):
     """y: normed tokens [N, H]; ``selected`` [N, k] int32, the experts each

@@ -104,7 +104,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from brpc_tpu.models.chunked_loss import chunked_next_token_loss
-from brpc_tpu.models.experts import expert_mlp, swiglu
+from brpc_tpu.models.experts import chosen, expert_mlp, swiglu
 from brpc_tpu.models.llama import _dense_init, attention, rope
 from brpc_tpu.models.train_step import apply_updates
 from brpc_tpu.ops import gated_delta
@@ -322,7 +322,7 @@ def route(cfg: HybridConfig, y: jax.Array, router: jax.Array):
     selected = checkpoint_name(selected.astype(jnp.int32), gm.LAYOUT_NAME)
     # from the saved selection, not from top_k's values: a layer's
     # recomputation then runs the softmax and no top_k (as deepseek.route)
-    w = jnp.take_along_axis(p, selected, axis=1)
+    w = chosen(p, selected)
     return selected, w / jnp.sum(w, axis=1, keepdims=True)
 
 

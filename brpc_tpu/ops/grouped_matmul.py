@@ -34,10 +34,20 @@ row can be fetched from). The transposes of ``dispatch`` and ``combine`` are
 gathers through the inverse map, since a scatter-add of rows serialises on a
 TPU. When lowered for another platform, or at shapes the kernels do not
 take, the products are plain einsums over the tiles and the row movement
-plain gathers over the whole bound (``lax.platform_dependent``: one traced
-function for every platform). ``moe_grouped_lowerings`` counts the kernel
-products a lowered program holds, ``moe_rows_lowerings`` its row movements
-by kernel.
+plain gathers of whole rows over the bound (``lax.platform_dependent``: one
+traced function for every platform). ``moe_grouped_lowerings`` counts the
+kernel products a lowered program holds, ``moe_rows_lowerings`` its row
+movements by kernel.
+
+No scalar is looked up over the bound, on any platform: XLA:TPU gathers and
+scatters single elements one at a time, 8 ns each, and the bound is 50,000 to
+90,000 of them (PERF.md section 7 row 7). The layout is a permutation of the
+bound's rows: ``dest``, continued over the rows that hold nothing, and
+``row_source`` are each other's inverse, and a permutation is applied, or
+inverted, by a sort that carries what is to move (``group_layout``,
+``rows_of``, ``assignments_of``). An assignment's place comes from a running
+count and a compare against the groups held, and what a tile's rows share is
+made once a tile.
 """
 
 from __future__ import annotations
@@ -67,9 +77,14 @@ LAYOUT_NAME = "moe_group_layout"
 
 class GroupLayout(NamedTuple):
     """Where each assignment's row lies, and what each row holds."""
-    dest: jax.Array         # [A] row of assignment a (any row where not held)
+    # [A] row of assignment a: its group's first row + those of the group
+    # before it; where not held an empty row of its own, any of them
+    dest: jax.Array
     held: jax.Array         # [A] bool: the assignment's expert lives here
-    row_source: jax.Array   # [M] the row's assignment (rising in a group)
+    # [M] the row's assignment (rising in a group): a permutation of 0..M-1,
+    # ``dest``'s inverse; a row that holds nothing has an assignment not held
+    # or a spare number from A on
+    row_source: jax.Array
     row_valid: jax.Array    # [M] bool: the row holds an assignment
     tile_group: jax.Array   # [M / tile] group of each row tile
     n_tiles: jax.Array      # [1] tiles in use (the rest of the bound is idle)
@@ -96,34 +111,96 @@ def bound_rows(n_assignments: int, n_groups: int, tile: int) -> int:
     return (-(-n_assignments // tile) + n_groups) * tile
 
 
+def _rows_before(sizes: jax.Array, tile: int):
+    """Per group, from the rows present in each: its tiles (at least one),
+    the tiles up to and with it, its first row, and its first row less the
+    assignments of the groups before it (the rows those left empty)."""
+    tiles = jnp.maximum(-(-sizes // tile), 1)
+    tile_end = jnp.cumsum(tiles)
+    row_start = (tile_end - tiles) * tile
+    return tiles, tile_end, row_start, row_start - (jnp.cumsum(sizes) - sizes)
+
+
+def _empty_row(j, sizes: jax.Array, tile: int):
+    """The j-th row (from 0) that holds no assignment, the idle tiles' rows
+    after the groups' own: j plus the assignments of every group whose
+    rows begin at or before it. ``sizes`` [G] against j [...]: a compare
+    and a sum over the groups, no lookup."""
+    *_, empty_before = _rows_before(sizes, tile)
+    return j + jnp.sum(jnp.where(j[..., None] >= empty_before, sizes, 0),
+                       axis=-1)
+
+
+def _every_row(dest: jax.Array, sizes: jax.Array, n_rows: int, tile: int):
+    """``dest`` [A] continued over the spare numbers A..M-1, [M]: they take
+    the empty rows left when every assignment not held has taken one, so
+    the whole is a permutation of the bound's rows."""
+    spare = jnp.arange(dest.shape[0], n_rows, dtype=jnp.int32)
+    return jnp.concatenate(
+        [dest, _empty_row(spare - jnp.sum(sizes), sizes, tile)])
+
+
 def group_layout(group_of: jax.Array, n_groups: int, tile: int) -> GroupLayout:
     """``group_of``: [A] int32, the held group (0..n_groups-1) of each
-    assignment, or ``n_groups`` for one whose expert lives elsewhere."""
+    assignment, or ``n_groups`` for one whose expert lives elsewhere.
+
+    Made with no lookup of a scalar over A or M. An assignment's place in its
+    group is a running count over [A, G] (G the groups held), read with its
+    group's first row by a compare against the groups. The rows' sources are
+    the inverse of that map, and a permutation is inverted by a sort: every
+    assignment not held and M - A spare numbers take the empty rows in turn
+    (``_every_row``), so that ``dest`` with the spare rows is a permutation
+    of the bound's rows, and sorting the row numbers carries each row's
+    source to its place. What is the same for a tile's rows is made a tile
+    at a time and broadcast."""
     (a,) = group_of.shape
     m = bound_rows(a, n_groups, tile)
     held = group_of < n_groups
-    order = jnp.argsort(group_of, stable=True).astype(jnp.int32)
     one_hot = group_of[:, None] == jnp.arange(n_groups, dtype=jnp.int32)
-    rank = jnp.cumsum(one_hot.astype(jnp.int32), axis=0) - 1       # [A, G]
-    sizes = rank[-1] + 1
-    tiles = jnp.maximum(-(-sizes // tile), 1)
-    tile_end = jnp.cumsum(tiles)
-    row_start = (tile_end - tiles) * tile          # first row of each group
-    first = jnp.cumsum(sizes) - sizes              # first sorted assignment
-    safe = jnp.minimum(group_of, n_groups - 1)
-    dest = row_start[safe] + jnp.take_along_axis(
-        rank, safe[:, None], axis=1)[:, 0]
+    count = jnp.cumsum(one_hot.astype(jnp.int32), axis=0)  # [A, G], inclusive
+    sizes = count[-1]
+    _, tile_end, row_start, _ = _rows_before(sizes, tile)
+    number = jnp.arange(a, dtype=jnp.int32)
+    dest = jnp.where(
+        held, jnp.sum(jnp.where(one_hot, row_start + count - 1, 0), axis=1),
+        _empty_row(number - jnp.sum(count, axis=1), sizes, tile))
+    _, row_source = lax.sort((_every_row(dest, sizes, m, tile),
+                              jnp.arange(m, dtype=jnp.int32)), num_keys=1)
+    tile_number = jnp.arange(m // tile, dtype=jnp.int32)
     tile_group = jnp.minimum(
-        jnp.searchsorted(tile_end, jnp.arange(m // tile, dtype=jnp.int32),
-                         side="right"), n_groups - 1).astype(jnp.int32)
-    row = jnp.arange(m, dtype=jnp.int32)
-    g = tile_group[row // tile]
-    in_group = row - row_start[g]
-    row_valid = (in_group < sizes[g]) & (row // tile < tile_end[-1])
-    row_source = order[jnp.clip(first[g] + in_group, 0, a - 1)]
+        jnp.sum(tile_number[:, None] >= tile_end, axis=1, dtype=jnp.int32),
+        n_groups - 1)
+    # rows of its group before the tile, and in it: [M / tile], then by row
+    before = tile_number * tile - row_start[tile_group]
+    present = jnp.where(tile_number < tile_end[-1],
+                        sizes[tile_group] - before, 0)
+    row_valid = (jnp.arange(tile, dtype=jnp.int32) < present[:, None]
+                 ).reshape(m)
     return GroupLayout(*(checkpoint_name(field, LAYOUT_NAME) for field in (
-        dest.astype(jnp.int32), held, row_source, row_valid, tile_group,
+        dest, held, row_source, row_valid, tile_group,
         tile_end[-1:].astype(jnp.int32), sizes)))
+
+
+def rows_of(lay: GroupLayout, values: jax.Array) -> jax.Array:
+    """values [A], one an assignment -> [M]: row r's is that of the
+    assignment it holds (anything where it holds none). The sort of
+    ``group_layout`` again, carrying the values where that carried their
+    numbers: the layout keeps integers only."""
+    (m,) = lay.row_source.shape
+    _, by_row = lax.sort(
+        (_every_row(lay.dest, lay.group_sizes, m,
+                    m // lay.tile_group.shape[0]),
+         jnp.pad(values, (0, m - values.shape[0]))), num_keys=1)
+    return by_row
+
+
+def assignments_of(lay: GroupLayout, values: jax.Array) -> jax.Array:
+    """values [M], one a row -> [A]: assignment a's is that of the row
+    ``dest[a]``, the inverse of ``rows_of``: ``row_source`` is a permutation
+    of 0..M-1, and sorting it carries every row's value to its source's
+    place."""
+    _, by_source = lax.sort((lay.row_source, values), num_keys=1)
+    return by_source[:lay.dest.shape[0]]
 
 
 # -- kernel or plain form -----------------------------------------------------
@@ -168,10 +245,12 @@ def rows_kernels_take(n_tokens: int, width: int, tile: int, dtype) -> bool:
 def _maps(lay: GroupLayout, n: int):
     """The layout as ``dispatch`` and ``combine`` read it, for n tokens of
     k assignments each: dest [n,k], held [n,k], row_token [M], row_slot
-    [M], and the rows in a tile."""
+    [M], and the rows in a tile. A row that holds a spare number reads as
+    the last assignment's (a kernel fetches every row of a tile in use)."""
     k = lay.dest.shape[0] // n
+    source = jnp.minimum(lay.row_source, n * k - 1)
     return (lay.dest.reshape(n, k), lay.held.reshape(n, k),
-            lay.row_source // k, lay.row_source % k,
+            source // k, source % k,
             lay.row_valid.shape[0] // lay.tile_group.shape[0])
 
 
@@ -499,14 +578,15 @@ def _combine_bwd(interpret, res, dy):
     fetched for d_rows with ``rows``: the kernel form takes it in the same
     pass over the row tiles and gathers no row by token."""
     rows, weights, lay = res
-    dest, held, row_token, row_slot, tile = _maps(lay, weights.shape[0])
-    w_row = weights[row_token, row_slot]                          # [M]
+    dest, held, row_token, _, tile = _maps(lay, weights.shape[0])
+    w_row = rows_of(lay, weights.reshape(-1))                     # [M]
 
     def kernel(dy, rows, *, interpret):
         d_rows, dots = _gather_rows(
             dy, w_row, rows, row_token=row_token, row_valid=lay.row_valid,
             n_tiles=lay.n_tiles, tile=tile, interpret=interpret)
-        return d_rows, jnp.where(held, dots[dest], 0)
+        return d_rows, jnp.where(
+            held, assignments_of(lay, dots).reshape(held.shape), 0)
 
     def plain(dy, rows):
         d_rows = jnp.where(

@@ -474,6 +474,100 @@ def test_group_layout_places_every_held_assignment_once():
         np.asarray(lay.tile_group))
 
 
+def _group_layout_by_lookups(group_of, n_groups, tile):
+    """``gm.group_layout`` as it stood before PR 40, the plain reference of
+    the one that looks nothing up: an argsort, then every field read by a
+    gather of scalars over the assignments or the rows."""
+    (a,) = group_of.shape
+    m = gm.bound_rows(a, n_groups, tile)
+    held = group_of < n_groups
+    order = jnp.argsort(group_of, stable=True).astype(jnp.int32)
+    one_hot = group_of[:, None] == jnp.arange(n_groups, dtype=jnp.int32)
+    rank = jnp.cumsum(one_hot.astype(jnp.int32), axis=0) - 1
+    sizes = rank[-1] + 1
+    tiles = jnp.maximum(-(-sizes // tile), 1)
+    tile_end = jnp.cumsum(tiles)
+    row_start = (tile_end - tiles) * tile
+    first = jnp.cumsum(sizes) - sizes
+    safe = jnp.minimum(group_of, n_groups - 1)
+    dest = row_start[safe] + jnp.take_along_axis(
+        rank, safe[:, None], axis=1)[:, 0]
+    tile_group = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(m // tile, dtype=jnp.int32),
+                         side="right"), n_groups - 1).astype(jnp.int32)
+    row = jnp.arange(m, dtype=jnp.int32)
+    g = tile_group[row // tile]
+    in_group = row - row_start[g]
+    row_valid = (in_group < sizes[g]) & (row // tile < tile_end[-1])
+    row_source = order[jnp.clip(first[g] + in_group, 0, a - 1)]
+    return gm.GroupLayout(dest.astype(jnp.int32), held, row_source, row_valid,
+                          tile_group, tile_end[-1:].astype(jnp.int32), sizes)
+
+
+# (k, the router's width, experts held) of the three expert cells
+_EXPERT_CELLS = {"qwen3_next": (10, 512, 32), "laguna_xs2": (8, 256, 16),
+                 "kanana2": (6, 128, 16)}
+
+
+def _layout_case(name):
+    """(group_of [A], groups, tile) of a seeded case."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    if name in _EXPERT_CELLS:       # the cell's routing at 512 tokens
+        k, width, groups = _EXPERT_CELLS[name]
+        selected = np.argsort(rng.random((512, width)), axis=1)[:, :k]
+        tile = gm.choose_tile(512 * k, groups)
+        return np.minimum(selected, groups).reshape(-1), groups, tile
+    groups, tile, a = 3, 8, 80
+    group_of = {
+        "an_empty_held_group": lambda: _grouped_case()[0].reshape(-1),
+        "none_held": lambda: np.full(a, groups),
+        "all_in_one_group": lambda: np.full(a, 1),
+        # group 0 has a whole tile and group 2 two, to the row
+        "groups_of_whole_tiles": lambda: rng.permutation(
+            np.repeat([0, 1, 2, 3], [8, 5, 16, a - 29])),
+        "every_assignment_held": lambda: rng.integers(0, groups, a),
+    }[name]()
+    return group_of, groups, tile
+
+
+@pytest.mark.parametrize("case", [
+    "an_empty_held_group", "none_held", "all_in_one_group",
+    "groups_of_whole_tiles", "every_assignment_held", *_EXPERT_CELLS])
+def test_group_layout_is_the_one_made_by_lookups(case):
+    """Field for field: ``dest`` where held and ``row_source`` where the row
+    holds an assignment (elsewhere any row will do), the rest everywhere;
+    and what the new one promises besides: ``dest`` with the spare rows, and
+    ``row_source``, are permutations of the bound's rows, each the other's
+    inverse over the assignments."""
+    group_of, groups, tile = _layout_case(case)
+    group_of = jnp.asarray(group_of, jnp.int32)
+    got = jax.tree_util.tree_map(np.asarray,
+                                 gm.group_layout(group_of, groups, tile))
+    want = jax.tree_util.tree_map(
+        np.asarray, _group_layout_by_lookups(group_of, groups, tile))
+    for field in ("held", "row_valid", "tile_group", "n_tiles",
+                  "group_sizes"):
+        assert np.array_equal(getattr(got, field), getattr(want, field)), field
+        assert getattr(got, field).dtype == getattr(want, field).dtype, field
+    assert np.array_equal(got.dest[want.held], want.dest[want.held])
+    assert np.array_equal(got.row_source[want.row_valid],
+                          want.row_source[want.row_valid])
+    assert got.dest.dtype == got.row_source.dtype == np.int32
+    a, m = group_of.shape[0], got.row_valid.shape[0]
+    assert np.array_equal(np.sort(got.row_source), np.arange(m))
+    assert np.array_equal(got.row_source[got.dest], np.arange(a))
+    if case == "groups_of_whole_tiles":
+        assert list(got.group_sizes) == [tile, 5, 2 * tile]
+    # what rides the sort: a value an assignment to the rows and back
+    lay = gm.group_layout(group_of, groups, tile)
+    values = jnp.asarray(np.random.default_rng(0).standard_normal(a),
+                         jnp.float32)
+    by_row = gm.rows_of(lay, values)
+    assert np.array_equal(np.asarray(by_row)[want.row_valid],
+                          np.asarray(values)[want.row_source[want.row_valid]])
+    assert np.array_equal(np.asarray(gm.assignments_of(lay, by_row)), values)
+
+
 @pytest.mark.parametrize("interpret", [None, True],
                          ids=["einsum", "kernels_interpreted"])
 def test_grouped_product_matches_a_loop_over_the_experts(interpret):
@@ -668,6 +762,30 @@ def _bound_sized_gathers(text: str, row_counts, width: int):
     return re.findall(rf"= \w+\[(?:{counts}),{width}\]\S* gather\(", text)
 
 
+def _scalar_moves_compiled(text: str, least: int):
+    """The gathers and scatters of a compiled program that move single
+    elements at ``least`` indices or more: [(op, indices)], as
+    ``_scalar_moves`` finds them in a jaxpr."""
+    shapes = {name: [int(d) for d in dims.split(",") if d]
+              for name, dims in re.findall(
+                  r"%([\w.\-]+) = \w+\[([\d,]*)\]", text)}
+    found = []
+    for name, dims, op, operands, rest in re.findall(
+            r"%([\w.\-]+) = \w+\[([\d,]*)\]\S* (gather|scatter)"
+            r"\(([^)]*)\)([^\n]*)", text):
+        if op == "gather":
+            sizes = re.search(r"slice_sizes=\{([\d,]*)\}", rest).group(1)
+            single = all(size == "1" for size in sizes.split(","))
+            count = int(np.prod([int(d) for d in dims.split(",") if d]))
+        else:
+            single = "update_window_dims={}" in rest
+            updates = operands.split(",")[-1].strip().lstrip("%")
+            count = int(np.prod(shapes[updates]))
+        if single and count >= least:
+            found.append((op, count))
+    return found
+
+
 # Kernel-eligible and small, as _ELIGIBLE is: 4 heads of 128 + 64 / 128, 8
 # experts of which 2 are held, each [256, 128]; 384 tokens, top-2.
 _LATENT = deepseek.DeepseekConfig(
@@ -721,6 +839,11 @@ def test_deepseek_step_moves_rows_by_the_tiles_in_use_on_tpu(v5e_device):
     # nor are the two products' cotangents of the rows added over the bound
     assert re.findall(rf"= \w+\[{bound},{_LATENT.hidden}\]\S* add\(",
                       text) == []
+    # and no scalar is moved by a gather or a scatter at as many indices as
+    # the layer has assignments; the walk sees the loss's pick of a target
+    # logit a token, the one gather of scalars the step has
+    assert _scalar_moves_compiled(text, _T * k) == []
+    assert _scalar_moves_compiled(text, _T) == [("gather", _T)]
 
 
 # -- what the deepseek step keeps across its recomputation -------------------
@@ -1007,3 +1130,134 @@ def test_hybrid_step_takes_every_kernel_on_tpu(v5e_device, lowerings):
     assert lowerings() == (1, 0)
     assert gdn.get_value() - before == 1
     assert conv.get_value() - conv_before == 1
+
+
+# -- PR 40: the expert layer reads no scalar through a gather over its bound --
+
+from brpc_tpu.models import experts, windowed  # noqa: E402
+
+_WINDOWED = windowed.WindowedConfig.tiny()
+_ROUTES = {
+    "hybrid": (hybrid, lambda y, r: hybrid.route(_HYBRID, y, r)),
+    "deepseek_biased": (deepseek, lambda y, r: deepseek.route(
+        _LATENT, y, r, jnp.linspace(-0.3, 0.3, r.shape[1]))),
+    "deepseek_unbiased": (deepseek, lambda y, r: deepseek.route(
+        _WINDOWED, y, r, 0.0)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ROUTES))
+def test_router_weights_are_take_along_axis_to_the_bit(monkeypatch, case):
+    """``experts.chosen`` in both routers against the gather it took the
+    place of: the weights, and the gradients through them of the tokens and
+    of the router's matrix, bit for bit, with ties in the scores (two
+    experts with one column, a token of zeros)."""
+    module, route = _ROUTES[case]
+    rng = np.random.default_rng(5)
+    router = rng.standard_normal((64, 8)).astype(np.float32)
+    router[:, 5] = router[:, 2]
+    y = rng.standard_normal((96, 64)).astype(np.float32)
+    y[7] = 0
+    y, router = jnp.asarray(y, jnp.bfloat16), jnp.asarray(router)
+    cotangent = jnp.asarray(rng.standard_normal((96, 2)), jnp.float32)
+
+    def run():
+        def loss(y, router):
+            selected, weights = route(y, router)
+            return jnp.sum(weights * cotangent), (selected, weights)
+        (_, out), grads = jax.value_and_grad(loss, (0, 1), has_aux=True)(
+            y, router)
+        return jax.tree_util.tree_map(np.asarray, (*out, *grads))
+
+    got = run()
+    monkeypatch.setattr(module, "chosen", lambda scores, selected:
+                        jnp.take_along_axis(scores, selected, axis=1))
+    want = run()
+    for name, g, w in zip(("selected", "weights", "dy", "drouter"), got,
+                          want):
+        assert g.dtype == w.dtype and np.array_equal(g, w), name
+    assert np.any(got[2]) and np.any(got[3])
+
+
+def _scalar_moves(jaxpr, least: int):
+    """The gathers and scatters of a jaxpr, nested ones too, that move
+    single elements at ``least`` indices or more: [(primitive, indices)]."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "gather":
+            single = all(size == 1 for size in eqn.params["slice_sizes"])
+            count = int(np.prod(eqn.invars[1].aval.shape[:-1]))
+        elif name.startswith("scatter"):
+            single = not eqn.params["dimension_numbers"].update_window_dims
+            count = int(np.prod(eqn.invars[2].aval.shape))
+        else:
+            single, count = False, 0
+        if single and count >= least:
+            found.append((name, count))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _scalar_moves(sub, least)
+    return found
+
+
+_EXPERT_LAYERS = {"hybrid": (hybrid, _HYBRID), "deepseek": (deepseek, _LATENT),
+                  "windowed": (windowed, _WINDOWED)}
+
+
+def _expert_layer_jaxpr(model: str, monkeypatch, interpret, n=64):
+    """The jaxpr of value and gradient of ``model``'s ``moe_mlp`` on n
+    tokens under the models' checkpoint (the layout saved by name, the rest
+    run again), and N * k. ``interpret`` True: the kernels' path."""
+    module, cfg = _EXPERT_LAYERS[model]
+    if interpret:
+        choose = gm._choose
+        monkeypatch.setattr(
+            gm, "_choose", lambda kernel, plain, taken, counter, _, *operands:
+            choose(kernel, plain, taken, counter, True, *operands))
+    h, e, held = cfg.hidden, getattr(
+        cfg, "n_experts", getattr(cfg, "n_routed_experts", 0)), cfg.n_held
+    f = cfg.moe_intermediate
+    shapes = {"router": (h, e), "router_bias": (e,), "shared_w": (h,),
+              "w_gate": (held, h, f), "w_up": (held, h, f),
+              "w_down": (held, f, h), "shared_gate": (h, f),
+              "shared_up": (h, f), "shared_down": (f, h)}
+    lp = {name: jax.ShapeDtypeStruct(shape, jnp.float32)
+          for name, shape in shapes.items()}
+
+    @functools.partial(
+        jax.checkpoint,
+        policy=jax.checkpoint_policies.save_only_these_names(gm.LAYOUT_NAME))
+    def layer(y, lp):
+        return module.moe_mlp(cfg, y, lp)[0]
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda y, lp: jnp.sum(layer(y, lp)), (0, 1)))(
+            jax.ShapeDtypeStruct((n, h), jnp.float32), lp)
+    return jaxpr.jaxpr, n * cfg.experts_per_token
+
+
+@pytest.mark.parametrize("interpret", [None, True],
+                         ids=["plain", "kernels_interpreted"])
+@pytest.mark.parametrize("model", sorted(_EXPERT_LAYERS))
+def test_expert_layer_moves_no_scalar_by_gather_over_its_bound(
+        monkeypatch, model, interpret):
+    """Forward, recomputed and backward, in every model that has the layer:
+    no gather, scatter or scatter-add of single elements with as many
+    indices as the layer has assignments (XLA:TPU moves them one at a time).
+    Rows are fetched whole (the plain path's ``x[row_token]``), tables of a
+    tile's worth are read by tile."""
+    jaxpr, assignments = _expert_layer_jaxpr(model, monkeypatch, interpret)
+    assert _scalar_moves(jaxpr, assignments) == []
+    kernels = _kernels_in(jaxpr)
+    assert bool(kernels) == bool(interpret)
+
+
+def test_the_pin_sees_a_gather_of_scalars(monkeypatch):
+    """The same walk finds the router's gather when it is put back, in the
+    forward pass, the recomputation and (as a scatter-add) the backward."""
+    monkeypatch.setattr(hybrid, "chosen", lambda scores, selected:
+                        jnp.take_along_axis(scores, selected, axis=1))
+    jaxpr, assignments = _expert_layer_jaxpr("hybrid", monkeypatch, None)
+    found = _scalar_moves(jaxpr, assignments)
+    assert sorted({name for name, _ in found}) == ["gather", "scatter-add"]
+    assert {count for _, count in found} == {assignments}
