@@ -1,9 +1,18 @@
 """A sparse expert layer after its routing: one chip's share of the routed
 experts and what every chip computes alike, for every model that has such a
-layer (``models/deepseek.py``: sigmoid scores, a selection bias, a scaling
-factor, unweighted shared experts; ``models/hybrid.py``: softmax scores,
-one shared expert behind a sigmoid gate). How the experts are chosen and
-weighted is the model's; what follows is the same for all of them.
+layer (``models/deepseek.py``, kanana-2: sigmoid scores, a selection bias, a
+scaling factor, unweighted shared experts; ``models/hybrid.py``,
+Qwen3-Next: softmax scores, one shared expert behind a sigmoid gate;
+``models/windowed.py``: Laguna-XS.2, sigmoid scores with a scaling beside one
+unweighted shared expert, and Mellum2, softmax scores and no shared expert at
+all). How the experts are chosen and weighted is the model's, by one of two
+routers: ``deepseek.route`` (sigmoid scores; ``moe_routed_scaling_factor``,
+a selection bias) and ``hybrid.route`` (softmax scores renormalised over the
+chosen: ``norm_topk_prob: true``). What follows the routing is the
+same for all of them, and ``shared`` says what is added beside it: a
+function of the tokens where the published config has a shared expert
+(``shared_expert_intermediate_size``, ``n_shared_experts``), None where it
+has none.
 
 The layer is told which experts it holds — ``n_held`` of them from
 ``expert_offset`` — and computes what its own give for the assignments
@@ -45,7 +54,9 @@ def expert_mlp(y, selected, weights, w_gate, w_up, w_down, *, n_held: int,
     """y: normed tokens [N, H]; ``selected`` [N, k] int32, the experts each
     token chose among ALL the router's; ``weights`` [N, k] float32, theirs;
     the held experts' stacks [n_held, H, F], [n_held, H, F], [n_held, F, H];
-    ``shared``: y -> [N, H], what is added whatever the routing. Returns
+    ``shared``: y -> [N, H], what is added whatever the routing, or None
+    where the model has no shared expert (nothing is added and no
+    ``moe.shared`` scope opens). Returns
     ([N, H], stats): the assignments routed to held experts, those dropped
     (0 by construction, counted all the same), the fullest and the mean held
     expert's rows, the rows of the bound in use (whole tiles: what the row
@@ -65,8 +76,9 @@ def expert_mlp(y, selected, weights, w_gate, w_up, w_down, *, n_held: int,
         rows = product(hidden, w_down)
     with jax.named_scope("moe.combine"):
         routed = gm.combine(rows, weights, lay)
-    with jax.named_scope("moe.shared"):
-        alike = shared(y)
+    if shared is not None:
+        with jax.named_scope("moe.shared"):
+            alike = shared(y)
     with jax.named_scope("moe.sort"):               # the layout's counts
         n_routed = jnp.sum(lay.held.astype(jnp.int32))
         stats = {
@@ -77,5 +89,7 @@ def expert_mlp(y, selected, weights, w_gate, w_up, w_down, *, n_held: int,
             "rows_in_use": lay.n_tiles[0] * tile,
             "selected": selected,
         }
+    if shared is None:
+        return routed, stats
     with jax.named_scope("moe.shared"):
         return routed + alike, stats

@@ -35,7 +35,9 @@ and the per-head q/k norms.
 - **Expert layer** (every layer). ``p = softmax(y·W_g)`` in float32 over ALL
   ``n_experts``; the ``experts_per_token`` largest; weights ``p_sel /
   sum p_sel``; experts SwiGLU of ``moe_intermediate``; plus ``sigmoid(y·w_s) ·
-  SwiGLU(y)``, the shared expert. What follows the routing is
+  SwiGLU(y)``, the shared expert. ``route`` is the softmax router of every
+  model that has one (``models/windowed.py`` calls it for Mellum2, as it
+  calls ``deepseek.route`` for Laguna-XS.2); what follows the routing is
   ``models/experts.py``, shared with ``models/deepseek.py``, and so is the
   contract of a chip's share: told ``n_held`` and ``expert_offset`` it routes
   over all, normalises over all the selected wherever they live, computes
@@ -315,7 +317,9 @@ def gated_attention(cfg: HybridConfig, x: jax.Array, lp: Params,
 
 def route(cfg: HybridConfig, y: jax.Array, router: jax.Array):
     """y: [N, H] -> (selected experts [N, k] int32, their weights [N, k]
-    float32): softmax over all the experts, the k largest, renormalised."""
+    float32): softmax over all the experts, the k largest, renormalised
+    (``norm_topk_prob: true``). ``cfg``: any config with
+    ``experts_per_token`` (this model's, ``WindowedConfig``)."""
     p = jax.nn.softmax(jnp.dot(y.astype(jnp.float32), router,
                                precision=lax.Precision.HIGHEST), axis=-1)
     _, selected = lax.top_k(p, cfg.experts_per_token)

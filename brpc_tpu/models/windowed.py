@@ -1,22 +1,49 @@
-"""Window-and-full-attention sparse-expert transformer (``model_type:
-laguna``, poolside/Laguna-XS.2, 33B-A3B): three sliding-window attention
-layers of 64 query heads to one full-attention layer of 48, all over 8 KV
-heads of 128, each kind with a rope of its own, a per-head sigmoid gate on
-every head's output, and after the one leading dense layer 256
-sigmoid-routed experts (top-8) beside one shared expert. TPU-first
-functional JAX with the entry points of the other models — a frozen config
-with a ``tiny()`` preset, ``init_params``, ``hidden_states``, ``forward``,
-``loss_fn`` and ``make_train_step``.
+"""Window-and-full-attention sparse-expert transformers: sliding-window
+attention layers and full-attention layers in one stack, each kind with a
+rope of its own, sparse experts after every attention block. One model file
+for two published configurations, told apart by what their ``config.json``
+states and never by a name:
 
-The layers, as published (``config.json``). Every layer is ``x = x +
-attn(norm(x)); x = x + mlp(norm(x))`` with RMSNorm (``llama.rms_norm``).
-Layer ``i`` is full attention where ``i % period == 0``, else sliding-window,
-so the stack is full, window, window, window, full, ...; layer 0's MLP is a
-dense SwiGLU of ``intermediate``, every other layer's the experts.
+- ``model_type: laguna`` (poolside/Laguna-XS.2, 33B-A3B; the defaults of
+  ``WindowedConfig``): full, window, window, window ten times over, 48 query
+  heads on full layers and 64 on window layers over 8 KV heads of 128, a
+  window of 512, yarn on the leading half of a full layer's heads, a
+  per-head sigmoid gate on every head's output, layer 0's MLP dense, then
+  256 sigmoid-routed experts (top-8) beside one shared expert.
+- ``model_type: mellum`` (JetBrains/Mellum2-12B-A2.5B-Instruct;
+  ``WindowedConfig.mellum2()``): window, window, window, full seven times
+  over, 32 query heads over 4 KV heads of 128 on every layer, a window of
+  1,024, a per-head RMSNorm on q and on k before the rope, yarn on the whole
+  head of the full layers, no output gate, every layer's MLP sparse: 64
+  softmax-routed experts (top-8) and no shared one.
+
+TPU-first functional JAX with the entry points of the other models — a
+frozen config with a ``tiny()`` preset, ``init_params``, ``hidden_states``,
+``forward``, ``loss_fn`` and ``make_train_step``.
+
+Which published key sets which switch of ``WindowedConfig``:
+``layer_types`` / ``mlp_layer_types`` -> the fields of those names (``full``
+/ ``window``, ``dense`` / ``sparse``), read up to ``n_layers``: the stack is
+laid out from them (``layout``); ``num_attention_heads_per_layer`` (or
+``num_attention_heads`` where every layer has as many) -> ``full_heads``,
+``window_heads``; ``gating`` -> ``attn_gate``; the family's q/k norm
+(Qwen3-MoE's keys, which Mellum2's are) -> ``qk_norm``;
+``moe_routed_scaling_factor`` with sigmoid scores -> ``router="sigmoid"``
+and ``routed_scaling``, ``norm_topk_prob`` with softmax scores ->
+``router="softmax"``; ``shared_expert_intermediate_size`` ->
+``shared_intermediate`` (0: no shared expert); ``rope_parameters``'
+``partial_rotary_factor`` of the full layers -> ``full_rotary_factor`` (1
+where the group has none).
+
+The layers. Every layer is ``x = x + attn(norm(x)); x = x + mlp(norm(x))``
+with RMSNorm (``llama.rms_norm``); a layer's MLP is a dense SwiGLU of
+``intermediate`` where ``mlp_layer_types`` says ``dense``, else the experts.
 
 - **Attention** (H = ``full_heads`` or ``window_heads`` query heads, d =
   ``head_dim``). ``q = y·W_q`` [T, H, d], ``k = y·W_k``, ``v = y·W_v`` [T,
-  ``n_kv_heads``, d], ``g = y·W_g`` [T, H]. Rope, halves rotated
+  ``n_kv_heads``, d]; with ``attn_gate``, ``g = y·W_g`` [T, H]; with
+  ``qk_norm``, ``q <- rmsnorm_d(q)·w_q`` and ``k <- rmsnorm_d(k)·w_k`` per
+  head, weights of d, before the rope. Rope, halves rotated
   (``llama.rope``'s convention). Window layers: plain rope of
   ``window_rope_theta`` on the whole head. Full layers: yarn on the leading
   ``full_rotary_factor`` of the head (``yarn_inv_freq``: interpolated and
@@ -27,42 +54,50 @@ dense SwiGLU of ``intermediate``, every other layer's the experts.
   window`` (window), float32 softmax, through ``llama.attention``: on a TPU
   the fused kernels, for a window the band kernels that skip the tiles
   outside it (``ops/flash_attention.py``), the dense masked form elsewhere.
-  ``o_h <- sigmoid(g_h) · o_h`` per head; ``·W_o``. No biases, no q/k norm.
-- **Experts.** ``s = sigmoid(y·W_r)`` in float32 over ALL ``n_experts``; the
-  ``experts_per_token`` largest; weights ``s_sel / sum s_sel ·
-  routed_scaling`` (``deepseek.route``, with no selection bias); experts
-  SwiGLU of ``moe_intermediate``; plus one unweighted shared SwiGLU of
-  ``shared_intermediate``. What follows the routing is
+  With ``attn_gate``, ``o_h <- sigmoid(g_h) · o_h`` per head; ``·W_o``. No
+  biases.
+- **Experts.** ``router="sigmoid"``: ``s = sigmoid(y·W_r)`` in float32 over
+  ALL ``n_experts``; the ``experts_per_token`` largest; weights ``s_sel /
+  sum s_sel · routed_scaling`` (``deepseek.route``, with no selection bias).
+  ``router="softmax"``: ``p = softmax(y·W_r)`` in float32 over all; the
+  largest; weights ``p_sel / sum p_sel`` (``hybrid.route``). Experts SwiGLU
+  of ``moe_intermediate``; plus, where ``shared_intermediate`` is not 0, one
+  unweighted shared SwiGLU of that width. What follows the routing is
   ``models/experts.py``, and so is the contract of a chip's share: told
   ``n_held`` and ``expert_offset`` it routes over all, normalises over all
   the selected wherever they live, computes what its own give, drops
   nothing.
 
-What the published config leaves to convention (the benchmark's
-configuration file lists each under ``assumed``): SwiGLU with silu; the gate
-per head, read from the layer's normed input; the router as above; no q/k
-norm; the rotated lanes as two halves; no auxiliary balance loss.
+What the published configs leave to convention (the benchmark's
+configuration files list each under ``assumed``): SwiGLU with silu; the gate
+per head, read from the layer's normed input; the routers as above; the
+rotated lanes as two halves; no auxiliary balance loss; no
+multi-token-prediction head.
 
-How it runs. Layer 0 is a tree of its own (``first``). The layers after it
-are stacked by kind in periods of ``period - 1`` window layers and the full
-layer that follows them — ``window`` [periods, period - 1, ...], ``full``
+How it runs (``layout``, from the two tables). A leading layer whose MLP is
+dense is a tree of its own (``first``; Laguna's layer 0, none in Mellum2).
+The layers after it are periods of window layers ended by a full layer,
+stacked by kind — ``window`` [periods, windows a period, ...], ``full``
 [periods, ...] — and run under ONE ``lax.scan`` over periods whose body scans
 the period's window layers and then runs its full layer; window layers left
-over at the end (the published 40 layers end in three) are ``tail``, scanned
-after. One compiled body of each kind whatever the depth. Each layer is
-recomputed in the backward pass (``jax.checkpoint``) from its input and what
-``SAVED_NAMES`` names: the attention kernel's output and log-sum-exp, and the
-experts' integer routing layout. bf16 compute; float32 master weights,
-router, softmax and loss (``models/chunked_loss.py``).
+over at the end (Laguna's published 40 layers end in three) are ``tail``,
+scanned after. So the published full layer stands first in Laguna's period
+of four and last in Mellum2's, and both are the same scan. One compiled body
+of each kind whatever the depth. Each layer is recomputed in the backward
+pass (``jax.checkpoint``) from its input and what ``SAVED_NAMES`` names: the
+attention kernel's output and log-sum-exp, and the experts' integer routing
+layout. bf16 compute; float32 master weights, router, norms' arithmetic,
+softmax and loss (``models/chunked_loss.py``).
 
 ``make_train_step``'s step also returns ``stats``, a row an expert layer in
 the layers' order, of what ``experts.expert_mlp`` counts. Named scopes:
-``swa.qkv``, ``swa.rope``, ``swa.attn`` (around the kernels' own
-``attn.*``), ``swa.out`` and ``full.*`` likewise, ``dense.mlp``,
-``moe.router``, ``moe.sort``, ``moe.experts``, ``moe.combine``,
-``moe.shared``; ``windowed.glue`` around the norms, residuals and reshapes
-between them; ``embed``, ``weights.cast``, ``loss.chunk`` and ``opt.update``
-as in every model.
+``swa.qkv``, ``swa.qknorm`` (with ``qk_norm``), ``swa.rope``, ``swa.attn``
+(around the kernels' own ``attn.*``), ``swa.out`` and ``full.*`` likewise,
+``dense.mlp`` (a dense layer), ``moe.router``, ``moe.sort``,
+``moe.experts``, ``moe.combine``, ``moe.shared`` (a shared expert);
+``windowed.glue`` around the norms, residuals and reshapes between them;
+``embed``, ``weights.cast``, ``loss.chunk`` and ``opt.update`` as in every
+model.
 """
 
 from __future__ import annotations
@@ -77,7 +112,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from brpc_tpu.models import deepseek
+from brpc_tpu.models import deepseek, hybrid
 from brpc_tpu.models.chunked_loss import chunked_next_token_loss
 from brpc_tpu.models.experts import expert_mlp, swiglu
 from brpc_tpu.models.llama import _dense_init, attention, rms_norm, rope
@@ -93,18 +128,25 @@ _FLOAT32_LEAVES = ("router",)       # never cast to the compute dtype
 SAVED_NAMES = (*RESIDUAL_NAMES, gm.LAYOUT_NAME)
 
 
+_LAGUNA_LAYERS = ("full", "window", "window", "window") * 10
+_MELLUM_LAYERS = ("window", "window", "window", "full") * 7
+
+
 @dataclasses.dataclass(frozen=True)
 class WindowedConfig:
     """The defaults are Laguna-XS.2 as published, every expert held."""
     vocab_size: int = 100352
     hidden: int = 2048
     n_layers: int = 40
-    period: int = 4                   # a full layer, then period - 1 window
+    layer_types: tuple = _LAGUNA_LAYERS       # "full" / "window" a layer ...
+    mlp_layer_types: tuple = ("dense",) + ("sparse",) * 39  # "dense"/"sparse"
     full_heads: int = 48
     window_heads: int = 64
     n_kv_heads: int = 8
     head_dim: int = 128
     window: int = 512
+    attn_gate: bool = True            # a per-head sigmoid gate on the output
+    qk_norm: bool = False             # a per-head RMSNorm on q and on k
     window_rope_theta: float = 10000.0
     full_rope_theta: float = 500000.0         # the full layers' yarn ...
     full_rotary_factor: float = 0.5
@@ -113,12 +155,13 @@ class WindowedConfig:
     yarn_beta_fast: float = 64.0
     yarn_beta_slow: float = 1.0
     yarn_attention_factor: float = 1.4158883083359672   # 0.1 ln 64 + 1
-    intermediate: int = 8192          # layer 0's dense SwiGLU
+    intermediate: int = 8192          # a dense layer's SwiGLU
     n_experts: int = 256              # the router's width, always whole
     experts_per_token: int = 8
     moe_intermediate: int = 512       # one expert's SwiGLU
-    shared_intermediate: int = 512    # the shared expert's
-    routed_scaling: float = 2.5
+    shared_intermediate: int = 512    # the shared expert's; 0: there is none
+    router: str = "sigmoid"           # or "softmax"
+    routed_scaling: float = 2.5       # the sigmoid router's
     norm_eps: float = 1e-6
     n_held: int = 256                 # experts this chip holds ...
     expert_offset: int = 0            # ... from this one on
@@ -140,17 +183,71 @@ class WindowedConfig:
             shared_intermediate=32, n_held=n_held,
             expert_offset=expert_offset)
 
+    @staticmethod
+    def mellum2(**changed) -> "WindowedConfig":
+        """Mellum2-12B-A2.5B-Instruct as published, every expert held."""
+        return dataclasses.replace(WindowedConfig(
+            vocab_size=98304, hidden=2304, n_layers=28,
+            layer_types=_MELLUM_LAYERS, mlp_layer_types=("sparse",) * 28,
+            full_heads=32, window_heads=32, n_kv_heads=4, window=1024,
+            attn_gate=False, qk_norm=True, window_rope_theta=500000.0,
+            full_rotary_factor=1.0, yarn_factor=16.0,
+            yarn_original_positions=8192, yarn_beta_fast=32.0,
+            yarn_attention_factor=1.2772588722239782,   # 0.1 ln 16 + 1
+            intermediate=7168, n_experts=64, moe_intermediate=896,
+            shared_intermediate=0, router="softmax", n_held=64), **changed)
+
+    @staticmethod
+    def tiny_mellum2(n_held: int = 2, expert_offset: int = 0
+                     ) -> "WindowedConfig":
+        """``tiny()``'s sizes laid out as Mellum2: one period, 3 window
+        layers and then the full one, 4 query heads over 2 KV heads on every
+        layer, q/k norms, no gate, no dense layer, no shared expert."""
+        return WindowedConfig.mellum2(
+            vocab_size=256, hidden=64, n_layers=4, full_heads=4,
+            window_heads=4, n_kv_heads=2, head_dim=32, window=16,
+            n_experts=8, experts_per_token=2, moe_intermediate=32,
+            n_held=n_held, expert_offset=expert_offset)
+
     @property
     def layer_kinds(self) -> tuple:
-        """``"full"`` or ``"window"`` for every layer, by the published
-        pattern."""
-        return tuple("full" if i % self.period == 0 else "window"
-                     for i in range(self.n_layers))
+        """``"full"`` or ``"window"`` for every layer held, by the published
+        table."""
+        return tuple(self.layer_types[:self.n_layers])
+
+    @property
+    def layout(self) -> tuple:
+        """(leading dense layers, window layers a period, whole periods,
+        window layers left over), from the two per-layer tables: after the
+        leading layers whose MLP is dense, periods of window layers ended by
+        a full layer, then window layers alone."""
+        kinds = self.layer_kinds
+        mlps = tuple(self.mlp_layer_types[:self.n_layers])
+        if len(kinds) < self.n_layers or len(mlps) < self.n_layers:
+            raise ValueError(f"the per-layer tables hold {len(kinds)} and "
+                             f"{len(mlps)} of {self.n_layers} layers")
+        n_first = mlps.index("sparse") if "sparse" in mlps else len(mlps)
+        rest = kinds[n_first:]
+        if n_first > 1 or "dense" in mlps[n_first:] \
+                or kinds[:n_first] != ("full",) * n_first or "full" not in rest:
+            raise ValueError(
+                "the stack this file lays out is at most one leading full "
+                "layer with a dense MLP and then sparse layers of which one "
+                f"at least is full; the tables say {kinds}, {mlps}")
+        per = rest.index("full")
+        periods, n_tail = divmod(len(rest), per + 1)
+        if rest != (("window",) * per + ("full",)) * periods \
+                + ("window",) * n_tail:
+            raise ValueError(f"the layers after the dense ones are not "
+                             f"periods of {per} window layers and a full "
+                             f"one, then window layers: {rest}")
+        return n_first, per, periods, n_tail
 
     @property
     def stacks(self) -> tuple:
-        """(whole periods after layer 0, window layers left over)."""
-        return divmod(self.n_layers - 1, self.period)
+        """(whole periods after the leading dense layers, window layers left
+        over)."""
+        return self.layout[2:]
 
 
 def yarn_inv_freq(cfg: WindowedConfig) -> np.ndarray:
@@ -178,54 +275,61 @@ def yarn_rope(cfg: WindowedConfig, x: jax.Array,
               positions: jax.Array) -> jax.Array:
     """The full layers' rope. x: [B, T, H, D]: the leading ``rot`` lanes
     rotated as two halves by ``yarn_inv_freq``, cos and sin scaled by
-    ``attention_factor``; the other lanes pass."""
+    ``attention_factor``; the other lanes, where there are any, pass."""
     rot = int(cfg.head_dim * cfg.full_rotary_factor)
     angles = positions[..., None].astype(jnp.float32) * yarn_inv_freq(cfg)
     cos = (jnp.cos(angles) * cfg.yarn_attention_factor)[:, :, None, :]
     sin = (jnp.sin(angles) * cfg.yarn_attention_factor)[:, :, None, :]
     x1, x2 = jnp.split(x[..., :rot].astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate(
-        [(x1 * cos - x2 * sin).astype(x.dtype),
-         (x1 * sin + x2 * cos).astype(x.dtype), x[..., rot:]], axis=-1)
+    parts = [(x1 * cos - x2 * sin).astype(x.dtype),
+             (x1 * sin + x2 * cos).astype(x.dtype)]
+    if rot < x.shape[-1]:
+        parts.append(x[..., rot:])
+    return jnp.concatenate(parts, axis=-1)
 
 
 def init_params(key: jax.Array, cfg: WindowedConfig) -> Params:
-    """Matrices normal(0, fan_in^-1/2), norms 1."""
+    """Matrices normal(0, fan_in^-1/2), norms 1. ``first`` and ``tail`` are
+    there where the layout has such layers."""
     h, pd, d = cfg.hidden, cfg.param_dtype, cfg.head_dim
-    periods, n_tail = cfg.stacks
+    n_first, per, periods, n_tail = cfg.layout
     kv_out = cfg.n_kv_heads * d
     k_emb, k_first, k_win, k_full, k_tail, k_out = jax.random.split(key, 6)
 
     def stack(key, lead, heads, mlp):
         ks = iter(jax.random.split(key, 16))
+        gate = (("wg", (h, heads), h),) if cfg.attn_gate else ()
         layers = {name: _dense_init(next(ks), lead + shape, pd, fan_in)
                   for name, shape, fan_in in (
                       ("wq", (h, heads * d), h), ("wk", (h, kv_out), h),
-                      ("wv", (h, kv_out), h), ("wg", (h, heads), h),
+                      ("wv", (h, kv_out), h), *gate,
                       ("wo", (heads * d, h), heads * d), *mlp)}
         layers["attn_norm"] = jnp.ones(lead + (h,), pd)
         layers["mlp_norm"] = jnp.ones(lead + (h,), pd)
+        if cfg.qk_norm:
+            layers["q_norm"] = jnp.ones(lead + (d,), pd)
+            layers["k_norm"] = jnp.ones(lead + (d,), pd)
         return layers
 
     f, fs = cfg.moe_intermediate, cfg.shared_intermediate
     dense = (("w_gate", (h, cfg.intermediate), h),
              ("w_up", (h, cfg.intermediate), h),
              ("w_down", (cfg.intermediate, h), cfg.intermediate))
+    shared = (("shared_gate", (h, fs), h), ("shared_up", (h, fs), h),
+              ("shared_down", (fs, h), fs)) if fs else ()
     sparse = (("router", (h, cfg.n_experts), h),
               ("w_gate", (cfg.n_held, h, f), h),
               ("w_up", (cfg.n_held, h, f), h),
-              ("w_down", (cfg.n_held, f, h), f),
-              ("shared_gate", (h, fs), h), ("shared_up", (h, fs), h),
-              ("shared_down", (fs, h), fs))
+              ("w_down", (cfg.n_held, f, h), f), *shared)
     params = {
         "embed": _dense_init(k_emb, (cfg.vocab_size, h), pd, 1.0),
-        "first": stack(k_first, (), cfg.full_heads, dense),
-        "window": stack(k_win, (periods, cfg.period - 1), cfg.window_heads,
-                        sparse),
+        "window": stack(k_win, (periods, per), cfg.window_heads, sparse),
         "full": stack(k_full, (periods,), cfg.full_heads, sparse),
         "final_norm": jnp.ones((h,), pd),
         "lm_head": _dense_init(k_out, (h, cfg.vocab_size), pd, h),
     }
+    if n_first:
+        params["first"] = stack(k_first, (), cfg.full_heads, dense)
     if n_tail:
         params["tail"] = stack(k_tail, (n_tail,), cfg.window_heads, sparse)
     return params
@@ -245,7 +349,12 @@ def attention_block(cfg: WindowedConfig, kind: str, x: jax.Array, lp: Params,
         q = (y @ lp["wq"]).reshape(b, t, nh, d)
         k = (y @ lp["wk"]).reshape(b, t, nkv, d)
         v = (y @ lp["wv"]).reshape(b, t, nkv, d)
-        gate = jnp.dot(y, lp["wg"], preferred_element_type=jnp.float32)
+        if cfg.attn_gate:
+            gate = jnp.dot(y, lp["wg"], preferred_element_type=jnp.float32)
+    if cfg.qk_norm:
+        with jax.named_scope(f"{scope}.qknorm"):
+            q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
     with jax.named_scope(f"{scope}.rope"):
         if full:
             q, k = yarn_rope(cfg, q, positions), yarn_rope(cfg, k, positions)
@@ -255,23 +364,30 @@ def attention_block(cfg: WindowedConfig, kind: str, x: jax.Array, lp: Params,
     with jax.named_scope(f"{scope}.attn"):
         o = attention(q, k, v, window=None if full else cfg.window)
     with jax.named_scope(f"{scope}.out"):
-        o = (o.reshape(b, t, nh, d).astype(jnp.float32)
-             * jax.nn.sigmoid(gate)[..., None]).astype(x.dtype)
+        if cfg.attn_gate:
+            o = (o.reshape(b, t, nh, d).astype(jnp.float32)
+                 * jax.nn.sigmoid(gate)[..., None]).astype(x.dtype)
         return x + o.reshape(b, t, nh * d) @ lp["wo"]
 
 
 def moe_mlp(cfg: WindowedConfig, y: jax.Array, lp: Params):
     """The expert layer's MLP on normed tokens y: [N, H] -> ([N, H], stats):
     what the held experts give for the assignments routed to them, plus the
-    shared expert."""
+    shared expert where the model has one."""
     with jax.named_scope("moe.router"):
-        # DeepSeek-V3's router without its selection bias
-        selected, weights = deepseek.route(cfg, y, lp["router"], 0.0)
+        if cfg.router == "softmax":
+            selected, weights = hybrid.route(cfg, y, lp["router"])
+        elif cfg.router == "sigmoid":   # DeepSeek-V3's, no selection bias
+            selected, weights = deepseek.route(cfg, y, lp["router"], 0.0)
+        else:
+            raise ValueError(f"router {cfg.router!r}: sigmoid or softmax")
+    shared = None
+    if cfg.shared_intermediate:
+        shared = lambda y: swiglu(  # noqa: E731
+            y, lp["shared_gate"], lp["shared_up"], lp["shared_down"])
     return expert_mlp(
         y, selected, weights, lp["w_gate"], lp["w_up"], lp["w_down"],
-        n_held=cfg.n_held, expert_offset=cfg.expert_offset,
-        shared=lambda y: swiglu(y, lp["shared_gate"], lp["shared_up"],
-                                lp["shared_down"]))
+        n_held=cfg.n_held, expert_offset=cfg.expert_offset, shared=shared)
 
 
 def _cast(lp: Params, dtype) -> Params:
@@ -282,8 +398,8 @@ def _cast(lp: Params, dtype) -> Params:
 
 def hidden_states(params: Params, tokens: jax.Array, cfg: WindowedConfig):
     """tokens: [B, T] -> (final-normed states [B, T, H], the expert layers'
-    stats, a row a layer from layer 1 on). Master weights stay float32; a
-    layer's compute-dtype copy is made inside its scan step."""
+    stats, a row a sparse layer in the layers' order). Master weights stay
+    float32; a layer's compute-dtype copy is made inside its scan step."""
     with jax.named_scope("embed"):
         x = params["embed"][tokens].astype(cfg.dtype)
         b, t, h = x.shape
@@ -319,13 +435,14 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: WindowedConfig):
         x, full_stats = full(x, lps["full"])
         return x, {"window": window_stats, "full": full_stats}
 
-    x = first(x, params["first"])
+    if "first" in params:
+        x = first(x, params["first"])
     x, stats = lax.scan(period, x, {"window": params["window"],
                                     "full": params["full"]})
     if "tail" in params:
         x, tail_stats = lax.scan(window, x, params["tail"])
     with jax.named_scope("windowed.glue"):
-        # [periods, period - 1, ...] and [periods, ...] -> [layers, ...]
+        # [periods, windows a period, ...] and [periods, ...] -> [layers, ...]
         stats = jax.tree_util.tree_map(
             lambda win, full: jnp.concatenate(
                 [win, full[:, None]], axis=1).reshape(-1, *full.shape[1:]),

@@ -279,14 +279,27 @@ def _call(kernel, name: str, interpret: bool, grid: int, prefetch, operands,
 
 
 def _row_major(a):
-    """[R,H] -> [R, H / L, L], L the lane width: every row a run of tiles of
+    """[R,H] -> [R, C, L], L the lane width: every row a run of tiles of
     its own, which one DMA fetches. (The tiles of [R,H] interleave 8 or 16
-    rows, and Mosaic slices no single row out of them.) To XLA this reshape
-    is a relayout of every row, so it is made of arrays with a row a token;
-    rows over the bound go through ``_pack_rows``, by the tiles in use."""
+    rows, and Mosaic slices no single row out of them.) A row is whole tiles
+    where its H / L planes are a multiple of 8; where they are not (2,304 =
+    18 x 128: no power of two) C is the next multiple, 24, and the planes
+    past H / L hold zeros that a DMA carries and nothing reads
+    (``_real_planes``). To XLA this reshape is a relayout of every row, so it
+    is made of arrays with a row a token; rows over the bound go through
+    ``_pack_rows``, by the tiles in use."""
     r, h = a.shape
     lanes = 128 if h % 128 == 0 else h
-    return a.reshape(r, h // lanes, lanes)
+    a = a.reshape(r, h // lanes, lanes)
+    spare = -(h // lanes) % 8 if lanes == 128 else 0
+    return jnp.pad(a, ((0, 0), (0, spare), (0, 0))) if spare else a
+
+
+def _real_planes(planes, width: int, pieces: int = 1) -> int:
+    """Of a packed ref's planes (``_row_major``, or ``_planes`` with its
+    pieces a plane) those that hold a row of ``width``: the others pad the
+    row to whole tiles."""
+    return width // (planes.shape[2] * pieces)
 
 
 def _pack_kernel(n_tiles_ref, *refs):
@@ -295,7 +308,8 @@ def _pack_kernel(n_tiles_ref, *refs):
     @pl.when(pl.program_id(0) < n_tiles_ref[0])
     def _():
         lanes = out_ref.shape[2]
-        for q in range(out_ref.shape[1]):   # a store wants a static plane
+        # a store wants a static plane
+        for q in range(_real_planes(out_ref, rows_refs[0].shape[1])):
             piece, *more = (ref[:, q * lanes:(q + 1) * lanes]
                             for ref in rows_refs)
             if more:
@@ -391,8 +405,9 @@ def _gather_kernel(n_tiles_ref, token_ref, src_ref, valid_ref, *rest,
                     out_ref.dtype)
             return dots
 
-        dots = lax.fori_loop(0, planes.shape[1], plane,
-                             jnp.zeros((tile, 1), jnp.float32))
+        dots = lax.fori_loop(
+            0, _real_planes(planes, out_ref.shape[1], pieces), plane,
+            jnp.zeros((tile, 1), jnp.float32))
         if scaled:
             dots_ref[...] = dots
 
@@ -463,7 +478,8 @@ def _combine_kernel(first_ref, place_ref, rows_ref, held_ref, weights_ref,
             out_ref[:, _columns(q, i, pieces, lanes)] = total.astype(
                 out_ref.dtype)
 
-    lax.fori_loop(0, planes.shape[1], plane, None)
+    lax.fori_loop(0, _real_planes(planes, out_ref.shape[1], pieces), plane,
+                  None)
 
 
 def _combine_rows(weights, *rows, lay: GroupLayout, interpret: bool):

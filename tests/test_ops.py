@@ -688,12 +688,24 @@ def test_bf16_rows_move_bit_for_bit_through_the_interpreted_kernels():
             assert np.max(np.abs(p - q)) <= 1e-2 * np.max(np.abs(p)), name
 
 
-def test_grouped_kernels_compile_for_v5e(v5e_device):
-    """The three kernels at the kanana cell's size: 49,152 assignments of
-    which any number may be held, 16 experts of 2,048 x 768."""
-    tile = gm.choose_tile(49152, 16)
-    m = gm.bound_rows(49152, 16, tile)
-    assert (tile, m) == (256, 53248)
+# the cells' expert layers that differ in kind: (assignments, hidden, expert
+# width, bound). kanana's powers of two; Mellum2's 2,304 = 18 x 128 and 896 =
+# 7 x 128, at which PR 41's step did not compile until a packed row was
+# whole sublane tiles (``gm._row_major``)
+_CELL_EXPERTS = {"kanana": (8192 * 6, 2048, 768, 53248),
+                 "mellum": (8192 * 8, 2304, 896, 69632)}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_EXPERTS))
+def test_grouped_kernels_compile_for_v5e(v5e_device, cell):
+    """The three kernels at a cell's size: 49,152 or 65,536 assignments of
+    which any number may be held, 16 experts of 2,048 x 768 or 2,304 x
+    896."""
+    a, h, f, bound = _CELL_EXPERTS[cell]
+    tile = gm.choose_tile(a, 16)
+    m = gm.bound_rows(a, 16, tile)
+    assert (tile, m) == (256, bound)
+    assert gm.kernels_take((16, h, f), tile, jnp.bfloat16)
     sharding = jax.sharding.SingleDeviceSharding(v5e_device)
     s = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dtype, sharding=sharding)
@@ -706,21 +718,25 @@ def test_grouped_kernels_compile_for_v5e(v5e_device):
         return jax.grad(loss, (0, 1, 2))(x, w_in, w_out)
 
     text = jax.jit(grads).lower(
-        s((m, 2048), jnp.bfloat16), s((16, 2048, 768), jnp.bfloat16),
-        s((16, 768, 2048), jnp.bfloat16), s((m // tile,), jnp.int32),
+        s((m, h), jnp.bfloat16), s((16, h, f), jnp.bfloat16),
+        s((16, f, h), jnp.bfloat16), s((m // tile,), jnp.int32),
         s((1,), jnp.int32)).compile().as_text()
     assert {"moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"} <= set(
         re.findall(r"%(moe_gmm_\w+?)(?:\.\d+)? =", text))
 
 
-def test_rows_kernels_compile_for_v5e(v5e_device):
-    """``dispatch``, ``combine`` and their transposes at the kanana cell's
-    size: 8,192 tokens of 6 assignments, 16 experts held, rows of 2,048 in
-    tiles of 256 inside a bound of 53,248."""
-    n, k, groups, h = 8192, 6, 16, 2048
+@pytest.mark.parametrize("cell", sorted(_CELL_EXPERTS))
+def test_rows_kernels_compile_for_v5e(v5e_device, cell):
+    """``dispatch``, ``combine`` and their transposes at a cell's size:
+    8,192 tokens of 6 or 8 assignments, 16 experts held, rows of 2,048 (16
+    planes of 128 lanes) or 2,304 (18, packed as 24) in tiles of 256 inside
+    a bound of 53,248 or 69,632."""
+    a, h, _, bound = _CELL_EXPERTS[cell]
+    n, groups = 8192, 16
+    k = a // n
     tile = gm.choose_tile(n * k, groups)
     m = gm.bound_rows(n * k, groups, tile)
-    assert (tile, m) == (256, 53248)
+    assert (tile, m) == (256, bound)
     assert gm.rows_kernels_take(n, h, tile, jnp.bfloat16)
     sharding = jax.sharding.SingleDeviceSharding(v5e_device)
     s = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731

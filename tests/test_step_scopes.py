@@ -59,7 +59,18 @@ MODELS = {
                      "windowed.glue", "dense.mlp", "swa.qkv", "swa.rope",
                      "swa.attn/attn.dense", "swa.out", "full.qkv",
                      "full.rope", "full.attn/attn.dense", "full.out"}),
+    # the same file laid out from Mellum2's tables: q/k norms, no gate, no
+    # dense layer, no shared expert
+    "windowed_mellum": (
+        windowed, windowed.WindowedConfig.tiny_mellum2(),
+        _SHARED | (_MOE - {"moe.shared"}) | _CHUNKED | {
+            "windowed.glue", "swa.qkv", "swa.qknorm", "swa.rope",
+            "swa.attn/attn.dense", "swa.out", "full.qkv", "full.qknorm",
+            "full.rope", "full.attn/attn.dense", "full.out"}),
 }
+# scopes a model's step must NOT write: what its configuration has not
+ABSENT = {"windowed_mellum": {"moe.shared", "dense.mlp"},
+          "windowed": {"swa.qknorm", "full.qknorm"}}
 
 
 def _equations(jaxpr, stack=""):
@@ -96,6 +107,7 @@ def test_every_line_of_the_train_step_is_under_a_scope(model):
                              jnp.zeros((2, 64), jnp.int32))
     assert not bare, "under no scope of the program:\n" + "\n".join(bare)
     assert want <= seen, sorted(want - seen)
+    assert not ABSENT.get(model, set()) & seen
 
 
 def test_the_walk_finds_a_line_left_bare():
