@@ -51,9 +51,12 @@ with RMSNorm (``llama.rms_norm``); a layer's MLP is a dense SwiGLU of
   that turn ``beta_fast`` and ``beta_slow`` times over the original context,
   cos and sin times ``attention_factor``), the rest unrotated. Scores
   ``q·k / sqrt(d)`` masked to ``0 <= i - j`` (full) or ``0 <= i - j <
-  window`` (window), float32 softmax, through ``llama.attention``: on a TPU
-  the fused kernels, for a window the band kernels that skip the tiles
-  outside it (``ops/flash_attention.py``), the dense masked form elsewhere.
+  window`` (window), float32 softmax (``attend``): on a TPU the fused
+  kernels, for a window the band kernels that skip the tiles outside it
+  (``ops/flash_attention.py``), which read q and k head-major as one pass of
+  ``ops/qk_layout.py`` leaves them — norm, rope and the turn from the
+  projections' token-major layout done in float32 in registers; elsewhere
+  the norm and the rope as float32 passes and ``llama.attention``.
   With ``attn_gate``, ``o_h <- sigmoid(g_h) · o_h`` per head; ``·W_o``. No
   biases.
 - **Experts.** ``router="sigmoid"``: ``s = sigmoid(y·W_r)`` in float32 over
@@ -85,9 +88,12 @@ scanned after. So the published full layer stands first in Laguna's period
 of four and last in Mellum2's, and both are the same scan. One compiled body
 of each kind whatever the depth. Each layer is recomputed in the backward
 pass (``jax.checkpoint``) from its input and what ``SAVED_NAMES`` names: the
-attention kernel's output and log-sum-exp, and the experts' integer routing
-layout. bf16 compute; float32 master weights, router, norms' arithmetic,
-softmax and loss (``models/chunked_loss.py``).
+attention kernel's output and log-sum-exp, its head-major q and k (0.3 GB
+of Mellum2's four layers at 8,192 tokens, 0.75 GB of Laguna's five: the
+recomputation then runs no layout pass, and without q/k norms no ``y·W_q``
+either), and the experts' integer routing layout. bf16 compute; float32
+master weights, router, norms' arithmetic, softmax and loss
+(``models/chunked_loss.py``).
 
 ``make_train_step``'s step also returns ``stats``, a row an expert layer in
 the layers' order, of what ``experts.expert_mlp`` counts. Named scopes:
@@ -111,21 +117,31 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from brpc_tpu.models import deepseek, hybrid
 from brpc_tpu.models.chunked_loss import chunked_next_token_loss
 from brpc_tpu.models.experts import expert_mlp, swiglu
-from brpc_tpu.models.llama import _dense_init, attention, rms_norm, rope
+from brpc_tpu.models.llama import (_dense_init, attention, dense_attention,
+                                   rms_norm, rope)
 from brpc_tpu.models.train_step import apply_updates
 from brpc_tpu.ops import grouped_matmul as gm
-from brpc_tpu.ops.flash_attention import RESIDUAL_NAMES
+from brpc_tpu.ops import qk_layout
+from brpc_tpu.ops.flash_attention import (RESIDUAL_NAMES,
+                                          flash_attention_head_major,
+                                          supported as flash_supported)
+from brpc_tpu.ops.lowered import count_lowering
 
 Params = Dict[str, Any]
 
 _FLOAT32_LEAVES = ("router",)       # never cast to the compute dtype
 
+# ``checkpoint_name``s of the head-major q and k that ``qk_head_major``
+# returns: the attention kernels' residuals, so a layer that keeps them runs
+# no layout pass in its recomputation.
+QK_NAMES = ("qk_head_major_q", "qk_head_major_k")
 # What a layer keeps across its recomputation beside its input.
-SAVED_NAMES = (*RESIDUAL_NAMES, gm.LAYOUT_NAME)
+SAVED_NAMES = (*RESIDUAL_NAMES, *QK_NAMES, gm.LAYOUT_NAME)
 
 
 _LAGUNA_LAYERS = ("full", "window", "window", "window") * 10
@@ -335,6 +351,160 @@ def init_params(key: jax.Array, cfg: WindowedConfig) -> Params:
     return params
 
 
+def _rotary(cfg: WindowedConfig, full: bool) -> tuple:
+    """A kind of layer's rope: (the lanes of a head it turns, their ``rot /
+    2`` inverse frequencies, what its cos and sin are scaled by)."""
+    if full:
+        return (int(cfg.head_dim * cfg.full_rotary_factor),
+                yarn_inv_freq(cfg), cfg.yarn_attention_factor)
+    half = cfg.head_dim // 2        # ``llama.rope``'s, by its own arithmetic
+    return cfg.head_dim, cfg.window_rope_theta ** (
+        -jnp.arange(0, half, dtype=jnp.float32) / half), 1.0
+
+
+def _normed_and_turned(cfg: WindowedConfig, kind: str, q: jax.Array,
+                       k: jax.Array, norms, positions: jax.Array) -> tuple:
+    """The plain form of a layer's q and k: [B, T, H·d] each -> ([B, T, H +
+    ``n_kv_heads``, d], H): q's heads and then k's, each head normed
+    (``norms``: the layer's ``q_norm`` and ``k_norm``, or None without
+    ``qk_norm``) and turned by the rope of the layer's kind, each a float32
+    pass. The two go through the rope as one array: a program holds this
+    form beside the kernels until it is lowered
+    (``lax.platform_dependent``), and every ``jax.numpy`` op traced is
+    set-up."""
+    (b, t, _), d = q.shape, cfg.head_dim
+    scope = "full" if kind == "full" else "swa"
+    q = count_lowering(q, "qk_layout_plain_lowerings")
+    q, k = q.reshape(b, t, -1, d), k.reshape(b, t, -1, d)
+    if norms is not None:
+        with jax.named_scope(f"{scope}.qknorm"):
+            q = rms_norm(q, norms[0], cfg.norm_eps)
+            k = rms_norm(k, norms[1], cfg.norm_eps)
+    nh, x = q.shape[2], jnp.concatenate([q, k], axis=2)
+    with jax.named_scope(f"{scope}.rope"):
+        if kind == "full":
+            return yarn_rope(cfg, x, positions), nh
+        return rope(x, positions, cfg.window_rope_theta), nh
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _plain_head_major(cfg, kind, q, k, norms, positions):
+    """Under ``jax.jit``: Laguna's layer 0 and its full layers are one
+    kind at one shape, and the second trace finds the first's."""
+    x, nh = _normed_and_turned(cfg, kind, q, k, norms, positions)
+    with jax.named_scope("attn.layout"):
+        x = x.transpose(0, 2, 1, 3)
+    return x[:, :nh], x[:, nh:]
+
+
+def _kernel_operands(cfg, kind, norms, positions):
+    """(cos table, sin table, the norms' weights or None, lanes turned)."""
+    full = kind == "full"
+    rot, inv_freq, scale = _rotary(cfg, full)
+    weights = None
+    if norms is not None:
+        with jax.named_scope("full.qknorm" if full else "swa.qknorm"):
+            weights = jnp.stack(norms).astype(jnp.float32)
+    return (*qk_layout.rotation_tables(positions, inv_freq, cfg.head_dim,
+                                       scale), weights, rot)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def qk_head_major(cfg: WindowedConfig, kind: str, q: jax.Array, k: jax.Array,
+                  norms, positions: jax.Array) -> tuple:
+    """q: [B, T, H·d], k: [B, T, ``n_kv_heads``·d] as the projections leave
+    them (operands ``qk_layout.supported``) -> (q [B, H, T, d], k [B,
+    ``n_kv_heads``, T, d]) as the attention kernels read them, normed and
+    turned. Chosen by the platform being lowered for, in each pass on its
+    own (as ``ops/causal_conv.py`` chooses): ``ops/qk_layout.py``'s kernels
+    for TPU, one pass over q and k; elsewhere the plain form and its
+    transposes. No transform differentiates through the choice: the
+    backward pass is the backward kernel, or the plain form's own VJP.
+    ``qk_layout_kernel_lowerings`` / ``qk_layout_plain_lowerings`` count
+    which one each lowered program holds."""
+    def kernel(q, k, norms, positions):
+        cos, sin, weights, rot = _kernel_operands(cfg, kind, norms, positions)
+        return qk_layout.forward(
+            count_lowering(q, "qk_layout_kernel_lowerings"), k, cos, sin,
+            weights, rot // 2, cfg.norm_eps)
+
+    return lax.platform_dependent(
+        q, k, norms, positions, tpu=kernel,
+        default=functools.partial(_plain_head_major, cfg, kind))
+
+
+def _qk_head_major_fwd(cfg, kind, q, k, norms, positions):
+    out = qk_head_major(cfg, kind, q, k, norms, positions)
+    out = tuple(checkpoint_name(x, name) for x, name in zip(out, QK_NAMES))
+    return out, (q, k, norms, positions)
+
+
+def _qk_head_major_bwd(cfg, kind, residuals, cotangents):
+    def kernel(dq, dk, q, k, norms, positions):
+        cos, sin, weights, rot = _kernel_operands(cfg, kind, norms, positions)
+        dq, dk, dw = qk_layout.backward(dq, dk, q, k, cos, sin, weights,
+                                        rot // 2, cfg.norm_eps)
+        return dq, dk, None if norms is None else tuple(
+            dw[i].astype(w.dtype) for i, w in enumerate(norms))
+
+    def plain(dq, dk, q, k, norms, positions):
+        return jax.vjp(lambda q, k, norms: _plain_head_major(
+            cfg, kind, q, k, norms, positions), q, k, norms)[1]((dq, dk))
+
+    return (*lax.platform_dependent(*cotangents, *residuals, tpu=kernel,
+                                    default=plain), None)
+
+
+qk_head_major.defvjp(_qk_head_major_fwd, _qk_head_major_bwd)
+
+
+def attention_head_major(q: jax.Array, k: jax.Array, v: jax.Array, window):
+    """``llama.attention`` (causal, with its counters) for q: [B, Hq, T, D]
+    and k: [B, Hkv, T, D] head-major already and bf16 operands the fused
+    kernels take: they read q and k as they are; the dense form takes them
+    token-major again."""
+    def dense(q, k, v):
+        with jax.named_scope("attn.dense"):
+            return dense_attention(
+                count_lowering(q.transpose(0, 2, 1, 3),
+                               "attn_dense_lowerings"),
+                k.transpose(0, 2, 1, 3), v, window=window)
+
+    def kernel(q, k, v):
+        return flash_attention_head_major(
+            count_lowering(q, "attn_kernel_lowerings"), k, v, window=window)
+
+    return lax.platform_dependent(q, k, v, tpu=kernel, default=dense)
+
+
+def attend(cfg: WindowedConfig, kind: str, q: jax.Array, k: jax.Array,
+           v: jax.Array, norms, positions: jax.Array) -> jax.Array:
+    """Attention of a ``"full"`` or ``"window"`` layer on q: [B, T, H·d] and
+    k: [B, T, ``n_kv_heads``·d] as the projections leave them and v: [B, T,
+    ``n_kv_heads``, d] -> [B, T, H·d]: each head of q and k normed and
+    turned by the rope of the layer's kind, then ``llama.attention``'s
+    contract. Operands that both the q/k layout kernels and the attention
+    kernels take go head-major through ``qk_head_major`` to
+    ``attention_head_major``; everything else runs the plain form and
+    ``llama.attention`` from token-major operands."""
+    full = kind == "full"
+    scope = "full" if full else "swa"
+    (b, t, _), d = q.shape, cfg.head_dim
+    window = None if full else cfg.window
+    if (v.dtype == jnp.bfloat16
+            and qk_layout.supported(q.shape, k.shape, q.dtype, d,
+                                    _rotary(cfg, full)[0])
+            and flash_supported((b, t, q.shape[2] // d, d), v.shape, q.dtype,
+                                window=window)):
+        with jax.named_scope(f"{scope}.rope"):
+            q, k = qk_head_major(cfg, kind, q, k, norms, positions)
+        with jax.named_scope(f"{scope}.attn"):
+            return attention_head_major(q, k, v, window)
+    x, nh = _normed_and_turned(cfg, kind, q, k, norms, positions)
+    with jax.named_scope(f"{scope}.attn"):
+        return attention(x[:, :, :nh], x[:, :, nh:], v, window=window)
+
+
 def attention_block(cfg: WindowedConfig, kind: str, x: jax.Array, lp: Params,
                     positions: jax.Array) -> jax.Array:
     """The attention block of a ``"full"`` or ``"window"`` layer with its
@@ -346,23 +516,13 @@ def attention_block(cfg: WindowedConfig, kind: str, x: jax.Array, lp: Params,
     nkv, d = cfg.n_kv_heads, cfg.head_dim
     with jax.named_scope(f"{scope}.qkv"):
         y = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = (y @ lp["wq"]).reshape(b, t, nh, d)
-        k = (y @ lp["wk"]).reshape(b, t, nkv, d)
+        q, k = y @ lp["wq"], y @ lp["wk"]
         v = (y @ lp["wv"]).reshape(b, t, nkv, d)
         if cfg.attn_gate:
             gate = jnp.dot(y, lp["wg"], preferred_element_type=jnp.float32)
-    if cfg.qk_norm:
-        with jax.named_scope(f"{scope}.qknorm"):
-            q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
-            k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
-    with jax.named_scope(f"{scope}.rope"):
-        if full:
-            q, k = yarn_rope(cfg, q, positions), yarn_rope(cfg, k, positions)
-        else:
-            q = rope(q, positions, cfg.window_rope_theta)
-            k = rope(k, positions, cfg.window_rope_theta)
-    with jax.named_scope(f"{scope}.attn"):
-        o = attention(q, k, v, window=None if full else cfg.window)
+    o = attend(cfg, kind, q, k, v,
+               (lp["q_norm"], lp["k_norm"]) if cfg.qk_norm else None,
+               positions)
     with jax.named_scope(f"{scope}.out"):
         if cfg.attn_gate:
             o = (o.reshape(b, t, nh, d).astype(jnp.float32)

@@ -34,7 +34,10 @@ kernels work head-major ([B, H, T, D]): Mosaic wants the last two block
 dimensions to be (a multiple of 8, a multiple of 128) or the whole array
 dimension, and a one-head block of the [B, T, H, D] layout has 1 against H
 there. The wrapper pays the transposes for it, outside the custom VJP, so
-the residuals are kept head-major and the backward pass repeats none. Per-row
+the residuals are kept head-major and the backward pass repeats none; a
+caller whose q and k are head-major already (ops/qk_layout.py writes them
+so) enters at ``flash_attention_head_major``, which turns v and the output
+alone. Per-row
 statistics travel as [B, H, T/BLOCK, 1, BLOCK]: a row vector per tile, whole
 in its last two dimensions whatever the block.
 
@@ -524,6 +527,16 @@ def default_blocks(t: int, window=None, block_q=None, block_k=None) -> tuple:
                  for backward in (False, True))
 
 
+def _checked_blocks(t: int, causal: bool, window, block_q, block_k) -> tuple:
+    if window is not None and not (causal and window >= 1):
+        raise ValueError(f"a window of {window} needs causal attention and "
+                         f"at least one position")
+    blocks = default_blocks(t, window, block_q, block_k)
+    if any(t % block for pair in blocks for block in pair):
+        raise ValueError(f"seq {t} must divide blocks {blocks}")
+    return blocks
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "block_q", "block_k", "interpret", "window"),
@@ -544,14 +557,26 @@ def flash_attention(
     (and from whether there is a ``window``, which needs ``causal``);
     ``block_q`` / ``block_k`` override them in both passes (tests)."""
     b, t, hq, _ = q.shape
-    if window is not None and not (causal and window >= 1):
-        raise ValueError(f"a window of {window} needs causal attention and "
-                         f"at least one position")
-    blocks = default_blocks(t, window, block_q, block_k)
-    if any(t % block for pair in blocks for block in pair):
-        raise ValueError(f"seq {t} must divide blocks {blocks}")
+    blocks = _checked_blocks(t, causal, window, block_q, block_k)
     with jax.named_scope("attn.layout"):                    # [B, H, T, D]
         q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     out = _attend(q, k, v, causal, blocks, interpret, window)
+    with jax.named_scope("attn.layout"):
+        return out.transpose(0, 2, 1, 3).reshape(b, t, hq * v.shape[3])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
+def flash_attention_head_major(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                               interpret: bool = False,
+                               window: int | None = None) -> jax.Array:
+    """Causal ``flash_attention`` for a caller whose q and k are head-major
+    already (ops/qk_layout.py): q: [B,Hq,T,Dqk], k: [B,Hkv,T,Dqk], v:
+    [B,T,Hkv,Dv] -> [B,T,Hq*Dv]. Neither q nor k is transposed, in either
+    pass; v and the output go the way they go there."""
+    b, hq, t, _ = q.shape
+    blocks = _checked_blocks(t, True, window, None, None)
+    with jax.named_scope("attn.layout"):
+        v = v.transpose(0, 2, 1, 3)
+    out = _attend(q, k, v, True, blocks, interpret, window)
     with jax.named_scope("attn.layout"):
         return out.transpose(0, 2, 1, 3).reshape(b, t, hq * v.shape[3])

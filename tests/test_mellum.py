@@ -28,12 +28,13 @@ import pytest
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark"))
 
+import lowered_step  # noqa: E402
 import reference  # noqa: E402
 import reference_mellum  # noqa: E402
 
-from brpc_tpu import obs  # noqa: E402
 from brpc_tpu.models import hybrid, windowed  # noqa: E402
 from brpc_tpu.ops import grouped_matmul as gm  # noqa: E402
+from brpc_tpu.ops import qk_layout as ql  # noqa: E402
 
 fa = importlib.import_module("brpc_tpu.ops.flash_attention")
 
@@ -198,10 +199,11 @@ def test_the_ropes_the_mask_and_the_norms_are_on_the_right_layers(
         real_norm(x, w, eps))[1])
     jax.eval_shape(lambda p, t: windowed.loss_fn(p, t, TINY32)[0], params,
                    tokens[0])
-    assert calls[:5] == [("norm", 32), ("norm", 32), ("rope", 4, 500000.0),
-                         ("rope", 2, 500000.0), ("attn", 4, 16)]
-    assert calls[5:] == [("norm", 32), ("norm", 32), ("yarn", 4),
-                         ("yarn", 2), ("attn", 4, None)]
+    # the plain form turns q's heads and k's as one array (PR 43)
+    assert calls[:4] == [("norm", 32), ("norm", 32),
+                         ("rope", 4 + 2, 500000.0), ("attn", 4, 16)]
+    assert calls[4:] == [("norm", 32), ("norm", 32), ("yarn", 4 + 2),
+                         ("attn", 4, None)]
 
 
 @pytest.mark.parametrize("path", ["dense", "kernels_interpreted"])
@@ -258,23 +260,36 @@ KERNEL = dataclasses.replace(TINY, hidden=256, full_heads=2, window_heads=2,
 
 
 def test_every_kernel_interpreted_follows_the_reference(monkeypatch):
-    """bf16 through the band and causal attention kernels and the expert
-    layer's six, all by the Pallas interpreter: loss and every leaf's
-    gradient inside what bf16 allows, the policy's names saved."""
+    """bf16 through the q/k layout kernels (norm, rope and transpose in one
+    pass), the band and causal attention kernels and the expert layer's six,
+    all by the Pallas interpreter: loss and every leaf's gradient inside
+    what bf16 allows, the policy's names saved."""
     choose = gm._choose
     monkeypatch.setattr(
         gm, "_choose", lambda kernel, plain, taken, counter, _, *operands:
         choose(kernel, plain, taken, counter, True, *operands))
-    windows = []
-    monkeypatch.setattr(windowed, "attention", lambda q, k, v, window: (
-        windows.append(window),
-        fa.flash_attention(q, k, v, window=window, interpret=True))[1])
+    windows, layouts = [], []
+    # the branch a program lowered for TPU holds, its kernels interpreted
+    monkeypatch.setattr(windowed.lax, "platform_dependent",
+                        lambda *operands, tpu, default: tpu(*operands))
+    monkeypatch.setattr(
+        windowed, "flash_attention_head_major", lambda q, k, v, window: (
+            windows.append(window), fa.flash_attention_head_major(
+                q, k, v, window=window, interpret=True))[1])
+    for name in ("forward", "backward"):
+        real = getattr(ql, name)
+        monkeypatch.setattr(ql, name, lambda *operands, real=real, name=name: (
+            layouts.append(name), real(*operands, interpret=True))[1])
     p = jax.jit(lambda k: reference_mellum.mellum_init(k, KERNEL_SIZES))(
         reference.seed_key(SEED + 2))
     t = reference.token_batches(SEED, 1, 1, 128, 256)[0]
     (loss, stats), grads = jax.jit(jax.value_and_grad(
         lambda p, t: windowed.loss_fn(p, t, KERNEL), has_aux=True))(p, t)
     assert set(windows) == {64, None}
+    # a kind of layer: the pass, the pass again for the VJP's residuals
+    # (its results are saved by name, so no third in the recomputation),
+    # and the backward kernel
+    assert sorted(layouts) == ["backward"] * 2 + ["forward"] * 4
     (want_loss, _), want = jax.jit(jax.value_and_grad(
         lambda p, t: reference_mellum.mellum_loss(p, t, KERNEL_SIZES),
         has_aux=True))(p, t)
@@ -438,24 +453,26 @@ def _abstract_step(cfg, batch, seq):
         jax.ShapeDtypeStruct((batch, seq), jnp.int32))
 
 
-def test_the_cells_program_lowered_for_tpu_holds_every_kernel():
+@pytest.fixture(scope="module")
+def lowered():
+    return lowered_step.lowered_for_tpu(lambda: _abstract_step(CELL, 1, 8192))
+
+
+def test_the_cells_program_lowered_for_tpu_holds_every_kernel(lowered):
     """At the cell's shapes (4 layers, 1 x 8,192 tokens, 16 of 64 experts of
     2,304 x 896) the program lowered for TPU holds the band kernels at a
     window of 1,024, the causal kernels and the expert layer's six, counts a
     kernel lowering a kind of layer and no dense attention, no plain
     product and no plain row movement."""
-    obs.set_enabled(True)
-    names = ("attn_kernel_lowerings", "attn_dense_lowerings",
-             "moe_grouped_lowerings", "moe_rows_lowerings")
-    before = [obs.counter(n).get_value() for n in names]
-    traced = _abstract_step(CELL, 1, 8192)
+    traced, text, counts = lowered
     fwd, bwd = fa.default_blocks(8192, 1024)
     assert fa.band_calls(traced.jaxpr.jaxpr) == {
         ("attn_band_fwd", *fwd), ("attn_band_bwd", *bwd)}
-    text = traced.lower(lowering_platforms=("tpu",)).as_text()
-    counts = [obs.counter(n).get_value() - b for n, b in zip(names, before)]
     # window and full layers have one shape of heads: one lowering serves both
-    assert counts[:2] == [1, 0] and counts[2] > 0 and counts[3] > 0
+    assert counts["attn_kernel_lowerings"] == 1
+    assert counts["attn_dense_lowerings"] == 0
+    assert counts["moe_grouped_lowerings"] > 0
+    assert counts["moe_rows_lowerings"] > 0
     found = set(re.findall(r"(attn_band_\w+|attn_flash_\w+|moe_gmm_\w+|"
                            r"moe_rows_\w+)", text))
     assert {"attn_band_fwd", "attn_band_bwd", "attn_flash_fwd",
@@ -468,6 +485,42 @@ def test_the_cells_program_lowered_for_tpu_holds_every_kernel():
     assert gm.kernels_take((16, 896, 2304), tile, jnp.bfloat16)
     assert gm.rows_kernels_take(8192, 2304, tile, jnp.bfloat16)
     assert gm.bound_rows(8192 * 8, 16, tile) == 69632
+
+
+def test_q_and_k_reach_the_attention_kernels_in_one_pass(lowered):
+    """The program lowered for TPU holds the q/k layout kernel's two bodies,
+    counted once (window and full layers have one shape of heads: one
+    lowering serves both) and the plain form never; under ``*.rope``
+    no float32 [1, 8192, H, 128] is left (the norms and ropes were five
+    such passes a layer), and under ``attn.layout`` only v, the output and
+    their cotangents are turned: v in the forward pass and again in the
+    recomputation of each kind of layer, dv once, the output once and dO
+    once, where q and k were turned beside v and dq, dk beside dv."""
+    _, text, counts = lowered
+    assert {"qk_layout_fwd", "qk_layout_bwd"} <= set(
+        re.findall(r"qk_layout_\w+", text))
+    assert counts["qk_layout_kernel_lowerings"] == 1
+    assert counts["qk_layout_plain_lowerings"] == 0
+    assert lowered_step.float32_heads_under_rope(text) == []
+    assert lowered_step.layout_transposes(text) == {
+        "1x8192x4x128xbf16": 4, "1x4x8192x128xbf16": 2,       # v, dv
+        "1x32x8192x128xbf16": 4, "1x8192x32x128xbf16": 2}     # the output, dO
+
+
+def test_the_steps_pallas_call_sites_are_pinned(lowered):
+    """Every Pallas call site of the lowered step is traced and lowered by
+    Mosaic in every run's set-up, warm or cold: 0.05–0.1 s a site (ISSUE
+    43). The step held 42 before the q/k layout kernels and holds 45 with
+    them: the forward kernel a kind of layer (its output is saved by name,
+    so the recomputation runs none) and one backward kernel, which the two
+    kinds, alike in every shape, share. A PR that adds sites sees here what
+    set-up it is spending."""
+    _, text, _ = lowered
+    sites = lowered_step.pallas_sites(text)
+    assert len(sites) - sum(s.startswith("qk_layout") for s in sites) == 42
+    assert sorted(s for s in sites if s.startswith("qk_layout")) == [
+        "qk_layout_bwd", "qk_layout_fwd", "qk_layout_fwd"]
+    assert len(sites) == 45
 
 
 def test_step_names_its_scopes_and_no_shared_expert():

@@ -1277,3 +1277,39 @@ def test_the_pin_sees_a_gather_of_scalars(monkeypatch):
     found = _scalar_moves(jaxpr, assignments)
     assert sorted({name for name, _ in found}) == ["gather", "scatter-add"]
     assert {count for _, count in found} == {assignments}
+
+
+# -- PR 43: q and k from the projections to the attention kernels in one pass -
+
+from brpc_tpu.ops import qk_layout  # noqa: E402
+
+_QK_CALLS = re.compile(r"%(qk_layout_\w+?)(?:\.\d+)? = [^\n]*custom-call\(")
+
+
+@pytest.mark.parametrize("heads,kv_heads,rot,norm", [
+    (64, 8, 128, False), (48, 8, 64, False), (32, 4, 128, True)],
+    ids=["laguna_window", "laguna_full", "mellum"])
+def test_qk_layout_kernels_compile_for_v5e_at_the_cells_sizes(
+        v5e_device, heads, kv_heads, rot, norm):
+    """1 x 8,192 positions of a layer's q and k, heads of 128, bf16: Mosaic
+    and XLA:TPU take both kernels, one call each for q and k together; what
+    XLA keeps beside them is no float32 array of q's size."""
+    sharding = jax.sharding.SingleDeviceSharding(v5e_device)
+    s = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=sharding)
+
+    @jax.jit
+    def both(q, k, cos, sin, weights, dq, dk):
+        return (*qk_layout.forward(q, k, cos, sin, weights, rot // 2, 1e-6),
+                *qk_layout.backward(dq, dk, q, k, cos, sin, weights, rot // 2,
+                                    1e-6))
+
+    table = s(1, 8192, 128, dtype=jnp.float32)
+    text = both.trace(
+        s(1, 8192, heads * 128), s(1, 8192, kv_heads * 128), table, table,
+        s(2, 128, dtype=jnp.float32) if norm else None,
+        s(1, heads, 8192, 128), s(1, kv_heads, 8192, 128)
+    ).lower().compile().as_text()
+    assert sorted(_QK_CALLS.findall(text)) == ["qk_layout_bwd",
+                                               "qk_layout_fwd"]
+    assert re.findall(rf"f32\[1,(8192,{heads}|{heads},8192),128\]", text) == []

@@ -32,10 +32,10 @@ import pytest
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark"))
 
+import lowered_step  # noqa: E402
 import reference  # noqa: E402
 import reference_swa  # noqa: E402
 
-from brpc_tpu import obs  # noqa: E402
 from brpc_tpu.models import deepseek, experts, llama, windowed  # noqa: E402
 from brpc_tpu.ops import grouped_matmul as gm  # noqa: E402
 
@@ -179,7 +179,8 @@ def test_the_two_ropes():
 def test_the_ropes_and_the_mask_are_on_the_right_layers(monkeypatch, params,
                                                         tokens):
     """A window layer calls the plain rope and attention with the window; a
-    full layer (layer 0 too) calls the yarn rope and attention with none."""
+    full layer (layer 0 too) calls the yarn rope and attention with none.
+    The plain form turns q's heads and k's as one array (PR 43)."""
     calls = []
     real = windowed.attention
     monkeypatch.setattr(windowed, "rope", lambda x, p, theta: (
@@ -191,10 +192,9 @@ def test_the_ropes_and_the_mask_are_on_the_right_layers(monkeypatch, params,
         real(q, k, v, window=window))[1])
     jax.eval_shape(lambda p, t: windowed.loss_fn(p, t, TINY32)[0], params,
                    tokens[0])
-    first, window, full = calls[:3], calls[3:6], calls[6:9]
-    assert first == full == [("yarn", 4), ("yarn", 2), ("attn", 4, None)]
-    assert window == [("rope", 6, 10000.0), ("rope", 2, 10000.0),
-                      ("attn", 6, 16)]
+    first, window, full = calls[:2], calls[2:4], calls[4:6]
+    assert first == full == [("yarn", 4 + 2), ("attn", 4, None)]
+    assert window == [("rope", 6 + 2, 10000.0), ("attn", 6, 16)]
 
 
 @pytest.mark.parametrize("cfg,loss_tol,leaf_tol", [
@@ -370,30 +370,66 @@ def _abstract_step(cfg, batch, seq):
         jax.ShapeDtypeStruct((batch, seq), jnp.int32))
 
 
-def test_the_cells_program_lowered_for_tpu_holds_every_kernel():
+@pytest.fixture(scope="module")
+def lowered():
+    return lowered_step.lowered_for_tpu(lambda: _abstract_step(CELL, 1, 8192))
+
+
+def test_the_cells_program_lowered_for_tpu_holds_every_kernel(lowered):
     """At the cell's shapes (5 layers, 1 x 8,192 tokens, 16 of 256 experts)
     the program lowered for TPU holds the band kernels (window layers), the
     causal kernels (full layers) and the expert layer's, counts a kernel
     lowering for each kind of layer and no dense attention."""
-    obs.set_enabled(True)
-    names = ("attn_kernel_lowerings", "attn_dense_lowerings")
-    before = [obs.counter(n).get_value() for n in names]
-    traced = _abstract_step(CELL, 1, 8192)
+    traced, text, counts = lowered
     # the band calls' tiles as the traced step shows them: what the
     # benchmark's driver counts the visited pairs from
     fwd, bwd = fa.default_blocks(8192, 512)
     assert fa.band_calls(traced.jaxpr.jaxpr) == {
         ("attn_band_fwd", *fwd), ("attn_band_bwd", *bwd)}
-    text = traced.lower(lowering_platforms=("tpu",)).as_text()
     # one a kind of attention: layer 0 and the full layers share theirs
-    assert [obs.counter(n).get_value() - b
-            for n, b in zip(names, before)] == [2, 0]
+    assert counts["attn_kernel_lowerings"] == 2
+    assert counts["attn_dense_lowerings"] == 0
     found = set(re.findall(r"(attn_band_\w+|attn_flash_\w+|moe_gmm_\w+|"
                            r"moe_rows_\w+)", text))
     assert {"attn_band_fwd", "attn_band_bwd", "attn_flash_fwd",
             "attn_flash_bwd", "moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs",
             "moe_rows_gather", "moe_rows_combine", "moe_rows_pack"} <= found
     assert gm.bound_rows(8192 * 8, 16, gm.choose_tile(8192 * 8, 16)) >= 65536
+
+
+def test_q_and_k_reach_the_attention_kernels_in_one_pass(lowered):
+    """The program lowered for TPU holds the q/k layout kernel's two bodies,
+    counted once a kind of layer (64 heads, and 48: layer 0 and the full
+    layers share theirs) and the plain form never; under ``*.rope`` no
+    float32 [1, 8192, H, 128] is left, and under ``attn.layout`` only v, the
+    output and their cotangents are turned: v (8 heads) in the forward pass
+    and again in the recomputation of the window layers, the full layers and
+    layer 0, dv once each, the output and dO once each."""
+    _, text, counts = lowered
+    assert {"qk_layout_fwd", "qk_layout_bwd"} <= set(
+        re.findall(r"qk_layout_\w+", text))
+    assert counts["qk_layout_kernel_lowerings"] == 2
+    assert counts["qk_layout_plain_lowerings"] == 0
+    assert lowered_step.float32_heads_under_rope(text) == []
+    assert lowered_step.layout_transposes(text) == {
+        "1x8192x8x128xbf16": 6, "1x8x8192x128xbf16": 3,       # v, dv
+        "1x64x8192x128xbf16": 2, "1x8192x64x128xbf16": 1,     # window: o, dO
+        "1x48x8192x128xbf16": 4, "1x8192x48x128xbf16": 2}     # full, layer 0
+
+
+def test_the_steps_pallas_call_sites_are_pinned(lowered):
+    """The step held 44 Pallas call sites before the q/k layout kernels and
+    holds 49 with them: a forward kernel for the window layers, the full
+    layers and layer 0 (their outputs are saved by name, so no recomputation
+    runs one) and a backward kernel for the window layers and for the full
+    ones, which layer 0 shares. Each is traced and lowered by Mosaic in
+    every run's set-up (tests/test_mellum.py has the price of a site)."""
+    _, text, _ = lowered
+    sites = lowered_step.pallas_sites(text)
+    assert len(sites) - sum(s.startswith("qk_layout") for s in sites) == 44
+    assert sorted(s for s in sites if s.startswith("qk_layout")) == [
+        "qk_layout_bwd"] * 2 + ["qk_layout_fwd"] * 3
+    assert len(sites) == 49
 
 
 def test_the_dense_form_states_no_band_call():
