@@ -57,8 +57,11 @@ with RMSNorm (``llama.rms_norm``); a layer's MLP is a dense SwiGLU of
   ``ops/qk_layout.py`` leaves them — norm, rope and the turn from the
   projections' token-major layout done in float32 in registers; elsewhere
   the norm and the rope as float32 passes and ``llama.attention``.
-  With ``attn_gate``, ``o_h <- sigmoid(g_h) · o_h`` per head; ``·W_o``. No
-  biases.
+  With ``attn_gate``, ``o_h <- sigmoid(g_h) · o_h`` per head, g in float32:
+  on a TPU in the attention kernels' epilogue, on the float32 accumulator
+  before its one cast, the gradient of g from the backward pass's
+  ``delta``; elsewhere after attention, in float32 (``_gated``). ``·W_o``.
+  No biases.
 - **Experts.** ``router="sigmoid"``: ``s = sigmoid(y·W_r)`` in float32 over
   ALL ``n_experts``; the ``experts_per_token`` largest; weights ``s_sel /
   sum s_sel · routed_scaling`` (``deepseek.route``, with no selection bias).
@@ -88,7 +91,8 @@ scanned after. So the published full layer stands first in Laguna's period
 of four and last in Mellum2's, and both are the same scan. One compiled body
 of each kind whatever the depth. Each layer is recomputed in the backward
 pass (``jax.checkpoint``) from its input and what ``SAVED_NAMES`` names: the
-attention kernel's output and log-sum-exp, its head-major q and k (0.3 GB
+attention kernel's output (gated, where there is a gate: the recomputation
+runs no gate) and log-sum-exp, its head-major q and k (0.3 GB
 of Mellum2's four layers at 8,192 tokens, 0.75 GB of Laguna's five: the
 recomputation then runs no layout pass, and without q/k norms no ``y·W_q``
 either), and the experts' integer routing layout. bf16 compute; float32
@@ -458,35 +462,55 @@ def _qk_head_major_bwd(cfg, kind, residuals, cotangents):
 qk_head_major.defvjp(_qk_head_major_fwd, _qk_head_major_bwd)
 
 
-def attention_head_major(q: jax.Array, k: jax.Array, v: jax.Array, window):
+def _gated(o: jax.Array, gate: jax.Array) -> jax.Array:
+    """The plain form of the per-head output gate: o: [B, T, H·d] times the
+    sigmoid of the float32 logits ``gate`` [B, T, H], head by head, in
+    float32."""
+    b, t, nh = gate.shape
+    return (o.reshape(b, t, nh, -1).astype(jnp.float32)
+            * jax.nn.sigmoid(gate)[..., None]).astype(o.dtype).reshape(
+                b, t, -1)
+
+
+def attention_head_major(q: jax.Array, k: jax.Array, v: jax.Array, window,
+                         gate=None):
     """``llama.attention`` (causal, with its counters) for q: [B, Hq, T, D]
     and k: [B, Hkv, T, D] head-major already and bf16 operands the fused
     kernels take: they read q and k as they are; the dense form takes them
-    token-major again."""
-    def dense(q, k, v):
+    token-major again. ``gate``: the per-head output gate's float32 logits
+    [B, T, Hq], or None: the kernels take it into their epilogue
+    (``attn_gated_kernel_lowerings`` counts it), the dense form applies it
+    after as ``_gated``."""
+    def dense(q, k, v, *gate):
         with jax.named_scope("attn.dense"):
-            return dense_attention(
+            o = dense_attention(
                 count_lowering(q.transpose(0, 2, 1, 3),
                                "attn_dense_lowerings"),
                 k.transpose(0, 2, 1, 3), v, window=window)
+        return _gated(o, *gate) if gate else o
 
-    def kernel(q, k, v):
+    def kernel(q, k, v, *gate):
+        gate = (count_lowering(g, "attn_gated_kernel_lowerings") for g in gate)
         return flash_attention_head_major(
-            count_lowering(q, "attn_kernel_lowerings"), k, v, window=window)
+            count_lowering(q, "attn_kernel_lowerings"), k, v, *gate,
+            window=window)
 
-    return lax.platform_dependent(q, k, v, tpu=kernel, default=dense)
+    gate = () if gate is None else (gate,)
+    return lax.platform_dependent(q, k, v, *gate, tpu=kernel, default=dense)
 
 
 def attend(cfg: WindowedConfig, kind: str, q: jax.Array, k: jax.Array,
-           v: jax.Array, norms, positions: jax.Array) -> jax.Array:
+           v: jax.Array, norms, positions: jax.Array,
+           gate=None) -> jax.Array:
     """Attention of a ``"full"`` or ``"window"`` layer on q: [B, T, H·d] and
     k: [B, T, ``n_kv_heads``·d] as the projections leave them and v: [B, T,
     ``n_kv_heads``, d] -> [B, T, H·d]: each head of q and k normed and
     turned by the rope of the layer's kind, then ``llama.attention``'s
-    contract. Operands that both the q/k layout kernels and the attention
-    kernels take go head-major through ``qk_head_major`` to
-    ``attention_head_major``; everything else runs the plain form and
-    ``llama.attention`` from token-major operands."""
+    contract, each head's output times the sigmoid of its ``gate`` logit
+    (float32 [B, T, H]) where there is one. Operands that both the q/k
+    layout kernels and the attention kernels take go head-major through
+    ``qk_head_major`` to ``attention_head_major``; everything else runs the
+    plain form and ``llama.attention`` from token-major operands."""
     full = kind == "full"
     scope = "full" if full else "swa"
     (b, t, _), d = q.shape, cfg.head_dim
@@ -499,10 +523,14 @@ def attend(cfg: WindowedConfig, kind: str, q: jax.Array, k: jax.Array,
         with jax.named_scope(f"{scope}.rope"):
             q, k = qk_head_major(cfg, kind, q, k, norms, positions)
         with jax.named_scope(f"{scope}.attn"):
-            return attention_head_major(q, k, v, window)
+            return attention_head_major(q, k, v, window, gate)
     x, nh = _normed_and_turned(cfg, kind, q, k, norms, positions)
     with jax.named_scope(f"{scope}.attn"):
-        return attention(x[:, :, :nh], x[:, :, nh:], v, window=window)
+        o = attention(x[:, :, :nh], x[:, :, nh:], v, window=window)
+    if gate is None:
+        return o
+    with jax.named_scope(f"{scope}.out"):
+        return _gated(o, gate)
 
 
 def attention_block(cfg: WindowedConfig, kind: str, x: jax.Array, lp: Params,
@@ -510,10 +538,9 @@ def attention_block(cfg: WindowedConfig, kind: str, x: jax.Array, lp: Params,
     """The attention block of a ``"full"`` or ``"window"`` layer with its
     residual. x: [B, T, H]."""
     b, t, _ = x.shape
-    full = kind == "full"
-    scope = "full" if full else "swa"
-    nh = cfg.full_heads if full else cfg.window_heads
+    scope = "full" if kind == "full" else "swa"
     nkv, d = cfg.n_kv_heads, cfg.head_dim
+    gate = None
     with jax.named_scope(f"{scope}.qkv"):
         y = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
         q, k = y @ lp["wq"], y @ lp["wk"]
@@ -522,12 +549,9 @@ def attention_block(cfg: WindowedConfig, kind: str, x: jax.Array, lp: Params,
             gate = jnp.dot(y, lp["wg"], preferred_element_type=jnp.float32)
     o = attend(cfg, kind, q, k, v,
                (lp["q_norm"], lp["k_norm"]) if cfg.qk_norm else None,
-               positions)
+               positions, gate)
     with jax.named_scope(f"{scope}.out"):
-        if cfg.attn_gate:
-            o = (o.reshape(b, t, nh, d).astype(jnp.float32)
-                 * jax.nn.sigmoid(gate)[..., None]).astype(x.dtype)
-        return x + o.reshape(b, t, nh * d) @ lp["wo"]
+        return x + o @ lp["wo"]
 
 
 def moe_mlp(cfg: WindowedConfig, y: jax.Array, lp: Params):

@@ -5,20 +5,25 @@ No array shaped like the score matrix touches HBM in either pass. The
 forward kernel owns one [BLOCK_Q, D] query tile and streams K/V tiles
 through the MXU with the online-softmax recurrence (running max / sum /
 accumulator); it also returns each row's log-sum-exp, which with q, k, v and
-the output is all the backward pass keeps. Of those five the kernel's own two
-results carry names (``RESIDUAL_NAMES``: the head-major output and the
-log-sum-exp), so that a caller who recomputes its layers under
-``jax.checkpoint`` can save them by name and not run the forward kernel a
-second time; q, k and v are its inputs and are the caller's to keep or
-recompute. The backward pass is one kernel, per KV head and key tile: it
-recomputes the probabilities and dS from q, k, the log-sum-exp and ``delta``
-once for each pair of tiles and takes all three gradients from them. It loops
-over the head's whole query group, so that the GQA sum happens in dK's and
-dV's accumulators; dQ of the group gathers in VMEM, in float32, across the
-head's key tiles (the grid's last axis, which runs in order) and is written
-once, at the last of them. Causal programs stop at the diagonal, so the
-wasted triangle is skipped at tile granularity, and only the tiles the
-diagonal crosses pay for a mask (guide: /opt/skills/guides/pallas_guide.md).
+the output is all the backward pass keeps. Of those five the kernel's own
+two results carry names (``RESIDUAL_NAMES``: the head-major output — with a
+gate, the gated output — and the log-sum-exp), so that a caller who
+recomputes its layers under ``jax.checkpoint`` can save them by name and not
+run the forward kernel a second time; q, k and v are its inputs and are the
+caller's to keep or recompute. A per-head output gate (``gate``: one float32
+logit a query head and position, ``flash_attention_head_major``) is the
+forward kernel's epilogue, each row of ``acc / l`` times the sigmoid of its
+logit before the one cast; its gradient is ``(1 - sigmoid) * delta``, and
+the backward kernel is the same with and without one. The backward pass is
+one kernel, per KV head and key tile: it recomputes the probabilities and dS
+from q, k, the log-sum-exp and ``delta`` once for each pair of tiles and
+takes all three gradients from them. It loops over the head's whole query
+group, so that the GQA sum happens in dK's and dV's accumulators; dQ of the
+group gathers in VMEM, in float32, across the head's key tiles (the grid's
+last axis, which runs in order) and is written once, at the last of them.
+Causal programs stop at the diagonal, so the wasted triangle is skipped at
+tile granularity, and only the tiles the diagonal crosses pay for a mask
+(guide: /opt/skills/guides/pallas_guide.md).
 
 Precision: the MXU gets its operands in the dtype they arrive in (bf16 on
 the training path) and accumulates in float32; scores, softmax statistics,
@@ -291,8 +296,11 @@ def band_calls(jaxpr) -> set:
     return found
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
-                causal: bool, scale: float, window=None):
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, block_k: int, causal: bool,
+                scale: float, window=None):
+    """``refs``: the gate's logits where there is a gate, then the output
+    and the log-sum-exp."""
+    *gate_ref, o_ref, lse_ref = refs
     block_q = q_ref.shape[2]
     t, d_v = v_ref.shape[2:]
     qi = pl.program_id(2)
@@ -326,7 +334,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
         carry = lax.fori_loop(start, stop,
                               functools.partial(step, masked=masked), carry)
     m, l, acc = carry
-    o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
+    o = acc / l
+    if gate_ref:        # each row scaled by its sigmoid, before the one cast
+        o = o * jax.nn.sigmoid(gate_ref[0][0, 0, 0]).reshape(block_q, 1)
+    o_ref[0, 0] = o.astype(o_ref.dtype)
     lse_ref[0, 0, 0] = (m + jnp.log(l)).reshape(1, block_q)
 
 
@@ -416,15 +427,23 @@ def _whole_spec(t: int, width: int, group: int):
                         lambda bi, h, i: (bi, h // group, 0, 0))
 
 
-def _forward(q, k, v, causal: bool, blocks: tuple, interpret: bool,
+def _forward(q, k, v, gate, causal: bool, blocks: tuple, interpret: bool,
              window=None):
-    """Head-major q [B,Hq,T,Dqk], k [B,Hkv,T,Dqk], v [B,Hkv,T,Dv] -> (o
-    [B,Hq,T,Dv], float32 lse [B,Hq,T/BQ,1,BQ])."""
+    """Head-major q [B,Hq,T,Dqk], k [B,Hkv,T,Dqk], v [B,Hkv,T,Dv] and the
+    gate's float32 logits [B,Hq,T] or None -> (o [B,Hq,T,Dv], each row
+    times the sigmoid of its logit where there is a gate, float32 lse
+    [B,Hq,T/BQ,1,BQ])."""
     b, hq, t, d = q.shape
     d_v = v.shape[3]
     group = hq // k.shape[1]
     block_q, block_k = blocks
     kind = "flash" if window is None else "band"
+    row_spec = pl.BlockSpec((1, 1, 1, 1, block_q),
+                            lambda bi, h, i: (bi, h, i, 0, 0))
+    operands, gate_specs = (q, k, v), []
+    if gate is not None:        # a row a query tile, as the lse is written
+        operands += (gate.reshape(b, hq, t // block_q, 1, block_q),)
+        gate_specs = [row_spec]
     with jax.named_scope(f"attn.{kind}_fwd"):
         return _call(
             functools.partial(_fwd_kernel, block_k=block_k, causal=causal,
@@ -432,18 +451,20 @@ def _forward(q, k, v, causal: bool, blocks: tuple, interpret: bool,
             f"attn_{kind}_fwd", interpret,
             grid=(b, hq, t // block_q),
             in_specs=[_tile_spec(block_q, d), _whole_spec(t, d, group),
-                      _whole_spec(t, d_v, group)],
-            out_specs=[_tile_spec(block_q, d_v),
-                       pl.BlockSpec((1, 1, 1, 1, block_q),
-                                    lambda bi, h, i: (bi, h, i, 0, 0))],
+                      _whole_spec(t, d_v, group), *gate_specs],
+            out_specs=[_tile_spec(block_q, d_v), row_spec],
             out_shape=[jax.ShapeDtypeStruct((b, hq, t, d_v), q.dtype),
                        jax.ShapeDtypeStruct((b, hq, t // block_q, 1, block_q),
                                             jnp.float32)],
-        )(q, k, v)
+        )(*operands)
 
 
-def _backward(q, k, v, o, lse, do, causal: bool, blocks: tuple,
+def _backward(q, k, v, gate, o, lse, do, causal: bool, blocks: tuple,
               interpret: bool, window=None):
+    """With a gate, ``o`` is the gated output σ·O and ``do`` its cotangent:
+    the kernel's dO is σ·do, ``delta`` = Σ dO·O = Σ do·o is the formula
+    without a gate, and the logits' cotangent σ(1 − σ) Σ O·do is (1 − σ) ·
+    delta. The backward kernel is the same either way."""
     b, hq, t, d = q.shape
     d_v = v.shape[3]
     hkv = k.shape[1]
@@ -465,7 +486,13 @@ def _backward(q, k, v, o, lse, do, causal: bool, blocks: tuple,
         # pass's were (the same bytes in the same order)
         lse = lse.reshape(b, hq, t // block_q, 1, block_q)
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1).reshape(lse.shape)
+                        axis=-1)
+        dgate = None
+        if gate is not None:
+            sigma = jax.nn.sigmoid(gate)
+            dgate = (1 - sigma) * delta
+            do = (do.astype(jnp.float32) * sigma[..., None]).astype(do.dtype)
+        delta = delta.reshape(lse.shape)
 
         def group_spec(width):
             return pl.BlockSpec((1, group, t, width),
@@ -495,21 +522,22 @@ def _backward(q, k, v, o, lse, do, causal: bool, blocks: tuple,
         if not together:
             dk, dv = (x.reshape(b, hkv, shared, t, -1).sum(2).astype(k.dtype)
                       for x in (dk, dv))
-    return dq, dk, dv
+    return dq, dk, dv, dgate
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _attend(q, k, v, causal, blocks, interpret, window):
-    """``blocks``: the forward pass's (query tile, key tile), then the
-    backward pass's."""
-    return _forward(q, k, v, causal, blocks[0], interpret, window)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _attend(q, k, v, gate, causal, blocks, interpret, window):
+    """``gate``: the gate's float32 logits [B, Hq, T], or None for no gate.
+    ``blocks``: the forward pass's (query tile, key tile), then the backward
+    pass's."""
+    return _forward(q, k, v, gate, causal, blocks[0], interpret, window)[0]
 
 
-def _attend_fwd(q, k, v, causal, blocks, interpret, window):
-    o, lse = _forward(q, k, v, causal, blocks[0], interpret, window)
+def _attend_fwd(q, k, v, gate, causal, blocks, interpret, window):
+    o, lse = _forward(q, k, v, gate, causal, blocks[0], interpret, window)
     o = checkpoint_name(o, RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
-    return o, (q, k, v, o, lse)
+    return o, (q, k, v, gate, o, lse)
 
 
 def _attend_bwd(causal, blocks, interpret, window, residuals, do):
@@ -560,23 +588,30 @@ def flash_attention(
     blocks = _checked_blocks(t, causal, window, block_q, block_k)
     with jax.named_scope("attn.layout"):                    # [B, H, T, D]
         q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    out = _attend(q, k, v, causal, blocks, interpret, window)
+    out = _attend(q, k, v, None, causal, blocks, interpret, window)
     with jax.named_scope("attn.layout"):
         return out.transpose(0, 2, 1, 3).reshape(b, t, hq * v.shape[3])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "window"))
-def flash_attention_head_major(q: jax.Array, k: jax.Array, v: jax.Array, *,
+def flash_attention_head_major(q: jax.Array, k: jax.Array, v: jax.Array,
+                               gate: jax.Array | None = None, *,
                                interpret: bool = False,
                                window: int | None = None) -> jax.Array:
     """Causal ``flash_attention`` for a caller whose q and k are head-major
     already (ops/qk_layout.py): q: [B,Hq,T,Dqk], k: [B,Hkv,T,Dqk], v:
     [B,T,Hkv,Dv] -> [B,T,Hq*Dv]. Neither q nor k is transposed, in either
-    pass; v and the output go the way they go there."""
+    pass; v and the output go the way they go there. ``gate``: a per-head
+    output gate's float32 logits [B,T,Hq] or None; with them each head's
+    output is ``o_h * sigmoid(g_h)``, taken in float32 by the forward
+    kernel before its one cast, and the logits' gradient comes from the
+    backward pass's ``delta``."""
     b, hq, t, _ = q.shape
     blocks = _checked_blocks(t, True, window, None, None)
     with jax.named_scope("attn.layout"):
         v = v.transpose(0, 2, 1, 3)
-    out = _attend(q, k, v, True, blocks, interpret, window)
+        if gate is not None:
+            gate = gate.transpose(0, 2, 1)
+    out = _attend(q, k, v, gate, True, blocks, interpret, window)
     with jax.named_scope("attn.layout"):
         return out.transpose(0, 2, 1, 3).reshape(b, t, hq * v.shape[3])

@@ -1,13 +1,14 @@
 """What tests/test_windowed.py and tests/test_mellum.py read from a cell's
 train step as lowered for TPU on the CPU: the text with its locations, how
 often each counted choice was lowered, and what is left under the scopes the
-q/k layout kernels emptied."""
+q/k layout kernels and the gated attention kernels emptied."""
 
 import re
 
 from brpc_tpu import obs
 
-LOWERING_COUNTERS = ("attn_kernel_lowerings", "attn_dense_lowerings",
+LOWERING_COUNTERS = ("attn_kernel_lowerings", "attn_gated_kernel_lowerings",
+                     "attn_dense_lowerings",
                      "moe_grouped_lowerings", "moe_rows_lowerings",
                      "qk_layout_kernel_lowerings", "qk_layout_plain_lowerings")
 
@@ -43,10 +44,11 @@ def layout_transposes(text) -> dict:
     return found
 
 
-def float32_heads_under_rope(text) -> list:
-    """The lines under ``*.rope`` that hold a float32 [1, 8192, H, D]."""
+def float32_heads_under(text, scope: str) -> list:
+    """The lines under ``*<scope>`` (``.rope``, ``.out``) that hold a
+    float32 [1, 8192, H, D]."""
     return [line for line, name in _named_lines(text)
-            if ".rope" in name
+            if scope in name
             and re.search(r"tensor<1x8192x\d+x\d+xf32>", line)]
 
 

@@ -470,6 +470,7 @@ def test_the_cells_program_lowered_for_tpu_holds_every_kernel(lowered):
         ("attn_band_fwd", *fwd), ("attn_band_bwd", *bwd)}
     # window and full layers have one shape of heads: one lowering serves both
     assert counts["attn_kernel_lowerings"] == 1
+    assert counts["attn_gated_kernel_lowerings"] == 0     # no gate
     assert counts["attn_dense_lowerings"] == 0
     assert counts["moe_grouped_lowerings"] > 0
     assert counts["moe_rows_lowerings"] > 0
@@ -501,7 +502,7 @@ def test_q_and_k_reach_the_attention_kernels_in_one_pass(lowered):
         re.findall(r"qk_layout_\w+", text))
     assert counts["qk_layout_kernel_lowerings"] == 1
     assert counts["qk_layout_plain_lowerings"] == 0
-    assert lowered_step.float32_heads_under_rope(text) == []
+    assert lowered_step.float32_heads_under(text, ".rope") == []
     assert lowered_step.layout_transposes(text) == {
         "1x8192x4x128xbf16": 4, "1x4x8192x128xbf16": 2,       # v, dv
         "1x32x8192x128xbf16": 4, "1x8192x32x128xbf16": 2}     # the output, dO

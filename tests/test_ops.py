@@ -384,7 +384,7 @@ def test_flash_backward_is_one_kernel_at_the_cells_geometries(v5e_device,
     rows = s(1, hq, t // block, 1, block, dtype=jnp.float32)
     text = jax.jit(functools.partial(
         _backward, causal=True, blocks=(block, block), interpret=False)
-    ).lower(s(1, hq, t, d_qk), s(1, hkv, t, d_qk), s(1, hkv, t, d_v),
+    ).lower(s(1, hq, t, d_qk), s(1, hkv, t, d_qk), s(1, hkv, t, d_v), None,
             s(1, hq, t, d_v), rows, s(1, hq, t, d_v)).compile().as_text()
     assert _ATTN_CALLS.findall(text) == ["attn_flash_bwd"]
     assert text.count("tpu_custom_call") == 1
@@ -1313,3 +1313,122 @@ def test_qk_layout_kernels_compile_for_v5e_at_the_cells_sizes(
     assert sorted(_QK_CALLS.findall(text)) == ["qk_layout_bwd",
                                                "qk_layout_fwd"]
     assert re.findall(rf"f32\[1,(8192,{heads}|{heads},8192),128\]", text) == []
+
+
+# -- PR 44: a per-head output gate in the attention kernels' epilogue ---------
+
+import importlib  # noqa: E402
+
+# the module: ``brpc_tpu.ops.flash_attention`` the attribute is the function
+fa = importlib.import_module("brpc_tpu.ops.flash_attention")
+
+_GATES = {"normal": lambda g: 3 * g, "zero": jnp.zeros_like,
+          "positive": lambda g: g + 20, "negative": lambda g: g - 20}
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("window", [None, 64], ids=["causal", "band"])
+@pytest.mark.parametrize("case", [
+    ("normal", jnp.float32, True), ("zero", jnp.float32, True),
+    ("positive", jnp.float32, True), ("negative", jnp.float32, True),
+    ("normal", jnp.bfloat16, True), ("negative", jnp.bfloat16, True),
+    ("normal", jnp.float32, False), ("normal", jnp.bfloat16, False)],
+    ids=lambda c: f"{c[0]}-{jnp.dtype(c[1]).name}"
+                  f"{'' if c[2] else '-a_head_at_a_time'}")
+def test_gated_kernels_match_the_plain_form(monkeypatch, case, window):
+    """``flash_attention_head_major(..., gate=g)`` interpreted against dense
+    attention and then ``o * sigmoid(g)`` in float32: the output and the
+    cotangents of q, k, v and the logits, 16 query heads over 2 KV heads
+    (groups of 8, as Laguna's 64 and 48 over 8), three tiles of 128, logits
+    that make the gate about a half (0), about 1 (+20) and about 0 (-20),
+    and with each query head through the backward kernel on its own. The
+    tolerance is of each array's largest element, as above."""
+    logits, dtype, together = case
+    tol = 2e-5 if dtype == jnp.float32 else 4e-2
+    b, t, hq, hkv, d = 1, 384, 16, 2, 128
+    q, k, v = _inputs(jax.random.PRNGKey(9), b=b, t=t, hq=hq, hkv=hkv, d=d,
+                      dtype=dtype)
+    g = _GATES[logits](jax.random.normal(jax.random.PRNGKey(10), (b, t, hq)))
+    w = jax.random.normal(jax.random.PRNGKey(11), (b, t, hq * d))
+    if not together:
+        monkeypatch.setattr(fa, "_VMEM_LIMIT", 2 * fa._resident(
+            1, t, d, d, dtype))
+    assert fa.heads_together(hq // hkv, t, d, d, dtype) == together
+
+    def gated(q, k, v, g):
+        return fa.flash_attention_head_major(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v, g,
+            window=window, interpret=True).astype(jnp.float32)
+
+    def plain(q, k, v, g):
+        o = llama.dense_attention(q, k, v, window=window)
+        return (o.reshape(b, t, hq, d).astype(jnp.float32)
+                * jax.nn.sigmoid(g)[..., None]).reshape(b, t, hq * d)
+
+    for f in (gated, plain):
+        assert f(q, k, v, g).shape == (b, t, hq * d)
+    got, want = gated(q, k, v, g), plain(q, k, v, g)
+    assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want))
+    grads = [jax.grad(lambda *a: jnp.sum(f(*a) * w), (0, 1, 2, 3))(q, k, v, g)
+             for f in (gated, plain)]
+    for name, mine, ref in zip(("dq", "dk", "dv", "dg"), *grads):
+        assert mine.dtype == ref.dtype and mine.shape == ref.shape, name
+        mine, ref = np.asarray(mine, np.float32), np.asarray(ref, np.float32)
+        assert np.max(np.abs(mine - ref)) <= tol * np.max(np.abs(ref)), name
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["no_gate", "gate"])
+@pytest.mark.parametrize("window", [None, 512], ids=["causal", "band"])
+def test_without_a_gate_the_calls_are_the_parents(gated, window):
+    """Forward and backward traced: without a gate the forward kernel takes
+    q, k and v and the backward kernel its six operands, and no sigmoid is
+    taken anywhere (the parent's program; tests/test_qk_layout.py pins the
+    token-major callers' jaxpr); with one, the forward kernel takes the
+    logits as a fourth operand, a row a query tile, and the backward kernel
+    is the same call."""
+    s = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype)
+    gate = (s(1, 1024, 16, dtype=jnp.float32),) if gated else ()
+
+    def loss(q, k, v, *gate):
+        return jnp.sum(fa.flash_attention_head_major(
+            q, k, v, *gate, window=window).astype(jnp.float32))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=tuple(range(3 + gated))))(
+        s(1, 16, 1024, 128), s(1, 2, 1024, 128), s(1, 1024, 2, 128), *gate)
+    eqns = list(_pallas_calls(jaxpr.jaxpr))
+    kind = "flash" if window is None else "band"
+    calls = {e.params["name"]: e for e in eqns
+             if e.primitive.name == "pallas_call"}
+    assert sorted(calls) == [f"attn_{kind}_bwd", f"attn_{kind}_fwd"]
+    fwd, bwd = calls[f"attn_{kind}_fwd"], calls[f"attn_{kind}_bwd"]
+    assert len(fwd.invars) == 3 + gated and len(bwd.invars) == 6
+    if gated:
+        assert fwd.invars[3].aval.shape == (1, 16, 2, 1, 512)
+    assert any(e.primitive.name == "logistic" for e in eqns) == gated
+
+
+@pytest.mark.parametrize("heads,window", [(64, 512), (48, None)],
+                         ids=["laguna_window", "laguna_full"])
+def test_gated_kernels_compile_for_v5e_at_the_cells_geometry(v5e_device,
+                                                              heads, window):
+    """1 x 8,192 positions, Laguna's heads over 8 KV heads of 128, bf16, the
+    gate's logits float32: Mosaic and XLA:TPU take the forward kernel with
+    its epilogue and the backward kernel, one call each."""
+    sharding = jax.sharding.SingleDeviceSharding(v5e_device)
+    s = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=sharding)
+    text = jax.jit(jax.grad(lambda q, k, v, g: jnp.sum(
+        fa.flash_attention_head_major(q, k, v, g, window=window).astype(
+            jnp.float32)), (0, 1, 2, 3))).trace(
+        s(1, heads, 8192, 128), s(1, 8, 8192, 128), s(1, 8192, 8, 128),
+        s(1, 8192, heads, dtype=jnp.float32)).lower().compile().as_text()
+    kind = "flash" if window is None else "band"
+    assert sorted(re.findall(r"%(attn_\w+?)(?:\.\d+)? = [^\n]*custom-call\(",
+                             text)) == [f"attn_{kind}_bwd", f"attn_{kind}_fwd"]

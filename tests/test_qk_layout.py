@@ -246,7 +246,7 @@ def _todays_wrapper(q, k, v, *, causal=True, block_q=None, block_k=None,
         raise ValueError(f"seq {t} must divide blocks {blocks}")
     with jax.named_scope("attn.layout"):                    # [B, H, T, D]
         q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    out = fa._attend(q, k, v, causal, blocks, interpret, window)
+    out = fa._attend(q, k, v, None, causal, blocks, interpret, window)
     with jax.named_scope("attn.layout"):
         return out.transpose(0, 2, 1, 3).reshape(b, t, hq * v.shape[3])
 

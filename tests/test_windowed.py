@@ -304,6 +304,39 @@ def test_the_gate_is_a_sigmoid_a_head(params):
     assert _scale_gap(gated, ungated) > 0.1
 
 
+@pytest.mark.parametrize("window", [None, 64], ids=["full", "window"])
+def test_the_kernels_gate_is_the_plain_form_s(monkeypatch, window):
+    """``attention_head_major`` with the gate's logits [B, T, H]: the
+    kernels' branch (interpreted), whose forward kernel takes them into its
+    epilogue, against the plain branch, which gates after
+    (``windowed._gated``): the output and the cotangents of q, k, v and the
+    logits, in float32, 8 query heads over 1 KV head of 128, three tiles."""
+    b, t, hq, d = 1, 384, 8, 128
+    ks = jax.random.split(jax.random.PRNGKey(13), 5)
+    q = jax.random.normal(ks[0], (b, hq, t, d))
+    k = jax.random.normal(ks[1], (b, 1, t, d))
+    v = jax.random.normal(ks[2], (b, t, 1, d))
+    gate = 3 * jax.random.normal(ks[3], (b, t, hq))
+    w = jax.random.normal(ks[4], (b, t, hq * d))
+    monkeypatch.setattr(
+        windowed, "flash_attention_head_major",
+        lambda q, k, v, gate, window: fa.flash_attention_head_major(
+            q, k, v, gate, window=window, interpret=True))
+    results = []
+    for branch in ("tpu", "default"):
+        monkeypatch.setattr(
+            windowed.lax, "platform_dependent",
+            lambda *operands, branch=branch, **branches: branches[branch](
+                *operands))
+        results.append(jax.value_and_grad(
+            lambda *a: jnp.sum(windowed.attention_head_major(
+                *a[:3], window, a[3]) * w), (0, 1, 2, 3))(q, k, v, gate))
+    (got, grads), (want, wants) = results
+    assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
+    for name, g, r in zip(("dq", "dk", "dv", "dgate"), grads, wants):
+        assert _scale_gap(g, r) <= 2e-5, name
+
+
 # -- a chip's share of the expert layer ---------------------------------------
 
 def _whole_layer(params):
@@ -386,8 +419,10 @@ def test_the_cells_program_lowered_for_tpu_holds_every_kernel(lowered):
     fwd, bwd = fa.default_blocks(8192, 512)
     assert fa.band_calls(traced.jaxpr.jaxpr) == {
         ("attn_band_fwd", *fwd), ("attn_band_bwd", *bwd)}
-    # one a kind of attention: layer 0 and the full layers share theirs
+    # one a kind of attention: layer 0 and the full layers share theirs;
+    # each takes the gate into the kernels
     assert counts["attn_kernel_lowerings"] == 2
+    assert counts["attn_gated_kernel_lowerings"] == 2
     assert counts["attn_dense_lowerings"] == 0
     found = set(re.findall(r"(attn_band_\w+|attn_flash_\w+|moe_gmm_\w+|"
                            r"moe_rows_\w+)", text))
@@ -402,19 +437,32 @@ def test_q_and_k_reach_the_attention_kernels_in_one_pass(lowered):
     counted once a kind of layer (64 heads, and 48: layer 0 and the full
     layers share theirs) and the plain form never; under ``*.rope`` no
     float32 [1, 8192, H, 128] is left, and under ``attn.layout`` only v, the
-    output and their cotangents are turned: v (8 heads) in the forward pass
-    and again in the recomputation of the window layers, the full layers and
-    layer 0, dv once each, the output and dO once each."""
+    output, the gate's logits and their cotangents are turned: v (8 heads)
+    and the logits in the forward pass and again in the recomputation of the
+    window layers, the full layers and layer 0, dv and the logits'
+    cotangent once each, the output and dO once each."""
     _, text, counts = lowered
     assert {"qk_layout_fwd", "qk_layout_bwd"} <= set(
         re.findall(r"qk_layout_\w+", text))
     assert counts["qk_layout_kernel_lowerings"] == 2
     assert counts["qk_layout_plain_lowerings"] == 0
-    assert lowered_step.float32_heads_under_rope(text) == []
+    assert lowered_step.float32_heads_under(text, ".rope") == []
     assert lowered_step.layout_transposes(text) == {
         "1x8192x8x128xbf16": 6, "1x8x8192x128xbf16": 3,       # v, dv
         "1x64x8192x128xbf16": 2, "1x8192x64x128xbf16": 1,     # window: o, dO
-        "1x48x8192x128xbf16": 4, "1x8192x48x128xbf16": 2}     # full, layer 0
+        "1x48x8192x128xbf16": 4, "1x8192x48x128xbf16": 2,     # full, layer 0
+        "1x8192x64xf32": 2, "1x64x8192xf32": 1,               # window: gate
+        "1x8192x48xf32": 4, "1x48x8192xf32": 2}               # full: gate
+
+
+def test_the_gate_rides_the_attention_kernels(lowered):
+    """The per-head gate is the forward kernel's epilogue and its gradient
+    comes from the backward pass's ``delta``: under ``swa.out`` and
+    ``full.out`` no float32 [1, 8192, H, 128] is left (the parent's gate
+    was such a pass in the forward pass, the recomputation and the backward
+    pass), only the turn to token-major, ``@ wo`` and the residual."""
+    _, text, _ = lowered
+    assert lowered_step.float32_heads_under(text, ".out") == []
 
 
 def test_the_steps_pallas_call_sites_are_pinned(lowered):
