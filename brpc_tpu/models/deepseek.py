@@ -18,8 +18,8 @@ deepseek-ai/DeepSeek-V3 ``config.json``; HF ``modeling_deepseek_v3.py``):
   rope on the rope dims, pairs interleaved ((2i, 2i+1) are a pair); scores
   scaled by (nope + rope)^-1/2, causal, float32 softmax. Training-time MLA
   is plain multi-head attention with q/k of 192 and v of 128, and goes
-  through ``llama.attention``: the fused kernels on a TPU, the dense form
-  elsewhere.
+  through ``flash_attention.attention``: the fused kernels on a TPU, the
+  dense form elsewhere.
 - **Experts**: ``s = sigmoid(y·Wg)`` in float32 over ALL ``n_routed_experts``;
   the ``experts_per_token`` largest of ``s + b`` are selected (``b``:
   ``router_bias``, DeepSeek's ``e_score_correction_bias``); their weights
@@ -70,20 +70,18 @@ works over) and the experts each token selected.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.ad_checkpoint import checkpoint_name
 
-from brpc_tpu.models.chunked_loss import chunked_next_token_loss
-from brpc_tpu.models.experts import chosen, expert_mlp, swiglu as _swiglu
-from brpc_tpu.models.llama import _dense_init, attention, rms_norm
-from brpc_tpu.models.train_step import apply_updates
+from brpc_tpu.models import train_step
+from brpc_tpu.models.experts import (expert_mlp, sigmoid_route,
+                                     swiglu as _swiglu)
+from brpc_tpu.models.layers import dense_init, rms_norm
 from brpc_tpu.ops import grouped_matmul as gm
-from brpc_tpu.ops.flash_attention import RESIDUAL_NAMES
+from brpc_tpu.ops.flash_attention import RESIDUAL_NAMES, attention
 
 Params = Dict[str, Any]
 
@@ -148,7 +146,7 @@ def init_params(key: jax.Array, cfg: DeepseekConfig) -> Params:
         ks = iter(jax.random.split(key, 5 + len(extra)))
 
         def mat(shape, fan_in):
-            return _dense_init(next(ks), (n,) + shape, pd, fan_in)
+            return dense_init(next(ks), (n,) + shape, pd, fan_in)
 
         layers = {
             "wq": mat((h, nh * qk), h),
@@ -175,14 +173,14 @@ def init_params(key: jax.Array, cfg: DeepseekConfig) -> Params:
     moe["router_bias"] = 0.01 * jax.random.normal(
         jax.random.fold_in(k_moe, 1), (n_moe, e), jnp.float32)
     return {
-        "embed": _dense_init(k_emb, (cfg.vocab_size, h), pd, 1.0),
+        "embed": dense_init(k_emb, (cfg.vocab_size, h), pd, 1.0),
         "dense": stack(k_dense, cfg.n_dense_layers, [
             ("w_gate", (h, cfg.intermediate), h),
             ("w_up", (h, cfg.intermediate), h),
             ("w_down", (cfg.intermediate, h), cfg.intermediate)]),
         "moe": moe,
         "final_norm": jnp.ones((h,), pd),
-        "lm_head": _dense_init(k_out, (h, cfg.vocab_size), pd, h),
+        "lm_head": dense_init(k_out, (h, cfg.vocab_size), pd, h),
     }
 
 
@@ -229,26 +227,14 @@ def mla(cfg: DeepseekConfig, x: jax.Array, lp: Params,
         return x + o @ lp["wo"]
 
 
-def route(cfg: DeepseekConfig, y: jax.Array, router: jax.Array,
-          bias: jax.Array):
-    """y: [N, H] -> (selected experts [N, k] int32, their weights [N, k]
-    float32). The selection sees ``s + bias``, the weights ``s`` alone."""
-    s = jax.nn.sigmoid(jnp.dot(y.astype(jnp.float32), router,
-                               precision=lax.Precision.HIGHEST))
-    _, selected = lax.top_k(s + bias, cfg.experts_per_token)
-    selected = checkpoint_name(selected.astype(jnp.int32), gm.LAYOUT_NAME)
-    w = chosen(s, selected)
-    w = w / jnp.sum(w, axis=1, keepdims=True) * cfg.routed_scaling
-    return selected, w
-
-
 def moe_mlp(cfg: DeepseekConfig, y: jax.Array, lp: Params):
     """The expert layer's MLP on normed tokens y: [N, H] -> ([N, H], stats):
     what the held experts give for the assignments routed to them, plus the
-    shared experts (``models/experts.py``, shared with ``models/hybrid.py``:
-    everything after the routing)."""
+    shared experts (``models/experts.py``: the router and everything after
+    the routing)."""
     with jax.named_scope("moe.router"):
-        selected, weights = route(cfg, y, lp["router"], lp["router_bias"])
+        selected, weights = sigmoid_route(cfg, y, lp["router"],
+                                          lp["router_bias"])
     return expert_mlp(
         y, selected, weights, lp["w_gate"], lp["w_up"], lp["w_down"],
         n_held=cfg.n_held, expert_offset=cfg.expert_offset,
@@ -256,44 +242,26 @@ def moe_mlp(cfg: DeepseekConfig, y: jax.Array, lp: Params):
                                  lp["shared_down"]))
 
 
-def _cast(lp: Params, dtype) -> Params:
-    with jax.named_scope("weights.cast"):
-        return {k: v if k in _FLOAT32_LEAVES else v.astype(dtype)
-                for k, v in lp.items()}
-
-
 def hidden_states(params: Params, tokens: jax.Array, cfg: DeepseekConfig):
     """tokens: [B, T] -> (final-normed states [B, T, H], per-expert-layer
     stats). Master weights stay float32; each layer's compute-dtype copy is
     made inside its scan step, and each step is recomputed in the backward
     pass from its input and what ``SAVED_NAMES`` names."""
-    with jax.named_scope("embed"):
-        x = params["embed"][tokens].astype(cfg.dtype)
-        b, t, h = x.shape
-        positions = jnp.broadcast_to(jnp.arange(t), tokens.shape)
-    recomputed = functools.partial(
-        jax.checkpoint,
-        policy=jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES))
+    x, positions = train_step.embed(params, tokens, cfg.dtype)
+    recomputed = train_step.recomputed(SAVED_NAMES)
 
     @recomputed
     def dense_layer(x, lp):
-        lp = _cast(lp, cfg.dtype)
+        lp = train_step.cast(lp, cfg.dtype, _FLOAT32_LEAVES)
         x = mla(cfg, x, lp, positions)
         with jax.named_scope("dsv3.dense_mlp"):
             y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
             return x + _swiglu(y, lp["w_gate"], lp["w_up"],
                                lp["w_down"]), None
 
-    @recomputed
-    def moe_layer(x, lp):
-        lp = _cast(lp, cfg.dtype)
-        x = mla(cfg, x, lp, positions)
-        with jax.named_scope("dsv3.glue"):
-            y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps).reshape(b * t, h)
-        out, stats = moe_mlp(cfg, y, lp)
-        with jax.named_scope("dsv3.glue"):
-            return x + out.reshape(b, t, h), stats
-
+    moe_layer = recomputed(train_step.expert_layer(
+        cfg, _FLOAT32_LEAVES, lambda x, lp: mla(cfg, x, lp, positions),
+        rms_norm, "dsv3.glue", moe_mlp))
     x, _ = lax.scan(dense_layer, x, params["dense"])
     x, stats = lax.scan(moe_layer, x, params["moe"])
     with jax.named_scope("dsv3.glue"):
@@ -304,33 +272,20 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: DeepseekConfig):
 def forward(params: Params, tokens: jax.Array, cfg: DeepseekConfig):
     """tokens: [B, T] int32 -> logits [B, T, vocab] float32, whole (tests
     and small batches; the loss does not call this)."""
-    x, _ = hidden_states(params, tokens, cfg)
-    return jnp.dot(x, params["lm_head"].astype(cfg.dtype),
-                   preferred_element_type=jnp.float32)
+    return train_step.whole_logits(hidden_states(params, tokens, cfg),
+                                   params, cfg)
 
 
 def loss_fn(params: Params, tokens: jax.Array, cfg: DeepseekConfig):
     """Next-token cross-entropy (the last position predicts nothing), and
-    the forward pass's stats. The head is taken a chunk of positions at a
-    time, each chunk's float32 logits recomputed in the backward pass."""
-    x, stats = hidden_states(params, tokens, cfg)
-    with jax.named_scope("loss.chunk"):
-        head = params["lm_head"].astype(cfg.dtype)
-        return chunked_next_token_loss((x,), head, tokens,
-                                       lambda nlls: nlls[0]), stats
+    the forward pass's stats (``train_step.next_token_loss``)."""
+    return train_step.next_token_loss(hidden_states(params, tokens, cfg),
+                                      params, tokens, cfg)
 
 
 def make_train_step(cfg: DeepseekConfig, optimizer):
     """Returns jittable (params, opt_state, tokens) -> (params, opt_state,
     loss, stats). ``router_bias`` is held fixed: its gradient is nought, and
     the optimizer's decay of it is taken out again."""
-
-    def step(params, opt_state, tokens):
-        (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params, tokens, cfg)
-        params, opt_state = apply_updates(
-            optimizer, grads, opt_state, params,
-            frozen=(("moe", "router_bias"),))
-        return params, opt_state, loss, stats
-
-    return step
+    return train_step.make_train_step(loss_fn, cfg, optimizer,
+                                      frozen=(("moe", "router_bias"),))

@@ -1,18 +1,19 @@
-"""A sparse expert layer after its routing: one chip's share of the routed
+"""A sparse expert layer: its two routers, one chip's share of the routed
 experts and what every chip computes alike, for every model that has such a
 layer (``models/deepseek.py``, kanana-2: sigmoid scores, a selection bias, a
 scaling factor, unweighted shared experts; ``models/hybrid.py``,
 Qwen3-Next: softmax scores, one shared expert behind a sigmoid gate;
 ``models/windowed.py``: Laguna-XS.2, sigmoid scores with a scaling beside one
 unweighted shared expert, and Mellum2, softmax scores and no shared expert at
-all). How the experts are chosen and weighted is the model's, by one of two
-routers: ``deepseek.route`` (sigmoid scores; ``moe_routed_scaling_factor``,
-a selection bias) and ``hybrid.route`` (softmax scores renormalised over the
-chosen: ``norm_topk_prob: true``). What follows the routing is the
-same for all of them, and ``shared`` says what is added beside it: a
-function of the tokens where the published config has a shared expert
-(``shared_expert_intermediate_size``, ``n_shared_experts``), None where it
-has none.
+all). How the experts are chosen and weighted is the model's, by one of the
+two routers: ``sigmoid_route`` (sigmoid scores; ``moe_routed_scaling_factor``,
+a selection bias) and ``softmax_route`` (softmax scores renormalised over
+the chosen: ``norm_topk_prob: true``). What follows the routing is the
+same for all of them (``expert_mlp``), and ``shared`` says what is added
+beside it: a function of the tokens where the published config has a shared
+expert (``shared_expert_intermediate_size``, ``n_shared_experts``), None
+where it has none. Each model keeps its own ``moe_mlp``, which routes and
+says what is shared.
 
 The layer is told which experts it holds — ``n_held`` of them from
 ``expert_offset`` — and computes what its own give for the assignments
@@ -28,6 +29,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from brpc_tpu.ops import grouped_matmul as gm
 
@@ -47,6 +50,36 @@ def chosen(scores: jax.Array, selected: jax.Array) -> jax.Array:
     return jnp.stack(
         [jnp.sum(jnp.where(selected[:, j, None] == expert, scores, 0), axis=1)
          for j in range(selected.shape[1])], axis=1)
+
+
+def sigmoid_route(cfg, y: jax.Array, router: jax.Array, bias):
+    """y: [N, H] -> (selected experts [N, k] int32, their weights [N, k]
+    float32): ``s = sigmoid(y·router)`` over all the experts, the k
+    (``cfg.experts_per_token``) largest of ``s + bias``, weights ``s`` (not
+    ``bias``) renormalised over the chosen, times ``cfg.routed_scaling``.
+    The selection carries ``gm.LAYOUT_NAME``: a layer's recomputation that
+    saves it runs the sigmoid and no ``top_k``."""
+    s = jax.nn.sigmoid(jnp.dot(y.astype(jnp.float32), router,
+                               precision=lax.Precision.HIGHEST))
+    _, selected = lax.top_k(s + bias, cfg.experts_per_token)
+    selected = checkpoint_name(selected.astype(jnp.int32), gm.LAYOUT_NAME)
+    w = chosen(s, selected)
+    w = w / jnp.sum(w, axis=1, keepdims=True) * cfg.routed_scaling
+    return selected, w
+
+
+def softmax_route(cfg, y: jax.Array, router: jax.Array):
+    """y: [N, H] -> (selected experts [N, k] int32, their weights [N, k]
+    float32): softmax over all the experts, the k largest, renormalised
+    (``norm_topk_prob: true``)."""
+    p = jax.nn.softmax(jnp.dot(y.astype(jnp.float32), router,
+                               precision=lax.Precision.HIGHEST), axis=-1)
+    _, selected = lax.top_k(p, cfg.experts_per_token)
+    selected = checkpoint_name(selected.astype(jnp.int32), gm.LAYOUT_NAME)
+    # from the saved selection, not from top_k's values: a layer's
+    # recomputation then runs the softmax and no top_k
+    w = chosen(p, selected)
+    return selected, w / jnp.sum(w, axis=1, keepdims=True)
 
 
 def expert_mlp(y, selected, weights, w_gate, w_up, w_down, *, n_held: int,

@@ -30,15 +30,14 @@ and the per-head q/k norms.
   ``y·W_q`` gives each head its query (first half) and its gate (second);
   q and k RMS-normed per head; rope, halves rotated, on the first
   ``partial_rotary_factor`` of each head; causal softmax attention scaled by
-  head_dim^-1/2 through ``llama.attention`` (the fused kernels on a TPU);
+  head_dim^-1/2 through ``flash_attention.attention`` (the fused kernels on
+  a TPU);
   ``o · sigmoid(gate)``; ``·W_o``. No biases.
 - **Expert layer** (every layer). ``p = softmax(y·W_g)`` in float32 over ALL
   ``n_experts``; the ``experts_per_token`` largest; weights ``p_sel /
   sum p_sel``; experts SwiGLU of ``moe_intermediate``; plus ``sigmoid(y·w_s) ·
-  SwiGLU(y)``, the shared expert. ``route`` is the softmax router of every
-  model that has one (``models/windowed.py`` calls it for Mellum2, as it
-  calls ``deepseek.route`` for Laguna-XS.2); what follows the routing is
-  ``models/experts.py``, shared with ``models/deepseek.py``, and so is the
+  SwiGLU(y)``, the shared expert. The router (``experts.softmax_route``)
+  and what follows the routing are ``models/experts.py``, and so is the
   contract of a chip's share: told ``n_held`` and ``expert_offset`` it routes
   over all, normalises over all the selected wherever they live, computes
   what its own give, drops nothing.
@@ -97,22 +96,19 @@ and reshapes between them, ``embed``, ``weights.cast``, ``loss.chunk`` and
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.ad_checkpoint import checkpoint_name
 
-from brpc_tpu.models.chunked_loss import chunked_next_token_loss
-from brpc_tpu.models.experts import chosen, expert_mlp, swiglu
-from brpc_tpu.models.llama import _dense_init, attention, rope
-from brpc_tpu.models.train_step import apply_updates
+from brpc_tpu.models import train_step
+from brpc_tpu.models.experts import expert_mlp, softmax_route, swiglu
+from brpc_tpu.models.layers import dense_init, rope
 from brpc_tpu.ops import gated_delta
 from brpc_tpu.ops import grouped_matmul as gm
 from brpc_tpu.ops.causal_conv import conv_silu
-from brpc_tpu.ops.flash_attention import RESIDUAL_NAMES
+from brpc_tpu.ops.flash_attention import RESIDUAL_NAMES, attention
 
 Params = Dict[str, Any]
 
@@ -195,7 +191,7 @@ def init_params(key: jax.Array, cfg: HybridConfig) -> Params:
         ks = iter(jax.random.split(key, 16))
 
         def mat(shape, fan_in):
-            return _dense_init(next(ks), lead + shape, pd, fan_in)
+            return dense_init(next(ks), lead + shape, pd, fan_in)
 
         layers = {name: mat(shape, fan_in) for name, shape, fan_in in (
             *mixer,
@@ -230,11 +226,11 @@ def init_params(key: jax.Array, cfg: HybridConfig) -> Params:
     full["q_norm"] = jnp.zeros((periods, cfg.head_dim), pd)
     full["k_norm"] = jnp.zeros((periods, cfg.head_dim), pd)
     return {
-        "embed": _dense_init(k_emb, (cfg.vocab_size, h), pd, 1.0),
+        "embed": dense_init(k_emb, (cfg.vocab_size, h), pd, 1.0),
         "linear": linear,
         "full": full,
         "final_norm": jnp.zeros((h,), pd),
-        "lm_head": _dense_init(k_out, (h, cfg.vocab_size), pd, h),
+        "lm_head": dense_init(k_out, (h, cfg.vocab_size), pd, h),
     }
 
 
@@ -315,27 +311,12 @@ def gated_attention(cfg: HybridConfig, x: jax.Array, lp: Params,
         return x + o @ lp["wo"]
 
 
-def route(cfg: HybridConfig, y: jax.Array, router: jax.Array):
-    """y: [N, H] -> (selected experts [N, k] int32, their weights [N, k]
-    float32): softmax over all the experts, the k largest, renormalised
-    (``norm_topk_prob: true``). ``cfg``: any config with
-    ``experts_per_token`` (this model's, ``WindowedConfig``)."""
-    p = jax.nn.softmax(jnp.dot(y.astype(jnp.float32), router,
-                               precision=lax.Precision.HIGHEST), axis=-1)
-    _, selected = lax.top_k(p, cfg.experts_per_token)
-    selected = checkpoint_name(selected.astype(jnp.int32), gm.LAYOUT_NAME)
-    # from the saved selection, not from top_k's values: a layer's
-    # recomputation then runs the softmax and no top_k (as deepseek.route)
-    w = chosen(p, selected)
-    return selected, w / jnp.sum(w, axis=1, keepdims=True)
-
-
 def moe_mlp(cfg: HybridConfig, y: jax.Array, lp: Params):
     """The expert layer's MLP on normed tokens y: [N, H] -> ([N, H], stats):
     what the held experts give for the assignments routed to them, plus the
     shared expert behind its gate."""
     with jax.named_scope("moe.router"):
-        selected, weights = route(cfg, y, lp["router"])
+        selected, weights = softmax_route(cfg, y, lp["router"])
 
     def shared(y):
         gate = jax.nn.sigmoid(jnp.dot(y, lp["shared_w"],
@@ -348,81 +329,40 @@ def moe_mlp(cfg: HybridConfig, y: jax.Array, lp: Params):
         n_held=cfg.n_held, expert_offset=cfg.expert_offset, shared=shared)
 
 
-def _cast(lp: Params, dtype) -> Params:
-    with jax.named_scope("weights.cast"):
-        return {k: v if k in _FLOAT32_LEAVES else v.astype(dtype)
-                for k, v in lp.items()}
-
-
 def hidden_states(params: Params, tokens: jax.Array, cfg: HybridConfig):
     """tokens: [B, T] -> (final-normed states [B, T, H], the expert layers'
     stats, a row a layer). Master weights stay float32; a layer's compute-dtype
     copy is made inside its scan step."""
-    with jax.named_scope("embed"):
-        x = params["embed"][tokens].astype(cfg.dtype)
-        b, t, h = x.shape
-        positions = jnp.broadcast_to(jnp.arange(t), tokens.shape)
+    x, positions = train_step.embed(params, tokens, cfg.dtype)
 
     def layer(mixer):
-        @functools.partial(
-            jax.checkpoint,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                *SAVED_NAMES))
-        def run(x, lp):
-            lp = _cast(lp, cfg.dtype)
-            x = mixer(x, lp)
-            with jax.named_scope("hybrid.glue"):
-                y = norm(x, lp["mlp_norm"], cfg.norm_eps).reshape(b * t, h)
-            out, stats = moe_mlp(cfg, y, lp)
-            with jax.named_scope("hybrid.glue"):
-                return x + out.reshape(b, t, h), stats
-        return run
+        return train_step.recomputed(SAVED_NAMES)(train_step.expert_layer(
+            cfg, _FLOAT32_LEAVES, mixer, norm, "hybrid.glue", moe_mlp))
 
-    linear = layer(lambda x, lp: gated_delta_net(cfg, x, lp))
-    full = layer(lambda x, lp: gated_attention(cfg, x, lp, positions))
-
-    def period(x, lps):
-        x, linear_stats = lax.scan(linear, x, lps["linear"])
-        x, full_stats = full(x, lps["full"])
-        return x, {"linear": linear_stats, "full": full_stats}
-
-    x, stats = lax.scan(period, x, {"linear": params["linear"],
-                                    "full": params["full"]})
+    x, stats = train_step.scan_periods(
+        x, layer(lambda x, lp: gated_delta_net(cfg, x, lp)),
+        layer(lambda x, lp: gated_attention(cfg, x, lp, positions)),
+        params["linear"], params["full"])
     with jax.named_scope("hybrid.glue"):
-        # [periods, interval - 1, ...] and [periods, ...] -> [layers, ...]
-        stats = jax.tree_util.tree_map(
-            lambda lin, full: jnp.concatenate(
-                [lin, full[:, None]], axis=1).reshape(-1, *full.shape[1:]),
-            stats["linear"], stats["full"])
+        stats = train_step.in_layer_order(*stats)
         return norm(x, params["final_norm"], cfg.norm_eps), stats
 
 
 def forward(params: Params, tokens: jax.Array, cfg: HybridConfig):
     """tokens: [B, T] int32 -> logits [B, T, vocab] float32, whole (tests
     and small batches; the loss does not call this)."""
-    x, _ = hidden_states(params, tokens, cfg)
-    return jnp.dot(x, params["lm_head"].astype(cfg.dtype),
-                   preferred_element_type=jnp.float32)
+    return train_step.whole_logits(hidden_states(params, tokens, cfg),
+                                   params, cfg)
 
 
 def loss_fn(params: Params, tokens: jax.Array, cfg: HybridConfig):
     """Next-token cross-entropy (the last position predicts nothing), and
-    the forward pass's stats; the head a chunk of positions at a time."""
-    x, stats = hidden_states(params, tokens, cfg)
-    with jax.named_scope("loss.chunk"):
-        head = params["lm_head"].astype(cfg.dtype)
-        return chunked_next_token_loss((x,), head, tokens,
-                                       lambda nlls: nlls[0]), stats
+    the forward pass's stats (``train_step.next_token_loss``)."""
+    return train_step.next_token_loss(hidden_states(params, tokens, cfg),
+                                      params, tokens, cfg)
 
 
 def make_train_step(cfg: HybridConfig, optimizer):
     """Returns jittable (params, opt_state, tokens) -> (params, opt_state,
     loss, stats)."""
-
-    def step(params, opt_state, tokens):
-        (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params, tokens, cfg)
-        params, opt_state = apply_updates(optimizer, grads, opt_state, params)
-        return params, opt_state, loss, stats
-
-    return step
+    return train_step.make_train_step(loss_fn, cfg, optimizer)

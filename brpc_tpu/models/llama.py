@@ -19,7 +19,6 @@ BASELINE.json).  Design choices are TPU-idiomatic rather than a torch port:
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Any, Dict
 
 import jax
@@ -27,10 +26,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from brpc_tpu.models.train_step import apply_updates
-from brpc_tpu.ops.flash_attention import (flash_attention,
-                                          supported as flash_supported)
-from brpc_tpu.ops.lowered import count_lowering
+from brpc_tpu.models.layers import dense_init, rms_norm, rope
+from brpc_tpu.models.train_step import apply_updates, cast, embed
+from brpc_tpu.ops.flash_attention import attention
 
 Params = Dict[str, Any]
 
@@ -70,11 +68,6 @@ class LlamaConfig:
         return LlamaConfig()
 
 
-def _dense_init(key, shape, dtype, fan_in):
-    scale = fan_in ** -0.5
-    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
-
-
 def init_params(key: jax.Array, cfg: LlamaConfig) -> Params:
     """Initialise a parameter pytree. Per-layer tensors are stacked on axis 0."""
     k_emb, k_layers, k_out = jax.random.split(key, 3)
@@ -85,7 +78,7 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Params:
     pd = cfg.param_dtype
 
     def stacked(key, shape, fan_in):
-        return _dense_init(key, (L,) + shape, pd, fan_in)
+        return dense_init(key, (L,) + shape, pd, fan_in)
 
     layers = {
         "wq": stacked(ks[0], (h, q_out), h),
@@ -99,10 +92,10 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Params:
         "mlp_norm": jnp.ones((L, h), pd),
     }
     return {
-        "embed": _dense_init(k_emb, (cfg.vocab_size, h), pd, 1.0),
+        "embed": dense_init(k_emb, (cfg.vocab_size, h), pd, 1.0),
         "layers": layers,
         "final_norm": jnp.ones((h,), pd),
-        "lm_head": _dense_init(k_out, (h, cfg.vocab_size), pd, h),
+        "lm_head": dense_init(k_out, (h, cfg.vocab_size), pd, h),
     }
 
 
@@ -137,72 +130,6 @@ def batch_specs() -> P:
     return P("dp", None)
 
 
-def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    return (x32 * lax.rsqrt(var + eps)).astype(x.dtype) * w
-
-
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embeddings. x: [B, T, H, D], positions: [B, T]."""
-    d_half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(0, d_half, dtype=jnp.float32) / d_half)
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B,T,Dh]
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.astype(x.dtype)
-
-
-def dense_attention(q, k, v, *, causal: bool = True, window=None):
-    """Grouped-query attention with the scores materialised. q: [B,T,Hq,D],
-    k: [B,T,Hkv,D], v: [B,T,Hkv,Dv] (Dv = D but for latent attention).
-    ``window``: query i sees key j iff 0 <= i - j < window."""
-    b, t, hq, d = q.shape
-    hkv = k.shape[2]
-    group = hq // hkv
-    q = q.reshape(b, t, hkv, group, d)
-    scores = jnp.einsum("bthgd,bshd->bhgts", q, k).astype(jnp.float32)
-    scores = scores * (d ** -0.5)
-    if causal:
-        mask = jnp.tril(jnp.ones((t, t), bool))
-        if window is not None:
-            mask &= ~jnp.tril(jnp.ones((t, t), bool), -window)
-        scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bhgts,bshd->bthgd", probs, v)
-    return out.reshape(b, t, hq * v.shape[-1])
-
-
-def attention(q, k, v, *, causal: bool = True, window=None):
-    """Grouped-query attention, by the implementation the operands and the
-    platform being lowered for allow: the fused blockwise kernel
-    (``ops.flash_attention``, no [T,T] array in HBM in either pass) for
-    causal bf16 attention at shapes it supports when lowered for TPU, the
-    dense form for everything else. One traced function serves every
-    platform. ``attn_kernel_lowerings`` / ``attn_dense_lowerings`` count
-    which one each lowered program holds. ``window``: sliding-window
-    attention, the same mask in both forms (the band kernels)."""
-
-    def dense(q, k, v):
-        with jax.named_scope("attn.dense"):
-            return dense_attention(
-                count_lowering(q, "attn_dense_lowerings"), k, v,
-                causal=causal, window=window)
-
-    def kernel(q, k, v):
-        return flash_attention(
-            count_lowering(q, "attn_kernel_lowerings"), k, v, causal=causal,
-            window=window)
-
-    if not (causal and q.dtype == k.dtype == v.dtype == jnp.bfloat16
-            and flash_supported(q.shape, k.shape, q.dtype, v.shape,
-                                window)):
-        return dense(q, k, v)
-    return lax.platform_dependent(q, k, v, tpu=kernel, default=dense)
-
-
 def _layer(cfg: LlamaConfig, x: jax.Array, lp: Params, positions: jax.Array,
            attn_fn=None) -> jax.Array:
     b, t, h = x.shape
@@ -233,17 +160,10 @@ def forward(params: Params, tokens: jax.Array, cfg: LlamaConfig,
     (bf16) — the cast happens per-layer inside the scan so only one layer's
     bf16 copy is live at a time.
     """
-    # Gather rows first, THEN cast: avoids materializing a full bf16 copy of
-    # the [vocab, hidden] table (≈1GB at 128k vocab) just to read B*T rows.
-    with jax.named_scope("embed"):
-        x = params["embed"][tokens].astype(cfg.dtype)
-        positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]),
-                                     tokens.shape)
+    x, positions = embed(params, tokens, cfg.dtype)
 
     def body(x, lp):
-        with jax.named_scope("weights.cast"):
-            lp = jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype), lp)
-        return _layer(cfg, x, lp, positions, attn_fn), None
+        return _layer(cfg, x, cast(lp, cfg.dtype), positions, attn_fn), None
 
     x, _ = lax.scan(body, x, params["layers"])
     with jax.named_scope("llama.head_loss"):

@@ -52,17 +52,16 @@ that predict a token. Named scopes: ``loop.layer.attn``, ``loop.layer.mlp``,
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from brpc_tpu.models import train_step
 from brpc_tpu.models.chunked_loss import chunked_next_token_loss
-from brpc_tpu.models.llama import _dense_init, attention, rms_norm, rope
-from brpc_tpu.models.train_step import apply_updates
-from brpc_tpu.ops.flash_attention import RESIDUAL_NAMES
+from brpc_tpu.models.layers import dense_init, rms_norm, rope
+from brpc_tpu.ops.flash_attention import RESIDUAL_NAMES, attention
 
 Params = Dict[str, Any]
 
@@ -101,7 +100,7 @@ def init_params(key: jax.Array, cfg: LoopedConfig) -> Params:
     ks = iter(jax.random.split(key, 10))
 
     def mat(shape, fan_in):
-        return _dense_init(next(ks), shape, pd, fan_in)
+        return dense_init(next(ks), shape, pd, fan_in)
 
     gain = lambda: jnp.ones((n, h), pd)  # noqa: E731
     return {
@@ -145,20 +144,13 @@ def hidden_states(params: Params, tokens: jax.Array,
     """tokens: [B, T] -> the final-normed states of every pass [R, B, T, H].
     Master weights stay float32; a layer's compute-dtype copy is made inside
     its scan step, so the gradient of the shared stack gathers in float32."""
-    with jax.named_scope("embed"):
-        x = params["embed"][tokens].astype(cfg.dtype)
-        positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]),
-                                     tokens.shape)
+    x, positions = train_step.embed(params, tokens, cfg.dtype)
     with jax.named_scope("weights.cast"):
         final_norm = params["final_norm"].astype(cfg.dtype)
 
-    @functools.partial(
-        jax.checkpoint,
-        policy=jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES))
+    @train_step.recomputed(RESIDUAL_NAMES)
     def layer(x, lp):
-        with jax.named_scope("weights.cast"):
-            lp = jax.tree_util.tree_map(lambda w: w.astype(cfg.dtype), lp)
-        return _layer(cfg, x, lp, positions), None
+        return _layer(cfg, x, train_step.cast(lp, cfg.dtype), positions), None
 
     def one_pass(x, _):
         x, _ = lax.scan(layer, x, params["layers"])
@@ -220,11 +212,4 @@ def loss_fn(params: Params, tokens: jax.Array, cfg: LoopedConfig):
 def make_train_step(cfg: LoopedConfig, optimizer):
     """Returns jittable (params, opt_state, tokens) -> (params, opt_state,
     loss, stats)."""
-
-    def step(params, opt_state, tokens):
-        (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params, tokens, cfg)
-        params, opt_state = apply_updates(optimizer, grads, opt_state, params)
-        return params, opt_state, loss, stats
-
-    return step
+    return train_step.make_train_step(loss_fn, cfg, optimizer)

@@ -36,7 +36,7 @@ and ``routed_scaling``, ``norm_topk_prob`` with softmax scores ->
 where the group has none).
 
 The layers. Every layer is ``x = x + attn(norm(x)); x = x + mlp(norm(x))``
-with RMSNorm (``llama.rms_norm``); a layer's MLP is a dense SwiGLU of
+with RMSNorm (``layers.rms_norm``); a layer's MLP is a dense SwiGLU of
 ``intermediate`` where ``mlp_layer_types`` says ``dense``, else the experts.
 
 - **Attention** (H = ``full_heads`` or ``window_heads`` query heads, d =
@@ -44,7 +44,7 @@ with RMSNorm (``llama.rms_norm``); a layer's MLP is a dense SwiGLU of
   ``n_kv_heads``, d]; with ``attn_gate``, ``g = y·W_g`` [T, H]; with
   ``qk_norm``, ``q <- rmsnorm_d(q)·w_q`` and ``k <- rmsnorm_d(k)·w_k`` per
   head, weights of d, before the rope. Rope, halves rotated
-  (``llama.rope``'s convention). Window layers: plain rope of
+  (``layers.rope``'s convention). Window layers: plain rope of
   ``window_rope_theta`` on the whole head. Full layers: yarn on the leading
   ``full_rotary_factor`` of the head (``yarn_inv_freq``: interpolated and
   extrapolated frequencies blended by a linear ramp between the dimensions
@@ -56,23 +56,23 @@ with RMSNorm (``llama.rms_norm``); a layer's MLP is a dense SwiGLU of
   (``ops/flash_attention.py``), which read q and k head-major as one pass of
   ``ops/qk_layout.py`` leaves them — norm, rope and the turn from the
   projections' token-major layout done in float32 in registers; elsewhere
-  the norm and the rope as float32 passes and ``llama.attention``.
+  the norm and the rope as float32 passes and ``flash_attention.attention``.
   With ``attn_gate``, ``o_h <- sigmoid(g_h) · o_h`` per head, g in float32:
   on a TPU in the attention kernels' epilogue, on the float32 accumulator
   before its one cast, the gradient of g from the backward pass's
-  ``delta``; elsewhere after attention, in float32 (``_gated``). ``·W_o``.
-  No biases.
+  ``delta``; elsewhere after attention, in float32
+  (``flash_attention.gated``). ``·W_o``. No biases.
 - **Experts.** ``router="sigmoid"``: ``s = sigmoid(y·W_r)`` in float32 over
   ALL ``n_experts``; the ``experts_per_token`` largest; weights ``s_sel /
-  sum s_sel · routed_scaling`` (``deepseek.route``, with no selection bias).
-  ``router="softmax"``: ``p = softmax(y·W_r)`` in float32 over all; the
-  largest; weights ``p_sel / sum p_sel`` (``hybrid.route``). Experts SwiGLU
-  of ``moe_intermediate``; plus, where ``shared_intermediate`` is not 0, one
-  unweighted shared SwiGLU of that width. What follows the routing is
-  ``models/experts.py``, and so is the contract of a chip's share: told
-  ``n_held`` and ``expert_offset`` it routes over all, normalises over all
-  the selected wherever they live, computes what its own give, drops
-  nothing.
+  sum s_sel · routed_scaling`` (``experts.sigmoid_route``, with no selection
+  bias). ``router="softmax"``: ``p = softmax(y·W_r)`` in float32 over all;
+  the largest; weights ``p_sel / sum p_sel`` (``experts.softmax_route``).
+  Experts SwiGLU of ``moe_intermediate``; plus, where
+  ``shared_intermediate`` is not 0, one unweighted shared SwiGLU of that
+  width. What follows the routing is ``models/experts.py``, and so is the
+  contract of a chip's share: told ``n_held`` and ``expert_offset`` it
+  routes over all, normalises over all the selected wherever they live,
+  computes what its own give, drops nothing.
 
 What the published configs leave to convention (the benchmark's
 configuration files list each under ``assumed``): SwiGLU with silu; the gate
@@ -123,16 +123,14 @@ import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from brpc_tpu.models import deepseek, hybrid
-from brpc_tpu.models.chunked_loss import chunked_next_token_loss
-from brpc_tpu.models.experts import expert_mlp, swiglu
-from brpc_tpu.models.llama import (_dense_init, attention, dense_attention,
-                                   rms_norm, rope)
-from brpc_tpu.models.train_step import apply_updates
+from brpc_tpu.models import train_step
+from brpc_tpu.models.experts import (expert_mlp, sigmoid_route,
+                                     softmax_route, swiglu)
+from brpc_tpu.models.layers import dense_init, rms_norm, rope
 from brpc_tpu.ops import grouped_matmul as gm
 from brpc_tpu.ops import qk_layout
-from brpc_tpu.ops.flash_attention import (RESIDUAL_NAMES,
-                                          flash_attention_head_major,
+from brpc_tpu.ops.flash_attention import (RESIDUAL_NAMES, attention,
+                                          attention_head_major, gated,
                                           supported as flash_supported)
 from brpc_tpu.ops.lowered import count_lowering
 
@@ -319,7 +317,7 @@ def init_params(key: jax.Array, cfg: WindowedConfig) -> Params:
     def stack(key, lead, heads, mlp):
         ks = iter(jax.random.split(key, 16))
         gate = (("wg", (h, heads), h),) if cfg.attn_gate else ()
-        layers = {name: _dense_init(next(ks), lead + shape, pd, fan_in)
+        layers = {name: dense_init(next(ks), lead + shape, pd, fan_in)
                   for name, shape, fan_in in (
                       ("wq", (h, heads * d), h), ("wk", (h, kv_out), h),
                       ("wv", (h, kv_out), h), *gate,
@@ -342,11 +340,11 @@ def init_params(key: jax.Array, cfg: WindowedConfig) -> Params:
               ("w_up", (cfg.n_held, h, f), h),
               ("w_down", (cfg.n_held, f, h), f), *shared)
     params = {
-        "embed": _dense_init(k_emb, (cfg.vocab_size, h), pd, 1.0),
+        "embed": dense_init(k_emb, (cfg.vocab_size, h), pd, 1.0),
         "window": stack(k_win, (periods, per), cfg.window_heads, sparse),
         "full": stack(k_full, (periods,), cfg.full_heads, sparse),
         "final_norm": jnp.ones((h,), pd),
-        "lm_head": _dense_init(k_out, (h, cfg.vocab_size), pd, h),
+        "lm_head": dense_init(k_out, (h, cfg.vocab_size), pd, h),
     }
     if n_first:
         params["first"] = stack(k_first, (), cfg.full_heads, dense)
@@ -361,7 +359,7 @@ def _rotary(cfg: WindowedConfig, full: bool) -> tuple:
     if full:
         return (int(cfg.head_dim * cfg.full_rotary_factor),
                 yarn_inv_freq(cfg), cfg.yarn_attention_factor)
-    half = cfg.head_dim // 2        # ``llama.rope``'s, by its own arithmetic
+    half = cfg.head_dim // 2        # ``layers.rope``'s, by its own arithmetic
     return cfg.head_dim, cfg.window_rope_theta ** (
         -jnp.arange(0, half, dtype=jnp.float32) / half), 1.0
 
@@ -462,55 +460,19 @@ def _qk_head_major_bwd(cfg, kind, residuals, cotangents):
 qk_head_major.defvjp(_qk_head_major_fwd, _qk_head_major_bwd)
 
 
-def _gated(o: jax.Array, gate: jax.Array) -> jax.Array:
-    """The plain form of the per-head output gate: o: [B, T, H·d] times the
-    sigmoid of the float32 logits ``gate`` [B, T, H], head by head, in
-    float32."""
-    b, t, nh = gate.shape
-    return (o.reshape(b, t, nh, -1).astype(jnp.float32)
-            * jax.nn.sigmoid(gate)[..., None]).astype(o.dtype).reshape(
-                b, t, -1)
-
-
-def attention_head_major(q: jax.Array, k: jax.Array, v: jax.Array, window,
-                         gate=None):
-    """``llama.attention`` (causal, with its counters) for q: [B, Hq, T, D]
-    and k: [B, Hkv, T, D] head-major already and bf16 operands the fused
-    kernels take: they read q and k as they are; the dense form takes them
-    token-major again. ``gate``: the per-head output gate's float32 logits
-    [B, T, Hq], or None: the kernels take it into their epilogue
-    (``attn_gated_kernel_lowerings`` counts it), the dense form applies it
-    after as ``_gated``."""
-    def dense(q, k, v, *gate):
-        with jax.named_scope("attn.dense"):
-            o = dense_attention(
-                count_lowering(q.transpose(0, 2, 1, 3),
-                               "attn_dense_lowerings"),
-                k.transpose(0, 2, 1, 3), v, window=window)
-        return _gated(o, *gate) if gate else o
-
-    def kernel(q, k, v, *gate):
-        gate = (count_lowering(g, "attn_gated_kernel_lowerings") for g in gate)
-        return flash_attention_head_major(
-            count_lowering(q, "attn_kernel_lowerings"), k, v, *gate,
-            window=window)
-
-    gate = () if gate is None else (gate,)
-    return lax.platform_dependent(q, k, v, *gate, tpu=kernel, default=dense)
-
-
 def attend(cfg: WindowedConfig, kind: str, q: jax.Array, k: jax.Array,
            v: jax.Array, norms, positions: jax.Array,
            gate=None) -> jax.Array:
     """Attention of a ``"full"`` or ``"window"`` layer on q: [B, T, H·d] and
     k: [B, T, ``n_kv_heads``·d] as the projections leave them and v: [B, T,
     ``n_kv_heads``, d] -> [B, T, H·d]: each head of q and k normed and
-    turned by the rope of the layer's kind, then ``llama.attention``'s
+    turned by the rope of the layer's kind, then ``attention``'s
     contract, each head's output times the sigmoid of its ``gate`` logit
     (float32 [B, T, H]) where there is one. Operands that both the q/k
     layout kernels and the attention kernels take go head-major through
     ``qk_head_major`` to ``attention_head_major``; everything else runs the
-    plain form and ``llama.attention`` from token-major operands."""
+    plain form and ``attention`` from token-major operands (both
+    ``ops/flash_attention.py``)."""
     full = kind == "full"
     scope = "full" if full else "swa"
     (b, t, _), d = q.shape, cfg.head_dim
@@ -530,7 +492,7 @@ def attend(cfg: WindowedConfig, kind: str, q: jax.Array, k: jax.Array,
     if gate is None:
         return o
     with jax.named_scope(f"{scope}.out"):
-        return _gated(o, gate)
+        return gated(o, gate)
 
 
 def attention_block(cfg: WindowedConfig, kind: str, x: jax.Array, lp: Params,
@@ -560,9 +522,9 @@ def moe_mlp(cfg: WindowedConfig, y: jax.Array, lp: Params):
     shared expert where the model has one."""
     with jax.named_scope("moe.router"):
         if cfg.router == "softmax":
-            selected, weights = hybrid.route(cfg, y, lp["router"])
+            selected, weights = softmax_route(cfg, y, lp["router"])
         elif cfg.router == "sigmoid":   # DeepSeek-V3's, no selection bias
-            selected, weights = deepseek.route(cfg, y, lp["router"], 0.0)
+            selected, weights = sigmoid_route(cfg, y, lp["router"], 0.0)
         else:
             raise ValueError(f"router {cfg.router!r}: sigmoid or softmax")
     shared = None
@@ -574,63 +536,36 @@ def moe_mlp(cfg: WindowedConfig, y: jax.Array, lp: Params):
         n_held=cfg.n_held, expert_offset=cfg.expert_offset, shared=shared)
 
 
-def _cast(lp: Params, dtype) -> Params:
-    with jax.named_scope("weights.cast"):
-        return {k: v if k in _FLOAT32_LEAVES else v.astype(dtype)
-                for k, v in lp.items()}
-
-
 def hidden_states(params: Params, tokens: jax.Array, cfg: WindowedConfig):
     """tokens: [B, T] -> (final-normed states [B, T, H], the expert layers'
     stats, a row a sparse layer in the layers' order). Master weights stay
     float32; a layer's compute-dtype copy is made inside its scan step."""
-    with jax.named_scope("embed"):
-        x = params["embed"][tokens].astype(cfg.dtype)
-        b, t, h = x.shape
-        positions = jnp.broadcast_to(jnp.arange(t), tokens.shape)
-    recomputed = functools.partial(
-        jax.checkpoint,
-        policy=jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES))
+    x, positions = train_step.embed(params, tokens, cfg.dtype)
+    recomputed = train_step.recomputed(SAVED_NAMES)
 
     @recomputed
     def first(x, lp):
-        lp = _cast(lp, cfg.dtype)
+        lp = train_step.cast(lp, cfg.dtype, _FLOAT32_LEAVES)
         x = attention_block(cfg, "full", x, lp, positions)
         with jax.named_scope("dense.mlp"):
             y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
             return x + swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"])
 
     def sparse(kind):
-        @recomputed
-        def run(x, lp):
-            lp = _cast(lp, cfg.dtype)
-            x = attention_block(cfg, kind, x, lp, positions)
-            with jax.named_scope("windowed.glue"):
-                y = rms_norm(x, lp["mlp_norm"], cfg.norm_eps).reshape(b * t, h)
-            out, stats = moe_mlp(cfg, y, lp)
-            with jax.named_scope("windowed.glue"):
-                return x + out.reshape(b, t, h), stats
-        return run
+        return recomputed(train_step.expert_layer(
+            cfg, _FLOAT32_LEAVES,
+            lambda x, lp: attention_block(cfg, kind, x, lp, positions),
+            rms_norm, "windowed.glue", moe_mlp))
 
-    window, full = sparse("window"), sparse("full")
-
-    def period(x, lps):
-        x, window_stats = lax.scan(window, x, lps["window"])
-        x, full_stats = full(x, lps["full"])
-        return x, {"window": window_stats, "full": full_stats}
-
+    window = sparse("window")
     if "first" in params:
         x = first(x, params["first"])
-    x, stats = lax.scan(period, x, {"window": params["window"],
-                                    "full": params["full"]})
+    x, stats = train_step.scan_periods(x, window, sparse("full"),
+                                       params["window"], params["full"])
     if "tail" in params:
         x, tail_stats = lax.scan(window, x, params["tail"])
     with jax.named_scope("windowed.glue"):
-        # [periods, windows a period, ...] and [periods, ...] -> [layers, ...]
-        stats = jax.tree_util.tree_map(
-            lambda win, full: jnp.concatenate(
-                [win, full[:, None]], axis=1).reshape(-1, *full.shape[1:]),
-            stats["window"], stats["full"])
+        stats = train_step.in_layer_order(*stats)
         if "tail" in params:
             stats = jax.tree_util.tree_map(
                 lambda a, b: jnp.concatenate([a, b]), stats, tail_stats)
@@ -641,29 +576,18 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: WindowedConfig):
 def forward(params: Params, tokens: jax.Array, cfg: WindowedConfig):
     """tokens: [B, T] int32 -> logits [B, T, vocab] float32, whole (tests
     and small batches; the loss does not call this)."""
-    x, _ = hidden_states(params, tokens, cfg)
-    return jnp.dot(x, params["lm_head"].astype(cfg.dtype),
-                   preferred_element_type=jnp.float32)
+    return train_step.whole_logits(hidden_states(params, tokens, cfg),
+                                   params, cfg)
 
 
 def loss_fn(params: Params, tokens: jax.Array, cfg: WindowedConfig):
     """Next-token cross-entropy (the last position predicts nothing), and
-    the forward pass's stats; the head a chunk of positions at a time."""
-    x, stats = hidden_states(params, tokens, cfg)
-    with jax.named_scope("loss.chunk"):
-        head = params["lm_head"].astype(cfg.dtype)
-        return chunked_next_token_loss((x,), head, tokens,
-                                       lambda nlls: nlls[0]), stats
+    the forward pass's stats (``train_step.next_token_loss``)."""
+    return train_step.next_token_loss(hidden_states(params, tokens, cfg),
+                                      params, tokens, cfg)
 
 
 def make_train_step(cfg: WindowedConfig, optimizer):
     """Returns jittable (params, opt_state, tokens) -> (params, opt_state,
     loss, stats)."""
-
-    def step(params, opt_state, tokens):
-        (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params, tokens, cfg)
-        params, opt_state = apply_updates(optimizer, grads, opt_state, params)
-        return params, opt_state, loss, stats
-
-    return step
+    return train_step.make_train_step(loss_fn, cfg, optimizer)

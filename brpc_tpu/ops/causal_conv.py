@@ -37,8 +37,8 @@ The names do not start with ``gdn_``: the benchmark's ``gdn_step_share`` and
 ``gdn_roofline`` take every op named ``gdn_*`` for the recurrence's kernels
 (ops/gated_delta.py) and set their time against the recurrence's work alone.
 
-Which form runs is chosen as ``ops/gated_delta.py`` and ``llama.attention``
-choose: by the operands while tracing (``kernels_take``: bf16, channels of
+Which form runs is chosen as every kernel of ``ops/`` is (``lowered.choose``):
+by the operands while tracing (``kernels_take``: bf16, channels of
 whole 128-lane tiles, positions of whole blocks, K at most 8), by the
 platform when lowered (``lax.platform_dependent``, the plain form as
 ``default``). ``conv_lowerings`` counts the programs lowered with the
@@ -57,12 +57,11 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from brpc_tpu.ops.lowered import count_lowering
+from brpc_tpu.ops.lowered import choose, compiler_params
 
 _HALO = 16               # rows of a bf16 tile: a run's neighbours, each way
 _LANES = 128
 _MAX_TAPS = 8
-_VMEM_LIMIT = 64 << 20
 # rows a trip of the kernels' loops widens to float32 at once
 _FWD_ROWS, _BWD_ROWS = 256, 128
 
@@ -217,13 +216,6 @@ def _width(c: int) -> int:
     return next(w for w in (512, 256, _LANES) if c % w == 0)
 
 
-def _params(sequential: bool):
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel",
-                             "arbitrary" if sequential else "parallel"),
-        vmem_limit_bytes=_VMEM_LIMIT)
-
-
 def _fwd_kernels(x, taps, *, interpret: bool):
     (b, t, _), (k, c) = x.shape, taps.shape
     block, width = _block(t), _width(c)
@@ -236,7 +228,7 @@ def _fwd_kernels(x, taps, *, interpret: bool):
                   pl.BlockSpec((k, width), lambda b, c, i: (0, c))],
         out_specs=here,
         out_shape=jax.ShapeDtypeStruct((b, t, c), x.dtype),
-        compiler_params=_params(sequential=False),
+        compiler_params=compiler_params(("parallel",) * 3),
     )(x, x, taps.astype(jnp.float32))
 
 
@@ -255,7 +247,8 @@ def _bwd_kernels(x, taps, dy, *, interpret: bool):
                    pl.BlockSpec((1, k, width), lambda b, c, i: (b, 0, c))],
         out_shape=[jax.ShapeDtypeStruct((b, t, c), x.dtype),
                    jax.ShapeDtypeStruct((b, k, c), jnp.float32)],
-        compiler_params=_params(sequential=True),
+        compiler_params=compiler_params(("parallel", "parallel",
+                                         "arbitrary")),
     )(x, x, x, dy, dy, taps.astype(jnp.float32))
     return dx, dw.sum(0)
 
@@ -267,21 +260,11 @@ def _bwd_plain(x, taps, dy):
     return dx, dw.astype(jnp.float32)
 
 
-def _choose(kernels, plain, interpret, x, *rest):
-    """The kernels through the Pallas interpreter or the compiler where
-    ``interpret`` says (tests); else by the platform the program is lowered
-    for. Only operands ``kernels_take`` come here."""
-    if interpret is not None:
-        return kernels(x, *rest, interpret=interpret)
-    return lax.platform_dependent(
-        x, *rest, default=plain,
-        tpu=lambda x, *rest: kernels(
-            count_lowering(x, "conv_lowerings"), *rest, interpret=False))
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _conv_silu(x, taps, interpret):
-    return _choose(_fwd_kernels, _plain, interpret, x, taps)
+    # only operands ``kernels_take`` come here
+    return choose(_fwd_kernels, _plain, True, "conv_lowerings", interpret, x,
+                  taps)
 
 
 def _conv_silu_fwd(x, taps, interpret):
@@ -290,7 +273,8 @@ def _conv_silu_fwd(x, taps, interpret):
 
 def _conv_silu_bwd(interpret, residuals, dy):
     x, taps = residuals
-    dx, dw = _choose(_bwd_kernels, _bwd_plain, interpret, x, taps, dy)
+    dx, dw = choose(_bwd_kernels, _bwd_plain, True, "conv_lowerings",
+                    interpret, x, taps, dy)
     # the channels past the convolved ones took no part
     dx = jnp.pad(dx, ((0, 0), (0, 0), (0, x.shape[2] - dx.shape[2])))
     return dx, dw.astype(taps.dtype)

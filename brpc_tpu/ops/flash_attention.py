@@ -30,7 +30,7 @@ the training path) and accumulates in float32; scores, softmax statistics,
 ``delta`` and every accumulator are float32; the probabilities and dS are
 cast to the operand dtype only as matmul operands.
 
-GQA layout matches brpc_tpu.models.llama: q [B, T, Hq, D], k/v
+GQA layout, as every model's projections leave it: q [B, T, Hq, D], k/v
 [B, T, Hkv, D]; the kv head for q head h is h // (Hq // Hkv). q and k share
 one width and v (with the output and dO) may have another: latent attention
 (models/deepseek.py) has q, k of 192 = 128 + 64 rope dims and v of 128. The
@@ -70,6 +70,13 @@ path as it was.
 
 ``flash_attention(..., interpret=True)`` runs the same kernels through the
 Pallas interpreter (CPU tests); on TPU leave it False.
+
+The models do not call the kernels: they call ``attention`` (token-major q
+and k) or ``attention_head_major`` (q and k head-major, an output gate or
+none), which choose the kernels or ``dense_attention`` by the operands while
+tracing and by the platform when lowered, and count which one each lowered
+program holds. ``dense_attention`` is also the plain form the tests hold the
+kernels to.
 """
 
 from __future__ import annotations
@@ -83,8 +90,9 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from brpc_tpu.ops.lowered import VMEM_LIMIT, compiler_params, count_lowering
+
 _MASKED = -1e30          # finite: exp(_MASKED - finite) is 0, never NaN
-_VMEM_LIMIT = 64 << 20   # of a v5e core's 128 MiB; Mosaic's default is 16
 
 # ``checkpoint_name``s of the forward kernel's head-major output and its
 # log-sum-exp. A ``jax.checkpoint`` whose policy saves them does not run the
@@ -137,7 +145,7 @@ def heads_together(group: int, t: int, d_qk: int, d_v: int, dtype) -> bool:
     """Whether the backward kernel takes a KV head's whole query group at
     once (the GQA sum then happens in dK's and dV's accumulators), or each
     query head on its own with the group summed afterwards."""
-    return _resident(group, t, d_qk, d_v, dtype) <= _VMEM_LIMIT // 2
+    return _resident(group, t, d_qk, d_v, dtype) <= VMEM_LIMIT // 2
 
 
 def supported(q_shape, kv_shape, dtype, v_shape=None, window=None) -> bool:
@@ -165,7 +173,7 @@ def supported(q_shape, kv_shape, dtype, v_shape=None, window=None) -> bool:
     d_v = (v_shape or kv_shape)[3]
     if d_qk % 64 or d_v % 128 or t % 128 or hq % hkv:
         return False
-    return _resident(1, t, d_qk, d_v, dtype) <= _VMEM_LIMIT // 2
+    return _resident(1, t, d_qk, d_v, dtype) <= VMEM_LIMIT // 2
 
 
 def _nt(a, b):
@@ -409,9 +417,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _call(kernel, name, interpret, last_axis="parallel", **kwargs):
     return pl.pallas_call(
         kernel, name=name, interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", last_axis),
-            vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=compiler_params(("parallel", "parallel", last_axis)),
         **kwargs)
 
 
@@ -581,7 +587,7 @@ def flash_attention(
     window: int | None = None,
 ) -> jax.Array:
     """q: [B,T,Hq,Dqk], k: [B,T,Hkv,Dqk], v: [B,T,Hkv,Dv] -> [B,T,Hq*Dv]
-    (llama.attention contract), differentiable. The tiles are chosen from T
+    (``attention``'s contract), differentiable. The tiles are chosen from T
     (and from whether there is a ``window``, which needs ``causal``);
     ``block_q`` / ``block_k`` override them in both passes (tests)."""
     b, t, hq, _ = q.shape
@@ -615,3 +621,90 @@ def flash_attention_head_major(q: jax.Array, k: jax.Array, v: jax.Array,
     out = _attend(q, k, v, gate, True, blocks, interpret, window)
     with jax.named_scope("attn.layout"):
         return out.transpose(0, 2, 1, 3).reshape(b, t, hq * v.shape[3])
+
+
+# -- the kernels or the dense form --------------------------------------------
+
+def dense_attention(q, k, v, *, causal: bool = True, window=None):
+    """Grouped-query attention with the scores materialised. q: [B,T,Hq,D],
+    k: [B,T,Hkv,D], v: [B,T,Hkv,Dv] (Dv = D but for latent attention).
+    ``window``: query i sees key j iff 0 <= i - j < window."""
+    b, t, hq, d = q.shape
+    hkv = k.shape[2]
+    group = hq // hkv
+    q = q.reshape(b, t, hkv, group, d)
+    scores = jnp.einsum("bthgd,bshd->bhgts", q, k).astype(jnp.float32)
+    scores = scores * (d ** -0.5)
+    if causal:
+        mask = jnp.tril(jnp.ones((t, t), bool))
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((t, t), bool), -window)
+        scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    out = jnp.einsum("bhgts,bshd->bthgd", probs, v)
+    return out.reshape(b, t, hq * v.shape[-1])
+
+
+def attention(q, k, v, *, causal: bool = True, window=None):
+    """Grouped-query attention, by the implementation the operands and the
+    platform being lowered for allow: the fused blockwise kernels
+    (``flash_attention``, no [T,T] array in HBM in either pass) for causal
+    bf16 attention at shapes they support (``supported``) when lowered for
+    TPU, ``dense_attention`` for everything else. One traced function
+    serves every platform. ``attn_kernel_lowerings`` /
+    ``attn_dense_lowerings`` count which one each lowered program holds.
+    ``window``: sliding-window attention, the same mask in both forms (the
+    band kernels)."""
+
+    def dense(q, k, v):
+        with jax.named_scope("attn.dense"):
+            return dense_attention(
+                count_lowering(q, "attn_dense_lowerings"), k, v,
+                causal=causal, window=window)
+
+    def kernel(q, k, v):
+        return flash_attention(
+            count_lowering(q, "attn_kernel_lowerings"), k, v, causal=causal,
+            window=window)
+
+    if not (causal and q.dtype == k.dtype == v.dtype == jnp.bfloat16
+            and supported(q.shape, k.shape, q.dtype, v.shape, window)):
+        return dense(q, k, v)
+    return lax.platform_dependent(q, k, v, tpu=kernel, default=dense)
+
+
+def gated(o: jax.Array, gate: jax.Array) -> jax.Array:
+    """The plain form of the per-head output gate: o: [B, T, H·d] times the
+    sigmoid of the float32 logits ``gate`` [B, T, H], head by head, in
+    float32."""
+    b, t, nh = gate.shape
+    return (o.reshape(b, t, nh, -1).astype(jnp.float32)
+            * jax.nn.sigmoid(gate)[..., None]).astype(o.dtype).reshape(
+                b, t, -1)
+
+
+def attention_head_major(q: jax.Array, k: jax.Array, v: jax.Array, window,
+                         gate=None):
+    """``attention`` (causal, with its counters) for q: [B, Hq, T, D] and k:
+    [B, Hkv, T, D] head-major already (ops/qk_layout.py writes them so) and
+    bf16 operands the fused kernels take: ``flash_attention_head_major``
+    reads q and k as they are; the dense form takes them token-major again.
+    ``gate``: the per-head output gate's float32 logits [B, T, Hq], or None:
+    the kernels take it into their epilogue (``attn_gated_kernel_lowerings``
+    counts it), the dense form applies it after as ``gated``."""
+    def dense(q, k, v, *gate):
+        with jax.named_scope("attn.dense"):
+            o = dense_attention(
+                count_lowering(q.transpose(0, 2, 1, 3),
+                               "attn_dense_lowerings"),
+                k.transpose(0, 2, 1, 3), v, window=window)
+        return gated(o, *gate) if gate else o
+
+    def kernel(q, k, v, *gate):
+        gate = (count_lowering(g, "attn_gated_kernel_lowerings") for g in gate)
+        return flash_attention_head_major(
+            count_lowering(q, "attn_kernel_lowerings"), k, v, *gate,
+            window=window)
+
+    gate = () if gate is None else (gate,)
+    return lax.platform_dependent(q, k, v, *gate, tpu=kernel, default=dense)

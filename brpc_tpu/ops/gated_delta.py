@@ -49,7 +49,7 @@ accumulator are float32. The per-chunk mathematics is ONE pair of functions,
 the kernels call on their blocks and the plain form calls under ``vmap`` and
 ``lax.scan``: the same chunked algorithm in plain ``jax.numpy`` wherever the
 kernels are not taken (another platform, float32, widths that are no multiple
-of 128), chosen by ``lax.platform_dependent`` like ``llama.attention``.
+of 128), chosen as every kernel of ``ops/`` is (``lowered.choose``).
 ``gdn_lowerings`` counts the programs lowered with the kernels.
 
 What the forward pass makes carries names, so that a caller who recomputes
@@ -78,11 +78,10 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from brpc_tpu.ops.lowered import count_lowering
+from brpc_tpu.ops.lowered import choose, compiler_params
 
 CHUNK = 64
 _INNER = 16              # the diagonal blocks of A inverted on their own
-_VMEM_LIMIT = 64 << 20
 
 # ``checkpoint_name``s of the forward pass's head-major output and of the
 # chunks' entry states, which is all the backward pass keeps beside its
@@ -408,10 +407,9 @@ def _call(kernel, name, interpret, sequential: bool = True, **kwargs):
     ``sequential``."""
     return pl.pallas_call(
         kernel, name=name, interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel",
-                                 "arbitrary" if sequential else "parallel"),
-            vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=compiler_params(
+            ("parallel", "parallel",
+             "arbitrary" if sequential else "parallel")),
         **kwargs)
 
 
@@ -569,19 +567,10 @@ def _bwd_plain(q, k, v, g_rows, b_rows, do, states, t_all, *, chunk: int):
 # -- kernel or plain form, and the rule's VJP ---------------------------------
 
 def _choose(kernels, plain, taken: bool, interpret, chunk: int, *operands):
-    """As ``llama.attention`` chooses: by the operands while tracing, by the
-    platform when lowered; ``interpret`` True / False forces the kernels
-    through the Pallas interpreter or the compiler (tests)."""
-    if interpret is not None:
-        return kernels(*operands, chunk=chunk, interpret=interpret)
-    plain = functools.partial(plain, chunk=chunk)
-    if not taken:
-        return plain(*operands)
-    return lax.platform_dependent(
-        *operands, default=plain,
-        tpu=lambda first, *rest: kernels(
-            count_lowering(first, "gdn_lowerings"), *rest, chunk=chunk,
-            interpret=False))
+    """``lowered.choose`` at ``chunk``, counted as ``gdn_lowerings``."""
+    return choose(functools.partial(kernels, chunk=chunk),
+                  functools.partial(plain, chunk=chunk), taken,
+                  "gdn_lowerings", interpret, *operands)
 
 
 def _rows(x, chunk: int):

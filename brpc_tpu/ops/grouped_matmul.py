@@ -63,9 +63,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from brpc_tpu.ops.lowered import count_lowering
-
-_VMEM_LIMIT = 64 << 20
+from brpc_tpu.ops.lowered import VMEM_LIMIT, choose, compiler_params
 
 # The ``checkpoint_name`` that every field of a ``group_layout`` carries, and
 # that a caller gives the routing the layout was made from: integers only,
@@ -203,25 +201,6 @@ def assignments_of(lay: GroupLayout, values: jax.Array) -> jax.Array:
     return by_source[:lay.dest.shape[0]]
 
 
-# -- kernel or plain form -----------------------------------------------------
-
-def _choose(kernel, plain, taken: bool, counter: str, interpret, *operands):
-    """``kernel(*operands, interpret=)`` or ``plain(*operands)``, chosen as
-    ``llama.attention`` chooses: by the operands while tracing (``taken``),
-    by the platform when lowered, where a program that keeps the kernels
-    adds one to ``counter``. ``interpret`` True / False forces the kernels
-    through the Pallas interpreter or the compiler (tests)."""
-    if interpret is not None:
-        return kernel(*operands, interpret=interpret)
-    if not taken:
-        return plain(*operands)
-    return lax.platform_dependent(
-        *operands,
-        tpu=lambda first, *rest: kernel(count_lowering(first, counter),
-                                        *rest, interpret=False),
-        default=plain)
-
-
 def _tile(i, n_tiles_ref):
     """The tile a grid step works on: its own, or past the tiles in use the
     last of them (same block as the step before: nothing is copied)."""
@@ -272,9 +251,7 @@ def _call(kernel, name: str, interpret: bool, grid: int, prefetch, operands,
             in_specs=in_specs, out_specs=out_specs,
             scratch_shapes=scratch_shapes),
         out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=compiler_params(("arbitrary",)),
     )(*prefetch, *operands)
 
 
@@ -523,10 +500,10 @@ def _combine_plain(weights, *rows, lay: GroupLayout):
 
 def _rows_choose(kernel, plain, n_tokens: int, width: int, dtype, lay,
                  interpret, *operands):
-    """``_choose`` for a movement between n tokens and rows of ``width``
+    """``choose`` for a movement between n tokens and rows of ``width``
     laid out by ``lay``."""
     tile = lay.row_valid.shape[0] // lay.tile_group.shape[0]
-    return _choose(
+    return choose(
         kernel, plain, rows_kernels_take(n_tokens, width, tile, dtype),
         "moe_rows_lowerings", interpret, *operands)
 
@@ -629,7 +606,7 @@ def kernels_take(rhs_shape, tile: int, dtype) -> bool:
     its float32 accumulator well inside VMEM."""
     _, k, n = rhs_shape
     return (dtype == jnp.bfloat16 and tile % 16 == 0 and k % 128 == 0
-            and n % 128 == 0 and k * n * 12 <= _VMEM_LIMIT // 2)
+            and n % 128 == 0 and k * n * 12 <= VMEM_LIMIT // 2)
 
 
 def _fwd_kernel(tile_group_ref, n_tiles_ref, lhs_ref, rhs_ref, out_ref, *,
@@ -730,11 +707,11 @@ def _drhs_dense(lhs, dout, tile_group, n_groups: int):
 
 
 def _product(kernel, dense, rhs_shape, interpret, a, b, tile_group, n_tiles):
-    """One of the three products, by kernel or einsum (``_choose``).
+    """One of the three products, by kernel or einsum (``choose``).
     ``kernel(a, b, tile_group, n_tiles, interpret=)``, ``dense(a, b,
     tile_group)``."""
     tile = a.shape[0] // tile_group.shape[0]
-    return _choose(
+    return choose(
         kernel, lambda a, b, tile_group, n_tiles: dense(a, b, tile_group),
         kernels_take(rhs_shape, tile, a.dtype), "moe_grouped_lowerings",
         interpret, a, b, tile_group, n_tiles)

@@ -23,11 +23,11 @@ token-major input it kept, and writes token-major gradients.
   ``partner`` swaps the two halves of the leading ``rot`` lanes (lane
   rotations), and ``C`` / ``S`` are ``[B, T, D]`` float32 tables that
   ``rotation_tables`` makes from the positions and the ``rot / 2`` inverse
-  frequencies with the XLA ops ``llama.rope`` runs — ``cos · scale`` twice
+  frequencies with the XLA ops ``layers.rope`` runs — ``cos · scale`` twice
   then ones, ``-sin · scale``, ``sin · scale`` then zeros — so the lanes
   past ``rot`` pass through and no table is a constant of the program.
 - The norm is ``x · rsqrt(mean x² + eps) · w`` in float32, never rounded
-  before the rotation (``llama.rms_norm`` rounds to the compute dtype twice
+  before the rotation (``layers.rms_norm`` rounds to the compute dtype twice
   on the way: this is the same arithmetic with fewer roundings). Its
   backward sums the weights' gradients in float32 over every position, a
   row of partial sums a batch row.
@@ -49,8 +49,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from brpc_tpu.ops.lowered import compiler_params
+
 _LANES = 128
-_VMEM_LIMIT = 64 << 20
 _ROWS = 256              # rows a trip of the kernels' loops widens to float32
 
 
@@ -79,7 +80,7 @@ def rotation_tables(positions: jax.Array, inv_freq, head_dim: int,
                     scale: float):
     """positions ``[B, T]``, the ``rot / 2`` inverse frequencies -> (C, S),
     float32 ``[B, T, head_dim]``: the rotation of halves ``[x1 cos - x2 sin,
-    x1 sin + x2 cos, rest]`` (``llama.rope``'s convention, cos and sin times
+    x1 sin + x2 cos, rest]`` (``layers.rope``'s convention, cos and sin times
     ``scale``) is ``x · C + partner(x) · S``."""
     angles = positions[..., None].astype(jnp.float32) * inv_freq
     cos, sin = jnp.cos(angles) * scale, jnp.sin(angles) * scale
@@ -198,11 +199,9 @@ def _specs(block: int, group: int, d: int, n_q: int):
 def _call(kernel, name, interpret, sequential_blocks: bool, **kwargs):
     return pl.pallas_call(
         kernel, name=name, interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=(
-                "parallel", "arbitrary" if sequential_blocks else "parallel",
-                "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=compiler_params(
+            ("parallel", "arbitrary" if sequential_blocks else "parallel",
+             "arbitrary")),
         **kwargs)
 
 

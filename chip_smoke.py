@@ -14,8 +14,9 @@ user calls, at Llama-3-8B widths with seeded random weights:
 
   kernel   the Pallas flash-attention kernels, forward and backward,
            compiled (not interpreted), at Llama-3-8B head geometry: output,
-           dQ, dK and dV against float32 ``llama.dense_attention``, and one
-           forward + backward of kernels and dense form timed side by side.
+           dQ, dK and dV against float32 ``flash_attention.dense_attention``,
+           and one forward + backward of kernels and dense form timed side
+           by side.
   train    three ``llama.make_train_step`` steps (AdamW, donated state) on
            one seeded batch: loss finite and falling.
   ps       ``DevicePsShardServer`` holding Llama-3-8B's 128,256 x 4,096
@@ -220,8 +221,8 @@ def phase_kernel(sizes: Sizes, dry: bool) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from brpc_tpu.models import llama
     from brpc_tpu.ops import flash_attention
+    from brpc_tpu.ops.flash_attention import dense_attention
 
     clock = Timer()
     _, out = _jax_devices(dry)
@@ -242,7 +243,7 @@ def phase_kernel(sizes: Sizes, dry: bool) -> dict:
         return jax.jit(run)
 
     args = (q, k, v, cotangent)
-    dense_form = forward_backward(llama.dense_attention)
+    dense_form = forward_backward(dense_attention)
     with clock("compile_s"):
         lowered = forward_backward(functools.partial(
             flash_attention, interpret=dry)).lower(*args)
@@ -289,7 +290,7 @@ def phase_kernel(sizes: Sizes, dry: bool) -> dict:
         out["max_err_over_scale"][name] = err
         if not err <= tol:
             raise SystemExit(f"chip_smoke: kernel {name} differs from "
-                             f"float32 llama.dense_attention by {err} of "
+                             f"float32 dense_attention by {err} of "
                              f"its scale > {tol}")
     out["seconds"] = clock.seconds
     return out

@@ -13,10 +13,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from brpc_tpu.models import llama
 from brpc_tpu.ops.flash_attention import (band_tiles, choose_block,
-                                          default_blocks, flash_attention,
-                                          supported)
+                                          default_blocks, dense_attention,
+                                          flash_attention, supported)
 
 fa = importlib.import_module("brpc_tpu.ops.flash_attention")
 
@@ -77,13 +76,13 @@ def test_band_kernels_match_the_dense_masked_form(case, dtype, tol):
     window, shape, (bq, bk) = _CASES[case]
     q, k, v, w = _inputs(3, dtype=dtype, **shape)
     want = _value_and_grads(functools.partial(
-        llama.dense_attention, window=window), q, k, v, w)
+        dense_attention, window=window), q, k, v, w)
     got = _value_and_grads(functools.partial(
         flash_attention, window=window, block_q=bq, block_k=bk,
         interpret=True), q, k, v, w)
     _agree(got, want, tol)
     if window >= q.shape[1]:        # ... which is then the causal form
-        _agree(want, _value_and_grads(llama.dense_attention, q, k, v, w),
+        _agree(want, _value_and_grads(dense_attention, q, k, v, w),
                1e-6)
 
 
@@ -93,8 +92,8 @@ def test_band_backward_a_query_head_at_a_time(monkeypatch, group):
     every query head its own program row, dK and dV summed afterwards."""
     q, k, v, w = _inputs(5, b=2, hq=2 * group, hkv=2)
     want = _value_and_grads(functools.partial(
-        llama.dense_attention, window=40), q, k, v, w)
-    monkeypatch.setattr(fa, "_VMEM_LIMIT",
+        dense_attention, window=40), q, k, v, w)
+    monkeypatch.setattr(fa, "VMEM_LIMIT",
                         2 * fa._resident(1, 128, 32, 32, jnp.float32))
     assert not fa.heads_together(group, 128, 32, 32, jnp.float32)
     got = _value_and_grads(functools.partial(
@@ -106,7 +105,7 @@ def test_band_backward_a_query_head_at_a_time(monkeypatch, group):
 def test_the_dense_mask_is_the_band():
     q, k, v, _ = _inputs(0, t=16, hq=1, hkv=1, d=8)
     v = jnp.eye(16)[None, :, None, :]               # probabilities, by key
-    p = np.asarray(llama.dense_attention(q, k, v, window=5))[0]
+    p = np.asarray(dense_attention(q, k, v, window=5))[0]
     i, j = np.indices((16, 16))
     assert ((p > 0) == ((i - j >= 0) & (i - j < 5))).all()
     np.testing.assert_allclose(p.sum(-1), 1.0, rtol=1e-6)

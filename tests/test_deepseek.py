@@ -30,7 +30,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
 import reference  # noqa: E402
 import reference_dsv3  # noqa: E402
 
-from brpc_tpu.models import deepseek  # noqa: E402
+from brpc_tpu.models import deepseek, experts  # noqa: E402
 from brpc_tpu.ops import flash_attention  # noqa: E402
 from brpc_tpu.ops import grouped_matmul as gm  # noqa: E402
 
@@ -260,11 +260,11 @@ def test_selection_sees_the_bias_and_weights_do_not(params):
     cfg = dataclasses.replace(TINY32, experts_per_token=3)
     s = np.asarray(jax.nn.sigmoid(jnp.dot(y, lp["router"],
                                           precision="highest")))
-    plain, _ = deepseek.route(cfg, y, lp["router"], jnp.zeros(8))
+    plain, _ = experts.sigmoid_route(cfg, y, lp["router"], jnp.zeros(8))
     bias = np.zeros(8, np.float32)
     bias[5] = 0.2                       # lifts expert 5 over some third picks
-    selected, weights = deepseek.route(cfg, y, lp["router"],
-                                       jnp.asarray(bias))
+    selected, weights = experts.sigmoid_route(cfg, y, lp["router"],
+                                              jnp.asarray(bias))
     selected, weights = np.asarray(selected), np.asarray(weights)
     assert np.array_equal(np.sort(selected, 1),
                           np.sort(np.argsort(-(s + bias), 1)[:, :3], 1))

@@ -264,7 +264,7 @@ def test_the_shares_add_up_to_the_uncut_layer(params):
 def test_weights_are_a_softmax_s_largest_renormalised(params):
     lp, y = {k: v[0] for k, v in params["full"].items()}, _tokens_in()
     with jax.default_matmul_precision("highest"):
-        selected, w = hybrid.route(TINY32, y, lp["router"])
+        selected, w = experts.softmax_route(TINY32, y, lp["router"])
     p = jax.nn.softmax(y @ lp["router"], axis=-1)
     top = np.argsort(-np.asarray(p), axis=1)[:, :2]
     assert np.array_equal(np.sort(np.asarray(selected), -1), np.sort(top, -1))
@@ -294,8 +294,8 @@ def test_deepseek_loss_traces_to_the_program_it_was():
 
     def before(cfg, y, lp):
         n, k = y.shape[0], cfg.experts_per_token
-        selected, weights = deepseek.route(cfg, y, lp["router"],
-                                           lp["router_bias"])
+        selected, weights = experts.sigmoid_route(cfg, y, lp["router"],
+                                                  lp["router_bias"])
         local = selected - cfg.expert_offset
         group_of = jnp.where((local >= 0) & (local < cfg.n_held), local,
                              cfg.n_held).reshape(n * k)

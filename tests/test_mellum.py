@@ -32,7 +32,7 @@ import lowered_step  # noqa: E402
 import reference  # noqa: E402
 import reference_mellum  # noqa: E402
 
-from brpc_tpu.models import hybrid, windowed  # noqa: E402
+from brpc_tpu.models import experts, windowed  # noqa: E402
 from brpc_tpu.ops import grouped_matmul as gm  # noqa: E402
 from brpc_tpu.ops import qk_layout as ql  # noqa: E402
 
@@ -215,9 +215,9 @@ def test_loss_logits_stats_and_every_gradient_leaf(
     reference's; the expert layer by its plain form and by its kernels
     through the Pallas interpreter."""
     if path == "kernels_interpreted":
-        choose = gm._choose
+        choose = gm.choose
         monkeypatch.setattr(
-            gm, "_choose", lambda kernel, plain, taken, counter, _, *operands:
+            gm, "choose", lambda kernel, plain, taken, counter, _, *operands:
             choose(kernel, plain, taken, counter, True, *operands))
     with jax.default_matmul_precision("highest"):
         (loss, stats), grads = jax.jit(jax.value_and_grad(
@@ -264,17 +264,18 @@ def test_every_kernel_interpreted_follows_the_reference(monkeypatch):
     pass), the band and causal attention kernels and the expert layer's six,
     all by the Pallas interpreter: loss and every leaf's gradient inside
     what bf16 allows, the policy's names saved."""
-    choose = gm._choose
+    choose = gm.choose
     monkeypatch.setattr(
-        gm, "_choose", lambda kernel, plain, taken, counter, _, *operands:
+        gm, "choose", lambda kernel, plain, taken, counter, _, *operands:
         choose(kernel, plain, taken, counter, True, *operands))
     windows, layouts = [], []
     # the branch a program lowered for TPU holds, its kernels interpreted
     monkeypatch.setattr(windowed.lax, "platform_dependent",
                         lambda *operands, tpu, default: tpu(*operands))
+    real_attention = fa.flash_attention_head_major
     monkeypatch.setattr(
-        windowed, "flash_attention_head_major", lambda q, k, v, window: (
-            windows.append(window), fa.flash_attention_head_major(
+        fa, "flash_attention_head_major", lambda q, k, v, window: (
+            windows.append(window), real_attention(
                 q, k, v, window=window, interpret=True))[1])
     for name in ("forward", "backward"):
         real = getattr(ql, name)
@@ -411,11 +412,12 @@ def test_the_shares_add_up_to_the_uncut_layer(params):
 
 
 def test_weights_are_the_largest_softmax_shares_renormalised(params):
-    """The softmax router is ``hybrid.route``, the one home of it."""
+    """The softmax router is ``experts.softmax_route``, the one home of
+    it."""
     lp = {k: v[0] for k, v in params["full"].items()}
     y = jax.random.normal(jax.random.PRNGKey(12), (96, 64))
     with jax.default_matmul_precision("highest"):
-        selected, w = hybrid.route(TINY32, y, lp["router"])
+        selected, w = experts.softmax_route(TINY32, y, lp["router"])
         p = np.asarray(jax.nn.softmax(y @ lp["router"], axis=-1))
     top = np.argsort(-p, axis=1)[:, :2]
     assert np.array_equal(np.sort(np.asarray(selected), -1), np.sort(top, -1))
@@ -434,8 +436,9 @@ def test_a_router_of_another_name_is_refused(params):
 
 def test_the_model_calls_the_one_softmax_router(monkeypatch, params):
     calls = []
-    real = hybrid.route
-    monkeypatch.setattr(hybrid, "route", lambda cfg, y, r: (
+    real = experts.softmax_route
+    assert windowed.softmax_route is real
+    monkeypatch.setattr(windowed, "softmax_route", lambda cfg, y, r: (
         calls.append(cfg.experts_per_token), real(cfg, y, r))[1])
     lp = {k: v[0] for k, v in params["full"].items()}
     windowed.moe_mlp(TINY32, jnp.ones((16, 64)), lp)

@@ -3,7 +3,8 @@ same kernels reach the TPU compiler is checked without a chip, by lowering
 for the TPU platform and by compiling ahead of time for a v5e topology
 (libtpu compiles without devices). The compiled kernels' numerics on the
 chip are chip_smoke.py's ``kernel`` phase. Last, the choice
-``llama.attention`` makes between the kernels and the dense form."""
+``flash_attention.attention`` makes between the kernels and the dense
+form."""
 
 import collections
 import dataclasses
@@ -19,7 +20,8 @@ import pytest
 from brpc_tpu import obs
 from brpc_tpu.models import llama
 from brpc_tpu.ops import flash_attention
-from brpc_tpu.ops.flash_attention import choose_block
+from brpc_tpu.ops.flash_attention import (attention, choose_block,
+                                          dense_attention)
 
 
 def _inputs(key, b=2, t=128, hq=4, hkv=2, d=32, dtype=jnp.float32):
@@ -33,7 +35,7 @@ def _inputs(key, b=2, t=128, hq=4, hkv=2, d=32, dtype=jnp.float32):
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_matches_dense(causal):
     q, k, v = _inputs(jax.random.PRNGKey(0))
-    want = llama.attention(q, k, v, causal=causal)
+    want = attention(q, k, v, causal=causal)
     got = flash_attention(q, k, v, causal=causal, block_q=32, block_k=32,
                           interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -42,7 +44,7 @@ def test_flash_matches_dense(causal):
 
 def test_flash_uneven_blocks():
     q, k, v = _inputs(jax.random.PRNGKey(1), t=64)
-    want = llama.attention(q, k, v, causal=True)
+    want = attention(q, k, v, causal=True)
     got = flash_attention(q, k, v, block_q=16, block_k=64, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -103,7 +105,7 @@ def test_flash_compiles_for_v5e(v5e_device, shape):
 
 def test_flash_bf16():
     q, k, v = _inputs(jax.random.PRNGKey(2), t=64, dtype=jnp.bfloat16)
-    want = llama.attention(q, k, v, causal=True)
+    want = attention(q, k, v, causal=True)
     got = flash_attention(q, k, v, block_q=32, block_k=32, interpret=True)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
@@ -135,7 +137,7 @@ def test_flash_grad_matches_dense(dtype, tol, group, causal, blocks):
         flash_attention, causal=causal, block_q=blocks[0], block_k=blocks[1],
         interpret=True), w), (0, 1, 2))(q, k, v)
     want = jax.grad(_weighted(functools.partial(
-        llama.dense_attention, causal=causal), w), (0, 1, 2))(q, k, v)
+        dense_attention, causal=causal), w), (0, 1, 2))(q, k, v)
     for name, g, r in zip(("dq", "dk", "dv"), got, want):
         assert g.dtype == r.dtype == dtype and g.shape == r.shape, name
         g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
@@ -185,7 +187,7 @@ def test_flash_backward_compiles_for_v5e(v5e_device, shape):
     assert sorted(_ATTN_CALLS.findall(compiled.as_text())) == _ONE_OF_EACH
 
 
-# -- the choice llama.attention makes ---------------------------------------
+# -- the choice flash_attention.attention makes -----------------------------
 
 # Kernel-eligible and small: 4 heads of 128 over 2 KV heads, bf16, and a
 # sequence (three tiles of 128) that is no weight's dimension, so that an
@@ -275,7 +277,7 @@ def test_one_step_through_the_kernel_matches_dense():
     grad = jax.jit(jax.value_and_grad(llama.loss_fn), static_argnums=(2, 3))
     loss, grads = grad(params, tokens, _ELIGIBLE,
                        functools.partial(flash_attention, interpret=True))
-    want_loss, want = grad(params, tokens, _ELIGIBLE, llama.dense_attention)
+    want_loss, want = grad(params, tokens, _ELIGIBLE, dense_attention)
     assert abs(float(loss) - float(want_loss)) <= 2e-3 * float(want_loss)
     norms = jax.tree_util.tree_map(
         lambda g, w: (float(jnp.linalg.norm(g)), float(jnp.linalg.norm(w))),
@@ -309,11 +311,11 @@ def test_flash_two_widths_match_dense(widths, dtype, tol):
                              d_v=widths[1], dtype=dtype)
     kernel = functools.partial(flash_attention, block_q=64, block_k=32,
                                interpret=True)
-    got, want = kernel(q, k, v), llama.dense_attention(q, k, v)
+    got, want = kernel(q, k, v), dense_attention(q, k, v)
     assert got.shape == want.shape == (1, 128, 2 * widths[1])
     w = jax.random.normal(jax.random.PRNGKey(8), want.shape)
     grads = jax.grad(_weighted(kernel, w), (0, 1, 2))(q, k, v)
-    wants = jax.grad(_weighted(llama.dense_attention, w), (0, 1, 2))(q, k, v)
+    wants = jax.grad(_weighted(dense_attention, w), (0, 1, 2))(q, k, v)
     for name, g, r in zip(("o", "dq", "dk", "dv"), (got, *grads),
                           (want, *wants)):
         assert g.dtype == r.dtype == dtype and g.shape == r.shape, name
@@ -372,8 +374,8 @@ def test_flash_backward_is_one_kernel_at_the_cells_geometries(v5e_device,
     ``attn_flash_bwd``, returns dQ, dK and dV, and what it holds in VMEM
     (Q, dO, float32 dQ and dQ's block of the query group, with the tiles) is
     what ``supported`` reckons and inside the kernels' limit."""
-    from brpc_tpu.ops.flash_attention import (_VMEM_LIMIT, _backward,
-                                              supported)
+    from brpc_tpu.ops.flash_attention import _backward, supported
+    from brpc_tpu.ops.lowered import VMEM_LIMIT
     t, hq, hkv, d_qk, d_v, block = _CELLS[cell]
     assert supported((1, t, hq, d_qk), (1, t, hkv, d_qk), jnp.bfloat16,
                      (1, t, hkv, d_v))
@@ -391,7 +393,7 @@ def test_flash_backward_is_one_kernel_at_the_cells_geometries(v5e_device,
     (call,) = (line for line in text.splitlines() if "custom-call(" in line)
     (used,) = re.findall(
         r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', call)
-    assert int(used) <= _VMEM_LIMIT
+    assert int(used) <= VMEM_LIMIT
 
 
 def test_compiled_backward_kernel_wants_a_query_tile_of_whole_lanes():
@@ -1025,9 +1027,9 @@ def test_a_query_head_at_a_time_matches_dense(monkeypatch, dtype, tol):
                       dtype=dtype)
     w = jax.random.normal(jax.random.PRNGKey(8), (1, 128, 16 * 256),
                           jnp.float32)
-    wants = jax.grad(_weighted(functools.partial(llama.dense_attention), w),
+    wants = jax.grad(_weighted(functools.partial(dense_attention), w),
                      (0, 1, 2))(q, k, v)
-    monkeypatch.setattr(fa, "_VMEM_LIMIT", 2 * fa._resident(
+    monkeypatch.setattr(fa, "VMEM_LIMIT", 2 * fa._resident(
         1, 128, 256, 256, dtype))
     assert not fa.heads_together(8, 128, 256, 256, dtype)
     assert fa.supported(q.shape, k.shape, dtype)
@@ -1154,11 +1156,11 @@ from brpc_tpu.models import experts, windowed  # noqa: E402
 
 _WINDOWED = windowed.WindowedConfig.tiny()
 _ROUTES = {
-    "hybrid": (hybrid, lambda y, r: hybrid.route(_HYBRID, y, r)),
-    "deepseek_biased": (deepseek, lambda y, r: deepseek.route(
-        _LATENT, y, r, jnp.linspace(-0.3, 0.3, r.shape[1]))),
-    "deepseek_unbiased": (deepseek, lambda y, r: deepseek.route(
-        _WINDOWED, y, r, 0.0)),
+    "hybrid": lambda y, r: experts.softmax_route(_HYBRID, y, r),
+    "deepseek_biased": lambda y, r: experts.sigmoid_route(
+        _LATENT, y, r, jnp.linspace(-0.3, 0.3, r.shape[1])),
+    "deepseek_unbiased": lambda y, r: experts.sigmoid_route(
+        _WINDOWED, y, r, 0.0),
 }
 
 
@@ -1168,7 +1170,7 @@ def test_router_weights_are_take_along_axis_to_the_bit(monkeypatch, case):
     place of: the weights, and the gradients through them of the tokens and
     of the router's matrix, bit for bit, with ties in the scores (two
     experts with one column, a token of zeros)."""
-    module, route = _ROUTES[case]
+    route = _ROUTES[case]
     rng = np.random.default_rng(5)
     router = rng.standard_normal((64, 8)).astype(np.float32)
     router[:, 5] = router[:, 2]
@@ -1186,7 +1188,7 @@ def test_router_weights_are_take_along_axis_to_the_bit(monkeypatch, case):
         return jax.tree_util.tree_map(np.asarray, (*out, *grads))
 
     got = run()
-    monkeypatch.setattr(module, "chosen", lambda scores, selected:
+    monkeypatch.setattr(experts, "chosen", lambda scores, selected:
                         jnp.take_along_axis(scores, selected, axis=1))
     want = run()
     for name, g, w in zip(("selected", "weights", "dy", "drouter"), got,
@@ -1226,9 +1228,9 @@ def _expert_layer_jaxpr(model: str, monkeypatch, interpret, n=64):
     run again), and N * k. ``interpret`` True: the kernels' path."""
     module, cfg = _EXPERT_LAYERS[model]
     if interpret:
-        choose = gm._choose
+        choose = gm.choose
         monkeypatch.setattr(
-            gm, "_choose", lambda kernel, plain, taken, counter, _, *operands:
+            gm, "choose", lambda kernel, plain, taken, counter, _, *operands:
             choose(kernel, plain, taken, counter, True, *operands))
     h, e, held = cfg.hidden, getattr(
         cfg, "n_experts", getattr(cfg, "n_routed_experts", 0)), cfg.n_held
@@ -1271,7 +1273,7 @@ def test_expert_layer_moves_no_scalar_by_gather_over_its_bound(
 def test_the_pin_sees_a_gather_of_scalars(monkeypatch):
     """The same walk finds the router's gather when it is put back, in the
     forward pass, the recomputation and (as a scatter-add) the backward."""
-    monkeypatch.setattr(hybrid, "chosen", lambda scores, selected:
+    monkeypatch.setattr(experts, "chosen", lambda scores, selected:
                         jnp.take_along_axis(scores, selected, axis=1))
     jaxpr, assignments = _expert_layer_jaxpr("hybrid", monkeypatch, None)
     found = _scalar_moves(jaxpr, assignments)
@@ -1357,7 +1359,7 @@ def test_gated_kernels_match_the_plain_form(monkeypatch, case, window):
     g = _GATES[logits](jax.random.normal(jax.random.PRNGKey(10), (b, t, hq)))
     w = jax.random.normal(jax.random.PRNGKey(11), (b, t, hq * d))
     if not together:
-        monkeypatch.setattr(fa, "_VMEM_LIMIT", 2 * fa._resident(
+        monkeypatch.setattr(fa, "VMEM_LIMIT", 2 * fa._resident(
             1, t, d, d, dtype))
     assert fa.heads_together(hq // hkv, t, d, d, dtype) == together
 
@@ -1367,7 +1369,7 @@ def test_gated_kernels_match_the_plain_form(monkeypatch, case, window):
             window=window, interpret=True).astype(jnp.float32)
 
     def plain(q, k, v, g):
-        o = llama.dense_attention(q, k, v, window=window)
+        o = dense_attention(q, k, v, window=window)
         return (o.reshape(b, t, hq, d).astype(jnp.float32)
                 * jax.nn.sigmoid(g)[..., None]).reshape(b, t, hq * d)
 

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from brpc_tpu.models import llama
+from brpc_tpu.ops.flash_attention import attention
 from brpc_tpu.parallel import (
     CollectiveChannel,
     make_mesh,
@@ -77,7 +77,7 @@ def _attn_inputs(key, b=2, t=64, hq=4, hkv=2, d=8):
 def test_ring_attention_matches_dense(causal):
     mesh = make_mesh({"sp": 8})
     q, k, v = _attn_inputs(jax.random.PRNGKey(0))
-    want = llama.attention(q, k, v, causal=causal)
+    want = attention(q, k, v, causal=causal)
     got = jax.jit(
         lambda q, k, v: ring_attention(
             q, k, v, mesh=mesh, axis="sp", causal=causal
@@ -91,7 +91,7 @@ def test_ring_attention_matches_dense(causal):
 def test_ulysses_attention_matches_dense(causal):
     mesh = make_mesh({"sp": 2})
     q, k, v = _attn_inputs(jax.random.PRNGKey(1))
-    want = llama.attention(q, k, v, causal=causal)
+    want = attention(q, k, v, causal=causal)
     got = jax.jit(
         lambda q, k, v: ulysses_attention(
             q, k, v, mesh=mesh, axis="sp", causal=causal
@@ -111,7 +111,7 @@ def test_ring_attention_grads():
         )
 
     def loss_dense(q, k, v):
-        return jnp.sum(llama.attention(q, k, v) ** 2)
+        return jnp.sum(attention(q, k, v) ** 2)
 
     # dq, dk and dv: the running max is held constant in the backward pass,
     # which must leave all three exact.

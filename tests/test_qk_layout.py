@@ -1,8 +1,8 @@
 """ops/qk_layout.py: q and k from their projections to the attention kernels
 in one pass (norm, rope and the turn to head-major), by the Pallas
 interpreter on the CPU against the plain form models/windowed.py ran before
-it and still runs wherever the kernels do not: ``llama.rms_norm`` ->
-``llama.rope`` / ``windowed.yarn_rope`` -> transpose.
+it and still runs wherever the kernels do not: ``layers.rms_norm`` ->
+``layers.rope`` / ``windowed.yarn_rope`` -> transpose.
 
 ``ops/qk_layout.py`` is the two kernels alone; their VJP and the choice
 between them and the plain form are ``windowed.qk_head_major``'s.
@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 
 from brpc_tpu import obs
-from brpc_tpu.models import deepseek, hybrid, llama, looped, windowed
+from brpc_tpu.models import deepseek, hybrid, layers, llama, looped, windowed
 from brpc_tpu.ops import qk_layout as ql
 
 fa = importlib.import_module("brpc_tpu.ops.flash_attention")
@@ -71,12 +71,12 @@ def _plain(cfg, kind, q, k, norms, positions=POSITIONS):
     b, t, _ = q.shape
     q, k = (x.reshape(b, t, -1, cfg.head_dim) for x in (q, k))
     if norms is not None:
-        q = llama.rms_norm(q, norms[0], cfg.norm_eps)
-        k = llama.rms_norm(k, norms[1], cfg.norm_eps)
+        q = layers.rms_norm(q, norms[0], cfg.norm_eps)
+        k = layers.rms_norm(k, norms[1], cfg.norm_eps)
     if kind == "full":
         q, k = (windowed.yarn_rope(cfg, x, positions) for x in (q, k))
     else:
-        q, k = (llama.rope(x, positions, cfg.window_rope_theta)
+        q, k = (layers.rope(x, positions, cfg.window_rope_theta)
                 for x in (q, k))
     return q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)
 
@@ -144,7 +144,7 @@ def test_kernels_follow_the_plain_form(name):
 
 def test_tables_are_the_ropes_own_cosines_and_sines():
     """``x · C + partner(x) · S`` written out: C is cos, cos, ones and S is
-    -sin, sin, zeros, of ``llama.rope``'s own angles, times the scale."""
+    -sin, sin, zeros, of ``layers.rope``'s own angles, times the scale."""
     rot, inv_freq, scale = windowed._rotary(LAGUNA, True)
     cos, sin = ql.rotation_tables(POSITIONS, inv_freq, 128, scale)
     assert cos.shape == sin.shape == (B, T, 128) and cos.dtype == jnp.float32
@@ -173,7 +173,7 @@ def _lowerings():
 def _plain_attention(cfg, kind, q, k, v, norms, positions):
     q, k = (x.transpose(0, 2, 1, 3)
             for x in _plain(cfg, kind, q, k, norms, positions))
-    return llama.dense_attention(
+    return fa.dense_attention(
         q, k, v, window=None if kind == "full" else cfg.window)
 
 

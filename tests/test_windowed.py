@@ -36,7 +36,7 @@ import lowered_step  # noqa: E402
 import reference  # noqa: E402
 import reference_swa  # noqa: E402
 
-from brpc_tpu.models import deepseek, experts, llama, windowed  # noqa: E402
+from brpc_tpu.models import experts, layers, windowed  # noqa: E402
 from brpc_tpu.ops import grouped_matmul as gm  # noqa: E402
 
 # the module: ``brpc_tpu.ops.flash_attention`` the attribute is the function
@@ -160,7 +160,7 @@ def test_yarn_frequencies_by_hand():
 def test_the_two_ropes():
     """The full layers' rope turns the leading half of a head by the yarn
     frequencies, scaled by the attention factor, and passes the rest; the
-    window layers' is ``llama.rope`` at 10,000 over the whole head."""
+    window layers' is ``layers.rope`` at 10,000 over the whole head."""
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 8, 2, 128))
     pos = jnp.arange(8)[None]
     out = np.asarray(windowed.yarn_rope(PUBLISHED, x, pos))
@@ -184,7 +184,7 @@ def test_the_ropes_and_the_mask_are_on_the_right_layers(monkeypatch, params,
     calls = []
     real = windowed.attention
     monkeypatch.setattr(windowed, "rope", lambda x, p, theta: (
-        calls.append(("rope", x.shape[2], theta)), llama.rope(x, p, theta))[1])
+        calls.append(("rope", x.shape[2], theta)), layers.rope(x, p, theta))[1])
     monkeypatch.setattr(windowed, "yarn_rope", lambda cfg, x, p: (
         calls.append(("yarn", x.shape[2])), x)[1])
     monkeypatch.setattr(windowed, "attention", lambda q, k, v, window: (
@@ -306,11 +306,12 @@ def test_the_gate_is_a_sigmoid_a_head(params):
 
 @pytest.mark.parametrize("window", [None, 64], ids=["full", "window"])
 def test_the_kernels_gate_is_the_plain_form_s(monkeypatch, window):
-    """``attention_head_major`` with the gate's logits [B, T, H]: the
-    kernels' branch (interpreted), whose forward kernel takes them into its
-    epilogue, against the plain branch, which gates after
-    (``windowed._gated``): the output and the cotangents of q, k, v and the
-    logits, in float32, 8 query heads over 1 KV head of 128, three tiles."""
+    """``flash_attention.attention_head_major`` with the gate's logits [B,
+    T, H]: the kernels' branch (interpreted), whose forward kernel takes
+    them into its epilogue, against the plain branch, which gates after
+    (``flash_attention.gated``): the output and the cotangents of q, k, v
+    and the logits, in float32, 8 query heads over 1 KV head of 128, three
+    tiles."""
     b, t, hq, d = 1, 384, 8, 128
     ks = jax.random.split(jax.random.PRNGKey(13), 5)
     q = jax.random.normal(ks[0], (b, hq, t, d))
@@ -318,18 +319,19 @@ def test_the_kernels_gate_is_the_plain_form_s(monkeypatch, window):
     v = jax.random.normal(ks[2], (b, t, 1, d))
     gate = 3 * jax.random.normal(ks[3], (b, t, hq))
     w = jax.random.normal(ks[4], (b, t, hq * d))
+    real = fa.flash_attention_head_major
     monkeypatch.setattr(
-        windowed, "flash_attention_head_major",
-        lambda q, k, v, gate, window: fa.flash_attention_head_major(
-            q, k, v, gate, window=window, interpret=True))
+        fa, "flash_attention_head_major",
+        lambda q, k, v, gate, window: real(q, k, v, gate, window=window,
+                                           interpret=True))
     results = []
     for branch in ("tpu", "default"):
         monkeypatch.setattr(
-            windowed.lax, "platform_dependent",
+            fa.lax, "platform_dependent",
             lambda *operands, branch=branch, **branches: branches[branch](
                 *operands))
         results.append(jax.value_and_grad(
-            lambda *a: jnp.sum(windowed.attention_head_major(
+            lambda *a: jnp.sum(fa.attention_head_major(
                 *a[:3], window, a[3]) * w), (0, 1, 2, 3))(q, k, v, gate))
     (got, grads), (want, wants) = results
     assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
@@ -379,7 +381,7 @@ def test_weights_are_the_largest_sigmoids_renormalised_and_scaled(params):
     lp = {k: v[0] for k, v in params["full"].items()}
     y = jax.random.normal(jax.random.PRNGKey(12), (96, 64))
     with jax.default_matmul_precision("highest"):
-        selected, w = deepseek.route(TINY32, y, lp["router"], 0.0)
+        selected, w = experts.sigmoid_route(TINY32, y, lp["router"], 0.0)
         s = np.asarray(jax.nn.sigmoid(y @ lp["router"]))
     top = np.argsort(-s, axis=1)[:, :2]
     assert np.array_equal(np.sort(np.asarray(selected), -1), np.sort(top, -1))
