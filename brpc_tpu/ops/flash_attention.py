@@ -135,10 +135,11 @@ def _resident(group: int, t: int, d_qk: int, d_v: int, dtype) -> int:
     query heads: Q and dO (double-buffered by the pipeline), dQ transposed
     in float32 and dQ's output block (double-buffered too)."""
     lanes_qk = -(-d_qk // 128) * 128
+    lanes_v = -(-d_v // 128) * 128
     size = jnp.dtype(dtype).itemsize
-    return group * t * (2 * (lanes_qk + d_v) * size     # Q, dO
-                        + d_qk * 4                      # dQ.T
-                        + 2 * lanes_qk * size)          # dQ's block
+    return group * t * (2 * (lanes_qk + lanes_v) * size  # Q, dO
+                        + d_qk * 4                          # dQ.T
+                        + 2 * lanes_qk * size)              # dQ's block
 
 
 def heads_together(group: int, t: int, d_qk: int, d_v: int, dtype) -> bool:
@@ -150,12 +151,12 @@ def heads_together(group: int, t: int, d_qk: int, d_v: int, dtype) -> bool:
 
 def supported(q_shape, kv_shape, dtype, v_shape=None, window=None) -> bool:
     """Whether the compiled kernels take these operands: whole query groups,
-    a v width (``v_shape``, k's where it is not given) that fills the MXU's
-    128 lanes, a q/k width that is a multiple of 64 (192 is a block as wide
-    as its array, which Mosaic takes whole and pads to 256 lanes in VMEM; a
-    192-wide contraction or result fills one and a half passes of the
-    128-wide MXU), and a sequence whose resident blocks leave VMEM room for
-    the score tiles. The backward kernel holds the most (``_resident``):
+    q/k and v widths (``v_shape``, k's where it is not given) that are
+    multiples of 64 (192 or 64 is a block as wide as its array, which Mosaic
+    takes whole and pads to 256 or 128 lanes in VMEM; a 192-wide
+    contraction or result fills one and a half passes of the 128-wide MXU,
+    a 64-wide one half a pass: models/mamba.py's heads), and a sequence
+    whose resident blocks leave VMEM room for the score tiles. The backward kernel holds the most (``_resident``):
     27.3 MB at T = 8,192, one head a group, 192 / 128, and 16.8 MB at
     T = 2,048, four heads a group, 128 / 128, where a group of 4 fits 4,096
     tokens. A group that does not fit whole — 8 query heads a KV head of
@@ -171,7 +172,7 @@ def supported(q_shape, kv_shape, dtype, v_shape=None, window=None) -> bool:
         return False
     hkv = kv_shape[2]
     d_v = (v_shape or kv_shape)[3]
-    if d_qk % 64 or d_v % 128 or t % 128 or hq % hkv:
+    if d_qk % 64 or d_v % 64 or t % 128 or hq % hkv:
         return False
     return _resident(1, t, d_qk, d_v, dtype) <= VMEM_LIMIT // 2
 

@@ -113,6 +113,27 @@ def _handle_leak_gate(request):
             pytrace=False)
 
 
+@pytest.fixture(scope="session", autouse=True)
+def _shared_compile_cache(tmp_path_factory):
+    """One persistent compilation cache for the JAX programs that tests
+    start as child processes (the benchmark's dry runs, chip_smoke.py's),
+    shared by the session's workers: a cell's step, reference and set-up
+    are compiled by the first run that needs them and loaded by the rest.
+    This process is not touched (JAX read its configuration at import),
+    and a cache the environment already names is kept."""
+    if "JAX_COMPILATION_CACHE_DIR" in os.environ:
+        yield
+        return
+    base = tmp_path_factory.getbasetemp()
+    if os.environ.get("PYTEST_XDIST_WORKER"):
+        base = base.parent          # each worker's directory is a child
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("JAX_COMPILATION_CACHE_DIR", str(base / "jax_cache"))
+        # the small compilations too: a dry run's reference is many
+        mp.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+        yield
+
+
 def pytest_collection_modifyitems(config, items):
     needy = [item for item in items
              if item.fspath.basename in _NATIVE_TEST_FILES

@@ -348,8 +348,13 @@ def test_supported_states_the_rule_for_two_widths():
     assert not heads_together(8, 8192, 256, 256, bf16)
     assert supported((1, 8192, 16, 256), (1, 8192, 2, 256), bf16)
     assert not supported((1, 8320, 16, 256), (1, 8320, 2, 256), bf16)
+    # v of 64, half a lane (granite-4.0-h-micro's heads, PR 47): a block as
+    # wide as its array; narrower than that, no
+    assert supported((1, 8192, 32, 192), (1, 8192, 32, 192), bf16,
+                     (1, 8192, 32, 64))
+    assert supported((1, 4096, 32, 64), (1, 4096, 8, 64), bf16)
     assert not supported((1, 8192, 32, 192), (1, 8192, 32, 192), bf16,
-                         (1, 8192, 32, 64))         # v narrower than a lane
+                         (1, 8192, 32, 32))         # v narrower still
     assert not supported((1, 8192, 32, 96), (1, 8192, 32, 96), bf16,
                          (1, 8192, 32, 128))
     assert not supported((1, 32768, 32, 192), (1, 32768, 32, 192), bf16,
@@ -1434,3 +1439,107 @@ def test_gated_kernels_compile_for_v5e_at_the_cells_geometry(v5e_device,
     kind = "flash" if window is None else "band"
     assert sorted(re.findall(r"%(attn_\w+?)(?:\.\d+)? = [^\n]*custom-call\(",
                              text)) == [f"attn_{kind}_bwd", f"attn_{kind}_fwd"]
+
+
+# -- the hybrid Mamba-2 model: the scan's kernels, the convolution's bias and
+# attention at heads of 64 ------------------------------------------------------
+
+from brpc_tpu.models import mamba  # noqa: E402
+
+_SSD_CALLS = re.compile(r"%(ssd_chunk_\w+?)(?:\.\d+)? = [^\n]*custom-call\(")
+
+
+def test_ssd_kernels_compile_for_v5e_at_the_cells_size(v5e_device):
+    """1 x 4,096 positions of xBC (64 heads of 64, then B and C of 128),
+    float32 steps and decays, bf16: Mosaic and XLA:TPU take both kernels,
+    one call each; B and C are reached by block index (no bf16 slice of
+    them is made) and no head's [C, C] leaves a kernel."""
+    from brpc_tpu.ops import ssd
+
+    sharding = jax.sharding.SingleDeviceSharding(v5e_device)
+    s = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=sharding)
+
+    @jax.jit
+    def both(xbc, dt, a, dy):
+        y, vjp = jax.vjp(lambda *z: ssd.ssd(*z, head_dim=64), xbc, dt, a)
+        return (y, *vjp(dy))
+
+    text = both.trace(s(1, 4096, 4352), s(1, 4096, 64, dtype=jnp.float32),
+                      s(1, 4096, 64, dtype=jnp.float32),
+                      s(1, 4096, 4096)).lower().compile().as_text()
+    assert sorted(_SSD_CALLS.findall(text)) == ["ssd_chunk_bwd",
+                                                 "ssd_chunk_fwd"]
+    # the forward kernel reads x, B and C from xbc itself, three times
+    assert re.search(r"ssd_chunk_fwd[^\n]*custom-call\((%[\w.]+), \1, \1,",
+                     text)
+    assert not re.search(r"= bf16\[1,4096,128\][^\n]* slice\(", text)
+    # of [.., 128, 128] arrays only the chunks' entry states, a run of two
+    # heads each
+    assert set(re.findall(r"\w+\[[\d,]*,128,128\]", text)) == {
+        "bf16[1,32,32,128,128]"}
+
+
+def test_conv_silu_with_a_bias_compiles_for_v5e_at_the_mamba_cell_size(
+        v5e_device):
+    """1 x 4,096 positions of the Mamba layer's projection (8,448 wide, of
+    which xBC are the first 4,352 = 17 blocks of 256), four float32 taps and
+    a float32 bias: both kernels, one call each."""
+    sharding = jax.sharding.SingleDeviceSharding(v5e_device)
+    s = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=sharding)
+
+    @jax.jit
+    def both(x, taps, bias, dy):
+        y, vjp = jax.vjp(causal_conv.conv_silu, x, taps, bias)
+        return (y, *vjp(dy))
+
+    text = both.trace(s(1, 4096, 8448), s(4, 4352, dtype=jnp.float32),
+                      s(4352, dtype=jnp.float32),
+                      s(1, 4096, 4352)).lower().compile().as_text()
+    assert sorted(_CONV_CALLS.findall(text)) == [
+        "conv_silu_bwd", "conv_silu_fwd"]
+    assert re.findall(r"f32\[1,409\d,4352\]", text) == []
+
+
+def test_attention_kernels_compile_for_v5e_at_heads_of_64(v5e_device):
+    """granite-4.0-h-micro's attention layer: 32 query heads over 8 KV heads
+    of 64 at 4,096 positions, both kernels, no [T, T] array."""
+    sharding = jax.sharding.SingleDeviceSharding(v5e_device)
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,  # noqa: E731
+                                            sharding=sharding)
+    text = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v).astype(jnp.float32)), (0, 1, 2))).trace(
+        s(1, 4096, 32, 64), s(1, 4096, 8, 64),
+        s(1, 4096, 8, 64)).lower().compile().as_text()
+    assert sorted(_ATTN_CALLS.findall(text)) == _ONE_OF_EACH
+    assert re.findall(r"\w+\[[\d,]*4096,4096\]", text) == []
+
+
+# Kernel-eligible and small: one period of the published table, 4 SSM heads
+# of 64 over a state of 128, 4 query heads over 1 KV head of 64.
+_MAMBA = mamba.MambaConfig(vocab_size=1024, hidden=256, n_layers=10,
+                           n_heads=4, n_kv_heads=1, ssm_heads=4,
+                           ssm_head_dim=64, ssm_state=128, intermediate=512)
+
+
+def test_mamba_step_takes_every_kernel_on_tpu(v5e_device, lowerings):
+    """The step compiled for a v5e holds the scan's kernels (the forward
+    one twice: a layer's recomputation runs it again), the convolution's
+    (likewise) and the attention kernels at heads of 64, and no array shaped
+    like the scores; each program counted once."""
+    obs.set_enabled(True)
+    ssd_n, conv = obs.counter("ssd_lowerings"), obs.counter("conv_lowerings")
+    before, conv_before = ssd_n.get_value(), conv.get_value()
+    text = _abstract_step(_MAMBA, jax.sharding.SingleDeviceSharding(
+        v5e_device), mamba).lower().compile().as_text()
+    calls = collections.Counter(re.findall(
+        r"%((?:ssd_chunk|conv_silu|attn_flash)_\w+?)(?:\.\d+)? = [^\n]*"
+        r"custom-call\(", text))
+    assert calls == {"ssd_chunk_fwd": 2, "ssd_chunk_bwd": 1,
+                     "conv_silu_fwd": 2, "conv_silu_bwd": 1,
+                     "attn_flash_fwd": 1, "attn_flash_bwd": 1}
+    assert re.findall(rf"\w+\[[\d,]*{_T},{_T}\]", text) == []
+    assert lowerings() == (1, 0)
+    assert ssd_n.get_value() - before == 1
+    assert conv.get_value() - conv_before == 1

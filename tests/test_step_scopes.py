@@ -18,7 +18,7 @@ import optax
 import pytest
 from jax._src import core, source_info_util
 
-from brpc_tpu.models import deepseek, hybrid, llama, looped, windowed
+from brpc_tpu.models import deepseek, hybrid, llama, looped, mamba, windowed
 
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark"))
@@ -67,9 +67,14 @@ MODELS = {
             "windowed.glue", "swa.qkv", "swa.qknorm", "swa.rope",
             "swa.attn/attn.dense", "swa.out", "full.qkv", "full.qknorm",
             "full.rope", "full.attn/attn.dense", "full.out"}),
+    # nine Mamba-2 layers to one attention layer, a dense MLP after each
+    "mamba": (mamba, mamba.MambaConfig.tiny(), _SHARED | _CHUNKED | {
+        "ssm.glue", "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.norm",
+        "ssm.out", "gqa.qkv", "gqa.out", "attn.dense", "mlp"}),
 }
 # scopes a model's step must NOT write: what its configuration has not
 ABSENT = {"windowed_mellum": {"moe.shared", "dense.mlp"},
+          "mamba": _MOE,
           "windowed": {"swa.qknorm", "full.qknorm"}}
 
 
