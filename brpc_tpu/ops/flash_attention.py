@@ -61,12 +61,16 @@ attention) is the same two kernels under names of their own,
 first key tile the band's lower edge reaches (a key tile's loop stops at the
 last query tile it reaches), so the tiles outside the band are never
 visited, and only the tiles that one of the band's two edges cuts pay for a
-mask. A tile pair costs the same whatever part of it
-is visible, and a query tile of B rows visits B + W - 1 keys' worth of tiles
-for its B x W visible pairs (``choose_block`` has the measurements).
+mask. A query tile of B rows loads B + W - 1 keys' worth of tiles for its
+B x W visible pairs. Where W is a whole number of equal tiles (``_merges``),
+the lower edge's tile and the diagonal's are complementary triangles, and
+the kernels take them in one step: both score tiles on the MXU, one select
+into one tile, one softmax over it with no rescale between the two, and
+each tile's products from its own half; any other window pays a softmax
+and a mask a cut tile (``choose_block`` has the measurements).
 ``band_tiles`` counts, with the kernels' own arithmetic, the pairs of
-positions visited against the pairs visible. ``window=None`` is the causal
-path as it was.
+positions loaded against the pairs visible, and ``band_softmax_pairs`` the
+pairs the softmax runs over. ``window=None`` is the causal path as it was.
 
 ``flash_attention(..., interpret=True)`` runs the same kernels through the
 Pallas interpreter (CPU tests); on TPU leave it False.
@@ -114,9 +118,11 @@ def choose_block(t: int, backward: bool = False,
     the same at both (4.59 / 4.60 ms), at 8,192 tokens of 128 / 128 9.8 /
     9.1. The log-sum-exp's rows are the same bytes whatever the tile, so the
     two passes need not share one. A sequence none of them divides is one
-    tile. With a window the backward kernel stays at 512 too: every tile
-    pair the band touches costs the same whatever part of it is visible, and
-    a tile of B rows touches B + W - 1 keys' worth. At T = 8,192, W = 512, 64
+    tile. With a window the backward kernel stays at 512 too: a tile of B
+    rows touches B + W - 1 keys' worth, and where W is a whole number of
+    tiles the lower edge's and the diagonal's share one softmax
+    (``_merges``; its times in PERF.md section 7 row 6). Before that merge,
+    at T = 8,192, W = 512, 64
     heads over 8 of 128 (PERF.md section 6, PR 39; by query tile, key tile,
     two calls of one timing): forward 6.3 / 6.5 ms and backward 10.3 / 10.3
     at (512, 512) (2.0 times the visible pairs visited) against 7.2 / 7.2
@@ -256,6 +262,56 @@ def _band_query_tiles(kj, block_q: int, block_k: int, t: int, window: int):
     return (lo, c0, True), (c0, c1, False), (c1, hi, True)
 
 
+def _merges(window: int, block_q: int, block_k: int) -> bool:
+    """Whether the band kernels take the two tiles the band's edges cut as
+    one score tile: where the window is a whole number of equal tiles, a
+    query tile's lower edge's tile (``window // block_k`` tiles back) and
+    its diagonal's tile are complementary triangles in local coordinates,
+    key b visible to query a iff b > a in the first and b <= a in the
+    second."""
+    return window % block_k == 0 and block_q == block_k
+
+
+def _diagonal_half(shape, q_axis: int):
+    """Within a tile pair on the diagonal, in local coordinates: whether
+    the key is at or before the query (the pairs the diagonal's tile holds;
+    the lower edge's tile holds the others)."""
+    return (lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+            <= lax.broadcasted_iota(jnp.int32, shape, q_axis))
+
+
+def _halves(diag, x):
+    """A merged score tile's ``x`` split back into the diagonal's tile's
+    half and the lower edge's, each zero where the other tile sees."""
+    return jnp.where(diag, x, 0), jnp.where(diag, 0, x)
+
+
+def _band_key_loops(qi, block_q: int, block_k: int, window: int):
+    """The forward kernel's loops for query tile ``qi`` under a window: the
+    merged loop (first, one past the last lower edge's tile, each taken
+    with the diagonal's tile ``window // block_k`` on), or None where the
+    tiles do not merge, and the other loops as ``_band_key_tiles`` gives
+    them. The lower edge cuts at most one tile where they merge: a query
+    tile that has one runs no diagonal tile alone."""
+    edge, clear, diag = _band_key_tiles(qi, block_q, block_k, window)
+    if not _merges(window, block_q, block_k):
+        return None, (edge, clear, diag)
+    return edge[:2], (clear, (diag[0] + edge[1] - edge[0], diag[1], True))
+
+
+def _band_query_loops(kj, block_q: int, block_k: int, t: int, window: int):
+    """The backward kernel's loops for key tile ``kj`` under a window: the
+    merged loop (first, one past the last diagonal's query tile, each taken
+    with the lower edge's tile ``window // block_q`` on), or None where the
+    tiles do not merge, and the other loops. Where they merge and the edge's
+    tile lies past T, the diagonal's tile runs alone."""
+    diag, clear, edge = _band_query_tiles(kj, block_q, block_k, t, window)
+    if not _merges(window, block_q, block_k):
+        return None, (diag, clear, edge)
+    split = diag[0] + edge[1] - edge[0]
+    return (diag[0], split), ((split, diag[1], True), clear)
+
+
 def band_tiles(t: int, window: int, blocks: tuple) -> dict:
     """What the band kernels visit at a sequence of ``t``, by their own
     arithmetic: pairs of positions in the tile pairs the forward and the
@@ -269,6 +325,24 @@ def band_tiles(t: int, window: int, blocks: tuple) -> dict:
     w = min(window, t)
     return {"fwd_pairs": fwd * fq * fk, "bwd_pairs": bwd * bq * bk,
             "visible_pairs": w * (w + 1) // 2 + (t - w) * w}
+
+
+def band_softmax_pairs(t: int, window: int, blocks: tuple) -> dict:
+    """The pairs of positions the band kernels' softmax runs over at a
+    sequence of ``t``, a head, by their own arithmetic: a merged step's two
+    tiles are one tile of softmax. ``blocks``: as ``_attend`` takes them."""
+    def tiles(loops):
+        merged, ranges = loops
+        return (sum(int(stop - start) for start, stop, _ in ranges)
+                + (int(merged[1] - merged[0]) if merged else 0))
+
+    (fq, fk), (bq, bk) = blocks
+    fwd = sum(tiles(_band_key_loops(qi, fq, fk, window))
+              for qi in range(t // fq))
+    bwd = sum(tiles(_band_query_loops(kj, bq, bk, t, window))
+              for kj in range(t // bk))
+    return {"fwd_softmax_pairs": fwd * fq * fk,
+            "bwd_softmax_pairs": bwd * bq * bk}
 
 
 def _equations(jaxpr):
@@ -315,30 +389,55 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, block_k: int, causal: bool,
     qi = pl.program_id(2)
     q = q_ref[0, 0]                                          # [BQ, Dqk]
 
-    def step(kj, carry, masked):
-        m, l, acc = carry
+    def tiles(kj):
         rows = pl.ds(pl.multiple_of(kj * block_k, block_k), block_k)
-        k = k_ref[0, 0, rows, :]
-        v = v_ref[0, 0, rows, :]
-        s = _scores(q, k, scale, masked, qi * block_q, kj * block_k, 0,
-                    window)
+        return k_ref[0, 0, rows, :], v_ref[0, 0, rows, :]
+
+    def online(carry, s, products):
+        """The online softmax's step over the score tile ``s``;
+        ``products(p)``: the probabilities' [BQ, Dv] product with V."""
+        m, l, acc = carry
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
         l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + _nn(p.astype(v.dtype), v)
-        return m_new, l, acc
+        return m_new, l, acc * alpha + products(p)
 
+    def step(kj, carry, masked):
+        k, v = tiles(kj)
+        s = _scores(q, k, scale, masked, qi * block_q, kj * block_k, 0,
+                    window)
+        return online(carry, s, lambda p: _nn(p.astype(v.dtype), v))
+
+    def merged_step(kj, carry):
+        """The lower edge's tile ``kj`` and the diagonal's as one score
+        tile, each pair taken from the tile that sees it: one softmax, and
+        each tile's probabilities against its own V."""
+        (k_edge, v_edge), (k_diag, v_diag) = tiles(kj), tiles(kj + shift)
+        diag = _diagonal_half((block_q, block_k), 0)
+        s = jnp.where(diag, _nt(q, k_diag), _nt(q, k_edge)) * scale
+
+        def products(p):
+            p_diag, p_edge = _halves(diag, p)
+            return (_nn(p_diag.astype(v_diag.dtype), v_diag)
+                    + _nn(p_edge.astype(v_edge.dtype), v_edge))
+
+        return online(carry, s, products)
+
+    merged = None
     if window is None:
         n_clear, n_k = _key_tiles(qi, block_q, block_k, t, causal)
         ranges = ((0, n_clear, False), (n_clear, n_k, True))
     else:
         # the lower edge's tiles come first: a row all masked in them has
         # its sums wiped (alpha = 0) by its first visible key
-        ranges = _band_key_tiles(qi, block_q, block_k, window)
+        merged, ranges = _band_key_loops(qi, block_q, block_k, window)
+        shift = window // block_k
     carry = (jnp.full((block_q, 1), -jnp.inf, jnp.float32),
              jnp.zeros((block_q, 1), jnp.float32),
              jnp.zeros((block_q, d_v), jnp.float32))
+    if merged:
+        carry = lax.fori_loop(*merged, merged_step, carry)
     for start, stop, masked in ranges:
         carry = lax.fori_loop(start, stop,
                               functools.partial(step, masked=masked), carry)
@@ -369,11 +468,13 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     kj = pl.program_id(2)
     k = k_ref[0, 0]
     v = v_ref[0, 0]
+    merged = None
     if window is None:
         first, clear = _query_tiles(kj, block_q, block_k, t, causal)
         ranges = ((first, clear, True), (clear, t // block_q, False))
     else:
-        ranges = _band_query_tiles(kj, block_q, block_k, t, window)
+        merged, ranges = _band_query_loops(kj, block_q, block_k, t, window)
+        shift = window // block_q
 
     def tile(qj):
         return pl.ds(pl.multiple_of(qj * block_q, block_q), block_q)
@@ -394,9 +495,37 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dqt_acc[g, :, tile(qj)] += _tn(k, ds)
         return dk + _nn(ds, q), dv
 
+    def merged_step(qj, carry, g):
+        """The diagonal's query tile ``qj`` and the lower edge's as one
+        score tile, each pair taken from the tile that sees it, with that
+        tile's log-sum-exp and ``delta``: the probabilities and dS made
+        once, each tile's gradients from its own half."""
+        dk, dv = carry
+        edge = qj + shift
+        q_diag, q_edge = q_ref[0, g, tile(qj), :], q_ref[0, g, tile(edge), :]
+        do_diag = do_ref[0, g, tile(qj), :]
+        do_edge = do_ref[0, g, tile(edge), :]
+        diag = _diagonal_half((block_k, block_q), 1)
+        s = jnp.where(diag, _nt(k, q_diag), _nt(k, q_edge)) * scale
+        p = jnp.exp(s - jnp.where(diag, lse_ref[0, g, qj],
+                                  lse_ref[0, g, edge]))
+        p_diag, p_edge = _halves(diag, p)
+        dv = (dv + _nn(p_diag.astype(do_diag.dtype), do_diag)
+              + _nn(p_edge.astype(do_edge.dtype), do_edge))
+        dp = jnp.where(diag, _nt(v, do_diag), _nt(v, do_edge))
+        ds = p * (dp - jnp.where(diag, delta_ref[0, g, qj],
+                                 delta_ref[0, g, edge]))
+        ds_diag, ds_edge = (x.astype(q_diag.dtype) for x in _halves(diag, ds))
+        dqt_acc[g, :, tile(qj)] += _tn(k, ds_diag)
+        dqt_acc[g, :, tile(edge)] += _tn(k, ds_edge)
+        return dk + _nn(ds_diag, q_diag) + _nn(ds_edge, q_edge), dv
+
     dk = jnp.zeros((block_k, d), jnp.float32)
     carry = (dk, dk if d_v == d else jnp.zeros((block_k, d_v), jnp.float32))
     for g in range(group):
+        if merged:
+            carry = lax.fori_loop(
+                *merged, functools.partial(merged_step, g=g), carry)
         for start, stop, masked in ranges:
             carry = lax.fori_loop(
                 start, stop, functools.partial(step, g=g, masked=masked),

@@ -1,8 +1,10 @@
 """The band (sliding-window) attention kernels: numerics in interpret mode on
-the CPU against the dense masked form, forward and gradients; the tiles the
-kernels visit, counted by their own arithmetic against the tiles that
-intersect the band; that ``window=None`` is the causal path it was; and that
-the kernels compile for a v5e at the cell's geometry (no chip needed)."""
+the CPU against the dense masked form, forward and gradients, with the band's
+two cut tiles merged into one score tile and without; the tiles the kernels
+visit and the pairs their softmax runs over, counted by their own arithmetic
+against the tiles that intersect the band; that ``window=None`` is the causal
+path it was; and that the kernels compile for a v5e at the cells' geometry
+(no chip needed)."""
 
 import functools
 import importlib
@@ -13,9 +15,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from brpc_tpu.ops.flash_attention import (band_tiles, choose_block,
-                                          default_blocks, dense_attention,
-                                          flash_attention, supported)
+from brpc_tpu.ops.flash_attention import (band_softmax_pairs, band_tiles,
+                                          choose_block, default_blocks,
+                                          dense_attention, flash_attention,
+                                          supported)
 
 fa = importlib.import_module("brpc_tpu.ops.flash_attention")
 
@@ -46,7 +49,11 @@ def _agree(got, want, tol):
 # window of 1 (every query sees itself alone), a window of T and of more (the
 # causal mask), a window narrower than a tile (both edges cut one tile),
 # query groups of 3 and of 4, two sequences a batch, uneven tiles, and tiles
-# aligned to the window.
+# aligned to the window. Equal tiles that divide the window merge the lower
+# edge's tile and the diagonal's into one step: windows of one, two and three
+# tiles, a group of 3, two sequences, and a window of three tiles in four,
+# where one query tile has a lower edge and three key tiles' edges lie past
+# T (every merged case starts with W / B query tiles that have none).
 _CASES = {
     "no_multiple_of_a_block": (40, dict(), (32, 32)),
     "window_of_one": (1, dict(), (32, 32)),
@@ -61,11 +68,17 @@ _CASES = {
     "aligned": (64, dict(t=256), (32, 32)),
     "aligned_to_a_tile": (32, dict(t=128), (32, 32)),
     "aligned_wide_query_tile": (64, dict(t=256), (64, 32)),
+    # merged: "aligned_to_a_tile" (W = B) and "aligned" (W = 2B) above too
+    "merged_three_tiles": (96, dict(t=256), (32, 32)),
+    "merged_group_of_3": (32, dict(hq=6, hkv=2), (32, 32)),
+    "merged_two_sequences": (64, dict(b=2, hq=8, hkv=2), (32, 32)),
+    "merged_edges_past_t": (96, dict(t=128), (32, 32)),
 }
 
 
-# every case in float32; in bf16, the cell's dtype, the casts of three
-_BF16 = ("no_multiple_of_a_block", "group_of_3", "aligned")
+# every case in float32; in bf16, the cell's dtype, the casts of five
+_BF16 = ("no_multiple_of_a_block", "group_of_3", "aligned",
+         "merged_edges_past_t", "merged_group_of_3")
 
 
 @pytest.mark.parametrize("case,dtype,tol", [
@@ -86,20 +99,51 @@ def test_band_kernels_match_the_dense_masked_form(case, dtype, tol):
                1e-6)
 
 
+@pytest.mark.parametrize("window", [40, 64], ids=["cut_tiles", "merged"])
 @pytest.mark.parametrize("group", [3, 4])
-def test_band_backward_a_query_head_at_a_time(monkeypatch, group):
+def test_band_backward_a_query_head_at_a_time(monkeypatch, group, window):
     """The room made so small that the group does not stay whole in VMEM:
-    every query head its own program row, dK and dV summed afterwards."""
+    every query head its own program row, dK and dV summed afterwards; a
+    window of no whole number of tiles and one of two."""
     q, k, v, w = _inputs(5, b=2, hq=2 * group, hkv=2)
     want = _value_and_grads(functools.partial(
-        dense_attention, window=40), q, k, v, w)
+        dense_attention, window=window), q, k, v, w)
     monkeypatch.setattr(fa, "VMEM_LIMIT",
                         2 * fa._resident(1, 128, 32, 32, jnp.float32))
     assert not fa.heads_together(group, 128, 32, 32, jnp.float32)
     got = _value_and_grads(functools.partial(
-        flash_attention, window=40, block_q=32, block_k=32, interpret=True),
-        q, k, v, w)
+        flash_attention, window=window, block_q=32, block_k=32,
+        interpret=True), q, k, v, w)
     _agree(got, want, 3e-5)
+
+
+@pytest.mark.parametrize("window,t", [(128, 384), (256, 384), (40, 384)],
+                         ids=["merged", "merged_two_tiles", "cut_tiles"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 3e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["float32", "bfloat16"])
+def test_gated_head_major_band_matches_the_plain_form(window, t, dtype, tol):
+    """The entry Laguna's window layers call: q and k head-major, the gate's
+    logits into the forward kernel's epilogue, the tiles its own (128 at
+    384 positions, so a window of 128 or 256 merges the band's two cut
+    tiles and one of 40 does not), against the dense masked form gated
+    after it; the output and the cotangents of q, k, v and the logits."""
+    q, k, v, w = _inputs(7, t=t, dtype=dtype)
+    g = 3 * jax.random.normal(jax.random.PRNGKey(8), (1, t, 4))
+    assert default_blocks(t, window)[0] == (128, 128)
+
+    def kernel(q, k, v, g):
+        return fa.flash_attention_head_major(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v, g,
+            window=window, interpret=True)
+
+    def plain(q, k, v, g):
+        return fa.gated(dense_attention(q, k, v, window=window), g)
+
+    got, want = (jax.value_and_grad(
+        lambda *a: jnp.sum(f(*a).astype(jnp.float32) * w), (0, 1, 2, 3))(
+            q, k, v, g) for f in (kernel, plain))
+    _agree(got, want, tol)
 
 
 def test_the_dense_mask_is_the_band():
@@ -155,6 +199,13 @@ def test_the_kernels_visit_the_tiles_that_intersect_the_band(t, window, bq,
     assert counted == {
         "fwd_pairs": tiles * bq * bk, "bwd_pairs": tiles * bq * bk,
         "visible_pairs": int(((i - j >= 0) & (i - j < window)).sum())}
+    # a merged step is one tile of softmax for two loaded: one for each
+    # query tile W / B or more tiles in, where the tiles merge
+    merged = max(t // bq - window // bq, 0) if (
+        window % bk == 0 and bq == bk) else 0
+    assert band_softmax_pairs(t, window, ((bq, bk), (bq, bk))) == {
+        "fwd_softmax_pairs": (tiles - merged) * bq * bk,
+        "bwd_softmax_pairs": (tiles - merged) * bq * bk}
 
 
 def test_the_cells_band_by_hand():
@@ -173,6 +224,73 @@ def test_the_cells_band_by_hand():
         (16 * 2 - 1) * 512 * 512
     assert band_tiles(8192, 512, ((256, 256),) * 2)["fwd_pairs"] == \
         (32 * 3 - 3) * 256 * 256
+
+
+# The two cells' band at 8,192 tokens, tiles of 512 (Laguna's window of 512
+# and Mellum2's of 1,024), each as (window, the tile pairs the kernels load a
+# head, the pairs the band holds): the three keys of ``band_tiles`` as they
+# have read since the band kernels came, and the tiles of softmax where the
+# two cut tiles merge.
+_CELLS = {"laguna": (512, 16 * 2 - 1, 512 * 513 // 2 + (8192 - 512) * 512),
+          "mellum2": (1024, 16 * 3 - 3,
+                      1024 * 1025 // 2 + (8192 - 1024) * 1024)}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELLS))
+def test_the_cells_softmax_pairs_by_hand(cell):
+    """Laguna: the first query tile runs its diagonal alone, the other 15
+    their lower edge's tile and diagonal's as one: 16 tiles of softmax for
+    31 loaded, forward and backward (the last key tile's edge lies past T).
+    Mellum2: the first two query tiles have no lower edge (1 + 2 tiles),
+    the other 14 a merged step and one clear tile: 31 for 45 loaded."""
+    window, loaded, visible = _CELLS[cell]
+    blocks = default_blocks(8192, window)
+    assert blocks == ((512, 512), (512, 512))
+    tile = 512 * 512
+    assert band_tiles(8192, window, blocks) == {
+        "fwd_pairs": loaded * tile, "bwd_pairs": loaded * tile,
+        "visible_pairs": visible}
+    softmax = {"laguna": 1 + 15, "mellum2": 1 + 2 + 14 * 2}[cell]
+    assert band_softmax_pairs(8192, window, blocks) == {
+        "fwd_softmax_pairs": softmax * tile,
+        "bwd_softmax_pairs": softmax * tile}
+    # a window of no whole number of tiles merges nothing
+    unmerged = band_tiles(8192, window - 12, blocks)
+    assert band_softmax_pairs(8192, window - 12, blocks) == {
+        "fwd_softmax_pairs": unmerged["fwd_pairs"],
+        "bwd_softmax_pairs": unmerged["bwd_pairs"]}
+
+
+def _most_products_a_loop(call):
+    """The most matrix products in one loop body of a Pallas call: a step's
+    own (forward 2, backward 5), or a merged step's two tiles' (4, 10)."""
+    return max(sum(e.primitive.name == "dot_general"
+                   for e in fa._equations(sub))
+               for e in fa._equations(call.params["jaxpr"])
+               if e.primitive.name == "while"
+               for sub in jax.core.jaxprs_in_params(e.params))
+
+
+@pytest.mark.parametrize("window,bq,bk,merged", [
+    (512, 512, 512, True), (1024, 512, 512, True), (256, 256, 256, True),
+    (500, 512, 512, False), (512, 256, 512, False), (512, 512, 256, False),
+    (256, 512, 512, False)])
+def test_the_merged_step_is_traced_where_the_tiles_divide_the_window(
+        window, bq, bk, merged):
+    """The band's two cut tiles merge exactly where the window is a whole
+    number of tiles and the tiles are equal, in both kernels; any other
+    window or tiles keep a loop a kind of tile."""
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa: E731
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, window=window, block_q=bq, block_k=bk).astype(
+                jnp.float32)), argnums=(0, 1, 2)))(
+        s(1, 2048, 8, 128), s(1, 2048, 2, 128), s(1, 2048, 2, 128))
+    fwd, bwd = _calls(jaxpr.jaxpr)
+    assert (fwd.params["name"], bwd.params["name"]) == (
+        "attn_band_fwd", "attn_band_bwd")
+    assert (_most_products_a_loop(fwd), _most_products_a_loop(bwd)) == (
+        (4, 10) if merged else (2, 5))
 
 
 @pytest.mark.parametrize("t, hq, hkv, bq, bk", [
@@ -278,21 +396,24 @@ def v5e_device():
     return topo.devices[0]
 
 
-@pytest.mark.parametrize("window", [512, 500])
+@pytest.mark.parametrize("window,hq,hkv", [
+    (512, 64, 8), (500, 64, 8), (1024, 32, 4)])
 def test_band_kernels_compile_for_v5e_at_the_cells_geometry(v5e_device,
-                                                            window):
-    """1 x 8,192 tokens, 64 query heads over 8 KV heads of 128, bf16: one
-    Mosaic call of each band kernel, the query group a head at a time, no
-    array shaped like the scores."""
+                                                            window, hq, hkv):
+    """1 x 8,192 tokens, bf16, KV heads of 128: Laguna's 64 query heads over
+    8 under its window of 512 (the band's two cut tiles merged) and under
+    one of 500 (not merged), Mellum2's 32 over 4 under its 1,024 (merged,
+    with a clear tile between): one Mosaic call of each band kernel, the
+    query group a head at a time, no array shaped like the scores."""
     sharding = jax.sharding.SingleDeviceSharding(v5e_device)
     s = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
         shape, jnp.bfloat16, sharding=sharding)
-    assert not fa.heads_together(8, 8192, 128, 128, jnp.bfloat16)
+    assert not fa.heads_together(hq // hkv, 8192, 128, 128, jnp.bfloat16)
     text = jax.jit(jax.grad(
         lambda q, k, v: jnp.sum(flash_attention(q, k, v, window=window)
                                 .astype(jnp.float32)), argnums=(0, 1, 2))
-    ).lower(s(1, 8192, 64, 128), s(1, 8192, 8, 128),
-            s(1, 8192, 8, 128)).compile().as_text()
+    ).lower(s(1, 8192, hq, 128), s(1, 8192, hkv, 128),
+            s(1, 8192, hkv, 128)).compile().as_text()
     found = re.findall(r"%(attn_\w+?)(?:\.\d+)? = [^\n]*custom-call\(", text)
     assert sorted(found) == ["attn_band_bwd", "attn_band_fwd"]
     assert re.findall(r"\w+\[[\d,]*8192,8192\]", text) == []
