@@ -35,22 +35,34 @@ product of the pair is one product with the right factor laid out
 block-diagonally, its zeros adding nothing to any sum) and several pairs a
 trip of the loop; T is stored that way, [B, Hv, T/(2C), C, 2C], which is also
 the only form in which XLA keeps it at its size ([C, C] tiles of 64 lanes are
-padded to 128). ``gdn_chunk_fwd`` walks a head's chunks in order (the grid's
-last axis) with the float32 state in VMEM, writes the output and each chunk's
-entry state. ``gdn_chunk_bwd`` walks them in reverse with the state's
-cotangent in VMEM, recomputes every within-chunk quantity but T from q, k, v,
-gamma, beta and the chunk's entry state, and gives dq, dk, dv, dgamma and
-dbeta: no per-token state is ever stored. Both take T as an operand: the
-sequential kernels invert nothing. The MXU gets its operands in the dtype
-they arrive in (bf16 on the training path); the state, its cotangent, the
-decays, A, the inverse (its own products at ``highest``) and every
-accumulator are float32. The per-chunk mathematics is ONE pair of functions,
+padded to 128). Like it, the two sequential kernels run by key head: a
+grid step takes its key head's q and k once and walks the chunks (the grid's
+last axis) of all G = Hv / Hk value heads it serves, each value head with a
+float32 state of its own in VMEM ([G, dk, dv]). A chunk's chain is a few
+dependent products, which alone leave the MXU waiting on their latency; the
+G chains share nothing but q and k, so a chunk's function is mapped over the
+heads (``vmap``): each of its steps for the G heads lies side by side in
+the body and the scheduler interleaves them. Called once a head instead,
+the backward body's heads ran one after the other. G = 1 is one head a
+step. ``gdn_chunk_fwd`` walks the chunks in order, writes the output and
+each chunk's entry state. ``gdn_chunk_bwd`` walks them in reverse with the
+state's cotangent in VMEM, recomputes every within-chunk quantity but T
+from q, k, v, gamma, beta and the chunk's entry state, and gives dv, dgamma
+and dbeta a value head, and dq and dk a key head: the value heads' float32
+terms summed over the group in the kernel and rounded once. No per-token
+state is ever stored. Both take T as an operand: the sequential kernels
+invert nothing. The MXU gets its operands in the dtype they arrive in (bf16
+on the training path); the state, its cotangent, the decays, A, the
+inverse (its own products at ``highest``) and every accumulator are
+float32. The per-chunk mathematics is ONE pair of functions,
 ``_chunk_fwd`` / ``_chunk_bwd``, and one ``_chunk_prep`` before them, which
 the kernels call on their blocks and the plain form calls under ``vmap`` and
 ``lax.scan``: the same chunked algorithm in plain ``jax.numpy`` wherever the
 kernels are not taken (another platform, float32, widths that are no multiple
 of 128), chosen as every kernel of ``ops/`` is (``lowered.choose``).
-``gdn_lowerings`` counts the programs lowered with the kernels.
+``gdn_lowerings`` counts the programs lowered with the kernels, and the
+maxer ``gdn_value_heads_a_step`` reads the value heads a grid step of the
+sequential kernels walks, recorded as they are built.
 
 What the forward pass makes carries names, so that a caller who recomputes
 its layers under ``jax.checkpoint`` can save it: T (``INVERSE_NAME``, 33.5 MB
@@ -60,10 +72,11 @@ often the forward kernel runs, and the forward kernel's two results
 which would save its second run.
 
 Layout: q, k [B, T, Hk, dk], v [B, T, Hv, dv], g, beta [B, T, Hv]; key head
-h // (Hv // Hk) serves value head h. q and k come normalised and scaled as
-the caller wants them. Inside, arrays are head-major, and per-token scalars
-travel as one row a chunk, [B, Hv, T/C, 1, C] (a column is made from a row
-inside the kernel, by the diagonal of its broadcast).
+h // G serves value head h, so key head j's value heads are the block j of
+G heads that a kernel's grid step takes. q and k come normalised and scaled
+as the caller wants them. Inside, arrays are head-major, and per-token
+scalars travel as one row a chunk, [B, Hv, T/C, 1, C] (a column is made from
+a row inside the kernel, by the diagonal of its broadcast).
 """
 
 from __future__ import annotations
@@ -78,6 +91,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from brpc_tpu import obs
 from brpc_tpu.ops.lowered import choose, compiler_params
 
 CHUNK = 64
@@ -90,8 +104,11 @@ RESIDUAL_NAMES = ("gdn_out", "gdn_states")
 # ... and of what ``gdn_chunk_prep`` makes, every chunk's T: a caller that
 # recomputes its layers saves this one (models/hybrid.py).
 INVERSE_NAME = "gdn_inverse"
-# runs of chunks side by side that a trip of each kernel's loop takes: the
-# fastest of 1, 2, 4 and 8 each on a v5e at 8,192 tokens (PERF.md, PR 35)
+# runs of chunks side by side that a trip of each kernel's loop takes (a run
+# of the sequential kernels for all of a key head's value heads): the
+# fastest of 1, 2, 4 and 8 on a v5e at 8,192 tokens, one value head a grid
+# step; at two value heads a key head, the shortest static critical path of
+# 1, 2 and 4 in the v5e compiler's schedule (PERF.md section 6)
 _PREP_TRIP, _FWD_TRIP, _BWD_TRIP = 2, 4, 2
 
 
@@ -337,23 +354,28 @@ def _prep_kernel(k_ref, g_ref, b_ref, t_ref, *, chunk: int):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, t_ref, o_ref, states_ref,
                 s_acc, *, chunk: int):
+    """A key head's block of chunks, each chunk for all G value heads the
+    key head serves at once: ``_chunk_fwd`` mapped over the heads, so that
+    every step of the G chains lies side by side in the body and the
+    scheduler interleaves them. s_acc: the G float32 states."""
     @pl.when(pl.program_id(2) == 0)
     def _():
         s_acc[...] = jnp.zeros_like(s_acc)
 
     w = t_ref.shape[4] // chunk
+    heads = jax.vmap(_chunk_fwd, in_axes=(0, None, None, 0, 0, 0, 0))
 
     def run(ri):
         for j in range(w):
             ci = ri * w + j
             rows = pl.ds(pl.multiple_of(ci * chunk, chunk), chunk)
             s = s_acc[...]
-            states_ref[0, 0, ci] = s.astype(states_ref.dtype)
-            o, s_acc[...] = _chunk_fwd(
+            states_ref[0, :, ci] = s.astype(states_ref.dtype)
+            o, s_acc[...] = heads(
                 s, q_ref[0, 0, rows, :], k_ref[0, 0, rows, :],
-                v_ref[0, 0, rows, :], g_ref[0, 0, ci], b_ref[0, 0, ci],
-                t_ref[0, 0, ri, :, j * chunk:(j + 1) * chunk])
-            o_ref[0, 0, rows, :] = o.astype(o_ref.dtype)
+                v_ref[0, :, rows, :], g_ref[0, :, ci], b_ref[0, :, ci],
+                t_ref[0, :, ri, :, j * chunk:(j + 1) * chunk])
+            o_ref[0, :, rows, :] = o.astype(o_ref.dtype)
 
     _loop(t_ref.shape[2], _FWD_TRIP, run)
 
@@ -361,41 +383,45 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, t_ref, o_ref, states_ref,
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, states_ref, t_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_acc, *,
                 chunk: int):
+    """The same walk in reverse, ``_chunk_bwd`` mapped over the G heads with
+    their float32 cotangent states; a chunk's dq and dk are the key head's:
+    the G heads' float32 terms summed and rounded once."""
     @pl.when(pl.program_id(2) == 0)
     def _():
         ds_acc[...] = jnp.zeros_like(ds_acc)
 
     w = t_ref.shape[4] // chunk
+    heads = jax.vmap(_chunk_bwd, in_axes=(0, 0, None, None, 0, 0, 0, 0, 0))
 
     def run(ri):
         for j in reversed(range(w)):
             ci = ri * w + j
             rows = pl.ds(pl.multiple_of(ci * chunk, chunk), chunk)
-            dq, dk, dv, dg, db, ds_acc[...] = _chunk_bwd(
-                states_ref[0, 0, ci], ds_acc[...], q_ref[0, 0, rows, :],
-                k_ref[0, 0, rows, :], v_ref[0, 0, rows, :], g_ref[0, 0, ci],
-                b_ref[0, 0, ci], do_ref[0, 0, rows, :],
-                t_ref[0, 0, ri, :, j * chunk:(j + 1) * chunk])
-            dq_ref[0, 0, rows, :] = dq.astype(dq_ref.dtype)
-            dk_ref[0, 0, rows, :] = dk.astype(dk_ref.dtype)
-            dv_ref[0, 0, rows, :] = dv.astype(dv_ref.dtype)
-            dg_ref[0, 0, ci] = dg
-            db_ref[0, 0, ci] = db
+            dq, dk, dv, dg, db, ds_acc[...] = heads(
+                states_ref[0, :, ci], ds_acc[...], q_ref[0, 0, rows, :],
+                k_ref[0, 0, rows, :], v_ref[0, :, rows, :], g_ref[0, :, ci],
+                b_ref[0, :, ci], do_ref[0, :, rows, :],
+                t_ref[0, :, ri, :, j * chunk:(j + 1) * chunk])
+            dq_ref[0, 0, rows, :] = jnp.sum(dq, axis=0).astype(dq_ref.dtype)
+            dk_ref[0, 0, rows, :] = jnp.sum(dk, axis=0).astype(dk_ref.dtype)
+            dv_ref[0, :, rows, :] = dv.astype(dv_ref.dtype)
+            dg_ref[0, :, ci] = dg
+            db_ref[0, :, ci] = db
 
     _loop(t_ref.shape[2], _BWD_TRIP, run, reverse=True)
 
 
 def _specs(t: int, chunk: int, reverse: bool = False):
-    """The grid's steps a head and its block specs, by grid (batch, head,
-    block of chunks), the blocks taken last to first where ``reverse``:
-    ``rows(width, g)`` of a [B, H, T, width] operand, head ``h // g``;
+    """The grid's steps a head and its block specs, by grid (batch, key
+    head, block of chunks), the blocks taken last to first where
+    ``reverse``: ``rows(width, heads)`` of a [B, H, T, width] operand,
     ``per(every, a, b, heads)`` of a [B, H, T/every, a, b] one, ``heads``
-    of them a step."""
+    of them a step (a key head's value heads: G of them, or its one)."""
     block = _block(t, chunk)
     n = t // block
     at = (lambda i: n - 1 - i) if reverse else (lambda i: i)
-    rows = lambda width, g=1: pl.BlockSpec(              # noqa: E731
-        (1, 1, block, width), lambda bi, h, i: (bi, h // g, at(i), 0))
+    rows = lambda width, heads=1: pl.BlockSpec(          # noqa: E731
+        (1, heads, block, width), lambda bi, h, i: (bi, h, at(i), 0))
     per = lambda every, a, b, heads=1: pl.BlockSpec(     # noqa: E731
         (1, heads, block // every, a, b),
         lambda bi, h, i: (bi, h, at(i), 0, 0))
@@ -437,24 +463,32 @@ def _prep_kernels(k, g_rows, b_rows, *, chunk: int, interpret: bool):
         )(k, _runs(g_rows, w), _runs(b_rows, w))
 
 
+def _walked(group: int) -> None:
+    """``gdn_value_heads_a_step``: the value heads a grid step of the
+    sequential kernels walks, recorded as they are built."""
+    if obs.enabled():
+        obs.maxer("gdn_value_heads_a_step").update(group)
+
+
 def _fwd_kernels(q, k, v, g_rows, b_rows, t_all, *, chunk: int,
                  interpret: bool):
     b, hv, t, dv = v.shape
-    dk, group = q.shape[3], hv // q.shape[1]
-    run = t_all.shape[4]
+    hk, dk = q.shape[1], q.shape[3]
+    group, run = hv // hk, t_all.shape[4]
     n, rows, per = _specs(t, chunk)
+    _walked(group)
     with jax.named_scope("gdn.chunk_fwd"):
         return tuple(_call(
             functools.partial(_fwd_kernel, chunk=chunk), "gdn_chunk_fwd",
-            interpret, grid=(b, hv, n),
-            in_specs=[rows(dk, group), rows(dk, group), rows(dv),
-                      per(chunk, 1, chunk), per(chunk, 1, chunk),
-                      per(run, chunk, run)],
-            out_specs=[rows(dv), per(chunk, dk, dv)],
+            interpret, grid=(b, hk, n),
+            in_specs=[rows(dk), rows(dk), rows(dv, group),
+                      per(chunk, 1, chunk, group), per(chunk, 1, chunk, group),
+                      per(run, chunk, run, group)],
+            out_specs=[rows(dv, group), per(chunk, dk, dv, group)],
             out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                        jax.ShapeDtypeStruct((b, hv, t // chunk, dk, dv),
                                             v.dtype)],
-            scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((group, dk, dv), jnp.float32)],
         )(q, k, v, g_rows, b_rows, t_all))
 
 
@@ -464,30 +498,24 @@ def _bwd_kernels(q, k, v, g_rows, b_rows, do, states_in, t_all, *,
     hk, dk = q.shape[1], q.shape[3]
     group, run = hv // hk, t_all.shape[4]
     n, rows, per = _specs(t, chunk, reverse=True)
-    scalars = per(chunk, 1, chunk)
-    per_head = jax.ShapeDtypeStruct((b, hv, t, dk), jnp.float32)
+    scalars = per(chunk, 1, chunk, group)
+    _walked(group)
     with jax.named_scope("gdn.chunk_bwd"):
-        dq, dk_, dv_, dg, db = _call(
+        return tuple(_call(
             functools.partial(_bwd_kernel, chunk=chunk), "gdn_chunk_bwd",
-            interpret, grid=(b, hv, n),
-            in_specs=[rows(dk, group), rows(dk, group), rows(dv), scalars,
-                      scalars, rows(dv), per(chunk, dk, dv),
-                      per(run, chunk, run)],
-            out_specs=[rows(dk), rows(dk), rows(dv), scalars, scalars],
-            out_shape=[per_head, per_head,
+            interpret, grid=(b, hk, n),
+            in_specs=[rows(dk), rows(dk), rows(dv, group), scalars, scalars,
+                      rows(dv, group), per(chunk, dk, dv, group),
+                      per(run, chunk, run, group)],
+            out_specs=[rows(dk), rows(dk), rows(dv, group), scalars,
+                       scalars],
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                       jax.ShapeDtypeStruct(k.shape, k.dtype),
                        jax.ShapeDtypeStruct(v.shape, v.dtype),
                        jax.ShapeDtypeStruct(g_rows.shape, jnp.float32),
                        jax.ShapeDtypeStruct(g_rows.shape, jnp.float32)],
-            scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        )(q, k, v, g_rows, b_rows, do, states_in, t_all)
-    return _group_sum(dq, hk, q.dtype), _group_sum(dk_, hk, k.dtype), dv_, \
-        dg, db
-
-
-def _group_sum(per_value_head, hk: int, dtype):
-    """[B, Hv, T, dk] float32 -> the key heads' [B, Hk, T, dk]."""
-    b, hv, t, d = per_value_head.shape
-    return per_value_head.reshape(b, hk, hv // hk, t, d).sum(2).astype(dtype)
+            scratch_shapes=[pltpu.VMEM((group, dk, dv), jnp.float32)],
+        )(q, k, v, g_rows, b_rows, do, states_in, t_all))
 
 
 # -- the plain form -----------------------------------------------------------
@@ -539,6 +567,12 @@ def _fwd_plain(q, k, v, g_rows, b_rows, t_all, *, chunk: int):
         per_head, _per_value_head(q, group, chunk),
         _per_value_head(k, group, chunk), _by_chunk(v, chunk), g_rows, b_rows,
         _each_chunks(t_all, chunk))
+
+
+def _group_sum(per_value_head, hk: int, dtype):
+    """[B, Hv, T, dk] float32 -> the key heads' [B, Hk, T, dk]."""
+    b, hv, t, d = per_value_head.shape
+    return per_value_head.reshape(b, hk, hv // hk, t, d).sum(2).astype(dtype)
 
 
 def _bwd_plain(q, k, v, g_rows, b_rows, do, states, t_all, *, chunk: int):

@@ -96,6 +96,67 @@ def test_chunked_form_matches_the_recurrence_in_float32(decay, interpret):
     assert _worst(got, want) <= _F32_TOL
 
 
+# (Hk, Hv): a grid step of the sequential kernels walks G = Hv / Hk heads
+GROUPS = [pytest.param(2, 2, id="one_a_key_head"),
+          pytest.param(2, 4, id="two_a_key_head"),
+          pytest.param(1, 4, id="four_a_key_head")]
+
+
+@pytest.mark.parametrize("hk,hv", GROUPS)
+def test_interpreted_kernels_walk_a_key_heads_value_heads(hk, hv):
+    """The kernels through the interpreter at groups of 1, 2 and 4 value
+    heads a key head: output and all five gradients against the
+    recurrence at the float32 tolerance, and against the plain form, which
+    does the same float32 arithmetic a value head at a time, tighter."""
+    args, w = _inputs(hk=hk, hv=hv)
+    want = _value_and_grads(recurrence, args, w)
+    plain = _value_and_grads(gated_delta_rule, args, w)
+    got = _value_and_grads(
+        lambda *a: gated_delta_rule(*a, interpret=True), args, w)
+    assert all(bool(jnp.all(jnp.isfinite(x))) for x in got)
+    assert _worst(got, want) <= _F32_TOL
+    assert _worst(got, plain) <= 1e-6
+
+
+def _head_major(dtype, hk, hv):
+    """The backward kernel's operands at dk = dv = 128: head-major q, k, v,
+    gamma and beta as rows, T and the entry states by the plain form, and a
+    cotangent."""
+    (q, k, v, g, beta), _ = _inputs(seed=6, b=1, t=256, hk=hk, hv=hv,
+                                    dk=128, dv=128, dtype=dtype)
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    g, beta = (x.transpose(0, 2, 1) for x in (g, beta))
+    gamma = jnp.cumsum(gated_delta._rows(g, 64), axis=-1)
+    b_rows = gated_delta._rows(beta, 64)
+    t_all = gated_delta._prep_plain(k, gamma, b_rows, chunk=64)
+    _, states = gated_delta._fwd_plain(q, k, v, gamma, b_rows, t_all,
+                                       chunk=64)
+    do = jax.random.normal(jax.random.PRNGKey(9), v.shape, dtype)
+    return q, k, v, gamma, b_rows, do, states, t_all
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-6),
+                                       (jnp.bfloat16, 2.0 ** -8)],
+                         ids=["float32", "bf16"])
+@pytest.mark.parametrize("hk,hv", GROUPS)
+def test_backward_kernel_sums_dq_and_dk_over_the_group(hk, hv, dtype, tol):
+    """dq and dk leave the backward kernel as the key heads' [B, Hk, T,
+    dk] in q's and k's dtype — each value head's float32 terms summed in
+    the kernel and rounded once — and are what the plain form's float32
+    group sum gives; dv stays a value head's."""
+    operands = _head_major(dtype, hk, hv)
+    q, k, v = operands[:3]
+    got = gated_delta._bwd_kernels(*operands, chunk=64, interpret=True)
+    want = gated_delta._bwd_plain(*operands, chunk=64)
+    assert (got[0].shape, got[1].shape, got[2].shape) == (
+        q.shape, k.shape, v.shape)
+    assert got[0].dtype == got[1].dtype == got[2].dtype == dtype
+    for a, b in zip(got[:2], want[:2]):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        assert float(jnp.max(jnp.abs(a - b))) <= tol * float(
+            jnp.max(jnp.abs(b)))
+
+
 @pytest.mark.parametrize("interpret", FORMS)
 def test_bf16_is_loose_and_fails_the_float32_tolerance(interpret):
     """bf16 operands, float32 state and accumulators: inside the loose
@@ -152,9 +213,9 @@ def test_kernels_take_bf16_whole_lanes_and_whole_groups():
     assert not take((1, 8200, 16, 128), (1, 8200, 32, 128), bf16)
 
 
-def _lowered(dtype, platform, dk=128):
+def _lowered(dtype, platform, dk=128, hk=1, hv=2):
     args = tuple(jax.ShapeDtypeStruct(x.shape, x.dtype) for x in _inputs(
-        b=1, t=128, hk=1, hv=2, dk=dk, dv=128, dtype=dtype)[0])
+        b=1, t=128, hk=hk, hv=hv, dk=dk, dv=128, dtype=dtype)[0])
     grad = jax.jit(jax.grad(
         lambda *a: jnp.sum(gated_delta_rule(*a).astype(jnp.float32)),
         argnums=(0, 1, 2, 3, 4)))
@@ -168,9 +229,12 @@ def test_program_holds_the_kernels_only_on_tpu_at_shapes_they_take(
         dtype, platform, dk, kernels):
     """One traced function for every platform; the choice is settled when
     the program is lowered, and ``gdn_lowerings`` counts the programs that
-    kept the kernels (once: the backward pass counts nothing)."""
+    kept the kernels (once: the backward pass counts nothing), and
+    ``gdn_value_heads_a_step`` the value heads a grid step of theirs walks
+    (a key head's two)."""
     obs.set_enabled(True)
     before = obs.counter("gdn_lowerings").get_value()
+    obs.maxer("gdn_value_heads_a_step").reset()
     text = _lowered(dtype, platform, dk)
     assert obs.counter("gdn_lowerings").get_value() - before == int(kernels)
     assert ("tpu_custom_call" in text) is kernels
@@ -178,6 +242,24 @@ def test_program_holds_the_kernels_only_on_tpu_at_shapes_they_take(
         assert text.count("stablehlo.custom_call @tpu_custom_call") == 3
         assert sorted(set(re.findall(r"gdn_chunk_\w+", text))) == [
             "gdn_chunk_bwd", "gdn_chunk_fwd", "gdn_chunk_prep"]
+        assert obs.maxer("gdn_value_heads_a_step").get_value() == 2
+
+
+@pytest.mark.parametrize("hk,hv", GROUPS)
+def test_a_grid_step_walks_the_value_heads_of_one_key_head(hk, hv):
+    """Lowered for TPU: still the three kernels; a grid step of the
+    sequential ones walks G = Hv / Hk value heads (one where Hk = Hv, the
+    grid and body of one head a step), and the backward kernel gives dq and
+    dk as the key heads' [B, Hk, T, dk], dv as the value heads'."""
+    obs.set_enabled(True)
+    obs.maxer("gdn_value_heads_a_step").reset()
+    text = _lowered(jnp.bfloat16, "tpu", hk=hk, hv=hv)
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 3
+    assert obs.maxer("gdn_value_heads_a_step").get_value() == hv // hk
+    (results,) = re.findall(
+        r'kernel_name = "gdn_chunk_bwd".*?-> \(([^)]*)\)', text)
+    keys, values = (f"tensor<1x{h}x128x128xbf16>" for h in (hk, hv))
+    assert results.split(", ")[:3] == [keys, keys, values]
 
 
 @pytest.mark.parametrize("t", [64, 128], ids=["one_chunk", "a_pair"])

@@ -1067,7 +1067,12 @@ def test_gated_delta_kernels_compile_for_v5e_at_the_cells_size(v5e_device):
     XLA:TPU take all three kernels; the backward pass keeps the chunks'
     entry states (128 of them a head), every chunk's T (two chunks side by
     side in the 128 lanes: 33.5 MB, where [64, 64] tiles would be padded to
-    twice that) and no per-token state."""
+    twice that) and no per-token state; a grid step of the sequential
+    kernels walks a key head's two value heads, and the backward kernel
+    sums dq and dk over them itself (no float32 [1, 32, 8,192, 128] a
+    value head is left)."""
+    obs.set_enabled(True)
+    obs.maxer("gdn_value_heads_a_step").reset()
     sharding = jax.sharding.SingleDeviceSharding(v5e_device)
     s = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dtype, sharding=sharding)
@@ -1084,6 +1089,8 @@ def test_gated_delta_kernels_compile_for_v5e_at_the_cells_size(v5e_device):
     assert "bf16[1,32,64,64,128]" in text             # T, a pair of chunks
     assert "bf16[1,32,128,64,64]" not in text
     assert re.findall(r"\w+\[[\d,]*8192,128,128\]", text) == []
+    assert "f32[1,32,8192,128]" not in text
+    assert obs.maxer("gdn_value_heads_a_step").get_value() == 2
 
 
 _CONV_CALLS = re.compile(r"%(conv_silu_\w+?)(?:\.\d+)? = [^\n]*custom-call\(")
@@ -1128,8 +1135,10 @@ def test_hybrid_step_takes_every_kernel_on_tpu(v5e_device, lowerings):
     by name and a recomputation that made it again would count two), the
     convolution's (the forward one twice likewise: y is not saved), the
     attention kernels, the grouped products and the row kernels, and no
-    array shaped like the scores."""
+    array shaped like the scores; a grid step of the rule's sequential
+    kernels walks the key head's two value heads."""
     obs.set_enabled(True)
+    obs.maxer("gdn_value_heads_a_step").reset()
     gdn, conv = obs.counter("gdn_lowerings"), obs.counter("conv_lowerings")
     before, conv_before = gdn.get_value(), conv.get_value()
     text = _abstract_step(_HYBRID, jax.sharding.SingleDeviceSharding(
@@ -1153,6 +1162,7 @@ def test_hybrid_step_takes_every_kernel_on_tpu(v5e_device, lowerings):
     assert lowerings() == (1, 0)
     assert gdn.get_value() - before == 1
     assert conv.get_value() - conv_before == 1
+    assert obs.maxer("gdn_value_heads_a_step").get_value() == 2
 
 
 # -- PR 40: the expert layer reads no scalar through a gather over its bound --
